@@ -10,8 +10,8 @@ measured rather than assumed:
   the fleet.  Every in-flight request on the dead link must be
   re-answered by survivors — **zero failed requests**, every answer
   bit-identical to the sequential single-engine reference — at
-  bounded p99; the host then respawns against its digest-keyed
-  persistent compile cache and rejoins with a **0-new-compiles**
+  bounded p99; the host then respawns against the persistent
+  compile cache and rejoins with a **0-new-compiles**
   re-warm receipt before re-entering rotation (membership epochs
   bumped for the leave AND the rejoin).
 - **hedge_ab**: EVERY host armed with seeded random stalls
@@ -79,13 +79,19 @@ def _mlp_spec(seed):
     return plans, params
 
 
-def _build_engine(seed, cache_root=None):
+def _build_engine(seed):
+    """The soak is a CPU program: a chip belongs to ONE process, and
+    this script runs several serve hosts per machine — so every
+    engine sits on the cpu backend and every host subprocess is
+    started with JAX_PLATFORMS=cpu (``_HostProc``).  All of them share
+    the ONE compile cache (``backends.enable_compile_cache``: the
+    environment's directory, else the checkout's), so a respawned
+    host re-warms with zero new compiles."""
     from veles_tpu.backends import Device
     from veles_tpu.serve import AOTEngine
     plans, params = _mlp_spec(seed)
     engine = AOTEngine(plans, params, SAMPLE_SHAPE, ladder=LADDER,
-                       device=Device(backend="cpu"),
-                       cache_root=cache_root)
+                       device=Device(backend="cpu"))
     return engine, engine.compile()
 
 
@@ -96,8 +102,7 @@ def host_main(args):
     straggler's ``serve.host.stall``); the driver's SIGKILL is the
     preemption."""
     from veles_tpu.serve import BinaryTransportServer, ContinuousBatcher
-    engine, receipt = _build_engine(args.seed,
-                                    cache_root=args.cache_root or None)
+    engine, receipt = _build_engine(args.seed)
     batcher = ContinuousBatcher(engine, max_delay_s=0.001,
                                 max_queue=4096).start()
     server = BinaryTransportServer(
@@ -120,7 +125,9 @@ def host_main(args):
 class _HostProc(object):
     """Driver-side handle on one serve-host subprocess."""
 
-    def __init__(self, host_id, seed, cache_root, chaos_spec=None):
+    def __init__(self, host_id, seed, chaos_spec=None):
+        # pinned to the CPU: several hosts share this machine, and a
+        # chip can only ever belong to one process
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         env.pop("VELES_CHAOS", None)
         if chaos_spec:
@@ -128,8 +135,7 @@ class _HostProc(object):
         self.host_id = host_id
         self.proc = subprocess.Popen(
             [sys.executable, os.path.abspath(__file__), "--host",
-             "--host-id", host_id, "--seed", str(seed),
-             "--cache-root", cache_root],
+             "--host-id", host_id, "--seed", str(seed)],
             env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
             text=True)
         deadline = time.monotonic() + 120.0
@@ -226,7 +232,6 @@ def run_soak(seed=11, fast=False, out=None, p99_bound_s=2.0):
     from veles_tpu import chaos
     from veles_tpu.serve import FleetRouter
 
-    workdir = tempfile.mkdtemp(prefix="fleet_soak_")
     engine, _ = _build_engine(seed)
     rng = numpy.random.RandomState(seed + 1)
     samples = rng.rand(64, *SAMPLE_SHAPE).astype(numpy.float32)
@@ -236,9 +241,7 @@ def run_soak(seed=11, fast=False, out=None, p99_bound_s=2.0):
     # ---- phase A: SIGKILL a host mid-stream -----------------------------
     duration = 6.0 if fast else 20.0
     clients = 4 if fast else 6
-    hosts = [_HostProc("h%d" % i, seed,
-                       os.path.join(workdir, "cache_h%d" % i))
-             for i in range(3)]
+    hosts = [_HostProc("h%d" % i, seed) for i in range(3)]
     router = FleetRouter(hedge_factor=2.0, hedge_floor_s=0.05,
                          hedge_tick_s=0.01).start()
     for h in hosts:
@@ -277,8 +280,7 @@ def run_soak(seed=11, fast=False, out=None, p99_bound_s=2.0):
             def rejoin():
                 delay = plan.fire("slave.rejoin_after")
                 time.sleep(delay.param if delay is not None else 1.0)
-                hosts[0] = respawned = _HostProc(
-                    "h0", seed, os.path.join(workdir, "cache_h0"))
+                hosts[0] = respawned = _HostProc("h0", seed)
                 router.add_host(address=("127.0.0.1", respawned.port),
                                 host_id="h0-rejoin")
                 kill_state["rejoined"] = time.perf_counter()
@@ -341,7 +343,6 @@ def run_soak(seed=11, fast=False, out=None, p99_bound_s=2.0):
         # so both legs face the same per-host stall patterns
         stallers = [
             _HostProc("s%d" % i, seed,
-                      os.path.join(workdir, "cache_s%d" % i),
                       chaos_spec=stall % (seed + 100 * (i + 1)))
             for i in range(2)]
         router = FleetRouter(hedge=hedge_on, hedge_factor=2.0,
@@ -453,7 +454,6 @@ def run_tenant_soak(seed=11, fast=False, out=None, slo_p99_s=2.0):
     from veles_tpu import chaos  # noqa: F401  (parity with run_soak)
     from veles_tpu.serve import FleetRouter, HedgeBudget, ServeOverload
 
-    workdir = tempfile.mkdtemp(prefix="qos_soak_")
     engine, _ = _build_engine(seed)
     rng = numpy.random.RandomState(seed + 1)
     samples = rng.rand(64, *SAMPLE_SHAPE).astype(numpy.float32)
@@ -465,7 +465,6 @@ def run_tenant_soak(seed=11, fast=False, out=None, slo_p99_s=2.0):
     flooders = 3  # the "3x" flood: 3 flooder threads per client pool
     stall = "seed=%d;serve.host.stall=stall:p0.05:0.15"
     hosts = [_HostProc("q%d" % i, seed,
-                       os.path.join(workdir, "cache_q%d" % i),
                        chaos_spec=stall % (seed + 100 * (i + 1)))
              for i in range(2)]
     # the front bound is what the flood saturates: small enough that
@@ -760,8 +759,6 @@ def run_alert_soak(seed=11, fast=False, out=None):
     for leg_name, chaos_on in (("steady", False), ("stall", True)):
         hosts = [
             _HostProc("%s%d" % (leg_name, i), seed,
-                      os.path.join(workdir,
-                                   "cache_%s_%d" % (leg_name, i)),
                       chaos_spec=(stall % (seed + 100 * (i + 1))
                                   if chaos_on else None))
             for i in range(2)]
@@ -1010,7 +1007,6 @@ def main(argv=None):
     parser.add_argument("--host", action="store_true",
                         help="internal: run as a serve-host subprocess")
     parser.add_argument("--host-id", default="host")
-    parser.add_argument("--cache-root", default="")
     parser.add_argument("--seed", type=int, default=11)
     parser.add_argument("--fast", action="store_true",
                         help="smoke profile (the slow-marked test)")

@@ -149,8 +149,7 @@ def run_soak(good_cycles=6, replicas=3, clients=4, fast=False,
     plans, params = _mlp_spec(seed=seed)
     pool = ReplicaPool(plans, params, (16,), replicas=replicas,
                        ladder=ladder, max_delay_s=0.001,
-                       max_queue=4096,
-                       cache_root=os.path.join(workdir, "cache"))
+                       max_queue=4096)
     pool.compile()
     pool.start()
     controller = FreshnessController(

@@ -31,7 +31,8 @@ must describe the SAME network):
    Model constants (documented, overridable by flags): v5e ICI
    2D torus, 1600 Gbit/s aggregate per chip -> ~100 GB/s usable per
    all-reduce direction; 1 us per hop launch latency; backward
-   fraction 0.6 of the step (MFU.json round-5 attribution).
+   fraction 0.6 of the step (an assumption: the split is not measured
+   on today's code).
 
     python scripts/scaling.py [--out SCALING.json]
                               [--multichip-out MULTICHIP_rNN.json]
@@ -254,28 +255,6 @@ def project_overlap(step_seconds_1chip, grad_bytes, n_buckets,
     return out
 
 
-def _bench_step_seconds():
-    """Single-chip AlexNet f32 step time from the newest plausible
-    bench record (skips records with clamped/failed measurements)."""
-    for bench_file in ("BENCH_r03.json", "BENCH_local.json",
-                       "BENCH_r02.json"):
-        path = os.path.join(REPO, bench_file)
-        if not os.path.exists(path):
-            continue
-        try:
-            parsed = json.load(open(path))
-            parsed = parsed.get("parsed", parsed)
-            step = parsed["extras"]["alexnet"]["float32"]["step_seconds"]
-        except (KeyError, ValueError, TypeError):
-            continue
-        # a real 227px AlexNet step cannot run in under 100 us or over
-        # 10 s on any current chip — reject corrupt records (round-2
-        # lesson: BENCH_r02 carried a floor-clamped 1e-9)
-        if 1e-4 < step < 10.0:
-            return step, bench_file
-    return None, None
-
-
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--out", default=os.path.join(REPO,
@@ -290,8 +269,10 @@ def main():
                         help="usable all-reduce bandwidth GB/s per chip "
                              "(v5e 2D-torus derated)")
     parser.add_argument("--step-seconds", type=float, default=None,
-                        help="single-chip step time from bench.py "
-                             "(defaults to BENCH extras if present)")
+                        help="single-chip step time from a chip run "
+                             "(PERF_LEDGER.jsonl); without it only a "
+                             "real pod's own n=1 row seeds the "
+                             "projection")
     parser.add_argument("--grad-bucket-mb", type=float, default=25.0,
                         help="bucket size target for the SPMD plane's "
                              "per-op collective audit + overlap model")
@@ -301,8 +282,8 @@ def main():
                              "per-op (each costs a full-model compile)")
     parser.add_argument("--bwd-fraction", type=float, default=0.6,
                         help="fraction of the step the backward+update "
-                             "occupies (MFU.json round-5 attribution); "
-                             "sizes the overlap window")
+                             "occupies (assumed, not measured); sizes "
+                             "the overlap window")
     parser.add_argument("--multichip-out", default=None, metavar="PATH",
                         help="also write a MULTICHIP-style weak-scaling "
                              "receipt (rows past n=8) to PATH")
@@ -332,8 +313,6 @@ def main():
     step_1 = args.step_seconds
     source = "flag"
     if step_1 is None:
-        step_1, source = _bench_step_seconds()
-    if step_1 is None:
         # only a TRUE single-chip row can seed the projection — an
         # n>=2 step time already contains all-reduce comm and would
         # double-count t_comm
@@ -344,11 +323,11 @@ def main():
             source = "measured on this pod (n=1)"
         else:
             sys.stderr.write(
-                "ERROR: no trustworthy single-chip step time: no "
-                "plausible BENCH_*.json record found and this host has "
-                "no real TPU pod.  Pass --step-seconds from a real-chip "
-                "bench run; refusing to project from oversubscribed-CPU "
-                "times (they are not TPU-representative).\n")
+                "ERROR: no single-chip step time: this host has no "
+                "real TPU pod and --step-seconds was not given.  Pass "
+                "a step time measured on the chip (PERF_LEDGER.jsonl); "
+                "refusing to project from CPU times (they are not "
+                "TPU-representative).\n")
             raise SystemExit(2)
 
     # measured bucket granularity: the per-op audit of the LARGEST

@@ -359,11 +359,20 @@ def test_trainer_compression_fallback_on_health_sync():
 @pytest.mark.dist
 def test_two_device_spmd_smoke_collective_bytes():
     """Tier-1-safe 2-device virtual-CPU SPMD smoke (SCALING.json
-    methodology, compile-only): the bucketed step's optimized HLO must
-    carry one all-reduce PER BUCKET, their sizes must match the plan,
-    and their sum must equal the flat path's single gradient
-    all-reduce — so the overlap path can never silently regress to
-    the flat monolith."""
+    methodology, compile-only): the bucketed step must move one
+    gradient payload PER BUCKET, their sizes must match the plan, and
+    their sum must equal the flat path's single gradient payload — so
+    the overlap path can never silently regress to the flat monolith.
+
+    Pinned on what holds by construction.  The LOWERED program carries
+    one all_reduce per bucket (plus the two metric psums), chained by
+    optimization barriers; in the OPTIMIZED HLO every bucket is still
+    its own operand.  Whether XLA then ISSUES them as separate ops is
+    the compiler's: the installed XLA:CPU (jax 0.9.0) combines these
+    KB-sized all-reduces — and the 4-byte loss psum with them, the
+    2708-vs-2704 of the old pin — into one tuple op, so the optimized
+    op count is not asserted here; chip_smoke.py reports it on a
+    four-chip host."""
     rng = numpy.random.RandomState(3)
     state = _mlp_state(rng, (16, 32, 4))
     x, labels = _batch(rng, n=16)
@@ -379,24 +388,32 @@ def test_two_device_spmd_smoke_collective_bytes():
                         1024)
     assert len(plan.buckets) >= 3
 
-    def grad_ops(step):
-        hlo = step.lower(*args).compile().as_text()
-        return [op["bytes"] for op in parse_collective_ops(hlo)
-                if op["kind"] == "all-reduce" and op["bytes"] >= 512]
+    def grad_payloads(step):
+        """(lowered all_reduce count, gradient payload bytes): the
+        metric psums are 4-byte scalars, a gradient bucket never is."""
+        lowered = step.lower(*args)
+        payloads = [part for op in parse_collective_ops(
+                        lowered.compile().as_text())
+                    if op["kind"] == "all-reduce"
+                    for part in op["parts"] if part >= 512]
+        return lowered.as_text().count("stablehlo.all_reduce"), payloads
 
     buck = build_train_step(_plans(), mesh=mesh,
                             grad_bucket_mb=bucket_mb, donate=False)
     flat = build_train_step(_plans(), mesh=mesh,
                             grad_bucket_mb=float("inf"), donate=False)
-    bucket_ops = grad_ops(buck)
-    flat_ops = grad_ops(flat)
+    bucket_lowered, bucket_payloads = grad_payloads(buck)
+    flat_lowered, flat_payloads = grad_payloads(flat)
+    metric_psums = 2  # loss + error count
 
-    assert len(flat_ops) == 1 and flat_ops[0] == grad_bytes
-    assert len(bucket_ops) == len(plan.buckets), \
+    assert flat_lowered == 1 + metric_psums
+    assert flat_payloads == [grad_bytes]
+    assert bucket_lowered == len(plan.buckets) + metric_psums, \
         "bucketed step regressed: %d collective(s) for %d buckets" % (
-            len(bucket_ops), len(plan.buckets))
-    assert sum(bucket_ops) == grad_bytes
-    assert sorted(bucket_ops) == sorted(b.nbytes for b in plan.buckets)
+            bucket_lowered - metric_psums, len(plan.buckets))
+    assert sum(bucket_payloads) == grad_bytes
+    assert sorted(bucket_payloads) == sorted(
+        b.nbytes for b in plan.buckets)
 
 
 # -- overlap model + comm receipts ----------------------------------------

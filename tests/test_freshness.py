@@ -43,8 +43,7 @@ def _pool(tmp_path, replicas=3, ladder=(8,), seed=11, **kwargs):
     plans, params = _mlp_spec(seed=seed)
     pool = ReplicaPool(plans, params, (16,), replicas=replicas,
                        ladder=ladder, max_delay_s=0.001,
-                       max_queue=4096,
-                       cache_root=str(tmp_path / "cache"), **kwargs)
+                       max_queue=4096, **kwargs)
     pool.compile()
     return pool
 
@@ -245,8 +244,7 @@ def _compiled_candidate(pool, params, plans=None):
     cand_plans = plans if plans is not None else pool.engine.plans
     rep = pool._live()[-1]
     engine = AOTEngine(cand_plans, params, pool.engine.sample_shape,
-                       device=rep.device, ladder=pool.engine.ladder,
-                       cache_root=pool.engine.cache_root)
+                       device=rep.device, ladder=pool.engine.ladder)
     engine.compile()
     return engine
 

@@ -282,8 +282,22 @@ def test_mfu_snapshot_pipeline(monkeypatch):
     hist = reg.histogram("step.train_s")
     for _ in range(8):
         hist.observe(0.001)  # 2e9 flops / 1ms = 2 TFLOP/s achieved
-    monkeypatch.setenv("VELES_PEAK_TFLOPS", "4")
-    monkeypatch.setattr(xla_introspect, "_peak_cache", {})
+    # no chip, no MFU: the CPU platform has no row in the peaks table
+    # and nothing is published under the device metric's name
+    assert xla_introspect.peak_flops() is None
+    assert xla_introspect.mfu_snapshot(reg) is None
+    assert reg.peek("xla.mfu_pct") is None
+    # a chip the table does not know is an error, never a default
+    class _Chip(object):
+        platform = "tpu"
+        device_kind = "TPU v9 imaginary"
+    with pytest.raises(LookupError, match="TPU v9 imaginary"):
+        xla_introspect.device_peaks(_Chip())
+    _Chip.device_kind = "TPU v5 lite"
+    assert xla_introspect.device_peaks(_Chip())["bf16"] == 197e12
+    # the pipeline itself, rated against a stand-in 4 TFLOP/s chip
+    monkeypatch.setattr(xla_introspect, "peak_flops",
+                        lambda dtype=None: 4e12)
     mfu = xla_introspect.mfu_snapshot(reg)
     assert mfu is not None and abs(mfu - 50.0) < 1.0
     assert reg.peek("xla.mfu_pct").value == mfu
@@ -296,11 +310,17 @@ def test_mfu_snapshot_pipeline(monkeypatch):
 
 
 def test_heartbeat_carries_compile_count_and_mfu_on_fused_run(
-        cpu_device, tmp_path):
+        cpu_device, tmp_path, monkeypatch):
     """Acceptance: heartbeat JSONL lines from a fused run carry
-    non-null compile.count and mfu_pct."""
+    non-null compile.count and mfu_pct (rated against a stand-in chip
+    row: the CPU platform itself has no peak, so no MFU)."""
+    from veles_tpu.observe import xla_introspect
     from veles_tpu.observe.profile import validate_heartbeat
     from tests.test_observe import _trace_smoke_run
+    # small enough that a toy MLP on the CPU rates above the gauge's
+    # three-decimal rounding
+    monkeypatch.setattr(xla_introspect, "peak_flops",
+                        lambda dtype=None: 1e9)
     registry.reset()
     doc, lines = _trace_smoke_run(cpu_device, tmp_path, pipeline=False)
     assert lines

@@ -207,7 +207,7 @@ def _matmul_256_digest():
 
 
 def test_autotune_matmul_round_robin_picks_and_persists():
-    """The autotuner measures candidates round-robin (congestion drift
+    """The autotuner measures candidates round-robin (load drift
     hits every tile equally), picks a majority-positive-median winner,
     and persists it in the digest-keyed ScheduleCache — or falls back
     to the defaults WITHOUT persisting when timing jitter swamps every

@@ -32,6 +32,17 @@ from veles_tpu.parallel.tensor import (  # noqa: E402
 #: pipeline) on this model; the bound gives ~6x headroom.
 ULP_BOUND_3_STEPS = 1e-3
 
+#: the microbatches=1 pipeline split executes the single-device op
+#: sequence on the same values, but it is a DIFFERENT program (a
+#: shard_map over the pipe axis), and the installed XLA:CPU (jax
+#: 0.9.0) no longer compiles the two to bit-equal arithmetic: the
+#: losses and every rank's buffers still agree bit for bit, the state
+#: differs from the unsplit step's by 1-2 ULP after 3 momentum steps
+#: (measured 3.0e-7 / 2.6e-7 max rel on these two models).  The bound
+#: sits 100x under the regrouped-reduction band above, so a lost
+#: wavefront tick or a divergent rank (1e-2 and up) cannot hide in it.
+SPLIT_ULP_BOUND_3_STEPS = 1e-5
+
 
 def _setup(seed=3, heads=4):
     specs = transformer_layers(blocks=2, heads=heads, hidden=16,
@@ -197,10 +208,12 @@ def test_tp_step_flops_feed_mfu_attribution():
 
 def test_pipeline_2_stage_split_bit_identical_over_3_steps():
     """Acceptance (satellite): the 2-stage pipeline split of the
-    2-block transformer is BIT-identical to the unsplit fused step
-    over 3 chained train steps (microbatches=1: every stage executes
-    the single-device op sequence; discarded wavefront ticks
-    contribute exact-zero gradients)."""
+    2-block transformer matches the unsplit fused step over 3 chained
+    train steps — losses bit for bit, state within
+    SPLIT_ULP_BOUND_3_STEPS (microbatches=1: every stage executes the
+    single-device op sequence; discarded wavefront ticks contribute
+    exact-zero gradients).  Name kept from when XLA:CPU compiled the
+    two programs to bit-equal arithmetic."""
     plans, state, x, y, bs = _setup(heads=2)
     ref_state, ref_losses = _reference(plans, state, x, y, bs)
 
@@ -210,7 +223,8 @@ def test_pipeline_2_stage_split_bit_identical_over_3_steps():
                                      donate=False)
     ps, losses, _ = _run3(step, ps, x, y, bs)
     assert losses == ref_losses, "loss must be bit-identical"
-    _assert_bit_identical(ref_state, unstack_pipeline_state(ps, layout))
+    assert _maxrel(ref_state, unstack_pipeline_state(ps, layout)) < \
+        SPLIT_ULP_BOUND_3_STEPS
 
 
 def test_pipeline_microbatches_ulp_bounded():
@@ -246,8 +260,9 @@ def test_pipeline_prefix_layer_grads_replicate_bit_identically():
     every other rank — without the enter conjugate's psum, 'replicated'
     prefix updates silently diverge per rank (rank 0 trains, the rest
     momentum-decay) and the finiteness guard fires non-uniformly.
-    With it, the prefix-bearing split stays BIT-identical to the
-    unsplit step over 3 chained steps on every rank."""
+    With it, every rank's buffers stay BIT-identical to each other
+    and the prefix-bearing split stays within SPLIT_ULP_BOUND_3_STEPS
+    of the unsplit step over 3 chained steps."""
     specs = ([{"type": "layer_norm", "learning_rate": 0.05,
                "gradient_moment": 0.9}] +
              transformer_layers(blocks=2, heads=2, hidden=16,
@@ -276,7 +291,7 @@ def test_pipeline_prefix_layer_grads_replicate_bit_identically():
             numpy.testing.assert_array_equal(shards[0], other,
                                              err_msg=key)
     got = unstack_pipeline_state(ps, layout)
-    _assert_bit_identical(ref_state, got)
+    assert _maxrel(ref_state, got) < SPLIT_ULP_BOUND_3_STEPS
     # the trained prefix must actually have MOVED (a zero-grad prefix
     # that merely matched the reference would mean the reference broke)
     assert not numpy.array_equal(numpy.asarray(got[0]["weights"]),

@@ -310,38 +310,24 @@ def test_quantized_engine_parity_and_snapshot_flag():
     assert float(numpy.abs(y32 - y8).max()) < 0.05
 
 
-def test_quantized_warm_restart_zero_compiles(tmp_path):
+def test_quantized_warm_restart_zero_compiles():
     """Acceptance: warm restart of a quantized engine = 0 new backend
-    compiles — the int8 Pallas forward persists in the digest-keyed
-    compile cache like any other program."""
-    import jax
-
-    plans, _params, qparams, _ = _quantized_mlp()
-    root = str(tmp_path / "qserve_cache")
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    prev_size = jax.config.jax_persistent_cache_min_entry_size_bytes
-    try:
-        cold = AOTEngine(plans, qparams, (16,), ladder=(8, 32),
-                         device=Device(backend="cpu"), cache_root=root)
-        cold_receipt = cold.compile()
-        assert cold_receipt["new_compiles"] >= 2
-        warm = AOTEngine(plans, qparams, (16,), ladder=(8, 32),
-                         device=Device(backend="cpu"), cache_root=root)
-        warm_receipt = warm.compile()
-        assert warm_receipt["new_compiles"] == 0, warm_receipt
-        assert warm_receipt["cache_hits"] >= 2
-        x = numpy.random.RandomState(4).rand(8, 16).astype(
-            numpy.float32)
-        assert (warm.infer(x) == cold.infer(x)).all()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_floor)
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes", prev_size)
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
+    compiles — the int8 Pallas forward persists in the compile cache
+    like any other program.  The hidden width is this test's own, so
+    the first start is cold whatever ran before."""
+    plans, _params, qparams, _ = _quantized_mlp(hidden=29)
+    cold = AOTEngine(plans, qparams, (16,), ladder=(8, 32),
+                     device=Device(backend="cpu"))
+    cold_receipt = cold.compile()
+    assert cold_receipt["new_compiles"] >= 2
+    warm = AOTEngine(plans, qparams, (16,), ladder=(8, 32),
+                     device=Device(backend="cpu"))
+    warm_receipt = warm.compile()
+    assert warm_receipt["new_compiles"] == 0, warm_receipt
+    assert warm_receipt["cache_hits"] >= 2
+    x = numpy.random.RandomState(4).rand(8, 16).astype(
+        numpy.float32)
+    assert (warm.infer(x) == cold.infer(x)).all()
 
 
 # -- (e) schedule-cache family -----------------------------------------------
@@ -569,30 +555,27 @@ def test_rejected_quantized_canary_restores_process_flags(tmp_path):
 
 
 def test_peak_tables_and_step_dtype(monkeypatch):
-    """The int8 peak table doubles bf16 where the hardware does
-    (v5e/v5p/v6) and never undercuts it; set_step_dtype drives the
-    ceiling mfu_snapshot divides by (via peak_flops' dtype default)
-    and the step-dtype gauge."""
+    """The peaks table carries an int8 rate beside bf16 (twice it on
+    v5e, never below it); set_step_dtype drives the ceiling
+    mfu_snapshot divides by (via peak_flops' dtype default) and the
+    step-dtype gauge."""
     from veles_tpu.observe import xla_introspect as xi
     from veles_tpu.observe.metrics import registry
 
-    bf16 = dict(xi.PEAK_BF16_TFLOPS)
-    int8 = dict(xi.PEAK_INT8_TFLOPS)
-    assert set(bf16) == set(int8)
-    for kind in bf16:
-        assert int8[kind] >= bf16[kind]
-    for kind in ("v5", "v5p", "v6"):
-        assert int8[kind] == 2 * bf16[kind]
+    for kind, row in xi.PEAKS.items():
+        assert row["int8"] >= row["bf16"] > 0 and row["hbm"] > 0, kind
+        assert row["source"], kind
+    v5e = xi.PEAKS["TPU v5 lite"]
+    assert (v5e["bf16"], v5e["int8"]) == (197e12, 393e12)
+    monkeypatch.setattr(xi, "device_peaks", lambda device=None: v5e)
     prev = xi.step_dtype()
     try:
+        assert xi.peak_flops() == v5e[prev]
         xi.set_step_dtype("int8")
         assert xi.step_dtype() == "int8"
         assert registry.peek("xla.step_dtype_int8").value == 1
-        # the env override applies to whatever dtype is asked for
-        monkeypatch.setenv("VELES_PEAK_TFLOPS", "123.5")
-        xi._peak_cache.pop(("peak", "int8"), None)
-        assert xi.peak_flops() == 123.5e12
-        xi._peak_cache.pop(("peak", "int8"), None)
+        assert xi.peak_flops() == 393e12
+        assert xi.peak_flops("bf16") == 197e12
         with pytest.raises(ValueError):
             xi.set_step_dtype("fp4")
     finally:

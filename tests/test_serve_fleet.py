@@ -32,14 +32,11 @@ class _Hosts(object):
     """N in-process serve hosts (engine + batcher + transport server)
     sharing ONE spec, plus socketpair plumbing into a router."""
 
-    def __init__(self, n, plans, params, cache_root=None):
+    def __init__(self, n, plans, params):
         self.entries = []
         for i in range(n):
-            kwargs = {}
-            if cache_root is not None:
-                kwargs["cache_root"] = cache_root
             engine = AOTEngine(plans, params, (16,), ladder=(8, 32),
-                               device=Device(backend="cpu"), **kwargs)
+                               device=Device(backend="cpu"))
             engine.compile()
             batcher = ContinuousBatcher(engine,
                                         max_delay_s=0.002).start()
@@ -239,13 +236,12 @@ def test_cascade_then_503_with_fleet_minimum_retry_after(fleet):
     assert out.shape == (4,)
 
 
-def test_rejoin_rewarm_zero_new_compiles_receipt(tmp_path):
-    """A host restarting against the shared digest-keyed persistent
-    cache re-warms with new_compiles == 0, and its rejoin hello
-    carries that receipt to the router before it re-enters rotation."""
+def test_rejoin_rewarm_zero_new_compiles_receipt():
+    """A host restarting against the shared persistent cache re-warms
+    with new_compiles == 0, and its rejoin hello carries that receipt
+    to the router before it re-enters rotation."""
     plans, params = _mlp_spec(seed=6)
-    cache_root = str(tmp_path / "fleet_cache")
-    hosts = _Hosts(2, plans, params, cache_root=cache_root)
+    hosts = _Hosts(2, plans, params)
     router = FleetRouter(hedge=False).start()
     try:
         h0 = hosts.connect(router, 0)
@@ -253,13 +249,12 @@ def test_rejoin_rewarm_zero_new_compiles_receipt(tmp_path):
         out = router.infer(numpy.zeros(16, numpy.float32),
                            timeout=15.0)
         assert out.shape == (4,)
-        # "restart" host 0: same spec, same shared cache directory
+        # "restart" host 0: same spec, same cache directory
         hosts.stop(0)
         _wait_for(lambda: router.snapshot()["hosts_live"] == 1,
                   what="host loss")
         engine = AOTEngine(plans, params, (16,), ladder=(8, 32),
-                           device=Device(backend="cpu"),
-                           cache_root=cache_root)
+                           device=Device(backend="cpu"))
         receipt = engine.compile()
         assert receipt["new_compiles"] == 0, \
             "the restart must deserialize its ladder from the cache"

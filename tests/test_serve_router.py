@@ -18,7 +18,7 @@ from veles_tpu.observe.metrics import registry
 from veles_tpu.serve import (
     AOTEngine, ReplicaPool, ServeOverload, ServeService)
 from veles_tpu.serve.batcher import serve_snapshot
-from tests.test_serve import _mlp_spec, _restore_jax_cache_config  # noqa: F401
+from tests.test_serve import _mlp_spec
 
 pytestmark = pytest.mark.serve
 
@@ -135,20 +135,17 @@ def test_metrics_aggregate_across_replicas():
     assert snap["queue_depth"] == sum(snap["replica_queue_depths"])
 
 
-def test_warm_fleet_restart_zero_compiles(
-        tmp_path, _restore_jax_cache_config):  # noqa: F811
-    """A restarted 2-replica fleet against the warm digest-keyed cache
+def test_warm_fleet_restart_zero_compiles():
+    """A restarted 2-replica fleet against the warm persistent cache
     performs 0 new backend compiles ACROSS ALL replicas (jax's cache
     key includes the device assignment, so the cold start wrote one
-    entry set per device and the restart deserializes them all)."""
-    plans, params = _mlp_spec(seed=13)
-    root = str(tmp_path / "fleet_cache")
-    cold = ReplicaPool(plans, params, (16,), replicas=2, ladder=(8,),
-                       cache_root=root)
+    entry set per device and the restart deserializes them all).  The
+    hidden width is this test's own: its first start is cold."""
+    plans, params = _mlp_spec(seed=13, hidden=27)
+    cold = ReplicaPool(plans, params, (16,), replicas=2, ladder=(8,))
     cold_receipt = cold.compile()
     assert cold_receipt["new_compiles"] >= 2  # >= one per device
-    warm = ReplicaPool(plans, params, (16,), replicas=2, ladder=(8,),
-                       cache_root=root)
+    warm = ReplicaPool(plans, params, (16,), replicas=2, ladder=(8,))
     warm_receipt = warm.compile()
     assert warm_receipt["new_compiles"] == 0, warm_receipt
     assert warm_receipt["cache_hits"] >= 2

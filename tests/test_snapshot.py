@@ -71,6 +71,43 @@ def test_snapshot_resume_continues_training(tmp_path, cpu_device):
     assert restored.decision.epoch_metrics[1] < 5.0
 
 
+def test_snapshot_keeps_state_not_activations(cpu_device):
+    """Weights and solver state travel; a forward's output and a GD's
+    err_input keep shape and dtype only (zeros after the restore), so a
+    snapshot does not grow with the minibatch."""
+    sw = _build(cpu_device, max_epochs=1)
+    sw.run()
+    fwd, gd = sw.forwards[0], sw.gds[0]
+    fwd.output.map_read()
+    assert numpy.abs(fwd.output.mem).max() > 0
+    restored = pickle.loads(pickle.dumps(
+        sw, protocol=pickle.HIGHEST_PROTOCOL))
+    back = restored.forwards[0]
+    assert back.output.shape == fwd.output.shape
+    assert back.output.dtype == fwd.output.dtype
+    assert not back.output.mem.any()
+    assert restored.gds[0].err_input.shape == gd.err_input.shape
+    fwd.weights.map_read()
+    numpy.testing.assert_array_equal(back.weights.mem, fwd.weights.mem)
+    gd.accum_weights.map_read()
+    numpy.testing.assert_array_equal(restored.gds[0].accum_weights.mem,
+                                     gd.accum_weights.mem)
+
+
+def test_shallow_array_keeps_bfloat16_and_reads_no_device(cpu_device):
+    """Shape and dtype survive by the dtype object (bfloat16's ``.str``
+    is the void '<V2'), and dropping the bytes costs no device read."""
+    import jax.numpy as jnp
+    from veles_tpu.memory import Array
+    arr = Array(shallow_pickle=True)
+    arr.set_device_array(jnp.ones((3, 5), jnp.bfloat16), cpu_device)
+    back = pickle.loads(pickle.dumps(arr))
+    assert back.shape == (3, 5) and back.dtype == jnp.bfloat16
+    assert not back.mem.astype(numpy.float32).any()
+    # the host copy of the original is still the stale placeholder
+    assert not arr.mem.astype(numpy.float32).any()
+
+
 def test_snapshotter_unit_writes_and_imports(tmp_path, cpu_device):
     sw = _build(cpu_device, max_epochs=1)
     snap = Snapshotter(sw, directory=str(tmp_path), prefix="t",

@@ -474,8 +474,8 @@ def test_baseline_headline_metric_folding(tmp_path):
 
 def test_steady_state_rates_filters_warmup(tmp_path):
     """Heartbeat-derived rates follow the measure.py filter-passes
-    discipline: warmup/drain zero-rate buckets measure the weather,
-    not the program."""
+    discipline: warmup/drain zero-rate buckets measure the idle
+    machine, not the program."""
 
     def bucket(rate):
         return {"counters": {"req": {"delta": rate, "rate": rate}}}

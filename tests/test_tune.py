@@ -522,7 +522,7 @@ def test_pool_footprint_formula_is_shared():
                          "float32")
     family = FAMILIES["pool_bwd"]
     assert family.feasible(spec, {"owb": 2})
-    assert (pool_block_footprint(8, 3, 4, 2, (2, 2), (2, 2), 4)
+    assert (pool_block_footprint(4, 2, (2, 2), (2, 2), 4)
             <= POOL_VMEM_BUDGET_BYTES)
 
 

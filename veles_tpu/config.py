@@ -14,7 +14,7 @@ import os
 import runpy
 import threading
 
-__all__ = ["Config", "root", "get", "validate_kwargs"]
+__all__ = ["Config", "root", "get", "validate_kwargs", "precision_dtype"]
 
 
 class Config(object):
@@ -147,9 +147,14 @@ def validate_kwargs(caller, **kwargs):
 #: The global configuration tree.
 root = Config("root")
 
+#: everything the program caches — the XLA compile cache (unless
+#: JAX_COMPILATION_CACHE_DIR places it elsewhere), tuned schedules, the
+#: native build, datasets, snapshots — lives under ONE git-ignored
+#: directory of the checkout.  The path is part of the compile cache's
+#: key, so it must not move between runs of one checkout.
 _DEFAULT_CACHE = os.path.join(
-    os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-    "veles_tpu")
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".veles_cache")
 
 root.common.update({
     "dirs": {
@@ -160,8 +165,10 @@ root.common.update({
         "user": os.path.expanduser("~/.veles_tpu_dir"),
     },
     "engine": {
-        # Numeric precision for model math.  bfloat16 keeps the MXU fed;
-        # float32 is the reference-compatible default for parity tests.
+        # Numeric precision for model math (precision_dtype() below):
+        # the dtype of parameters, solver state and full-batch datasets.
+        # bfloat16 keeps the MXU fed; float32 is the reference-compatible
+        # default for parity tests.
         "precision_type": os.environ.get("VELES_PRECISION", "float32"),
         # Speed/digits ladder (reference PRECISION_LEVEL analog):
         # 0 (default): fastest — f32 matmul products run a bf16x3 MXU
@@ -212,6 +219,20 @@ root.common.update({
     },
     "graphics": {"multicast_address": "239.192.1.1"},
 })
+
+def precision_dtype():
+    """``root.common.engine.precision_type`` as a numpy dtype: the
+    dtype forward units create their parameters in and full-batch
+    loaders store their dataset in (solver state follows the
+    parameters).  "bfloat16" is the ml_dtypes extension type jax
+    itself uses, so host buffers and device arrays agree."""
+    import numpy
+    name = root.common.engine.precision_type
+    if name == "bfloat16":
+        import ml_dtypes
+        return numpy.dtype(ml_dtypes.bfloat16)
+    return numpy.dtype(name)
+
 
 _site_lock = threading.Lock()
 _site_loaded = False

@@ -7,7 +7,8 @@ process).  Here evaluations run through a pluggable evaluator:
 
 - in-process (default): ``fitness_fn(candidate_spec) -> float``;
 - process pool: ``workers=N`` evaluates candidates concurrently in
-  subprocesses;
+  SPAWNED subprocesses pinned to the CPU — a chip belongs to one
+  process, and this one usually holds it already (``_cpu_worker_pool``);
 - control plane: ``farm_slaves=N`` farms each generation's candidate
   specs as jobs through the Server/Client stack
   (veles_tpu.jobfarm.JobFarm) — the reference's strategy — with
@@ -18,12 +19,41 @@ Fitness is MAXIMIZED (use -validation_error).
 """
 
 import concurrent.futures
+import contextlib
+import multiprocessing
+import os
 
 from veles_tpu.genetics.config import apply_values, extract_tunes
 from veles_tpu.genetics.core import Population
 from veles_tpu.logger import Logger
 
 __all__ = ["GeneticsOptimizer"]
+
+
+@contextlib.contextmanager
+def _cpu_worker_pool(workers):
+    """A process pool whose workers can never reach for the chip.
+
+    A chip belongs to ONE process: once this one has touched JAX it
+    holds the chip, and a child that needed it would fail or hang.  So
+    the workers are spawned (never forked: this process has threads
+    and, usually, a live JAX runtime) with ``JAX_PLATFORMS=cpu`` in the
+    environment they start from — jax reads it at import, before any
+    worker code runs.  Work that must run ON the chip evaluates
+    in-process (``workers=0``) or on other hosts (``farm_slaves`` /
+    ``GeneticsOptimizer.worker``)."""
+    saved = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        with concurrent.futures.ProcessPoolExecutor(
+                max_workers=workers,
+                mp_context=multiprocessing.get_context("spawn")) as pool:
+            yield pool
+    finally:
+        if saved is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = saved
 
 
 class GeneticsOptimizer(Logger):
@@ -44,8 +74,8 @@ class GeneticsOptimizer(Logger):
         #: optional whole-generation evaluator ``fn(specs) -> [fitness]``
         #: for fitness functions that must see a generation's candidates
         #: TOGETHER (the schedule autotuner's interleaved round-robin
-        #: timing: one sample of every candidate per pass, so a
-        #: congestion window cannot crown the wrong candidate).  Ignored
+        #: timing: one sample of every candidate per pass, so a drift
+        #: in machine load cannot crown the wrong candidate).  Ignored
         #: on the farm/process-pool paths, which are per-candidate by
         #: construction.
         self.batch_fitness_fn = batch_fitness_fn
@@ -117,8 +147,10 @@ class GeneticsOptimizer(Logger):
                     local_slaves=self.farm_slaves)
             fits = self._farm.submit(specs)
         elif self.workers and len(reps) > 1:
-            with concurrent.futures.ProcessPoolExecutor(
-                    max_workers=self.workers) as pool:
+            self.info("evaluating %d candidates on %d CPU-pinned "
+                      "worker processes (the chip, if any, stays with "
+                      "this process)", len(specs), self.workers)
+            with _cpu_worker_pool(self.workers) as pool:
                 fits = list(pool.map(self.fitness_fn, specs))
         elif self.batch_fitness_fn is not None:
             fits = list(self.batch_fitness_fn(specs)) if specs else []

@@ -20,6 +20,7 @@ checks.
 import numpy
 
 from veles_tpu.backends import NumpyDevice
+from veles_tpu.config import precision_dtype
 from veles_tpu.loader.base import (
     Loader, LoaderError, LoaderMSEMixin, TRAIN, VALID)
 from veles_tpu.memory import Array
@@ -38,7 +39,9 @@ class FullBatchLoader(Loader):
         self.original_data = Array()
         self.original_labels = []
         self.device = None
-        self.dtype = numpy.dtype(kwargs.get("dtype", numpy.float32))
+        #: storage dtype of the dataset and its minibatches; follows
+        #: the configured model precision so the two meet in one dtype
+        self.dtype = numpy.dtype(kwargs.get("dtype", precision_dtype()))
 
     @staticmethod
     def _coerce_array(value):

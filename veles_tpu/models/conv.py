@@ -30,17 +30,9 @@ def _norm_padding(padding):
 
 
 def conv2d(x, w, strides, padding, pet=None):
-    """The one conv entry point (autodiff gradients — deliberately).
-
-    Round-5 measurement (scripts/bwd_experiments.py +
-    scripts/step_ab.py, interleaved round-robin chains on the v5e):
-    jax-autodiff's conv gradients already run at ~190 TF/s at the
-    AlexNet shapes — near the bf16 MXU peak — and a hand-scheduled
-    custom VJP (dgrad as lhs-dilated conv, wgrad as batch-as-
-    contraction via ("CHWN", "IHWO", "HWNC")) is numerically exact
-    but changes the whole fused train step by 0.1 % (A/B speedup
-    1.001).  Stock autodiff keeps forward-mode AD usable; the scripts
-    keep the receipts."""
+    """The one conv entry point (autodiff gradients: stock autodiff
+    keeps forward-mode AD usable; the hand-scheduled backward attaches
+    one level up, in ``Conv.apply``)."""
     from jax import lax
     return lax.conv_general_dilated(
         x, w, window_strides=strides, padding=padding,

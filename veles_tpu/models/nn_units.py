@@ -17,7 +17,7 @@ import numpy
 
 from veles_tpu import prng
 from veles_tpu.backends import NumpyDevice
-from veles_tpu.config import root
+from veles_tpu.config import precision_dtype, root
 from veles_tpu.memory import Array
 from veles_tpu.units import Unit
 
@@ -45,7 +45,9 @@ class ForwardBase(Unit):
     def __init__(self, workflow, **kwargs):
         super(ForwardBase, self).__init__(workflow, **kwargs)
         self.input = None  # linked from loader/previous unit (Array)
-        self.output = Array()
+        # an activation, not state: a snapshot keeps its shape only
+        # (AlexNet at batch 256 would carry 0.8 GB of them)
+        self.output = Array(shallow_pickle=True)
         self.weights = Array()
         self.bias = Array()
         self.include_bias = kwargs.get("include_bias", True)
@@ -86,8 +88,15 @@ class ForwardBase(Unit):
         self.device = device
         super(ForwardBase, self).initialize(**kwargs)
         self.create_params()
+        dtype = precision_dtype()
         for arr in self.param_arrays():
             if arr:
+                if arr.dtype != dtype:
+                    # create_params fills float32; the configured
+                    # precision (root.common.engine.precision_type)
+                    # is what the model computes and snapshots in
+                    arr.map_write()
+                    arr.mem = arr.mem.astype(dtype)
                 arr.initialize(self.device)
         return True
 
@@ -219,7 +228,7 @@ class GradientDescentBase(Unit):
         self.input = None
         self.output = None
         self.err_output = None   # linked: next gd's err_input / evaluator
-        self.err_input = Array()
+        self.err_input = Array(shallow_pickle=True)  # transient too
         self.weights = None      # linked BY OBJECT from the forward unit
         self.bias = None
         self.include_bias = kwargs.get("include_bias", True)

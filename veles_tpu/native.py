@@ -4,9 +4,9 @@ Counterpart of the reference's libVeles consumption path: a package
 exported by Workflow.package_export is loaded and executed by the C++
 runtime (native/src/), with the greedy strip-packing arena planner and
 the batch-sharding thread-pool engine.  Build uses cmake+make the first
-time and caches the shared library under the user cache dir (NOT
-inside the repo: CMake drops generated .cpp probes into its build
-tree, which pollutes source-tree audits).
+time and caches the shared library under the program's cache root
+(``root.common.dirs.cache``: a git-ignored directory of the checkout,
+so a clean export builds it from ``native/src`` and nothing else).
 """
 
 import ctypes
@@ -27,10 +27,7 @@ def source_digest():
     """Hash of every native source file: the cache key (computed once,
     on first use — importing this module must not walk the source
     tree).  An existence-only check against a shared cache dir would
-    keep serving a stale .so across source changes and checkouts.
-    ``serve/engine.py``'s ``model_digest`` is the same pattern applied
-    to the AOT compile cache: digest-keyed cache dirs, content (not
-    existence) as the key."""
+    keep serving a stale .so across source changes and checkouts."""
     global _digest
     if _digest is None:
         import hashlib
@@ -48,10 +45,9 @@ def source_digest():
 
 def _lib_path():
     """Digest-keyed build dir + library path, resolved lazily."""
-    build_dir = os.path.join(
-        os.environ.get("XDG_CACHE_HOME",
-                       os.path.expanduser("~/.cache")),
-        "veles_tpu", "native_build", source_digest())
+    from veles_tpu.config import root
+    build_dir = os.path.join(root.common.dirs.cache, "native_build",
+                             source_digest())
     return build_dir, os.path.join(build_dir, "libveles_tpu_native.so")
 
 

@@ -77,7 +77,7 @@ def steady_state_rates(buckets, names=None):
     """Steady-state per-second rates from telemetry buckets, one per
     counter, under the measure.py discipline: per-bucket rate samples
     filtered through ``filter_passes`` (a zero-rate bucket during
-    warmup or drain measures the weather, not the program) and
+    warmup or drain measures the idle machine, not the program) and
     published as ``positive_majority_median`` — ``None``-valued
     metrics (no positive majority) are omitted."""
     from veles_tpu.tune.measure import (filter_passes,

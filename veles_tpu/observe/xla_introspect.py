@@ -16,19 +16,18 @@ the TPU-systems literature calls out as the ones that matter:
   falling back to a live-array census (``jax.live_arrays()``) where it
   does not (CPU), as ``xla.mem.*`` gauges;
 - **achieved MFU** — :func:`set_step_flops` records the XLA cost
-  model's FLOP count for the compiled fused step (the same
-  ``cost_analysis()`` number bench.py reports), and
+  model's FLOP count for the compiled fused step, and
   :func:`mfu_snapshot` divides by the recent median step time and the
-  chip's peak to publish a live ``xla.mfu_pct`` gauge the heartbeat
-  and web-status health block carry — cross-checkable against
-  ``bench.py``'s offline ``MFU.json``.
+  chip's peak from :data:`PEAKS` to publish a live ``xla.mfu_pct``
+  gauge the heartbeat and web-status health block carry.  No chip, no
+  MFU: on the CPU platform the gauge stays unpublished, and a chip
+  whose ``device_kind`` is not in the table is an error.
 
 Everything here imports jax lazily and is called OFF the step path
 (compile time, heartbeat thread, decision class end), preserving the
 observe-package invariant that telemetry never adds a host sync.
 """
 
-import os
 import threading
 
 from veles_tpu.observe.metrics import percentiles
@@ -38,29 +37,25 @@ __all__ = ["CompileWatcher", "watcher", "ensure_installed", "watch",
            "poll_recompiles", "device_memory_gauges", "set_step_flops",
            "set_fwd_flops", "set_step_dtype", "step_dtype",
            "peak_flops", "mfu_snapshot", "bwd_snapshot",
-           "compile_snapshot", "compile_delta", "PEAK_BF16_TFLOPS",
-           "PEAK_INT8_TFLOPS"]
+           "compile_snapshot", "compile_delta", "PEAKS",
+           "device_peaks"]
 
-#: bf16 MXU peak TFLOP/s by device-kind substring (public spec sheets);
-#: bench.py shares this table for its offline MFU context.
-PEAK_BF16_TFLOPS = (
-    ("v6", 918.0), ("v5p", 459.0), ("v5", 197.0), ("v4", 275.0),
-    ("v3", 123.0), ("v2", 45.0),
-)
+#: THE peaks table: one row per chip, keyed by the exact
+#: ``device_kind`` string jax reports, with where the numbers come
+#: from.  A kind that is not here has no peak — rating it is an error,
+#: never a default (add the row with its source instead).  int8 runs
+#: the MXU at twice the bf16 rate on v5e, so a quantized engine's steps
+#: rate against ``int8`` (docs/serving.md "Quantized ladder").
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16": 197e12,     # FLOP/s
+        "int8": 393e12,     # OP/s
+        "hbm": 819e9,       # bytes/s
+        "source": "Google Cloud documentation, \"TPU v5e\"",
+    },
+}
 
-#: int8 MXU peak TOP/s by device-kind substring: v5e/v5p/v6 run int8 at
-#: 2x the bf16 rate (spec sheets); v2-v4 have no separate 8-bit mode —
-#: their entries equal bf16 so an int8 MFU there is merely conservative,
-#: never inflated.  The quantized serve engine's MFU/attribution
-#: ceiling (docs/serving.md "Quantized ladder") — dividing an int8
-#: step by the bf16 peak would double-count the headroom the MXU's
-#: 8-bit mode actually provides.
-PEAK_INT8_TFLOPS = (
-    ("v6", 1836.0), ("v5p", 918.0), ("v5", 394.0), ("v4", 275.0),
-    ("v3", 123.0), ("v2", 45.0),
-)
-
-_PEAK_TABLES = {"bf16": PEAK_BF16_TFLOPS, "int8": PEAK_INT8_TFLOPS}
+_STEP_DTYPES = ("bf16", "int8")
 
 #: the jax.monitoring duration event emitted once per XLA backend
 #: compilation (jaxpr trace / MLIR lowering events are deliberately
@@ -311,7 +306,6 @@ def set_fwd_flops(flops, reg=None):
     reg.gauge("xla.fwd_flops").set(float(flops))
 
 
-_peak_cache = {}
 _peak_lock = threading.Lock()
 _step_dtype = ["bf16"]
 
@@ -322,9 +316,9 @@ def set_step_dtype(name, reg=None):
     quantized level), so :func:`mfu_snapshot` divides by the matching
     peak instead of always the bf16 ceiling.  Set by the quantized
     serve engine at compile; training paths keep the default."""
-    if name not in _PEAK_TABLES:
+    if name not in _STEP_DTYPES:
         raise ValueError("unknown step dtype %r (have %s)" %
-                         (name, sorted(_PEAK_TABLES)))
+                         (name, sorted(_STEP_DTYPES)))
     with _peak_lock:
         _step_dtype[0] = name
     reg = reg if reg is not None else _registry
@@ -337,75 +331,31 @@ def step_dtype():
         return _step_dtype[0]
 
 
-def _measured_peak():
-    """Fallback peak for chips without a spec-table entry (host CPU
-    under JAX_PLATFORMS=cpu): the achieved FLOP/s of a small f32
-    matmul, measured once and cached.  MFU against a measured matmul
-    ceiling is the honest definition available on such backends — and
-    it keeps ``mfu_pct`` live (non-null) on development runs so the
-    plumbing is exercised before a TPU ever sees it."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-    import numpy
-    n = 384
-    a = jnp.asarray(numpy.random.RandomState(7)
-                    .rand(n, n).astype(numpy.float32))
-    matmul = jax.jit(lambda x, y: x @ y)
-    jax.block_until_ready(matmul(a, a))  # compile outside the timing
-    best = None
-    for _ in range(3):
-        start = time.perf_counter()
-        jax.block_until_ready(matmul(a, a))
-        elapsed = time.perf_counter() - start
-        if best is None or elapsed < best:
-            best = elapsed
-    return 2.0 * n * n * n / max(best, 1e-9)
+def device_peaks(device=None):
+    """The :data:`PEAKS` row of ``device`` (default: the first local
+    device), None on the CPU platform — there is no device to rate
+    there, so nothing may be published under a device metric's name.
+    Raises LookupError for a chip the table does not know."""
+    if device is None:
+        import jax
+        device = jax.local_devices()[0]
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise LookupError(
+            "no peaks for device_kind %r on platform %r: add a row "
+            "(with its source) to observe.xla_introspect.PEAKS" %
+            (device.device_kind, device.platform)) from None
 
 
 def peak_flops(dtype=None):
-    """This process's peak FLOP/s reference for MFU, resolved once per
-    dtype: ``VELES_PEAK_TFLOPS`` env override -> the device-kind spec
-    table for ``dtype`` (``None`` -> the recorded :func:`step_dtype`,
-    so a quantized engine's steps rate against the int8 peak) ->
-    measured matmul ceiling (CPU dev runs, one ceiling for every
-    dtype — the interpreter has no 8-bit mode to rate against).  None
-    when jax itself is unusable."""
-    if dtype is None:
-        dtype = step_dtype()
-    table = _PEAK_TABLES.get(dtype, PEAK_BF16_TFLOPS)
-    key = ("peak", dtype)
-    with _peak_lock:
-        if key in _peak_cache:
-            return _peak_cache[key]
-        peak = None
-        env = os.environ.get("VELES_PEAK_TFLOPS", "")
-        if env:
-            try:
-                peak = float(env) * 1e12
-            except ValueError:
-                peak = None
-        if peak is None:
-            try:
-                import jax
-                kind = jax.local_devices()[0].device_kind.lower()
-                for kind_key, tflops in table:
-                    if kind_key in kind:
-                        peak = tflops * 1e12
-                        break
-            except Exception:
-                pass
-        if peak is None:
-            try:
-                peak = _peak_cache.get(("measured",))
-                if peak is None:
-                    peak = _measured_peak()
-                    _peak_cache[("measured",)] = peak
-            except Exception:
-                peak = None
-        _peak_cache[key] = peak
-        return peak
+    """This process's peak FLOP/s for MFU at ``dtype`` (``None`` -> the
+    recorded :func:`step_dtype`, so a quantized engine's steps rate
+    against the int8 peak); None on the CPU platform."""
+    row = device_peaks()
+    return None if row is None else row[dtype or step_dtype()]
 
 
 def mfu_snapshot(reg=None):

@@ -15,12 +15,10 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as _pltpu
 
 __all__ = ["interpret_mode", "interpret_for", "pad_to", "unpad", "kernel_cast",
-           "ceil_mult", "tpu_compiler_params", "mxu_partial_dot",
-           "mxu_int8_dot", "pallas_bwd_enabled", "DEBUG_NONFINITE",
-           "PALLAS_BWD_ENV"]
+           "ceil_mult", "mxu_partial_dot", "mxu_int8_dot",
+           "pallas_bwd_enabled", "DEBUG_NONFINITE", "PALLAS_BWD_ENV"]
 
 #: opt-in per-call output validation (docs/health.md); the check forces
 #: a device sync per eager kernel call, so it is for debugging only
@@ -42,17 +40,8 @@ def pallas_bwd_enabled():
     at import, and tests flip ``common.PALLAS_BWD_ENV`` directly."""
     env = PALLAS_BWD_ENV
     if env in ("", "auto"):
-        try:
-            return jax.default_backend() == "tpu"
-        except Exception:
-            return False
+        return jax.default_backend() == "tpu"
     return env != "0"
-
-#: jax renamed TPUCompilerParams -> CompilerParams across releases;
-#: resolve whichever this jax ships so the kernels run on both
-tpu_compiler_params = getattr(
-    _pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")
 
 
 def kernel_cast(x, dtype):
@@ -67,8 +56,11 @@ def kernel_cast(x, dtype):
 
 @functools.lru_cache(maxsize=None)
 def interpret_mode():
-    """True when running on a backend without Mosaic (CPU tests): Pallas
-    kernels then execute in interpreter mode, same numerics."""
+    """True when jax's default backend has no Mosaic (CPU tests):
+    Pallas kernels then execute in interpreter mode, same numerics.
+    ``backends.TPUDevice`` refuses to exist beside a non-TPU default
+    backend, so a run that asked for the chip never gets here with the
+    interpreter on."""
     return jax.default_backend() == "cpu"
 
 
@@ -76,15 +68,10 @@ def interpret_for(*arrays):
     """Per-call interpret decision: Pallas needs the interpreter whenever
     the operand actually lives on CPU, whatever the process default
     backend is (a TPU host can still run CPU-device workflows).  Tracers
-    carry no placement — fall back to the default-backend rule."""
+    carry no placement — they take the default-backend rule."""
     for x in arrays:
-        devices = getattr(x, "devices", None)
-        if devices is None:
-            continue
-        try:
-            return any(d.platform == "cpu" for d in devices())
-        except Exception:
-            continue
+        if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+            return any(d.platform == "cpu" for d in x.devices())
     return interpret_mode()
 
 

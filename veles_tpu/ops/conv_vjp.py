@@ -1,12 +1,11 @@
 """Fused conv-VJP Pallas kernel family — the hand-scheduled backward
 for the conv layers (docs/kernels.md).
 
-MFU.json's round-5 attribution showed the backward-vs-forward MFU gap
-(42% vs 71%) is COMPOSITION slack, not any single op: isolated conv
-gradients already run near peak under autodiff, but a congested step
-interleaves every layer's dgrad/wgrad/epilogue/bias ops freely and the
-MXU piles up.  This module replaces the autodiff conv backward with a
-scheduled composition:
+The thesis (docs/kernels.md; not measured on today's code — ROADMAP
+S1/D1 holds the A/B that decides it): a fused step's backward loses
+MXU time to COMPOSITION, not to any single op — the step interleaves
+every layer's dgrad/wgrad/epilogue/bias ops freely.  This module
+replaces the autodiff conv backward with a scheduled composition:
 
 - **wgrad** as a batch-contraction matmul over per-tap strided slices
   of the (padded) input — ONE Pallas kernel whose grid walks
@@ -25,16 +24,14 @@ scheduled composition:
 - **dgrad** as the explicit lhs-dilated conv (transposed conv: dilate
   ``err`` by the forward stride, convolve with the spatially-flipped
   I/O-swapped kernel) — the formulation XLA's own transpose rule uses,
-  kept as a lax conv because the round-5 receipts measured it near
-  peak already; the win is consuming the fused ``err`` instead of
+  kept as a lax conv; it consumes the fused ``err`` instead of
   recomputing the epilogue.
 
-Traffic note: the per-tap slices materialize ~taps x input bytes, like
-im2col — but the layers whose backward time dominates (AlexNet convs
-2/4/5/6, MFU.json) are MXU-bound by 3-7x over their HBM time, so the
-extra activation reads stay under the MXU roofline.  Kernels with more
-than ``MAX_FUSED_TAPS`` taps (AlexNet's 11x11 layer 0 — HBM-bound
-anyway) fall back to the stock autodiff VJP.
+Traffic note: the per-tap slices materialize ~taps x input bytes in
+HBM, like im2col (AlexNet conv2 at batch 256 in bf16: 25 taps x 186,624
+rows x 128 padded lanes = 1.2 GB).  Kernels with more than
+``MAX_FUSED_TAPS`` taps (AlexNet's 11x11 layer 0) keep the stock
+autodiff VJP; :func:`conv_vjp_route` names the road.
 
 Parity contract (tests/test_pallas_bwd.py, ``pallas`` marker): dgrad
 is bit-exact vs autodiff; wgrad/bias-grad are bit-exact on
@@ -47,6 +44,7 @@ bit-exactly (it IS the stock code path).
 """
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -55,11 +53,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from veles_tpu.ops import common as _common
 from veles_tpu.ops.common import (ceil_mult, interpret_for,
-                                   mxu_partial_dot, pad_to,
-                                   tpu_compiler_params, unpad)
+                                   mxu_partial_dot, pad_to, unpad)
 
 __all__ = ["fused_conv_vjp", "conv_act", "activation_grad",
-           "ACTIVATIONS", "MAX_FUSED_TAPS",
+           "ACTIVATIONS", "MAX_FUSED_TAPS", "conv_vjp_route",
            "CONV_VJP_KERNEL_VERSION"]
 
 #: bump when the wgrad kernel's algorithm changes: tuned schedules in
@@ -70,11 +67,16 @@ CONV_VJP_KERNEL_VERSION = 1
 
 #: kernels with more taps than this keep the autodiff VJP: the per-tap
 #: slice stack would multiply activation traffic past any MXU cover
-#: (AlexNet layer 0's 11x11 = 121 taps is the motivating case — and
-#: it is HBM-bound, so the fused schedule has nothing to win there)
+#: (AlexNet layer 0's 11x11 = 121 taps is the motivating case)
 MAX_FUSED_TAPS = 32
 
 _DEFAULT_BLOCKS = (256, 256, 512)  # (bi=Cin, bj=Cout, bk=P) tile sizes
+
+
+def conv_vjp_route(ky, kx):
+    """"pallas" or "autodiff": the road :func:`fused_conv_vjp` takes
+    for a ``ky x kx`` kernel."""
+    return "autodiff" if ky * kx > MAX_FUSED_TAPS else "pallas"
 
 
 # -- activation epilogues ----------------------------------------------------
@@ -280,7 +282,7 @@ def _fused_wgrad_jit(x, y, dy, activation, ky, kx, out_hw, padding,
             pltpu.VMEM((bi, bj), jnp.float32),
             pltpu.VMEM((8, bj), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -334,7 +336,12 @@ def fused_conv_vjp(x, w, y, err_output, *, activation="linear",
     """
     ky, kx = int(w.shape[0]), int(w.shape[1])
     oh, ow = int(err_output.shape[1]), int(err_output.shape[2])
-    if ky * kx > MAX_FUSED_TAPS:
+    if conv_vjp_route(ky, kx) == "autodiff":
+        # once per trace, never per step: the shape routes, out loud
+        logging.getLogger("veles_tpu.ops").info(
+            "conv_vjp: %dx%d kernel (%d taps > MAX_FUSED_TAPS=%d) "
+            "keeps the stock autodiff backward", ky, kx, ky * kx,
+            MAX_FUSED_TAPS)
         return _autodiff_conv_vjp(
             x, w, y, err_output, activation=activation, padding=padding,
             sliding=sliding, include_bias=include_bias,

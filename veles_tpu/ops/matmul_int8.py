@@ -42,8 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from veles_tpu.ops.common import (ceil_mult, interpret_for,
-                                   mxu_int8_dot, pad_to,
-                                   tpu_compiler_params, unpad)
+                                   mxu_int8_dot, pad_to, unpad)
 
 __all__ = ["matmul_int8", "matmul_int8_reference", "conv2d_int8",
            "MATMUL_INT8_KERNEL_VERSION", "INT8_SUBLANE"]
@@ -192,7 +191,7 @@ def _matmul_int8_jit(a, b, scale, bias, blocks, out_dtype, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, scale2, bias2)

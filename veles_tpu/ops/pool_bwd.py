@@ -1,22 +1,20 @@
 """Max-pool select-and-scatter backward Pallas kernel
 (docs/kernels.md).
 
-scripts/pool_bwd_experiment.py measured XLA's select-and-scatter max
-pool gradient beating the patches/argmax formulation 6x AND being the
-only value-exact routing — so select-and-scatter is the scheduled
-primitive here, fused with the incoming err cascade: the kernel
-multiplies the routing mask by the incoming cotangent in the same tile
-pass that computes it, instead of materializing a one-hot and a
-separate multiply.
+Formulation (one image x one 128-lane channel tile x one W tile per
+grid step): for each tap (kh, kw) of the window, in row-major window
+order, a tap element is SELECTED iff it equals the window max (the
+forward output ``y``, which the unit already holds — no recompute) and
+no earlier tap matched (first-match tie-break, the same scan order
+XLA's SelectAndScatter folds ge-select in).  The selected cotangent is
+accumulated back at the tap's input coordinates.
 
-Formulation (one image x one channel tile per grid step): for each tap
-(kh, kw) of the window, in row-major window order, a tap element is
-SELECTED iff it equals the window max (the forward output ``y``, which
-the unit already holds — no recompute) and no earlier tap matched
-(first-match tie-break, the same scan order XLA's SelectAndScatter
-folds ge-select in).  The selected cotangent is then scattered back to
-input coordinates through a stride-dilated shift — all on values
-resident in scoped VMEM, one pass over the window.
+Both the tap read and the scatter are STRIDED REF accesses
+(``ref[pl.ds(kh, oh, stride=sy), pl.ds(kw, ow, stride=sx), :]``) on f32
+VMEM scratch: Mosaic lowers those to strided loads/stores, where a
+strided slice or an ``.at[].add`` of a VALUE has no lowering (scatter).
+H is a major dim and W the sublane dim of the (H, W, 128) blocks, so
+neither stride touches the lane axis.
 
 Ceil-mode partial windows (models/pooling.py pads bottom/right) are
 covered by padding the input block with -inf: padded cells never equal
@@ -26,121 +24,134 @@ Parity (tests/test_pallas_bwd.py): routing is bit-exact vs the
 ``jax.vjp(lax.reduce_window)`` reference on exactly-representable
 cotangents (including ties and ceil-mode tails); random cotangents
 agree within ~1 ULP where >= 2 overlapping windows sum in a different
-order.  Windows larger than the VMEM budget (big-image VGG-style
-inputs with OVERLAPPING windows) fall back to autodiff;
-non-overlapping windows (kx == sx, ky == sy — the VGG 2x2/2 case)
-tile the W axis and stay on the kernel.
+order.  Blocks larger than the VMEM budget tile the W axis when the
+windows do not overlap (kx == sx, ky == sy — the VGG 2x2/2 case) and
+fall back to autodiff when they do; :func:`pool_bwd_route` names the
+road a shape takes.
 """
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from veles_tpu.ops.common import (ceil_mult, interpret_for, pad_to,
-                                   tpu_compiler_params, unpad)
+from veles_tpu.ops.common import ceil_mult, interpret_for, pad_to, unpad
 
 __all__ = ["max_pool_bwd", "max_pool", "POOL_VMEM_BUDGET_BYTES",
-           "POOL_BWD_KERNEL_VERSION", "pool_block_footprint"]
+           "POOL_BWD_KERNEL_VERSION", "pool_block_footprint",
+           "pool_bwd_route"]
 
 #: bump when the select-and-scatter kernel's algorithm changes: tuned
 #: W-tilings in the schedule cache are keyed to the algorithm they
-#: were measured on (stale versions miss, never serve)
-POOL_BWD_KERNEL_VERSION = 1
+#: were measured on (stale versions miss, never serve).  v2 = strided
+#: ref accesses + 128-lane channel tiles (the form Mosaic compiles).
+POOL_BWD_KERNEL_VERSION = 2
 
-#: per-grid-step VMEM budget for the pool blocks (x + y + dy + out +
-#: f32 accumulator); overlapping-window shapes that exceed it keep the
-#: autodiff backward rather than risk a Mosaic VMEM overflow
-POOL_VMEM_BUDGET_BYTES = 12 * 2 ** 20
+#: per-grid-step VMEM budget for the pool blocks (double-buffered
+#: x/y/dy/out windows + the two f32 scratch planes + tap temporaries),
+#: under Mosaic's 16 MiB default scoped-VMEM limit (the footprint
+#: formula below runs ~15-20 % over what Mosaic reports allocating);
+#: overlapping-window shapes that exceed it keep the autodiff backward
+POOL_VMEM_BUDGET_BYTES = 14 * 2 ** 20
+
+#: channels ride the lane axis one 128-wide tile per grid step
+_LANES = 128
 
 
-def _pool_bwd_kernel(x_ref, y_ref, dy_ref, out_ref, *, window, sliding,
-                     out_h, out_w, in_h, in_w):
-    """One (n, w-tile, c-tile) grid step of the routed scatter."""
+def _pool_bwd_kernel(x_ref, y_ref, dy_ref, out_ref, xf_ref, acc_ref, *,
+                     window, sliding, out_h, out_w):
+    """One (n, c-tile, w-tile) grid step of the routed scatter."""
     ky, kx = window
     sx, sy = sliding
-    xv = x_ref[0]                       # (Hp, Wp, cb), -inf padded
-    yv = y_ref[0]                       # (OH, OWb, cb)
+    # f32 planes: strided accesses need 32-bit rows (bf16 packs two
+    # rows per sublane), and the upcast is exact so the equality below
+    # routes exactly like the storage dtype would
+    xf_ref[...] = x_ref[0].astype(jnp.float32)   # (Hp, Wb, 128), -inf pad
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    yv = y_ref[0].astype(jnp.float32)            # (OH, OWb, 128)
     dyv = dy_ref[0].astype(jnp.float32)
-    span_h = (out_h - 1) * sy + 1
-    span_w = (out_w - 1) * sx + 1
-    matched = jnp.zeros(yv.shape, jnp.bool_)
-    acc = jnp.zeros(xv.shape, jnp.float32)
+    unmatched = jnp.ones(yv.shape, jnp.float32)
     for kh in range(ky):
         for kw in range(kx):
-            x_tap = jax.lax.slice(
-                xv, (kh, kw, 0),
-                (kh + span_h, kw + span_w, xv.shape[2]),
-                (sy, sx, 1))
-            sel = (x_tap == yv) & ~matched
-            matched = matched | sel
-            contrib = jnp.where(sel, dyv, 0.0)
-            if sx == 1 and sy == 1:
-                dilated = contrib
-            else:
-                z = jnp.zeros((out_h, sy, out_w, sx, contrib.shape[2]),
-                              jnp.float32)
-                z = z.at[:, 0, :, 0, :].set(contrib)
-                dilated = z.reshape(out_h * sy, out_w * sx,
-                                    contrib.shape[2])
-                dilated = dilated[:span_h, :span_w, :]
-            acc = acc.at[kh:kh + span_h, kw:kw + span_w, :].add(dilated)
-    out_ref[0] = acc[:in_h, :in_w, :].astype(out_ref.dtype)
+            tap = (pl.ds(kh, out_h, stride=sy),
+                   pl.ds(kw, out_w, stride=sx), slice(None))
+            sel = jnp.where(xf_ref[tap] == yv, unmatched, 0.0)
+            unmatched = unmatched - sel
+            acc_ref[tap] = acc_ref[tap] + sel * dyv
+    out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
-def pool_block_footprint(h, c, oh, owb, window, sliding, itemsize):
-    """VMEM bytes of one (image, W-tile) grid step: padded x block +
-    y/dy blocks + out block + the f32 accumulator.  The ONE footprint
-    formula — the kernel's planner below and the autotuner's
-    feasibility gate (tune/spec.py) both call it, so they cannot
-    drift when the block layout changes."""
+def pool_block_footprint(oh, owb, window, sliding, itemsize):
+    """VMEM bytes of one grid step over ``oh x owb`` output cells: the
+    double-buffered x/out and y/dy windows in the storage dtype, the
+    two f32 scratch planes, and the three f32 tap temporaries.  The
+    channel count does not enter — channels tile at 128 lanes whatever
+    it is.  The ONE footprint formula — the
+    kernel's planner below and the autotuner's feasibility gate
+    (tune/spec.py) both call it, so they cannot drift when the block
+    layout changes."""
     ky, kx = window
-    sx, _sy = sliding
-    cb = ceil_mult(c, 128)
-    wb = (owb - 1) * sx + kx
-    elems = ((h + ky) * wb            # padded x block
-             + 2 * oh * owb           # y + dy
-             + h * wb)                # out
-    return elems * cb * itemsize + (h + ky) * wb * cb * 4  # f32 acc
+    sx, sy = sliding
+    sublane = 8 * max(1, 4 // itemsize)
+    in_elems = ((oh - 1) * sy + ky) * ceil_mult(
+        (owb - 1) * sx + kx, sublane)
+    out_elems = oh * ceil_mult(owb, sublane)
+    return _LANES * (2 * itemsize * (2 * in_elems + 2 * out_elems)
+                     + 4 * (2 * in_elems + 3 * out_elems))
 
 
-def _plan_blocks(h, w_sp, c, oh, ow, window, sliding, itemsize,
-                 owb_override=None):
+def _plan_blocks(oh, ow, window, sliding, itemsize, owb_override=None):
     """(w-tiles, ow-block) fitting POOL_VMEM_BUDGET_BYTES, or None when
     the shape cannot tile (overlapping windows need the full W span).
+    A W block is a multiple of 8 output columns (W is the sublane axis
+    of the blocks) or the whole width.
 
     ``owb_override`` is a TUNED W block (docs/kernels.md
     "Autotuning"): honored only where halo-free tiling exists
-    (kx == sx, ky == sy) and the footprint fits the budget; an
-    infeasible/stale override logs a warning and falls back to the
-    static plan — it can never overflow VMEM or crash the call."""
+    (kx == sx, ky == sy), the block is sublane-aligned and the
+    footprint fits the budget; an infeasible/stale override logs a
+    warning and falls back to the static plan — it can never overflow
+    VMEM or crash the call."""
     ky, kx = window
     sx, sy = sliding
 
     def footprint(owb):
-        return pool_block_footprint(h, c, oh, owb, window, sliding,
-                                    itemsize)
+        return pool_block_footprint(oh, owb, window, sliding, itemsize)
 
     if (owb_override and 0 < owb_override < ow
             and kx == sx and ky == sy):
-        if footprint(owb_override) <= POOL_VMEM_BUDGET_BYTES:
+        if (owb_override % 8 == 0
+                and footprint(owb_override) <= POOL_VMEM_BUDGET_BYTES):
             return -(-ow // owb_override), owb_override
         import logging
         logging.getLogger("veles_tpu.tune").warning(
-            "tuned pool W block owb=%d exceeds the VMEM budget for "
-            "this shape; using the static plan", owb_override)
+            "tuned pool W block owb=%d is unaligned or exceeds the "
+            "VMEM budget for this shape; using the static plan",
+            owb_override)
     if footprint(ow) <= POOL_VMEM_BUDGET_BYTES:
         return 1, ow
     if kx != sx or ky != sy:
         return None  # overlapping windows: no halo-free W tiling
-    owb = ow
-    while owb > 1 and footprint(owb) > POOL_VMEM_BUDGET_BYTES:
-        owb = -(-owb // 2)
-    if footprint(owb) > POOL_VMEM_BUDGET_BYTES:
-        return None
-    return -(-ow // owb), owb
+    for owb in range((ow - 1) // 8 * 8, 0, -8):
+        if footprint(owb) <= POOL_VMEM_BUDGET_BYTES:
+            return -(-ow // owb), owb
+    return None
+
+
+def pool_bwd_route(x_shape, window, sliding, dtype):
+    """"pallas" or "autodiff": the road :func:`max_pool_bwd` takes for
+    this input shape (the static plan; a tuned W block changes the
+    tiling, never the road)."""
+    from veles_tpu.models.pooling import _out_len
+    ky, kx = window
+    sx, sy = sliding
+    _n, h, w_sp, _c = x_shape
+    plan = _plan_blocks(_out_len(h, ky, sy), _out_len(w_sp, kx, sx),
+                        window, sliding, jnp.dtype(dtype).itemsize)
+    return "autodiff" if plan is None else "pallas"
 
 
 @functools.partial(
@@ -152,10 +163,15 @@ def _max_pool_bwd_jit(x, y, dy, window, sliding, interpret, owb=None):
     n, h, w_sp, c = x.shape
     oh, ow = y.shape[1], y.shape[2]
 
-    plan = _plan_blocks(h, w_sp, c, oh, ow, window, sliding,
+    plan = _plan_blocks(oh, ow, window, sliding,
                         jnp.dtype(x.dtype).itemsize, owb_override=owb)
     if plan is None:
-        # VMEM-infeasible overlapping shape: stock autodiff routing
+        # VMEM-infeasible overlapping shape: stock autodiff routing —
+        # said once per trace, never per step
+        logging.getLogger("veles_tpu.ops").info(
+            "pool_bwd: %s input with a %s window / %s stride exceeds "
+            "POOL_VMEM_BUDGET_BYTES untiled and cannot tile; keeping "
+            "the stock autodiff backward", x.shape, window, sliding)
         from veles_tpu.models.pooling import MaxPooling
 
         def pool(x_):
@@ -171,12 +187,11 @@ def _max_pool_bwd_jit(x, y, dy, window, sliding, interpret, owb=None):
     # W coverage: full need_w when untiled; owb*sx per tile when tiled
     # (tiling only happens for kx == sx, where need_w == ow*sx exactly,
     # so block offsets are exact multiples of the block width)
-    bwx = need_w = (ow - 1) * sx + kx
+    bwx = (ow - 1) * sx + kx
     if n_wtiles > 1:
         bwx = owb * sx
     xw_total = n_wtiles * bwx
     neg_inf = jnp.asarray(-jnp.inf, x.dtype)
-    cb = ceil_mult(c, 128)
     # -inf padding everywhere a real (ceil-mode) window can peek past
     # the input — reduce_window's init semantics, so a padded cell can
     # never be selected over a real window max.  Channel padding is
@@ -185,29 +200,28 @@ def _max_pool_bwd_jit(x, y, dy, window, sliding, interpret, owb=None):
     xp = lax.pad(x, neg_inf,
                  [(0, 0, 0), (0, need_h - h, 0),
                   (0, xw_total - w_sp, 0), (0, 0, 0)])
-    xp = pad_to(xp, (None, None, None, cb))
-    y_p = pad_to(y, (None, None, owb, cb))
-    dy_p = pad_to(dy, (None, None, owb, cb))
+    xp = pad_to(xp, (None, None, None, _LANES))
+    y_p = pad_to(y, (None, None, owb, _LANES))
+    dy_p = pad_to(dy, (None, None, owb, _LANES))
+    cp = xp.shape[3]
+
+    def block(rows, cols):
+        return pl.BlockSpec((1, rows, cols, _LANES),
+                            lambda i, j, k: (i, 0, k, j))
 
     out = pl.pallas_call(
         functools.partial(
             _pool_bwd_kernel, window=window, sliding=sliding,
-            out_h=oh, out_w=owb, in_h=h,
-            in_w=w_sp if n_wtiles == 1 else bwx),
-        grid=(n, n_wtiles),
-        in_specs=[
-            pl.BlockSpec((1, need_h, bwx, cb),
-                         lambda i, j: (i, 0, j, 0)),
-            pl.BlockSpec((1, oh, owb, cb), lambda i, j: (i, 0, j, 0)),
-            pl.BlockSpec((1, oh, owb, cb), lambda i, j: (i, 0, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, w_sp if n_wtiles == 1 else bwx,
-                                cb),
-                               lambda i, j: (i, 0, j, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (n, h, w_sp if n_wtiles == 1 else xw_total, cb), x.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel")),
+            out_h=oh, out_w=owb),
+        grid=(n, cp // _LANES, n_wtiles),
+        in_specs=[block(need_h, bwx), block(oh, owb), block(oh, owb)],
+        out_specs=block(need_h, bwx),
+        out_shape=jax.ShapeDtypeStruct((n, need_h, xw_total, cp),
+                                       x.dtype),
+        scratch_shapes=[pltpu.VMEM((need_h, bwx, _LANES), jnp.float32),
+                        pltpu.VMEM((need_h, bwx, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(xp, y_p, dy_p)
     return unpad(out, (n, h, w_sp, c))

@@ -14,8 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from veles_tpu.ops.common import (ceil_mult, interpret_for, pad_to,
-                                   tpu_compiler_params)
+from veles_tpu.ops.common import ceil_mult, interpret_for, pad_to
 
 __all__ = ["reduce_rows", "reduce_cols"]
 
@@ -51,7 +50,7 @@ def reduce_cols(x, block=512):
         out_specs=pl.BlockSpec((1, np_), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, np_), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret_for(x),
     )(x)
@@ -89,7 +88,7 @@ def reduce_rows(x, block=512):
         out_specs=pl.BlockSpec((mp, 1), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, 1), x.dtype),
         scratch_shapes=[pltpu.VMEM((mp, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret_for(x),
     )(x)

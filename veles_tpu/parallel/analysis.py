@@ -29,11 +29,16 @@ def _base(kind):
 
 def parse_collective_ops(hlo_text, kinds=_COLLECTIVES):
     """Per-OP collective inventory of optimized HLO text: a list of
-    ``{"kind", "bytes"}`` in program order.  This is how the bucketed
-    gradient all-reduce is audited (scripts/scaling.py, the dist smoke
-    test): the flat path shows ONE ~250 MB all-reduce, the bucketed
-    path one op per bucket — if XLA's combiner ever re-fuses them, the
-    op count collapses and the regression is visible here."""
+    ``{"kind", "bytes", "parts", "elems"}`` in program order,
+    ``parts``/``elems`` the byte size and element count of each
+    operand of a tuple-shaped op (XLA:CPU widens a bfloat16 all-reduce
+    to float32, so element counts are what compares across
+    platforms).  This is how the bucketed gradient all-reduce is
+    audited (scripts/scaling.py, the dist smoke test): the flat path
+    moves ONE gradient payload, the bucketed path one per bucket.  XLA's all-reduce combiner may still
+    issue several payloads as one tuple op (XLA:CPU does, for KB-sized
+    buckets) — the op count then collapses while ``parts`` keeps every
+    bucket visible."""
     ops = []
     for line in hlo_text.splitlines():
         if "=" not in line:
@@ -42,7 +47,7 @@ def parse_collective_ops(hlo_text, kinds=_COLLECTIVES):
             if kind not in line:
                 continue
             shapes_part = line.split("=", 1)[1].split(kind, 1)[0]
-            nbytes = 0
+            parts, elems = [], []
             for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", shapes_part):
                 if dt not in _DTYPE_BYTES:
                     continue
@@ -50,8 +55,10 @@ def parse_collective_ops(hlo_text, kinds=_COLLECTIVES):
                 for d in dims.split(","):
                     if d:
                         count *= int(d)
-                nbytes += count * _DTYPE_BYTES[dt]
-            ops.append({"kind": _base(kind), "bytes": nbytes})
+                elems.append(count)
+                parts.append(count * _DTYPE_BYTES[dt])
+            ops.append({"kind": _base(kind), "bytes": sum(parts),
+                        "parts": parts, "elems": elems})
             break
     return ops
 

@@ -52,13 +52,6 @@ __all__ = ["DEFAULT_BUCKET_MB", "Bucket", "BucketPlan", "plan_buckets",
 #: of the backward is still outstanding.
 DEFAULT_BUCKET_MB = 25.0
 
-# jax API drift guard: optimization_barrier moved/appeared across
-# releases; without it the buckets still all-reduce correctly, XLA is
-# just free to re-combine them (the dist smoke test will catch that
-# on toolchains where it matters)
-_opt_barrier = getattr(lax, "optimization_barrier", None)
-
-
 class Bucket(object):
     """One all-reduce payload: contiguous element spans of flattened
     gradient leaves.  ``slices`` holds ``(leaf_index, start, stop)``
@@ -204,8 +197,8 @@ def bucketed_all_reduce(grads, axis_name, bucket_bytes=None, plan=None,
         parts = [flats[i][start:stop]
                  for (i, start, stop) in bucket.slices]
         vec = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        if chain and token is not None and _opt_barrier is not None:
-            vec, _ = _opt_barrier((vec, token))
+        if chain and token is not None:
+            vec, _ = lax.optimization_barrier((vec, token))
         vec = _reduce_one(vec, axis_name, impl, compress, axis_size)
         token = vec
         offset = 0
@@ -293,8 +286,8 @@ def chained_reduce_scatter(mats, axis_name, chain=True):
     out = []
     token = None
     for mat in mats:
-        if chain and token is not None and _opt_barrier is not None:
-            mat, _ = _opt_barrier((mat, token))
+        if chain and token is not None:
+            mat, _ = lax.optimization_barrier((mat, token))
         part = lax.psum_scatter(mat, axis_name, scatter_dimension=0,
                                 tiled=True)
         token = part

@@ -10,33 +10,11 @@ VELES_COORDINATOR is set) and get the same global view.
 import numpy
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh
 
 __all__ = ["make_mesh", "auto_mesh", "shard_map", "zero_slot_table",
            "zero_state", "unzero_state", "MeshManager", "mesh_snapshot"]
-
-# jax moved shard_map from jax.experimental.shard_map to the top-level
-# namespace (and renamed check_rep -> check_vma) across releases;
-# resolve whichever this jax ships and normalize the kwarg
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = _inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    if check_vma is not None:
-        key = ("check_vma" if "check_vma" in _SHARD_MAP_PARAMS
-               else "check_rep")
-        kwargs[key] = check_vma
-    # mesh by KEYWORD: the top-level API makes it keyword-only, and the
-    # experimental one accepts it either way
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
 
 
 def make_mesh(axes, devices=None):

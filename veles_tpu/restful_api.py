@@ -32,8 +32,6 @@ class RESTfulAPI(Unit):
         self.ladder = tuple(kwargs.get("ladder", (1, 8, 32, 128)))
         self.max_delay_s = kwargs.get("max_delay_s", 0.002)
         self.max_queue = kwargs.get("max_queue", 256)
-        self.cache_root = kwargs.get("cache_root")
-        self.persistent_cache = kwargs.get("persistent_cache", False)
         self.slo_p50_ms = kwargs.get("slo_p50_ms")
         self.slo_p99_ms = kwargs.get("slo_p99_ms")
         self.engine = None
@@ -50,9 +48,7 @@ class RESTfulAPI(Unit):
         from veles_tpu.serve import AOTEngine, ServeService
         loader = getattr(self.workflow, "loader", None)
         self.engine = AOTEngine.from_workflow(
-            self.workflow, ladder=self.ladder,
-            cache_root=self.cache_root,
-            persistent_cache=self.persistent_cache)
+            self.workflow, ladder=self.ladder)
         self.engine.compile()
         self._service_ = ServeService(
             self.engine, port=self.port, path=self.path,

@@ -5,8 +5,8 @@ inference runtime — rebuilt TPU-idiomatically in three layers:
 
 - :mod:`veles_tpu.serve.engine` — :class:`AOTEngine`: ahead-of-time
   compiled executables over a ladder of padded batch shapes, backed by
-  a persistent, model-digest-keyed XLA compilation cache so a restarted
-  server performs 0 new backend compiles (receipt:
+  the persistent XLA compilation cache so a restarted server performs
+  0 new backend compiles (receipt:
   ``engine.compile_receipt`` via the ``compile.count`` /
   ``compile.cache_hits`` counters);
 - :mod:`veles_tpu.serve.batcher` — :class:`ContinuousBatcher`: a worker
@@ -61,8 +61,7 @@ from veles_tpu.serve.qos import (  # noqa: F401
 from veles_tpu.serve.batcher import (  # noqa: F401
     ContinuousBatcher, ServeOverload, serve_snapshot)
 from veles_tpu.serve.engine import (  # noqa: F401
-    AOTEngine, DEFAULT_LADDER, enable_persistent_cache, model_digest,
-    value_digest)
+    AOTEngine, DEFAULT_LADDER, model_digest, value_digest)
 from veles_tpu.serve.fleet import (  # noqa: F401
     FleetRequest, FleetRouter, HostLink)
 from veles_tpu.serve.freshness import (  # noqa: F401
@@ -85,7 +84,6 @@ __all__ = ["AOTEngine", "BinaryTransportClient",
            "ServeService", "SnapshotWatcher", "TenantQuota",
            "TokenBucket", "DEFAULT_CLASS", "DEFAULT_LADDER",
            "SHED_ORDER", "SLO_CLASSES", "decode_tensor",
-           "enable_persistent_cache", "encode_tensor",
-           "export_model_spec", "format_result", "local_devices",
+           "encode_tensor", "export_model_spec", "format_result", "local_devices",
            "model_digest", "normalize_class", "parse_quota_spec",
            "serve_snapshot", "value_digest"]

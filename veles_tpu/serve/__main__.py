@@ -2,11 +2,10 @@
 
 Serves a trained workflow snapshot (the crash-consistent pickles
 ``snapshotter.py`` writes) behind one AOT engine + continuous batcher
-REPLICA per visible device (``--replicas`` overrides), with the
-persistent compilation cache ON by default so a restart of this
-process performs zero new backend compiles — and, because all replicas
-share the digest-keyed cache, a warm fleet start costs one compile
-set, not N:
+REPLICA per visible device (``--replicas`` overrides), against the
+persistent compilation cache (``JAX_COMPILATION_CACHE_DIR``, else
+``.veles_cache/jax_cache`` in the checkout), so a restart of this
+process performs zero new backend compiles:
 
     python -m veles_tpu.serve --snapshot mnist_current.pickle \\
         --port 8080 --transport-port 8081 \\
@@ -102,10 +101,6 @@ def build_parser():
                         help="max continuous-batching queue delay")
     parser.add_argument("--max-queue", type=int, default=256,
                         help="pending-request bound before 503 shedding")
-    parser.add_argument("--cache-root", default=None,
-                        help="persistent compile-cache root (default: "
-                        "~/.cache/veles_tpu/serve_cache; 'none' "
-                        "disables)")
     parser.add_argument("--slo-p50-ms", type=float, default=None)
     parser.add_argument("--slo-p99-ms", type=float, default=None)
     parser.add_argument("--watch-dir", default=None, metavar="DIR",
@@ -321,16 +316,10 @@ def main(argv=None):
 
     from veles_tpu.serve import ReplicaPool, ServeService
     ladder = tuple(int(b) for b in args.ladder.split(","))
-    cache_kwargs = {}
-    if args.cache_root != "none":
-        cache_kwargs["persistent_cache"] = True
-        if args.cache_root:
-            cache_kwargs["cache_root"] = args.cache_root
     pool_kwargs = dict(
         replicas=args.replicas, ladder=ladder,
         max_delay_s=args.max_delay_ms / 1e3, max_queue=args.max_queue,
-        slo_p50_ms=args.slo_p50_ms, slo_p99_ms=args.slo_p99_ms,
-        **cache_kwargs)
+        slo_p50_ms=args.slo_p50_ms, slo_p99_ms=args.slo_p99_ms)
     if args.quantize:
         plans, qparams, sample_shape = _quantize_spec(sw, args)
         pool = ReplicaPool(plans, qparams, sample_shape, **pool_kwargs)

@@ -205,6 +205,9 @@ class ContinuousBatcher(Logger):
             "serve.replica.%d" % replica
         self._m_depth = _registry.gauge(scope + ".queue_depth")
         self._g_rung_cap = _registry.gauge(scope + ".rung_cap")
+        # published from the start: "never degraded" must read as the
+        # top rung, not as a gauge nobody set
+        self._g_rung_cap.set(self._rung_cap)
         self._m_batch = _registry.histogram("serve.batch_size")
         self._m_latency = _registry.histogram("serve.latency_s")
         self._m_requests = _registry.counter("serve.requests")

@@ -43,7 +43,7 @@ that tier (docs/serving.md "Multi-host tier"):
   ``serve.fleet.requeues`` — transparently to the waiting client).
 - **re-warm before rotation**: a (re)joining host's hello carries its
   pool's compile-receipt summary; a host that restarted against the
-  shared digest-keyed persistent cache reports ``new_compiles == 0``
+  shared persistent compile cache reports ``new_compiles == 0``
   — the receipt the rejoin test and the soak assert before the router
   counts the host live.
 

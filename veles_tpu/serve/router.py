@@ -12,10 +12,10 @@ device plus its own :class:`ContinuousBatcher` worker — and the
 
 - **placement**: one replica per ``jax.local_devices()`` entry by
   default (``replicas=`` overrides; the CPU harness cycles devices),
-  every engine keyed to the SAME model digest so the persistent
-  compile cache makes a warm fleet restart compile NOTHING — the cold
-  fleet start is the only one that pays, and pays per device because
-  jax's cache key includes the device assignment;
+  every engine compiling the SAME programs, so the persistent compile
+  cache makes a warm fleet restart compile NOTHING — the cold fleet
+  start is the only one that pays, and pays per device because jax's
+  cache key includes the device assignment;
 - **routing**: each request goes to the least-loaded replica (queue
   depth at submit); an overloaded replica cascades the request to its
   siblings before the pool sheds with a 503-shaped
@@ -68,11 +68,12 @@ def local_devices(count=None):
 
     from veles_tpu.backends import Device
     jax_devices = jax.local_devices()
-    backend = "cpu" if jax_devices[0].platform == "cpu" else "tpu"
     n = int(count) if count else len(jax_devices)
     if n < 1:
         raise ValueError("need at least one replica")
-    return [Device(backend=backend,
+    # the platform NAME is the backend: a platform no backend is
+    # registered for raises here, it is never taken for a TPU
+    return [Device(backend=jax_devices[0].platform,
                    device_index=i % len(jax_devices))
             for i in range(n)]
 
@@ -473,18 +474,15 @@ class ReplicaPool(Logger):
     single batcher identically."""
 
     def __init__(self, plans, params, sample_shape, replicas=None,
-                 ladder=DEFAULT_LADDER, devices=None, cache_root=None,
-                 persistent_cache=False, dtype=numpy.float32,
-                 **batcher_kwargs):
+                 ladder=DEFAULT_LADDER, devices=None,
+                 dtype=numpy.float32, **batcher_kwargs):
         super(ReplicaPool, self).__init__()
         if devices is None:
             devices = local_devices(replicas)
         elif replicas:
             devices = [devices[i % len(devices)]
                        for i in range(int(replicas))]
-        self._engine_kwargs = dict(
-            ladder=ladder, cache_root=cache_root,
-            persistent_cache=persistent_cache, dtype=dtype)
+        self._engine_kwargs = dict(ladder=ladder, dtype=dtype)
         self._batcher_kwargs = dict(batcher_kwargs)
         self.replicas = []
         for i, device in enumerate(devices):
@@ -568,11 +566,11 @@ class ReplicaPool(Logger):
 
     def compile(self):
         """Compile every replica's ladder; returns the aggregate
-        receipt.  All replicas share the ONE digest-keyed persistent
-        cache directory; jax's cache key includes the device
-        assignment, so a cold fleet start writes one entry set per
-        device — and a warm fleet RESTART deserializes every one of
-        them: ``new_compiles == 0`` across all N replicas, asserted by
+        receipt.  All replicas share the ONE persistent cache
+        directory; jax's cache key includes the device assignment, so
+        a cold fleet start writes one entry set per device — and a
+        warm fleet RESTART deserializes every one of them:
+        ``new_compiles == 0`` across all N replicas, asserted by
         tests/test_serve_router.py."""
         start = time.perf_counter()
         per = [rep.engine.compile() for rep in self.replicas]

@@ -260,8 +260,7 @@ class ServeService(Logger):
         and cuts the batcher over between batches.  The single-engine
         service is simply a fleet of one entry — same receipt, same
         lock discipline, and the replacement engine inherits the
-        current one's ladder/dtype/cache_root so a later warm restart
-        still hits the configured cache."""
+        current one's ladder and dtype."""
         if self.router is not None:
             receipt = self.router.reload(
                 params, plans=plans, sample_shape=sample_shape)
@@ -275,9 +274,7 @@ class ServeService(Logger):
                 [entry], params, plans=plans,
                 sample_shape=sample_shape,
                 engine_kwargs=dict(
-                    ladder=current.ladder, dtype=current.dtype,
-                    cache_root=current.cache_root,
-                    persistent_cache=current.cache_dir is not None))
+                    ladder=current.ladder, dtype=current.dtype))
             self._engine = entry.engine
         self.last_reload = receipt
         return receipt

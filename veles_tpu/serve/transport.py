@@ -444,7 +444,7 @@ class BinaryTransportServer(Logger):
             if self.host_meta is not None:
                 # fleet-host identity + the re-warm receipt: a
                 # rejoining host proves it deserialized its ladder
-                # from the shared digest-keyed cache (new_compiles 0)
+                # from the shared persistent cache (new_compiles 0)
                 # before the router puts it back in rotation
                 host = dict(self.host_meta)
                 receipt = getattr(self.pool, "compile_receipt", None) \

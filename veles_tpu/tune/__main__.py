@@ -100,7 +100,9 @@ def _parser():
     parser.add_argument("--rounds", type=int, default=3,
                         help="interleaved passes per generation")
     parser.add_argument("--workers", type=int, default=0,
-                        help="process-pool evaluators")
+                        help="process-pool evaluators (CPU-pinned: "
+                        "compile fitness only; a chip belongs to one "
+                        "process)")
     parser.add_argument("--farm-slaves", type=int, default=0,
                         help="local control-plane farm workers")
     parser.add_argument("--farm-address", default="127.0.0.1:0")

@@ -10,8 +10,8 @@ Fitness = **negative measured seconds per kernel execution**, under
 the shared measurement discipline (``tune/measure.py``): the
 in-process path evaluates a whole GA generation's candidates with
 interleaved round-robin slope sampling — one sample of EVERY candidate
-per pass, ``filter_passes``/positive-majority ranking — so a
-congestion window cannot crown the wrong tile (the hazard
+per pass, ``filter_passes``/positive-majority ranking — so a drift
+in machine load cannot crown the wrong tile (the hazard
 ``ops/matmul.py`` documents).  Candidate schedules are quantized to
 MXU-legal multiples and VMEM-checked BEFORE any compile; duplicate or
 clamped-identical genomes hit the schedule-keyed fitness memo (plus
@@ -19,9 +19,11 @@ GeneticsOptimizer's own values-keyed memo) and never pay a second
 compile.
 
 Evaluator plumbing mirrors the GA's: ``workers=N`` uses the process
-pool, ``farm_slaves``/``farm_address`` the control-plane job farm
-(remote hosts join via :func:`GeneticsOptimizer.worker` quoting
-:func:`evaluate_candidate`) — a fleet can tune in parallel.  Those
+pool — CPU-pinned children, so compile-fitness only: measured fitness
+on a chip refuses it, the chip belongs to this process —
+``farm_slaves``/``farm_address`` the control-plane job farm (remote
+hosts with their own chips join via :func:`GeneticsOptimizer.worker`
+quoting :func:`evaluate_candidate`) — a fleet can tune in parallel.  Those
 paths score candidates independently (each with its own multi-pass
 filtered timing); only the in-process default gets cross-candidate
 interleaving.
@@ -202,6 +204,21 @@ class ScheduleTuner(Logger):
                          "using fitness=%r for the pool/farm run",
                          model_base)
             self.fitness_mode = model_base
+        if workers and self.fitness_mode == "measure":
+            import jax
+            if jax.default_backend() != "cpu":
+                # the pool's workers are pinned to the CPU (a chip
+                # belongs to one process — genetics/optimizer.py): they
+                # would time the Pallas interpreter and persist its
+                # ranking under this chip's device kind
+                raise ValueError(
+                    "workers=%d cannot time kernels for a %s chip: the "
+                    "chip belongs to this process and pool workers run "
+                    "on the CPU.  Time in-process (workers=0: one "
+                    "interleaved sample of every candidate per pass), "
+                    "or farm over hosts that have their own chips "
+                    "(farm_slaves / tune --worker)"
+                    % (workers, self.device_kind))
         self._model = None
         self._model_info = None
         self._best_measured = (PENALTY, None)
@@ -279,7 +296,7 @@ class ScheduleTuner(Logger):
         if mode == "compile":
             ranked = {key: compile_s[key] for key in runners}
         else:
-            # ONE sample of every candidate per pass: congestion drift
+            # ONE sample of every candidate per pass: load drift
             # spreads across all candidates equally
             samples = _measure.interleaved_slopes(
                 runners, 1, self.repeats + 1, rounds=self.rounds)

@@ -481,7 +481,9 @@ class AttentionFamily(object):
 class PoolBwdFamily(object):
     """Output-width block (W tiling) of the pool select-and-scatter
     backward.  Only non-overlapping windows (kx == sx, ky == sy) admit
-    halo-free W tiling, so overlapping shapes are untunable."""
+    halo-free W tiling, so overlapping shapes are untunable.  W is the
+    sublane axis of the kernel's blocks: a block is a multiple of 8
+    output columns or the whole width."""
 
     name = "pool_bwd"
 
@@ -493,16 +495,16 @@ class PoolBwdFamily(object):
 
     def quantize(self, spec, genes):
         ow = spec["shape"][5]
-        owb = int(round(float(genes["owb"])))
-        return {"owb": max(1, min(ow, owb))}
+        owb = int(round(float(genes["owb"]) / 8.0)) * 8
+        return {"owb": ow if owb >= ow else max(8, owb)}
 
     def footprint(self, spec, schedule):
         # the kernel planner's OWN footprint formula — shared, so the
         # feasibility gate can never drift from what Mosaic gets
         from veles_tpu.ops.pool_bwd import pool_block_footprint
-        n, h, w_sp, c, oh, ow, ky, kx, sy, sx = spec["shape"]
+        _n, _h, _w, _c, oh, _ow, ky, kx, sy, sx = spec["shape"]
         return pool_block_footprint(
-            h, c, oh, schedule["owb"], (ky, kx), (sx, sy),
+            oh, schedule["owb"], (ky, kx), (sx, sy),
             _itemsize(spec["dtype"]))
 
     def feasible(self, spec, schedule):
@@ -512,8 +514,9 @@ class PoolBwdFamily(object):
 
     def seeds(self, spec):
         ow = spec["shape"][5]
-        owbs = sorted({ow, -(-ow // 2), -(-ow // 4), 1}, reverse=True)
-        return [{"owb": owb} for owb in owbs if owb >= 1]
+        owbs = {self.quantize(spec, {"owb": owb})["owb"]
+                for owb in (ow, -(-ow // 2), -(-ow // 4), 8)}
+        return [{"owb": owb} for owb in sorted(owbs, reverse=True)]
 
     def default(self, spec):
         ow = spec["shape"][5]
