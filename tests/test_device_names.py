@@ -1,0 +1,221 @@
+"""The names the device trace and ``compiled.as_text()`` show: every
+Pallas kernel's ``name=`` constant is its HLO instruction name
+(``%veles_conv_wgrad``, the key of an ``XLA Ops`` event), and the fused
+step's ``jax.named_scope``s (``l<k>_<layer type>``, ``loss``,
+``update``, the loader's ``loader_gather``) ride in ``op_name``.
+
+Compiled here for a DESCRIBED v5e (no chip attached; the
+on-chip-measurement guide, section 2): the topology is described inside
+a module-scoped fixture, never at import, and every such compile lives
+in this one file.  The scopes change metadata only: the last test
+proves loss and gradients bit-identical with and without them."""
+
+import contextlib
+import re
+
+import numpy
+import pytest
+
+from veles_tpu import compiler
+from veles_tpu.models import zoo
+from veles_tpu.ops import common, conv_vjp, gather, pool_bwd
+
+#: conv + pool + dense, so both backward kernels are in the step
+TOY_CNN = [
+    {"type": "conv_str", "n_kernels": 8, "kx": 3, "ky": 3, "padding": 1,
+     "learning_rate": 0.01, "gradient_moment": 0.9},
+    {"type": "max_pooling", "kx": 3, "ky": 3, "sliding": (2, 2)},
+    {"type": "all2all_tanh", "output_sample_shape": 16,
+     "learning_rate": 0.01, "gradient_moment": 0.9},
+    {"type": "softmax", "output_sample_shape": 4,
+     "learning_rate": 0.01, "gradient_moment": 0.9},
+]
+TOY_INPUT = (14, 14, 3)
+TOY_SCOPES = ("l0_ConvStrictRELU", "l1_MaxPooling", "l2_All2AllTanh",
+              "l3_All2AllSoftmax")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % exc)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernels as the chip gets them: Mosaic (no interpreter), the
+    hand-scheduled backward on, and a silent compile cache (a deviceless
+    compile can be written to the persistent cache but not read back)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(common, "interpret_mode", lambda: False)
+    monkeypatch.setattr(common, "PALLAS_BWD_ENV", "1")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compiled_text(fn, sharding, *avals):
+    """``compiled.as_text()`` of ``fn`` for the described chip."""
+    import jax
+
+    def place(aval):
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                    sharding=sharding)
+
+    avals = jax.tree.map(place, avals)
+    return jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+
+
+def mosaic_calls(text):
+    """{instruction name: op_name} of the text's Mosaic custom calls."""
+    found = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = line.split(" = ", 1)[0].split()[-1]
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        found[name] = op_name.group(1) if op_name else ""
+    return found
+
+
+def aval(shape, dtype):
+    import jax
+    return jax.ShapeDtypeStruct(shape, numpy.dtype(dtype))
+
+
+def test_conv_wgrad_is_named_at_alexnet_conv2(topo, one_chip, mosaic):
+    """AlexNet conv2 at batch 256, bfloat16: 5x5 over 27x27x96 -> 256,
+    the step's second-largest cost (PERF.md section 5)."""
+    import jax.numpy as jnp
+    x = aval((256, 27, 27, 96), jnp.bfloat16)
+    y = aval((256, 27, 27, 256), jnp.bfloat16)
+
+    def wgrad(x, y, dy):
+        return conv_vjp._fused_wgrad_jit(
+            x, y, dy, "strict_relu", 5, 5, (27, 27), (2, 2, 2, 2),
+            (1, 1), 0, None, False)
+
+    calls = mosaic_calls(compiled_text(wgrad, one_chip, x, y, y))
+    assert len(calls) == 1, calls
+    (name, op_name), = calls.items()
+    assert re.match(r"^%veles_conv_wgrad(\.\d+)?$", name), name
+    assert conv_vjp.KERNEL_NAME in op_name
+
+
+def test_pool_bwd_is_named_at_alexnet_pool1(topo, one_chip, mosaic):
+    """AlexNet's first pooling at batch 256: 3x3 stride 2 over
+    55x55x96, whose backward feeds conv2's."""
+    import jax.numpy as jnp
+    x = aval((256, 55, 55, 96), jnp.bfloat16)
+    y = aval((256, 27, 27, 96), jnp.bfloat16)
+
+    def bwd(x, y, dy):
+        return pool_bwd._max_pool_bwd_jit(x, y, dy, (3, 3), (2, 2),
+                                          False)
+
+    calls = mosaic_calls(compiled_text(bwd, one_chip, x, y, y))
+    assert len(calls) == 1, calls
+    (name, op_name), = calls.items()
+    assert re.match(r"^%veles_pool_bwd(\.\d+)?$", name), name
+    assert pool_bwd.KERNEL_NAME in op_name
+
+
+def toy_step(batch=8):
+    import jax
+    plans, state, _ = zoo.build_plans_and_state(TOY_CNN, TOY_INPUT,
+                                                seed=3)
+    step = compiler._build_step_fn(plans, "softmax")
+    shapes = jax.tree.map(lambda leaf: aval(leaf.shape, leaf.dtype),
+                          state)
+    return plans, state, step, (
+        shapes, aval((batch,) + TOY_INPUT, numpy.float32),
+        aval((batch,), numpy.int32), aval((), numpy.float32))
+
+
+def test_fused_step_names_kernels_layers_and_phases(topo, one_chip,
+                                                    mosaic):
+    """Through jit, value_and_grad (jvp + transpose) and custom_vjp:
+    the kernels keep their names, and each sits under its layer's
+    scope."""
+    _, _, step, avals = toy_step()
+    text = compiled_text(step, one_chip, *avals)
+    calls = mosaic_calls(text)
+    by_kernel = {name.lstrip("%").split(".")[0]: op_name
+                 for name, op_name in calls.items()}
+    assert set(by_kernel) == {conv_vjp.KERNEL_NAME,
+                              pool_bwd.KERNEL_NAME}, calls
+    # a backward op's scope reads transpose(jvp(<the layer's scope>))
+    assert "jvp(l0_ConvStrictRELU)" in by_kernel[conv_vjp.KERNEL_NAME]
+    assert "jvp(l1_MaxPooling)" in by_kernel[pool_bwd.KERNEL_NAME]
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in TOY_SCOPES + ("loss", "update"):
+        assert any(re.search(r"[/(]%s[/)]" % scope, name)
+                   for name in op_names), scope
+
+
+def test_loader_gather_is_named_and_scoped(topo, one_chip, mosaic):
+    """The loader's gather programs: ``jit_gather_minibatch`` and
+    ``jit_gather_labels`` (the names the benchmark's reduction reads)
+    with every op under ``loader_gather``."""
+    import jax
+    rows = aval((4096, 1024), numpy.float32)
+    idx = aval((128,), numpy.int32)
+
+    def lowered(fn, *avals):
+        avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in avals]
+        return fn.trace(*avals).lower(lowering_platforms=("tpu",))
+
+    data = lowered(gather.gather_minibatch, rows, idx)
+    text = data.compile().as_text()
+    assert "HloModule jit_gather_minibatch" in text
+    (name, op_name), = mosaic_calls(text).items()
+    assert re.match(r"^%veles_gather_rows(\.\d+)?$", name), name
+    assert "/loader_gather/" in op_name
+    labels = lowered(gather.gather_labels,
+                     aval((4096,), numpy.int32), idx)
+    text = labels.compile().as_text()
+    assert "HloModule jit_gather_labels" in text
+    assert "/loader_gather/" in text
+
+
+def test_scopes_leave_loss_and_gradients_bit_identical(monkeypatch):
+    """CPU, interpreter kernels: the same step traced with
+    ``jax.named_scope`` a no-op gives the same bits."""
+    import jax
+    monkeypatch.setattr(common, "PALLAS_BWD_ENV", "1")
+    rng = numpy.random.RandomState(5)
+    x = rng.randn(8, *TOY_INPUT).astype(numpy.float32)
+    labels = rng.randint(0, 4, 8).astype(numpy.int32)
+
+    def run():
+        _, state, step, _ = toy_step()
+        new_state, metrics = jax.jit(step)(state, x, labels,
+                                           numpy.float32(8))
+        return jax.device_get((new_state, metrics))
+
+    scoped = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = run()
+    leaves_a, tree_a = jax.tree.flatten(scoped)
+    leaves_b, tree_b = jax.tree.flatten(plain)
+    assert tree_a == tree_b and len(leaves_a) > 10
+    for a, b in zip(leaves_a, leaves_b):
+        assert numpy.asarray(a).tobytes() == numpy.asarray(b).tobytes()
+    assert numpy.isfinite(scoped[1]["loss"]) and scoped[1]["loss"] > 0

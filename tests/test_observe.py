@@ -80,25 +80,89 @@ def test_validate_trace_rejects_overlapping_spans():
         validate_trace(doc)
 
 
-def test_traced_decorator_and_threads_get_own_tracks():
-    tracer = SpanTracer().start()
+def test_scope_feeds_every_sink_and_threads_get_own_tracks():
+    """The one primitive: one measurement lands in the histogram, the
+    timer, the trace (with the span's parent) and the flight ring; a
+    scope opened on another thread gets its own track and no parent
+    from this one."""
+    from veles_tpu.observe.flight import FlightRecorder
+    ring = FlightRecorder(capacity=16, enabled=True)
+    tracer = SpanTracer(flight=ring).start()
+    hist = MetricsRegistry().histogram("work_s")
+    timers = {}
 
-    @tracer.traced(cat="test")
     def work():
-        time.sleep(0.001)
+        with tracer.scope("work", cat="test", hist=hist,
+                          timers=(timers, "work")) as span:
+            time.sleep(0.001)
+        return span
 
-    work()
+    with tracer.scope("outer", cat="test", args={"level": 1}) as outer:
+        inner = work()
+        tracer.complete("stamped", inner.start, inner.elapsed / 2)
     thread = threading.Thread(target=work, name="observe-worker")
     thread.start()
     thread.join()
     tracer.stop()
+    # one measurement, every sink
+    assert hist.count == 2 and inner.elapsed >= 0.001
+    assert timers["work"] == pytest.approx(hist.total)
     spans = [e for e in tracer.events if e["ph"] == "X"]
-    assert len(spans) == 2
-    assert all("work" in e["name"] for e in spans)
-    assert len({e["tid"] for e in spans}) == 2
+    by_name = {}
+    for event in spans:
+        by_name.setdefault(event["name"], []).append(event)
+    assert sorted(by_name) == ["outer", "stamped", "work"]
+    mine, theirs = by_name["work"]
+    assert mine["dur"] == pytest.approx(inner.elapsed * 1e6)
+    # the parent is the enclosing open scope ON THAT THREAD
+    root, = by_name["outer"]
+    assert root["parent"] is None and root["args"] == {"level": 1}
+    assert mine["parent"] == root["sid"] == outer.sid
+    assert by_name["stamped"][0]["parent"] == root["sid"]
+    assert theirs["parent"] is None and theirs["tid"] != mine["tid"]
+    assert len({e["sid"] for e in spans}) == len(spans) == 4
     names = [e["args"]["name"] for e in tracer.events
              if e["ph"] == "M" and e["name"] == "thread_name"]
     assert "observe-worker" in names
+    validate_trace({"traceEvents": tracer.events})
+    # the flight ring carries the parent by name
+    ring_events = {(e["name"], e.get("parent"), e["thread"] ==
+                    "observe-worker") for e in ring.snapshot()}
+    assert ("work", "outer", False) in ring_events
+    assert ("work", None, True) in ring_events
+    assert ("outer", None, False) in ring_events
+
+
+def test_scope_measures_with_every_listener_off():
+    """Tracing off, flight ring off, no profiler session: the timer and
+    the histogram still get the measurement, nothing else is built."""
+    from veles_tpu.observe.flight import FlightRecorder
+    from veles_tpu.observe.trace import profiler_live, step_annotation
+    tracer = SpanTracer(flight=FlightRecorder(enabled=False))
+    timers = {"run": 1.0}
+    with tracer.scope("quiet", timers=(timers, "run")) as span:
+        assert tracer._open_scopes() == [span]
+        assert span._note is None  # no annotation without a session
+    assert timers["run"] == 1.0 + span.elapsed
+    assert tracer.events == [] and tracer._open_scopes() == []
+    assert not profiler_live()
+    with step_annotation("train_step", 3) as nothing:
+        assert nothing is None
+
+
+def test_validate_trace_rejects_a_child_outside_its_parent():
+    doc = {"traceEvents": [
+        {"name": "a", "ph": "X", "ts": 0.0, "dur": 100.0,
+         "pid": 1, "tid": 1, "sid": 1, "parent": None},
+        {"name": "b", "ph": "X", "ts": 200.0, "dur": 10.0,
+         "pid": 1, "tid": 1, "sid": 2, "parent": 1},
+    ]}
+    with pytest.raises(ValueError, match="not inside its parent"):
+        validate_trace(doc)
+    doc["traceEvents"][1]["ts"] = 20.0
+    validate_trace(doc)
+    # a chunk that left the parent behind still validates
+    validate_trace({"traceEvents": doc["traceEvents"][1:]})
 
 
 def test_tracer_bounded_memory():
@@ -408,6 +472,143 @@ def test_smoke_trace_and_heartbeat_schema(cpu_device, tmp_path):
     assert final["workflow"] == "StandardWorkflow"
     # health counters rode the decision's class-end sync into the line
     assert final["health"].get("skip_count") == 0
+
+
+def _host_lines(xplane_path):
+    """[[(name, start_ns, end_ns, stats)]] per line of ``/host:CPU``."""
+    from jax.profiler import ProfileData
+    lines = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            lines.append([(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                           dict(e.stats)) for e in line.events])
+    return lines
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["prefetcher", "synchronous"])
+def test_spans_land_on_the_profilers_clock(cpu_device, tmp_path,
+                                           pipeline):
+    """A profiler session started by ANYONE (here the test, not
+    ProfilerHook) gets the program's spans as ``veles/<span>`` on
+    ``/host:CPU`` of the same ``*.xplane.pb`` as the device ops, nested
+    as the program nests them, a ``train_step`` step annotation per
+    train step, and the worker's stages on their own line (with a
+    Prefetcher; without one the loader's gather sits in its unit's
+    span); the Chrome-JSON trace of the same run carries every span's
+    parent."""
+    import glob
+
+    import jax
+
+    from veles_tpu.observe.trace import tracer
+    from tests.test_pipeline_input import _build_fused
+
+    registry.reset()
+    sw = _build_fused(cpu_device, pipeline, max_epochs=2)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the annotations, not every frame
+    tracer.start()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        sw.run()
+    finally:
+        jax.profiler.stop_trace()
+        tracer.stop()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    lines = _host_lines(path)
+
+    def named(line, name):
+        return [e for e in line if e[0] == name]
+
+    def inside(inner, outers):
+        return any(o[1] <= inner[1] and inner[2] <= o[2] for o in outers)
+
+    graph, = [line for line in lines if named(line, "veles/fused.dispatch")]
+    steps = named(graph, "veles/fused.train_step")
+    trainer = sw.fused_trainer
+    assert len(steps) == trainer._iteration > 0
+    units = named(graph, "veles/" + trainer.name)
+    evals = named(graph, "veles/fused.eval_step")
+    assert len(units) == len(steps) + len(evals)
+    assert all(inside(step, units) for step in steps + evals)
+    dispatches = named(graph, "veles/fused.dispatch")
+    assert len(dispatches) == len(units)
+    assert all(inside(d, steps + evals) for d in dispatches)
+    stages = named(graph, "veles/fused.stage")
+    assert len(stages) == len(units)
+    assert all(inside(s, steps + evals) for s in stages)
+    run, = named(graph, "veles/%s.run" % sw.name)
+    assert all(inside(unit, [run]) for unit in units)
+    hops = named(graph, "veles/workflow.hop")
+    assert hops and all(inside(hop, [run]) for hop in hops)
+    assert not any(inside(hop, units) for hop in hops)
+    assert named(graph, "veles/decision.sync")
+    # one step annotation per train step, around its dispatch alone
+    marks = sorted(named(graph, "train_step"), key=lambda e: e[1])
+    assert [m[3]["step_num"] for m in marks] == list(
+        range(1, trainer._iteration + 1))
+    assert all(inside(m, steps) for m in marks)
+    train_dispatches = [d for d in dispatches if inside(d, steps)]
+    assert all(inside(d, marks) for d in train_dispatches)
+    loads = named(graph, "veles/" + sw.loader.name)
+    if pipeline:
+        # the worker's stages, the gather among them: another thread's
+        # line
+        assert named(graph, "veles/pipeline.wait")
+        worker, = [line for line in lines
+                   if named(line, "veles/pipeline.fill")]
+        assert worker is not graph
+        assert named(worker, "veles/pipeline.h2d")
+        assert all(inside(g, named(worker, "veles/pipeline.fill"))
+                   for g in named(worker, "veles/loader.gather"))
+        assert not named(graph, "veles/pipeline.h2d")
+        assert not named(graph, "veles/loader.gather")
+    else:
+        assert not any(named(line, "veles/pipeline.fill")
+                       for line in lines)
+        gathers = named(graph, "veles/loader.gather")
+        assert len(gathers) == len(units)
+        assert all(inside(g, loads) for g in gathers)
+
+    # the tracer's view of the same run: a parent on every span
+    events = tracer.events
+    validate_trace({"traceEvents": events})
+    spans = [e for e in events if e["ph"] == "X"]
+    assert all("parent" in e and e["sid"] for e in spans)
+    by_sid = {e["sid"]: e for e in spans}
+
+    def parent_name(event):
+        parent = by_sid.get(event["parent"])
+        return parent and parent["name"]
+
+    for event in spans:
+        want = {"fused.dispatch": ("fused.train_step", "fused.eval_step"),
+                "fused.stage": ("fused.train_step", "fused.eval_step"),
+                "fused.train_step": (trainer.name,),
+                "workflow.hop": (sw.name + ".run",),
+                trainer.name: (sw.name + ".run",),
+                "decision.sync": (sw.decision.name,),
+                "pipeline.wait": (sw.loader.name,),
+                "pipeline.fill": (None,), "pipeline.h2d": (None,),
+                "loader.gather": ("pipeline.fill" if pipeline
+                                  else sw.loader.name,),
+                sw.name + ".run": (None,)}.get(event["name"])
+        if want is not None:
+            assert parent_name(event) in want, event
+    # the same measurement in the registry: the histograms registered
+    # at initialise hold one observation per span
+    snap = registry.snapshot()["histograms"]
+    assert snap["step.dispatch_s"]["count"] == len(steps)
+    assert snap["step.eval_dispatch_s"]["count"] == len(evals)
+    assert snap["step.stage_s"]["count"] == len(units)
+    assert snap["workflow.hop_s"]["count"] == len(hops)
+    assert snap["decision.sync_s"]["count"] == len(
+        named(graph, "veles/decision.sync"))
+    assert snap["loader.gather_s"]["count"] >= len(units)
 
 
 def test_tracing_disabled_leaves_no_events_in_step_path(cpu_device):

@@ -106,6 +106,12 @@ def adopt_state(sw, new_state, device=None):
         arr.detach_device()   # ...then collect, dropping references
 
 
+def layer_scope(index, plan):
+    """``l<index>_<layer type>``: the ``jax.named_scope`` of one layer
+    of the plan, as device traces and ``compiled.as_text()`` show it."""
+    return "l%d_%s" % (index, plan.forward_cls.__name__)
+
+
 def _forward_for_loss(plans, params, x, key=None, remat=False,
                       layer_fn=None, fold_offset=0):
     """Forward pass; returns (pre-softmax logits | final output).
@@ -136,23 +142,28 @@ def _forward_for_loss(plans, params, x, key=None, remat=False,
 
     h = x
     for i, (plan, p) in enumerate(zip(plans, params)):
-        if layer_fn is not None:
-            override = layer_fn(i, plan, p, h, key)
-            if override is not None:
-                h = override
-                continue
-        if plan.forward_cls is All2AllSoftmax:
-            # keep logits for a numerically-stable CE
-            h = layer(All2All.apply)(p, h)
-        elif issubclass(plan.forward_cls, DropoutForward):
-            if key is not None:
-                mask = DropoutForward.make_mask(
-                    jax.random.fold_in(key, i + fold_offset), h.shape,
-                    plan.static.get("dropout_ratio", 0.5), h.dtype)
-                h = h * mask
-        else:
-            h = layer(functools.partial(
-                plan.forward_cls.apply, **plan.static))(p, h)
+        # metadata only: the layer's forward ops AND their transposes in
+        # the backward carry the scope in ``op_name``; instructions,
+        # shapes and numerics are those of the unscoped program
+        with jax.named_scope(layer_scope(i + fold_offset, plan)):
+            if layer_fn is not None:
+                override = layer_fn(i, plan, p, h, key)
+                if override is not None:
+                    h = override
+                    continue
+            if plan.forward_cls is All2AllSoftmax:
+                # keep logits for a numerically-stable CE
+                h = layer(All2All.apply)(p, h)
+            elif issubclass(plan.forward_cls, DropoutForward):
+                if key is not None:
+                    mask = DropoutForward.make_mask(
+                        jax.random.fold_in(key, i + fold_offset),
+                        h.shape, plan.static.get("dropout_ratio", 0.5),
+                        h.dtype)
+                    h = h * mask
+            else:
+                h = layer(functools.partial(
+                    plan.forward_cls.apply, **plan.static))(p, h)
     return h
 
 
@@ -250,6 +261,10 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
         else:
             out = _forward_for_loss(plans, params, x, key,
                                     remat=bwd_remat)
+        with jax.named_scope("loss"):
+            return loss_of(out, target, batch_size)
+
+    def loss_of(out, target, batch_size):
         if loss == "softmax":
             labels = target
             valid = labels >= 0
@@ -311,31 +326,32 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
         # gradients makes the squared-sum non-finite, so isfinite of
         # the norm covers every leaf; both flags stay LAZY device
         # scalars riding the existing metrics result — no host sync
-        if zero_update is not None:
-            # ZeRO-1: reduce-scatter + sharded update + all-gather in
-            # one coupled unit; the grad-norm's squared-sum comes back
-            # from the owned shards (psum over the data axis, so the
-            # skip verdict below is uniform across ranks).  The
-            # poisons above inject BEFORE the reduce-scatter, so a
-            # fault on one shard still spreads like a real bad chip.
-            new_state, gsq = zero_update(state, grads)
-        elif gsq_fn is not None:
-            gsq = gsq_fn(grads)
-        else:
-            gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                      for g in jax.tree_util.tree_leaves(grads))
-        grad_norm = jnp.sqrt(gsq)
-        step_finite = jnp.isfinite(loss_value) & jnp.isfinite(grad_norm)
+        with jax.named_scope("update"):
+            if zero_update is not None:
+                # ZeRO-1: reduce-scatter + sharded update + all-gather in
+                # one coupled unit; the grad-norm's squared-sum comes back
+                # from the owned shards (psum over the data axis, so the
+                # skip verdict below is uniform across ranks).  The
+                # poisons above inject BEFORE the reduce-scatter, so a
+                # fault on one shard still spreads like a real bad chip.
+                new_state, gsq = zero_update(state, grads)
+            elif gsq_fn is not None:
+                gsq = gsq_fn(grads)
+            else:
+                gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                          for g in jax.tree_util.tree_leaves(grads))
+            grad_norm = jnp.sqrt(gsq)
+            step_finite = jnp.isfinite(loss_value) & jnp.isfinite(grad_norm)
 
-        new_state = new_state if zero_update is not None else \
-            _apply_solver(plans, hypers, state, grads)
-        # a non-finite update is SKIPPED, not applied: every state leaf
-        # falls back to its pre-step value, so one poisoned minibatch
-        # leaves params (and solver accumulators) bit-identical to
-        # never having served it (tests/test_health.py proves equality)
-        new_state = [GradientDescentBase.select_state(step_finite,
-                                                      entry, old)
-                     for entry, old in zip(new_state, state)]
+            new_state = new_state if zero_update is not None else \
+                _apply_solver(plans, hypers, state, grads)
+            # a non-finite update is SKIPPED, not applied: every state leaf
+            # falls back to its pre-step value, so one poisoned minibatch
+            # leaves params (and solver accumulators) bit-identical to
+            # never having served it (tests/test_health.py proves equality)
+            new_state = [GradientDescentBase.select_state(step_finite,
+                                                          entry, old)
+                         for entry, old in zip(new_state, state)]
         if loss == "softmax":
             metrics = {"loss": loss_value, "n_err": aux}
         else:

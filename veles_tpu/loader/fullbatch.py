@@ -24,6 +24,8 @@ from veles_tpu.config import precision_dtype
 from veles_tpu.loader.base import (
     Loader, LoaderError, LoaderMSEMixin, TRAIN, VALID)
 from veles_tpu.memory import Array
+from veles_tpu.observe.metrics import registry as _registry
+from veles_tpu.observe.trace import tracer as _tracer
 from veles_tpu import ops
 
 __all__ = ["FullBatchLoader", "FullBatchLoaderMSE"]
@@ -79,6 +81,9 @@ class FullBatchLoader(Loader):
         # trailing-underscore attrs are not pickled; the mapped labels
         # are rebuilt from original_labels by _map_original_labels()
         self._mapped_original_labels_ = Array()
+        # the device path of fill_indices: the upload of the index
+        # window and the dispatch of the two gather programs
+        self._m_gather_ = _registry.histogram("loader.gather_s")
 
     @property
     def shape(self):
@@ -201,18 +206,21 @@ class FullBatchLoader(Loader):
             self.shuffled_indices.mem[start_offset:start_offset + count]
         self.minibatch_indices.mem[:count] = window[:count]
         self.minibatch_indices.mem[count:] = -1
-        idx_dev = self.device.put(window)
-        data = ops.gather_minibatch(
-            self.original_data.devmem, idx_dev, out_dtype=self.dtype)
-        if count < self.max_minibatch_size:
-            data = self._zero_tail(data, count)
-        self.minibatch_data.set_device_array(data, self.device)
-        if self.has_labels:
-            labels = ops.gather_labels(
-                self._mapped_original_labels_.devmem, idx_dev)
+        with _tracer.scope("loader.gather", cat="loader",
+                           hist=self._m_gather_):
+            idx_dev = self.device.put(window)
+            data = ops.gather_minibatch(
+                self.original_data.devmem, idx_dev, out_dtype=self.dtype)
             if count < self.max_minibatch_size:
-                labels = self._mask_tail_labels(labels, count)
-            self.minibatch_labels.set_device_array(labels, self.device)
+                data = self._zero_tail(data, count)
+            self.minibatch_data.set_device_array(data, self.device)
+            if self.has_labels:
+                labels = ops.gather_labels(
+                    self._mapped_original_labels_.devmem, idx_dev)
+                if count < self.max_minibatch_size:
+                    labels = self._mask_tail_labels(labels, count)
+                self.minibatch_labels.set_device_array(labels,
+                                                       self.device)
         return True
 
     @staticmethod

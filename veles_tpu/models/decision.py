@@ -23,6 +23,7 @@ from veles_tpu.loader.base import CLASS_NAME, TRAIN, VALID
 from veles_tpu.mutable import Bool
 from veles_tpu.observe.flight import flight as _flight
 from veles_tpu.observe.metrics import registry as _registry
+from veles_tpu.observe.trace import tracer as _tracer
 from veles_tpu.units import Unit
 
 __all__ = ["DecisionBase", "DecisionGD", "DecisionMSE"]
@@ -81,10 +82,23 @@ class DecisionBase(Unit):
         self.best_epoch = 0
         self.best_train_metric = None
 
+    def init_unpickled(self):
+        super(DecisionBase, self).init_unpickled()
+        # every place this unit forces a device scalar to the host
+        # (the class-end metric, the health counters): registered here,
+        # so it reads 0 where no class ended
+        self._m_sync_ = _registry.histogram("decision.sync_s")
+
     def initialize(self, **kwargs):
         super(DecisionBase, self).initialize(**kwargs)
         self._reset_epoch_accumulators()
         return True
+
+    def _sync(self):
+        """The scope around a ``float()``/``int()`` of a lazy device
+        scalar: the host waits here for every step dispatched so far."""
+        return _tracer.scope("decision.sync", cat="decision",
+                             hist=self._m_sync_)
 
     def _reset_epoch_accumulators(self):
         raise NotImplementedError
@@ -158,8 +172,9 @@ class DecisionBase(Unit):
         total = 0
         consec = 0
         for unit in self.health_sources:
-            skips = int(unit.skip_count)
-            unit_consec = int(unit.consecutive_skips)
+            with self._sync():
+                skips = int(unit.skip_count)
+                unit_consec = int(unit.consecutive_skips)
             total += skips
             consec = max(consec, unit_consec)
             hook = getattr(unit, "on_health_sync", None)
@@ -288,7 +303,8 @@ class DecisionGD(DecisionBase):
             return None
         # forces the device sync (once per finished class, not per
         # minibatch) and normalizes to a plain float for logs/JSON
-        return float(100.0 * self.epoch_n_err[class_index] / length)
+        with self._sync():
+            return float(100.0 * self.epoch_n_err[class_index] / length)
 
     # -- master-slave contract: slaves ship per-job error counts; the
     # master merges them and performs the class/epoch-end bookkeeping
@@ -354,7 +370,8 @@ class DecisionMSE(DecisionBase):
         if length == 0:
             return None
         # float() is the once-per-class device sync (see DecisionGD)
-        return math.sqrt(float(self.epoch_sse[class_index]) / length)
+        with self._sync():
+            return math.sqrt(float(self.epoch_sse[class_index]) / length)
 
     def __getstate__(self):
         state = super(DecisionMSE, self).__getstate__()

@@ -44,7 +44,10 @@ def lazy_add(a, b):
     global _JIT_ADD
     if _JIT_ADD is None:
         import jax
-        _JIT_ADD = jax.jit(lambda p, q: p + q)
+
+        def lazy_add(p, q):  # a host trace shows PjitFunction(lazy_add)
+            return p + q
+        _JIT_ADD = jax.jit(lazy_add)
     return _JIT_ADD(a, b)
 
 
@@ -59,7 +62,10 @@ def lazy_consec(prev, skipped):
     global _JIT_CONSEC
     if _JIT_CONSEC is None:
         import jax
-        _JIT_CONSEC = jax.jit(lambda p, s: (p + s) * s)
+
+        def lazy_consec(p, s):  # PjitFunction(lazy_consec) in a trace
+            return (p + s) * s
+        _JIT_CONSEC = jax.jit(lazy_consec)
     return _JIT_CONSEC(prev, skipped)
 
 

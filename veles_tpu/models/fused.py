@@ -12,14 +12,13 @@ besides the scalar metrics the decision unit needs.
 every already-written config gains the fused path without changes.
 """
 
-import time
-
 import numpy
 
 from veles_tpu import chaos
 from veles_tpu.loader.base import TRAIN
 from veles_tpu.observe.metrics import registry as _registry
 from veles_tpu.observe.profile import profiler_step
+from veles_tpu.observe.trace import step_annotation
 from veles_tpu.observe.trace import tracer as _tracer
 from veles_tpu.units import Unit
 
@@ -77,6 +76,14 @@ class FusedTrainer(Unit):
         # time under device backpressure, with zero extra host syncs
         self._m_train_step_ = _registry.histogram("step.train_s")
         self._m_eval_step_ = _registry.histogram("step.eval_s")
+        # the step's anatomy on the host: picking or staging the
+        # inputs, and the call of the compiled program alone; what is
+        # left of step.train_s is the span's self time (the dropout
+        # key, the lazy skip counters, the metrics bookkeeping)
+        self._m_stage_ = _registry.histogram("step.stage_s")
+        self._m_dispatch_ = _registry.histogram("step.dispatch_s")
+        self._m_eval_dispatch_ = _registry.histogram(
+            "step.eval_dispatch_s")
         self._m_steps_ = _registry.counter("train.steps")
         self._m_samples_ = _registry.counter("train.samples")
         #: XLA cost-model FLOPs of one compiled step (None until the
@@ -341,119 +348,130 @@ class FusedTrainer(Unit):
 
     _sync_state_to_units = sync
 
-    def run(self):
-        import jax
-
-        t0 = time.perf_counter()
-        if self._step_fn is None:
-            self._compile()
-        loader = self.sw.loader
-        is_train = loader.minibatch_class == TRAIN
+    def _stage(self, loader):
+        """(x, target) on the device: sharded over the mesh, the
+        Prefetcher's already-transferred arrays, or the loader's."""
+        targets = (loader.minibatch_labels if self.loss == "softmax"
+                   else loader.minibatch_targets)
+        if self.mesh is not None:
+            return (self._stage_sharded(loader.minibatch_data),
+                    self._stage_sharded(targets))
         prefetched = (self._prefetcher.current
                       if self._prefetcher is not None else None)
-        if self.mesh is not None:
-            x = self._stage_sharded(loader.minibatch_data)
-            target = self._stage_sharded(
-                loader.minibatch_labels if self.loss == "softmax"
-                else loader.minibatch_targets)
-        elif prefetched is not None:
+        if prefetched is not None:
             # pipelined path: the worker already filled + H2D'd this
             # minibatch one step ahead; its device arrays ARE the input
-            x = prefetched.data
-            target = (prefetched.labels if self.loss == "softmax"
-                      else prefetched.targets)
-        else:
-            x = loader.minibatch_data.device_array(self.device)
-            if self.loss == "softmax":
-                target = loader.minibatch_labels.device_array(self.device)
-            else:
-                target = loader.minibatch_targets.device_array(self.device)
-        batch_size = numpy.float32(loader.minibatch_size)
+            return prefetched.data, (
+                prefetched.labels if self.loss == "softmax"
+                else prefetched.targets)
+        return (loader.minibatch_data.device_array(self.device),
+                targets.device_array(self.device))
 
-        if is_train:
-            self._iteration += 1
-            key = None
-            if self._has_dropout:
-                key = jax.random.fold_in(
-                    jax.random.PRNGKey(self._dropout_base_key),
-                    self._iteration)
-            poisons = {}
-            if chaos.plan is not None:
-                # nan-injection rides INSIDE the jitted step as traced
-                # scalars (compiler.py); the healthy path never pays
-                for point, kwarg in (("step.grad", "grad_poison"),
-                                     ("step.loss", "loss_poison")):
-                    fault = chaos.plan.fire(point)
-                    if fault is not None:
-                        poisons[kwarg] = numpy.float32(
-                            numpy.nan if fault.param is None
-                            else fault.param)
+    def run(self):
+        loader = self.sw.loader
+        is_train = loader.minibatch_class == TRAIN
+        # one measurement feeds the step histogram, the trace span and
+        # the flight ring (which keeps the last N step spans for
+        # post-mortem dumps even when full tracing is off)
+        with _tracer.scope(
+                "fused.train_step" if is_train else "fused.eval_step",
+                cat="step", hist=(self._m_train_step_ if is_train
+                                  else self._m_eval_step_),
+                args={"iteration": self._iteration +
+                      (1 if is_train else 0)}) as span:
+            if self._step_fn is None:
+                self._compile()
+            with _tracer.scope("fused.stage", cat="step",
+                               hist=self._m_stage_):
+                x, target = self._stage(loader)
+            batch_size = numpy.float32(loader.minibatch_size)
+            if is_train:
+                self._train_step(x, target, batch_size)
+            else:
+                self._eval_step(x, target, batch_size)
+            self.n_samples = int(batch_size)
+        if not is_train:
+            return
+        if (self.mesh is not None and not self._comm_published_
+                and self._iteration >=
+                getattr(self, "_compiled_at_iter_", 0) + 2):
+            # the first post-compile step's wall includes the compile;
+            # this one is the first clean step time the overlap model
+            # can be sized on
+            self._publish_comm(span.elapsed)
+        self._m_steps_.inc()
+        self._m_samples_.inc(self.n_samples)
+        profiler_step()
+
+    def _train_step(self, x, target, batch_size):
+        import jax
+
+        self._iteration += 1
+        key = None
+        if self._has_dropout:
+            key = jax.random.fold_in(
+                jax.random.PRNGKey(self._dropout_base_key),
+                self._iteration)
+        poisons = {}
+        if chaos.plan is not None:
+            # nan-injection rides INSIDE the jitted step as traced
+            # scalars (compiler.py); the healthy path never pays
+            for point, kwarg in (("step.grad", "grad_poison"),
+                                 ("step.loss", "loss_poison")):
+                fault = chaos.plan.fire(point)
+                if fault is not None:
+                    poisons[kwarg] = numpy.float32(
+                        numpy.nan if fault.param is None
+                        else fault.param)
+        # the call of the compiled program ALONE; in a profiler trace
+        # the step annotation groups the device's ops by train step
+        with step_annotation("train_step", self._iteration), \
+                _tracer.scope("fused.dispatch", cat="step",
+                              hist=self._m_dispatch_):
             if key is not None or poisons:
                 self._state, metrics = self._step_fn(
                     self._state, x, target, batch_size, key, **poisons)
             else:
                 self._state, metrics = self._step_fn(
                     self._state, x, target, batch_size)
-            # all lazy device scalars: the decision unit forces the
-            # sync once per finished class, so the fused path stays
-            # one async dispatch per step
-            self.last_loss = metrics["loss"]
-            self.n_err = metrics["n_err"]
-            self.grad_norm = metrics["grad_norm"]
-            self.last_step_finite = metrics["finite"]
-            from veles_tpu.models.evaluator import lazy_add, lazy_consec
-            self.skip_count = lazy_add(self.skip_count,
-                                       metrics["skipped"])
-            self.consecutive_skips = lazy_consec(
-                self.consecutive_skips, metrics["skipped"])
-            # mse_sum from the step's aux metric matches EvaluatorMSE's
-            # definition (per-feature mean, summed over samples); the
-            # scalar loss is SSE/batch over ALL elements and would
-            # inflate epoch RMSE by sqrt(num_features).  The fallback
-            # product only exists inside the conditional — an eager
-            # default arg would dispatch one more op per step
-            if "mse_sum" in metrics:
-                self.mse_sum = metrics["mse_sum"]
-            elif self.loss != "softmax":
-                self.mse_sum = metrics["loss"] * batch_size
-            if self._step_flops_ is None:
-                self._publish_step_flops(
-                    x, target, batch_size, key, poisons)
-        else:
-            # eval minibatch: ONE jitted forward+metrics dispatch,
-            # result stays lazy on device until class end
-            params = [{"weights": s["weights"], "bias": s["bias"]}
-                      for s in self._state]
+        # all lazy device scalars: the decision unit forces the
+        # sync once per finished class, so the fused path stays
+        # one async dispatch per step
+        self.last_loss = metrics["loss"]
+        self.n_err = metrics["n_err"]
+        self.grad_norm = metrics["grad_norm"]
+        self.last_step_finite = metrics["finite"]
+        from veles_tpu.models.evaluator import lazy_add, lazy_consec
+        self.skip_count = lazy_add(self.skip_count,
+                                   metrics["skipped"])
+        self.consecutive_skips = lazy_consec(
+            self.consecutive_skips, metrics["skipped"])
+        # mse_sum from the step's aux metric matches EvaluatorMSE's
+        # definition (per-feature mean, summed over samples); the
+        # scalar loss is SSE/batch over ALL elements and would
+        # inflate epoch RMSE by sqrt(num_features).  The fallback
+        # product only exists inside the conditional — an eager
+        # default arg would dispatch one more op per step
+        if "mse_sum" in metrics:
+            self.mse_sum = metrics["mse_sum"]
+        elif self.loss != "softmax":
+            self.mse_sum = metrics["loss"] * batch_size
+        if self._step_flops_ is None:
+            self._publish_step_flops(
+                x, target, batch_size, key, poisons)
+
+    def _eval_step(self, x, target, batch_size):
+        """Eval minibatch: ONE jitted forward+metrics dispatch, result
+        stays lazy on device until class end."""
+        params = [{"weights": s["weights"], "bias": s["bias"]}
+                  for s in self._state]
+        with _tracer.scope("fused.dispatch", cat="step",
+                           hist=self._m_eval_dispatch_):
             if self.loss == "softmax":
                 self.n_err = self._eval_metrics(params, x, target)
             else:
                 self.mse_sum = self._eval_metrics(
                     params, x, target, batch_size)
-        self.n_samples = int(batch_size)
-        elapsed = time.perf_counter() - t0
-        if (is_train and self.mesh is not None
-                and not self._comm_published_
-                and self._iteration >=
-                getattr(self, "_compiled_at_iter_", 0) + 2):
-            # the first post-compile step's wall includes the compile;
-            # this one is the first clean step time the overlap model
-            # can be sized on
-            self._publish_comm(elapsed)
-        if is_train:
-            self._m_train_step_.observe(elapsed)
-            self._m_steps_.inc()
-            self._m_samples_.inc(self.n_samples)
-            profiler_step()
-        else:
-            self._m_eval_step_.observe(elapsed)
-        if _tracer.active:
-            # .active, not .enabled: the always-on flight recorder
-            # keeps the last N step spans for post-mortem dumps even
-            # when full tracing is off (docs/observability.md)
-            _tracer.complete(
-                "fused.train_step" if is_train else "fused.eval_step",
-                t0, elapsed, cat="step",
-                args={"iteration": self._iteration})
 
     def reset_health_counters(self):
         """Zero the skip accounting (after the decision's divergence

@@ -90,12 +90,15 @@ from veles_tpu.observe.timeseries import (SERIES_SCHEMA_VERSION,
                                           digest_values,
                                           fleet_summary,
                                           merge_digests, series)
-from veles_tpu.observe.trace import (CHUNK_SCHEMA_VERSION, SpanTracer,
-                                     instant, span, traced, tracer,
+from veles_tpu.observe.trace import (ANNOTATION_PREFIX,
+                                     CHUNK_SCHEMA_VERSION, SpanTracer,
+                                     instant, profiler_live, span,
+                                     step_annotation, tracer,
                                      validate_trace)
 
 __all__ = [
-    "SpanTracer", "tracer", "span", "instant", "traced", "validate_trace",
+    "SpanTracer", "tracer", "span", "instant", "validate_trace",
+    "profiler_live", "step_annotation", "ANNOTATION_PREFIX",
     "CHUNK_SCHEMA_VERSION",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "registry",
     "percentiles", "health_snapshot",

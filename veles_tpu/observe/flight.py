@@ -84,17 +84,18 @@ class FlightRecorder(object):
     # -- recording (hot) ---------------------------------------------------
 
     def record(self, kind, name, cat=None, wall=None, dur=None,
-               args=None):
+               args=None, parent=None):
         """Append one event: ``kind`` is span/instant/counter/heartbeat,
         ``wall`` the event's wall-clock time (now when omitted),
-        ``dur`` seconds for spans, ``args`` a small plain-data dict."""
+        ``dur`` seconds for spans, ``args`` a small plain-data dict,
+        ``parent`` the name of the span that enclosed this one."""
         if not self.enabled:
             return
         self._buf.append((
             time.time() if wall is None else wall,
             time.perf_counter(),
             threading.current_thread().name,
-            kind, name, cat, dur, args))
+            kind, name, cat, dur, args, parent))
 
     def __len__(self):
         return len(self._buf)
@@ -120,13 +121,15 @@ class FlightRecorder(object):
             if locked:
                 self._lock.release()
         events = []
-        for wall, mono, thread, kind, name, cat, dur, args in raw:
+        for wall, mono, thread, kind, name, cat, dur, args, parent in raw:
             event = {"ts": wall, "mono": mono, "thread": thread,
                      "kind": kind, "name": name}
             if cat is not None:
                 event["cat"] = cat
             if dur is not None:
                 event["dur_s"] = dur
+            if parent is not None:
+                event["parent"] = parent
             if args:
                 event["args"] = args
             events.append(event)

@@ -8,15 +8,29 @@ emitted on first sight), so the prefetch worker's fill/H2D spans render
 on a separate lane from the graph thread's unit-run spans and the
 overlap is visible directly.
 
+:meth:`SpanTracer.scope` is the ONE primitive an instrumented site
+uses: a context manager that takes one measurement and hands it to
+every consumer — the unit timer or registry histogram the site names,
+the tracer and the flight ring (with the span's PARENT: the enclosing
+open scope on that thread), and, while a ``jax.profiler`` session is
+live, a ``jax.profiler.TraceAnnotation("veles/<span>")``, so the span
+lands on ``/host:CPU`` of the same ``*.xplane.pb`` as the device ops, on
+one clock, whoever started the session.  :meth:`SpanTracer.complete`
+stays for sites that already hold both stamps.
+
 Design rules:
 
-- **zero overhead when disabled**: ``tracer.enabled`` is a plain bool;
-  hot call sites guard on it (one attribute load) and every public
-  method returns immediately when tracing is off.  ``span()`` returns
-  a shared no-op context manager;
+- **one measurement, one guard**: a site names its sinks in the one
+  ``scope(...)`` call; whether tracing, the flight ring or a profiler
+  session is on is tested inside, never at the site;
+- **cheap when nothing listens**: an un-entered annotation costs a
+  Python object, so it is built only while a session is live (one flag
+  test in C++, :func:`profiler_live`); the tracer and the ring are one
+  bool each;
 - **no locks on the hot path**: event dicts are appended to a plain
-  list (``list.append`` is atomic under the GIL); the lock guards only
-  start/save and first-sight thread registration;
+  list (``list.append`` is atomic under the GIL), open scopes live on a
+  per-thread stack; the lock guards only start/save and first-sight
+  thread registration;
 - **bounded memory**: past ``max_events`` new events are counted as
   dropped instead of growing the buffer without bound.
 
@@ -25,54 +39,101 @@ system instruments against; ``--trace PATH`` (launcher.py) starts it
 and saves the file at run end.
 """
 
-import functools
+import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 
 from veles_tpu.observe.flight import flight as _global_flight
 
-__all__ = ["SpanTracer", "tracer", "span", "instant", "traced",
-           "validate_trace", "CHUNK_SCHEMA_VERSION"]
+__all__ = ["SpanTracer", "tracer", "span", "instant", "profiler_live",
+           "step_annotation", "validate_trace", "ANNOTATION_PREFIX",
+           "CHUNK_SCHEMA_VERSION"]
 
 #: schema of the bounded trace chunks slaves ship to the master
 #: (observe/cluster.py collects them, observe/merge.py stitches them)
 CHUNK_SCHEMA_VERSION = 1
 
 
-class _NullSpan(object):
-    """Shared no-op context manager returned while tracing is off."""
+#: a scope's name on the profiler's ``/host:CPU`` plane
+ANNOTATION_PREFIX = "veles/"
 
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+_NO_ANNOTATION = contextlib.nullcontext()
+_annotation_cls = None
 
 
-_NULL_SPAN = _NullSpan()
+def profiler_live():
+    """True while a ``jax.profiler`` session records — started by
+    anyone: ``ProfilerHook``, the benchmark, a remote capture.  One flag
+    test in C++; False while jax was never imported."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return False
+        _annotation_cls = profiler.TraceAnnotation
+    return _annotation_cls.is_enabled()
 
 
-class _Span(object):
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_start")
+def step_annotation(name, step_num):
+    """``jax.profiler.StepTraceAnnotation(name, step_num=...)`` while a
+    profiler session is live (the trace then groups device ops by
+    step), else a shared no-op: building one costs a microsecond."""
+    if not profiler_live():
+        return _NO_ANNOTATION
+    from jax.profiler import StepTraceAnnotation
+    return StepTraceAnnotation(name, step_num=step_num)
 
-    def __init__(self, owner, name, cat, args):
+
+class _Scope(object):
+    """One open span (:meth:`SpanTracer.scope`).  ``elapsed`` holds the
+    measurement after exit; ``args`` may be set until then."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "_hist", "_timers",
+                 "_stack", "_note", "parent", "sid", "start", "elapsed")
+
+    def __init__(self, owner, name, cat, hist, timers, args):
         self._tracer = owner
-        self._name = name
-        self._cat = cat
-        self._args = args
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self._hist = hist
+        self._timers = timers
+        self.sid = None
 
     def __enter__(self):
-        self._start = time.perf_counter()
+        stack = self._stack = self._tracer._open_scopes()
+        self.parent = stack[-1] if stack else None
+        stack.append(self)
+        # profiler_live(), inlined once the class is resolved: this is
+        # the hot path
+        note = _annotation_cls
+        if note.is_enabled() if note is not None else profiler_live():
+            note = _annotation_cls(ANNOTATION_PREFIX + self.name)
+            note.__enter__()
+        else:
+            note = None
+        self._note = note
+        self.start = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
-        self._tracer.complete(
-            self._name, self._start, time.perf_counter() - self._start,
-            cat=self._cat, args=self._args)
+    def __exit__(self, exc_type, exc, traceback):
+        elapsed = self.elapsed = time.perf_counter() - self.start
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, traceback)
+        self._stack.pop()
+        if self._hist is not None:
+            self._hist.observe(elapsed)
+        if self._timers is not None:
+            timers, key = self._timers
+            timers[key] = timers.get(key, 0.0) + elapsed
+        owner = self._tracer
+        if owner.enabled or owner._flight.enabled:
+            owner._record(self.name, self.start, elapsed, self.cat,
+                          self.args, None, self.parent, self)
         return False
 
 
@@ -99,6 +160,8 @@ class SpanTracer(object):
         self._tids = {}
         self._tid_names = {}
         self._flight = flight if flight is not None else _global_flight
+        self._local = threading.local()
+        self._sids = itertools.count(1)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -189,53 +252,73 @@ class SpanTracer(object):
     def _ts(self, when):
         return (when - self._epoch) * 1e6
 
+    def _open_scopes(self):
+        """This thread's stack of open scopes, innermost last."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def _sid(self, scope):
+        if scope.sid is None:
+            scope.sid = next(self._sids)
+        return scope.sid
+
+    def scope(self, name, cat="span", hist=None, timers=None, args=None):
+        """THE instrumentation primitive: a context manager around one
+        piece of work that takes ONE ``perf_counter`` pair and
+
+        - adds it to ``hist`` (a registry histogram) and to
+          ``timers[key]`` (``timers=(dict, key)``: a unit's timers),
+        - records the span, with its parent, in the tracer and the
+          flight ring (whichever is on),
+        - and is a ``jax.profiler.TraceAnnotation("veles/<name>")``
+          while a profiler session is live.
+
+        The returned object keeps ``elapsed`` after exit."""
+        return _Scope(self, name, cat, hist, timers, args)
+
+    def span(self, name, cat="span", **args):
+        """:meth:`scope` with the span's args as keywords."""
+        return _Scope(self, name, cat, None, None, args or None)
+
     def complete(self, name, start, dur, cat="span", args=None,
                  tid=None):
-        """Record a complete ("X") event from perf_counter timings —
-        the primitive every instrumented timer calls, so the trace and
-        the accumulated timers always report the SAME measurement.
-        Always feeds the flight recorder's ring (compact tuple, no
-        serialization) so post-mortem dumps work without ``--trace``.
+        """Record a complete ("X") event from perf_counter timings, for
+        sites that already hold both stamps (:meth:`scope` ends here
+        too).  Always feeds the flight recorder's ring (compact tuple,
+        no serialization) so post-mortem dumps work without
+        ``--trace``.  The event's ``parent`` is the ``sid`` of the
+        innermost scope open on this thread (None at the root).
         ``tid`` overrides the recording thread's track — request-
-        scoped spans land on their :meth:`request_track` lane."""
+        scoped spans land on their :meth:`request_track` lane and have
+        no parent."""
+        parent = None
+        if tid is None:
+            stack = self._open_scopes()
+            if stack:
+                parent = stack[-1]
+        self._record(name, start, dur, cat, args, tid, parent, None)
+
+    def _record(self, name, start, dur, cat, args, tid, parent, scope):
         flt = self._flight
         if flt.enabled:
             flt.record("span", name, cat, self.wall_time(start), dur,
-                       args)
+                       args, None if parent is None else parent.name)
         if not self.enabled:
             return
         event = {"name": name, "cat": cat, "ph": "X",
                  "ts": self._ts(start), "dur": dur * 1e6,
                  "pid": self._pid,
-                 "tid": self._tid() if tid is None else tid}
+                 "tid": self._tid() if tid is None else tid,
+                 "sid": next(self._sids) if scope is None
+                 else self._sid(scope),
+                 "parent": None if parent is None
+                 else self._sid(parent)}
         if args:
             event["args"] = args
         self._append(event)
-
-    def span(self, name, cat="span", **args):
-        """Context manager recording one complete event around a block."""
-        if not self.enabled and not self._flight.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, args or None)
-
-    def traced(self, name=None, cat="span"):
-        """Decorator form of :meth:`span` (label defaults to the
-        function's qualified name)."""
-        def decorate(fn):
-            label = name or fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*a, **kw):
-                if not self.enabled and not self._flight.enabled:
-                    return fn(*a, **kw)
-                start = time.perf_counter()
-                try:
-                    return fn(*a, **kw)
-                finally:
-                    self.complete(label, start,
-                                  time.perf_counter() - start, cat=cat)
-            return wrapper
-        return decorate
 
     def instant(self, name, cat="event", **args):
         """Record a point event (protocol messages, faults, rollbacks)."""
@@ -381,6 +464,23 @@ def validate_trace(doc):
             per_track.setdefault(
                 (event["pid"], event["tid"]), []).append(event)
     epsilon = 1.0  # microsecond slack for float rounding
+    # parent contract: a span names its parent by ``sid``; where the
+    # document holds the parent too, it is on the same track and
+    # covers the child (a shipped chunk may have left the parent behind)
+    by_sid = {(e["pid"], e["sid"]): e for events in per_track.values()
+              for e in events if e.get("sid") is not None}
+    for (pid, _), event in by_sid.items():
+        parent = event.get("parent")
+        if parent is None or (pid, parent) not in by_sid:
+            continue
+        outer = by_sid[(pid, parent)]
+        if outer["tid"] != event["tid"] or \
+                event["ts"] < outer["ts"] - epsilon or \
+                event["ts"] + event["dur"] > \
+                outer["ts"] + outer["dur"] + epsilon:
+            raise ValueError(
+                "span %r is not inside its parent %r on one track" %
+                (event["name"], outer["name"]))
     for track, events in per_track.items():
         events.sort(key=lambda e: (e["ts"], -e["dur"]))
         stack = []
@@ -437,7 +537,3 @@ def span(name, cat="span", **args):
 
 def instant(name, cat="event", **args):
     return tracer.instant(name, cat=cat, **args)
-
-
-def traced(name=None, cat="span"):
-    return tracer.traced(name, cat=cat)

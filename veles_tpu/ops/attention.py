@@ -61,6 +61,11 @@ from veles_tpu.ops.common import (ceil_mult, interpret_for,
 __all__ = ["flash_attention", "attention_reference",
            "ATTENTION_KERNEL_VERSION"]
 
+#: the kernels' names in compiled HLO and device traces (``%<name>``)
+FWD_KERNEL_NAME = "veles_flash_fwd"
+DQ_KERNEL_NAME = "veles_flash_dq"
+DKV_KERNEL_NAME = "veles_flash_dkv"
+
 #: bump when the kernel's algorithm changes: tuned schedules in the
 #: cache are only valid for the algorithm they were measured on
 #: (v2: the backward reads (m, l) row statistics, not a logsumexp)
@@ -149,6 +154,7 @@ def _flash_fwd_jit(q, k, v, scale, precision_level, blocks, interpret):
         functools.partial(_fwd_kernel, n_k=n_k, scale=scale,
                           t_real=t, bk=bk,
                           precision_level=precision_level),
+        name=FWD_KERNEL_NAME,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
@@ -271,6 +277,7 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
         functools.partial(_bwd_dq_kernel, n_k=n_k, scale=scale,
                           t_real=t, bk=bk,
                           precision_level=precision_level),
+        name=DQ_KERNEL_NAME,
         grid=(b, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
@@ -294,6 +301,7 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
         functools.partial(_bwd_dkv_kernel, n_q=n_q, scale=scale,
                           t_real=t, bk=bk,
                           precision_level=precision_level),
+        name=DKV_KERNEL_NAME,
         grid=(b, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, bq, dhp), lambda bb, kk, i: (bb, i, 0)),

@@ -59,6 +59,9 @@ __all__ = ["fused_conv_vjp", "conv_act", "activation_grad",
            "ACTIVATIONS", "MAX_FUSED_TAPS", "conv_vjp_route",
            "CONV_VJP_KERNEL_VERSION"]
 
+#: the kernel's name in compiled HLO and device traces (``%veles_conv_wgrad``)
+KERNEL_NAME = "veles_conv_wgrad"
+
 #: bump when the wgrad kernel's algorithm changes: tuned schedules in
 #: the cache are only valid for the algorithm they were measured on
 #: (the version rides the schedule-cache digest, so old entries become
@@ -261,6 +264,7 @@ def _fused_wgrad_jit(x, y, dy, activation, ky, kx, out_hw, padding,
         functools.partial(_wgrad_kernel, n_k=n_k,
                           precision_level=precision_level,
                           activation=activation, err_dtype=x.dtype),
+        name=KERNEL_NAME,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bk, bi), lambda j, t, i, k: (t, k, i)),

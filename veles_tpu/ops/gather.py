@@ -20,6 +20,13 @@ from veles_tpu.ops.common import interpret_for, kernel_cast
 
 __all__ = ["gather_minibatch", "gather_labels"]
 
+#: every op of the two gather programs carries this scope in its
+#: ``op_name`` metadata (``jit(gather_minibatch)/loader_gather/...``)
+SCOPE = "loader_gather"
+
+#: the kernel's name in compiled HLO and device traces (``%veles_gather_rows``)
+KERNEL_NAME = "veles_gather_rows"
+
 
 def _gather_kernel(idx_ref, data_ref, out_ref):
     out_ref[:] = kernel_cast(data_ref[:], out_ref.dtype)
@@ -32,7 +39,12 @@ def gather_minibatch(dataset, indices, out_dtype=None):
     ``dataset`` stays in HBM/ANY; each grid step DMAs one sample row into
     VMEM addressed by the prefetched index.
     """
-    out_dtype = out_dtype or dataset.dtype
+    with jax.named_scope(SCOPE):
+        return _gather_rows(dataset, indices,
+                            out_dtype or dataset.dtype)
+
+
+def _gather_rows(dataset, indices, out_dtype):
     batch = indices.shape[0]
     sample_shape = dataset.shape[1:]
     flat = dataset.reshape(dataset.shape[0], -1)
@@ -58,6 +70,7 @@ def gather_minibatch(dataset, indices, out_dtype=None):
     )
     out = pl.pallas_call(
         _gather_kernel,
+        name=KERNEL_NAME,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, 1, wp), out_dtype),
         interpret=interpret_for(flat),
@@ -68,4 +81,5 @@ def gather_minibatch(dataset, indices, out_dtype=None):
 @jax.jit
 def gather_labels(labels, indices):
     """Label gather; labels are small, XLA's native gather is optimal."""
-    return jnp.take(labels, indices, axis=0)
+    with jax.named_scope(SCOPE):
+        return jnp.take(labels, indices, axis=0)

@@ -14,6 +14,9 @@ from veles_tpu.ops.common import interpret_for, kernel_cast
 
 __all__ = ["join"]
 
+#: the kernel's name in compiled HLO and device traces (``%veles_join``)
+KERNEL_NAME = "veles_join"
+
 
 def _make_join_kernel(widths):
     offsets = []
@@ -46,6 +49,7 @@ def join(*arrays, out_dtype=None):
     total = sum(widths)
     out = pl.pallas_call(
         _make_join_kernel(widths),
+        name=KERNEL_NAME,
         out_shape=jax.ShapeDtypeStruct((batch, total), out_dtype),
         interpret=interpret_for(*flats),
     )(*flats)

@@ -41,6 +41,9 @@ from veles_tpu.ops.common import (ceil_mult, interpret_for,
 __all__ = ["matmul", "matmul_benchmark", "autotune_matmul",
            "MATMUL_KERNEL_VERSION"]
 
+#: the kernel's name in compiled HLO and device traces (``%veles_matmul``)
+KERNEL_NAME = "veles_matmul"
+
 _DEFAULT_BLOCKS = (512, 512, 512)
 
 #: bump when the kernel's algorithm changes: persisted autotune tables
@@ -228,6 +231,7 @@ def _matmul_jit(a, b, precision_level, blocks, out_dtype, interpret):
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, n_k=n_k,
                           precision_level=precision_level),
+        name=KERNEL_NAME,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -47,6 +47,9 @@ from veles_tpu.ops.common import (ceil_mult, interpret_for,
 __all__ = ["matmul_int8", "matmul_int8_reference", "conv2d_int8",
            "MATMUL_INT8_KERNEL_VERSION", "INT8_SUBLANE"]
 
+#: the kernel's name in compiled HLO and device traces (``%veles_matmul_int8``)
+KERNEL_NAME = "veles_matmul_int8"
+
 #: int8's native MXU tile is (32, 128): the sublane quantum is 32 (vs
 #: f32's 8) because four int8 rows pack one 32-bit sublane register
 INT8_SUBLANE = 32
@@ -181,6 +184,7 @@ def _matmul_int8_jit(a, b, scale, bias, blocks, out_dtype, interpret):
 
     out = pl.pallas_call(
         functools.partial(_matmul_int8_kernel, n_k=n_k),
+        name=KERNEL_NAME,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -17,6 +17,9 @@ from veles_tpu.ops.common import (ceil_mult, interpret_for, kernel_cast,
 
 __all__ = ["mean_disp_normalize"]
 
+#: the kernel's name in compiled HLO and device traces (``%veles_normalize``)
+KERNEL_NAME = "veles_normalize"
+
 
 def _normalize_kernel(x_ref, mean_ref, rdisp_ref, out_ref):
     x = kernel_cast(x_ref[:], out_ref.dtype)
@@ -50,6 +53,7 @@ def mean_disp_normalize(x, mean, rdisp, out_dtype=jnp.float32, block=256):
     mp, wp = flat.shape
     out = pl.pallas_call(
         _normalize_kernel,
+        name=KERNEL_NAME,
         grid=(mp // bm, wp // bw),
         in_specs=[
             pl.BlockSpec((bm, bw), lambda i, j: (i, j)),

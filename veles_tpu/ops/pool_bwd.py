@@ -44,6 +44,9 @@ __all__ = ["max_pool_bwd", "max_pool", "POOL_VMEM_BUDGET_BYTES",
            "POOL_BWD_KERNEL_VERSION", "pool_block_footprint",
            "pool_bwd_route"]
 
+#: the kernel's name in compiled HLO and device traces (``%veles_pool_bwd``)
+KERNEL_NAME = "veles_pool_bwd"
+
 #: bump when the select-and-scatter kernel's algorithm changes: tuned
 #: W-tilings in the schedule cache are keyed to the algorithm they
 #: were measured on (stale versions miss, never serve).  v2 = strided
@@ -213,6 +216,7 @@ def _max_pool_bwd_jit(x, y, dy, window, sliding, interpret, owb=None):
         functools.partial(
             _pool_bwd_kernel, window=window, sliding=sliding,
             out_h=oh, out_w=owb),
+        name=KERNEL_NAME,
         grid=(n, cp // _LANES, n_wtiles),
         in_specs=[block(need_h, bwx), block(oh, owb), block(oh, owb)],
         out_specs=block(need_h, bwx),

@@ -29,6 +29,9 @@ __all__ = ["xorshift128plus", "xorshift1024star", "uniform_from_bits",
            "hardware_uniform", "numpy_xorshift128plus",
            "numpy_xorshift1024star"]
 
+#: the kernel's name in compiled HLO and device traces (``%veles_random``)
+KERNEL_NAME = "veles_random"
+
 U32 = jnp.uint32
 
 
@@ -210,6 +213,7 @@ def hardware_uniform(seed, shape):
         return jax.random.uniform(jax.random.PRNGKey(seed), shape)
     return pl.pallas_call(
         _hw_uniform_kernel,
+        name=KERNEL_NAME,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
     )(jnp.asarray([seed], jnp.int32))

@@ -18,6 +18,10 @@ from veles_tpu.ops.common import ceil_mult, interpret_for, pad_to
 
 __all__ = ["reduce_rows", "reduce_cols"]
 
+#: the kernels' names in compiled HLO and device traces (``%<name>``)
+COLS_KERNEL_NAME = "veles_reduce_cols"
+ROWS_KERNEL_NAME = "veles_reduce_rows"
+
 
 def _reduce_cols_kernel(in_ref, out_ref, acc_ref, *, n_k):
     """Sum over rows (axis 0): out[j] = sum_i in[i, j]."""
@@ -45,6 +49,7 @@ def reduce_cols(x, block=512):
     n_k = mp // bm
     out = pl.pallas_call(
         functools.partial(_reduce_cols_kernel, n_k=n_k),
+        name=COLS_KERNEL_NAME,
         grid=(n_k,),
         in_specs=[pl.BlockSpec((bm, np_), lambda k: (k, 0))],
         out_specs=pl.BlockSpec((1, np_), lambda k: (0, 0)),
@@ -83,6 +88,7 @@ def reduce_rows(x, block=512):
     n_k = np_ // bn
     out = pl.pallas_call(
         functools.partial(_reduce_rows_kernel, n_k=n_k),
+        name=ROWS_KERNEL_NAME,
         grid=(n_k,),
         in_specs=[pl.BlockSpec((mp, bn), lambda k: (0, k))],
         out_specs=pl.BlockSpec((mp, 1), lambda k: (0, 0)),

@@ -42,7 +42,6 @@ dropped master-slave job.
 import contextlib
 import queue
 import threading
-import time
 
 from veles_tpu import chaos
 from veles_tpu.loader.base import ServeShadow
@@ -92,7 +91,7 @@ class Prefetcher(Logger):
         # mid-run pickle (snapshotter) never observes a half-applied
         # serve mutating pending_minibatches_/failed_minibatches
         self._serve_mutex = threading.Lock()
-        self.stats = self._fresh_stats()
+        self._reset_stats()
         # telemetry (docs/observability.md): per-stage histograms feed
         # the heartbeat/bench percentiles; resolved once, not per serve
         self._m_wait = _registry.histogram("pipeline.wait_s")
@@ -100,9 +99,31 @@ class Prefetcher(Logger):
         self._m_h2d = _registry.histogram("pipeline.h2d_s")
         _registry.gauge("pipeline.depth").set(self.depth)
 
-    def _fresh_stats(self):
-        return {"depth": self.depth, "serves": 0, "applied": 0,
-                "wait_s": 0.0, "fill_s": 0.0, "h2d_s": 0.0}
+    def _reset_stats(self):
+        self._serves = self._applied = 0
+        self._timers_at_start = dict(self.loader.timers)
+
+    @property
+    def stats(self):
+        """This run's counts and stage seconds.  The seconds are the
+        loader's ``pipeline_*`` stage timers (the one place a stage's
+        time accumulates; ``print_stats`` shows them) since the worker
+        pool started."""
+        timers, base = self.loader.timers, self._timers_at_start
+        out = {"depth": self.depth, "serves": self._serves,
+               "applied": self._applied}
+        for stage in ("wait", "fill", "h2d"):
+            key = "pipeline_" + stage
+            out[stage + "_s"] = timers.get(key, 0.0) - base.get(key, 0.0)
+        return out
+
+    def _stage_scope(self, stage, hist, **args):
+        """One measurement of one stage for its histogram, the
+        loader's stage timer, the trace and the flight ring."""
+        return _tracer.scope(
+            "pipeline." + stage, cat="pipeline", hist=hist,
+            timers=(self.loader.timers, "pipeline_" + stage),
+            args=args or None)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -121,7 +142,7 @@ class Prefetcher(Logger):
         self._inflight = 0
         self._results = queue.Queue()
         self.current = None
-        self.stats = self._fresh_stats()
+        self._reset_stats()
         # staging slots are (re-)initialized lazily per serve in
         # _serve_one_locked, so a wholesale .mem swap is always healed
         self._pool = ThreadPool(minthreads=1, maxthreads=1,
@@ -191,15 +212,13 @@ class Prefetcher(Logger):
             raise failure[1].with_traceback(failure[2])
         while self._inflight < self.depth + 1 and not self._shutdown:
             self._submit()
-        if _tracer.enabled:
-            _tracer.counter("pipeline.inflight", self._inflight)
         item = self._take()
         if item is None:  # shut down mid-wait (Workflow.stop)
             return
         self._inflight -= 1
         self._apply(item)
         self.current = item
-        self.stats["applied"] += 1
+        self._applied += 1
 
     def _submit(self):
         pool = self._pool
@@ -212,29 +231,20 @@ class Prefetcher(Logger):
         pool.callInThread(self._serve_one, serial, slot)
 
     def _take(self):
-        start = time.perf_counter()
-        while True:
-            try:
-                item = self._results.get(timeout=0.2)
-                break
-            except queue.Empty:
-                pool = self._pool
-                if self._shutdown or pool is None:
-                    return None
-                failure = pool.failure
-                if failure is not None:
-                    self.shutdown()
-                    raise failure[1].with_traceback(failure[2])
-        waited = time.perf_counter() - start
-        self.stats["wait_s"] += waited
-        self._m_wait.observe(waited)
-        if _tracer.enabled:
-            _tracer.complete("pipeline.wait", start, waited,
-                             cat="pipeline")
-        timers = self.loader.timers
-        timers["pipeline_wait"] = timers.get(
-            "pipeline_wait", 0.0) + waited
-        return item
+        """The oldest served minibatch, waited for; None when shut down
+        mid-wait."""
+        with self._stage_scope("wait", self._m_wait):
+            while True:
+                try:
+                    return self._results.get(timeout=0.2)
+                except queue.Empty:
+                    pool = self._pool
+                    if self._shutdown or pool is None:
+                        return None
+                    failure = pool.failure
+                    if failure is not None:
+                        self.shutdown()
+                        raise failure[1].with_traceback(failure[2])
 
     def _apply(self, item):
         """Write the item's serve-time snapshot into the loader's REAL
@@ -282,52 +292,39 @@ class Prefetcher(Logger):
             # loader's live (applied) state
             shadow = ServeShadow(loader, threading.current_thread())
             loader._serve_shadow_ = shadow
-        t0 = time.perf_counter()
-        for arr in self._staged_arrays():
-            if not arr.staged:
-                # a wholesale .mem assignment dropped the slots (shape
-                # may have changed); re-stage around the new buffer so
-                # the in-flight-DMA protection never silently lapses
-                arr.stage_init(self.nslots)
-            arr.stage_begin(slot)
-        # NOTE two deviations from the synchronous Loader.run, both so
-        # that serving AHEAD never miscounts: the previous serve's
-        # pending record is NOT popped (every served-but-unconsumed
-        # minibatch keeps its requeue record until _apply retires it or
-        # shutdown moves it to failed_minibatches), and
-        # _on_successful_serve runs at APPLY time on the graph thread —
-        # like the master-slave contract, samples are counted when
-        # consumed, so a requeued serve is never counted twice
-        loader.serve_next_minibatch(None)
-        t1 = time.perf_counter()
+        # worker-thread spans land on their own track (Perfetto's, and
+        # the profiler's /host:CPU line of this thread), so the
+        # fill/H2D overlap with the graph thread's step spans is
+        # visible directly
+        with self._stage_scope("fill", self._m_fill, serial=serial):
+            for arr in self._staged_arrays():
+                if not arr.staged:
+                    # a wholesale .mem assignment dropped the slots
+                    # (shape may have changed); re-stage around the new
+                    # buffer so the in-flight-DMA protection never
+                    # silently lapses
+                    arr.stage_init(self.nslots)
+                arr.stage_begin(slot)
+            # NOTE two deviations from the synchronous Loader.run, both
+            # so that serving AHEAD never miscounts: the previous
+            # serve's pending record is NOT popped (every
+            # served-but-unconsumed minibatch keeps its requeue record
+            # until _apply retires it or shutdown moves it to
+            # failed_minibatches), and _on_successful_serve runs at
+            # APPLY time on the graph thread — like the master-slave
+            # contract, samples are counted when consumed, so a
+            # requeued serve is never counted twice
+            loader.serve_next_minibatch(None)
 
         item = PrefetchItem(serial)
         item.values = dict(shadow.values)
-        item.data = loader.minibatch_data.staged_capture(self.device)
-        if loader.minibatch_labels:
-            item.labels = loader.minibatch_labels.staged_capture(
-                self.device)
-        targets = getattr(loader, "minibatch_targets", None)
-        if targets is not None and bool(targets):
-            item.targets = targets.staged_capture(self.device)
-        t2 = time.perf_counter()
-
-        self.stats["serves"] += 1
-        self.stats["fill_s"] += t1 - t0
-        self.stats["h2d_s"] += t2 - t1
-        self._m_fill.observe(t1 - t0)
-        self._m_h2d.observe(t2 - t1)
-        if _tracer.enabled:
-            # worker-thread spans land on their own Perfetto track, so
-            # the fill/H2D overlap with the graph thread's step spans
-            # is visible directly
-            _tracer.complete("pipeline.fill", t0, t1 - t0,
-                             cat="pipeline", args={"serial": serial})
-            _tracer.complete("pipeline.h2d", t1, t2 - t1,
-                             cat="pipeline", args={"serial": serial})
-        timers = loader.timers
-        timers["pipeline_fill"] = timers.get(
-            "pipeline_fill", 0.0) + (t1 - t0)
-        timers["pipeline_h2d"] = timers.get(
-            "pipeline_h2d", 0.0) + (t2 - t1)
+        with self._stage_scope("h2d", self._m_h2d, serial=serial):
+            item.data = loader.minibatch_data.staged_capture(self.device)
+            if loader.minibatch_labels:
+                item.labels = loader.minibatch_labels.staged_capture(
+                    self.device)
+            targets = getattr(loader, "minibatch_targets", None)
+            if targets is not None and bool(targets):
+                item.targets = targets.staged_capture(self.device)
+        self._serves += 1
         self._results.put(item)

@@ -858,9 +858,34 @@ class SnapshotterBase(Unit):
 class Snapshotter(SnapshotterBase):
     """Pickles the whole workflow through the selected codec."""
 
+    def init_unpickled(self):
+        super(Snapshotter, self).init_unpickled()
+        self._m_write_ = _registry.histogram("snapshot.write_s")
+        # device -> unit Arrays (FusedTrainer.sync), apart from the
+        # pickle and the write
+        self._m_sync_ = _registry.histogram("snapshot.sync_s")
+
     def export(self):
         destination = self._destination()
-        start = time.perf_counter()
+        # snapshot.write_s is the checkpoint write cost
+        # (docs/checkpointing.md): the scope closes BEFORE the publish
+        # copy, and the train-dir snapshot is already durable whether
+        # or not the freshness view gets its copy
+        with _tracer.scope("snapshot.export", cat="snapshot",
+                           hist=self._m_write_,
+                           args={"destination": destination}) as span:
+            nbytes = span.args["bytes"] = self._write(destination)
+        if nbytes is None:
+            return
+        self._publish(destination)
+        _registry.counter("snapshot.exports").inc()
+        self.info("snapshot -> %s (%.1f MB, %.2f s)", destination,
+                  nbytes / 1e6, span.elapsed)
+
+    def _write(self, destination):
+        """Pickle the workflow into ``destination`` with its manifest,
+        link, db row and retention; the bytes written, or None where
+        the disk refused them."""
         self._prefetch_device_arrays()
         payload = pickle.dumps(self.workflow,
                                protocol=pickle.HIGHEST_PROTOCOL)
@@ -876,7 +901,7 @@ class Snapshotter(SnapshotterBase):
                 "snapshot write to %s failed (%s); previous snapshot "
                 "kept, training continues", destination, exc)
             self._remove_quiet(destination + ".tmp")
-            return
+            return None
         self.destination = destination
         epoch, metric = self._workflow_epoch_metric()
         try:
@@ -890,21 +915,7 @@ class Snapshotter(SnapshotterBase):
         self._update_current_link()
         self._record_in_db(destination, len(payload))
         self._apply_retention()
-        # elapsed stamped BEFORE the publish copy: snapshot.write_s is
-        # the checkpoint write cost (docs/checkpointing.md), and the
-        # train-dir snapshot is already durable whether or not the
-        # freshness view gets its copy
-        elapsed = time.perf_counter() - start
-        self._publish(destination)
-        _registry.counter("snapshot.exports").inc()
-        _registry.histogram("snapshot.write_s").observe(elapsed)
-        if _tracer.enabled:
-            _tracer.complete("snapshot.export", start, elapsed,
-                             cat="snapshot",
-                             args={"bytes": len(payload),
-                                   "destination": destination})
-        self.info("snapshot -> %s (%.1f MB, %.2f s)", destination,
-                  len(payload) / 1e6, elapsed)
+        return len(payload)
 
     def _publish(self, destination):
         """Trainer-side freshness hook: push the finished (verified,
@@ -1020,7 +1031,9 @@ class Snapshotter(SnapshotterBase):
         # fused workflows stage params back into unit Arrays first
         trainer = getattr(self.workflow, "fused_trainer", None)
         if trainer is not None:
-            trainer.sync()
+            with _tracer.scope("snapshot.sync", cat="snapshot",
+                               hist=self._m_sync_):
+                trainer.sync()
         seen = set()
         for unit in getattr(self.workflow, "units", ()):
             for value in vars(unit).values():

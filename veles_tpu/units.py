@@ -15,7 +15,6 @@ computations while keeping this graph as the orchestration layer.
 """
 
 import threading
-import time
 import uuid as uuid_module
 
 from veles_tpu.config import root
@@ -303,20 +302,22 @@ class Unit(Distributable, metaclass=UnitRegistry):
                     "%s scheduled to run after the workflow finished "
                     "— check its control links" % self)
             return False
-        start = time.perf_counter()
-        self.run()
-        elapsed = time.perf_counter() - start
-        self.timers["run"] += elapsed
+        wf = self.workflow
+        if wf is not None:
+            # the graph thread leaves the scheduler: the hop that began
+            # at the previous unit's end stops here
+            wf.end_hop()
+        # one measurement for the unit timer (print_stats), the trace
+        # span and the flight ring — they cannot disagree
+        with _tracer.scope(self.name, cat="unit",
+                           timers=(self.timers, "run")) as span:
+            self.run()
         self.run_calls += 1
-        if _tracer.active:
-            # the trace span and the accumulated timer are the SAME
-            # measurement — print_stats and Perfetto cannot disagree.
-            # .active (tracing on OR flight ring on) so the black-box
-            # recorder sees unit spans in ordinary untraced runs too
-            _tracer.complete(self.name, start, elapsed, cat="unit")
+        if wf is not None:
+            wf.begin_hop()
         self._ran = True
         if self.timings:
-            self.debug("%s ran in %.3f ms", self.name, elapsed * 1e3)
+            self.debug("%s ran in %.3f ms", self.name, span.elapsed * 1e3)
         return True
 
     def _check_gate_and_run(self, src):

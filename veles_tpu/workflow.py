@@ -18,10 +18,10 @@ import inspect
 import json
 import sys
 import threading
-import time
 from collections import deque
 
 from veles_tpu.mutable import Bool
+from veles_tpu.observe.metrics import registry as _registry
 from veles_tpu.observe.trace import tracer as _tracer
 from veles_tpu.plumbing import EndPoint, StartPoint
 from veles_tpu.units import Unit
@@ -80,6 +80,11 @@ class Workflow(Unit):
         # stats as of the CURRENT run's start, so print_stats reports
         # per-run deltas instead of misattributing earlier runs' time
         self._stats_baseline_ = None
+        # the graph thread's time BETWEEN units (worklist, gates,
+        # locks): one open "workflow.hop" scope from a unit's end to
+        # the next unit's start, inside this workflow's run() only
+        self._hop_ = None
+        self._m_hop_ = _registry.histogram("workflow.hop_s")
 
     # -- container behavior ------------------------------------------------
 
@@ -219,11 +224,13 @@ class Workflow(Unit):
             "units": {id(u): (dict(u.timers), u.run_calls)
                       for u in self._units if u is not self},
         }
-        # perf_counter, not time.time: wall-clock timers go backwards
-        # under NTP adjustment and disagree with the perf_counter
-        # deltas every other timer (units, pipeline stages) records
-        start = time.perf_counter()
         self.event("run", "begin")
+        # perf_counter (the scope's clock), not time.time: wall-clock
+        # timers go backwards under NTP adjustment and disagree with
+        # the deltas every other timer (units, pipeline stages) records
+        span = _tracer.scope("%s.run" % self.name, cat="workflow")
+        span.__enter__()
+        self.begin_hop()
         try:
             self.start_point.run_dependent()
             while not self._finished_.is_set():
@@ -238,13 +245,24 @@ class Workflow(Unit):
                 self.on_workflow_finished()
         finally:
             self._running_ = False
-            elapsed = time.perf_counter() - start
-            self._run_time_ += elapsed
-            if _tracer.active:
-                _tracer.complete("%s.run" % self.name, start, elapsed,
-                                 cat="workflow")
+            self.end_hop()
+            span.__exit__(None, None, None)
+            self._run_time_ += span.elapsed
             self.event("run", "end")
         return True
+
+    def begin_hop(self):
+        """A unit ended (or run() began): the graph thread is in the
+        scheduler until :meth:`end_hop`.  Only inside run()."""
+        if self._running_:
+            self._hop_ = _tracer.scope(
+                "workflow.hop", cat="sched", hist=self._m_hop_)
+            self._hop_.__enter__()
+
+    def end_hop(self):
+        hop, self._hop_ = self._hop_, None
+        if hop is not None:
+            hop.__exit__(None, None, None)
 
     def on_workflow_finished(self):
         # per-unit end-of-run hook (e.g. the input pipeline joins its
@@ -278,15 +296,9 @@ class Workflow(Unit):
     # -- master-slave contract (job level; see parallel/ for on-pod SPMD) --
 
     def _timed_method(self, name, fn, *args):
-        start = time.perf_counter()
-        try:
+        with _tracer.scope(name, cat="distributed",
+                           timers=(self._method_timers, name)):
             return fn(*args)
-        finally:
-            elapsed = time.perf_counter() - start
-            self._method_timers[name] = (
-                self._method_timers.get(name, 0.0) + elapsed)
-            if _tracer.active:
-                _tracer.complete(name, start, elapsed, cat="distributed")
 
     def generate_data_for_master(self):
         return [self._timed_method(
