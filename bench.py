@@ -567,12 +567,17 @@ def _train_step_images_per_sec(specs, input_shape, batch, dataset_size,
     import jax.numpy as jnp
 
     from veles_tpu.compiler import build_train_step
-    from veles_tpu.ops.gather import gather_labels, gather_minibatch
+    from veles_tpu.ops import gather
 
     plans, state, dataset, labels_all, order, dup, has_dropout = (
         setup if setup is not None else
         _setup_training(specs, input_shape, batch, dataset_size,
                         dtype_name, classes))
+    # the row stores, built once as FullBatchLoader.initialize builds
+    # them: the step program gathers from them with no op over the table
+    sample_shape = dataset.shape[1:]
+    dataset = jax.jit(gather.build_store)(dataset)
+    labels_all = jax.jit(gather.build_label_store)(labels_all)
     step = build_train_step(plans, donate=False)
     key = jax.random.PRNGKey(0) if has_dropout else None
 
@@ -592,8 +597,8 @@ def _train_step_images_per_sec(specs, input_shape, batch, dataset_size,
                        compiler_options=step_compiler_options())
     def one(state, offset, dataset, labels_all, order):
         idx = jax.lax.dynamic_slice(order, (offset,), (batch,))
-        x = gather_minibatch(dataset, idx)
-        y = gather_labels(labels_all, idx)
+        x = gather.gather_rows(dataset, idx, sample_shape)
+        y = gather.gather_labels(labels_all, idx)
         if key is not None:
             return step(state, x, y, numpy.float32(batch),
                         jax.random.fold_in(key, offset))

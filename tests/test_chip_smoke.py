@@ -35,7 +35,7 @@ TOY_KERNELS = dict(
     pools=(((2, 9, 9, 3), (3, 3), (2, 2)),
            ((2, 8, 8, 3), (2, 2), (2, 2))),
     reduce=(100, 70), normalize=(30, 50),
-    join=((4, 6), (4, 7), (4, 1)), gather=(50, 16, (4, 32)),
+    join=((4, 6), (4, 7), (4, 1)), gather=(50, 16, (5, 31)),
     uniform=(64, 128))
 
 

@@ -194,6 +194,60 @@ def test_loader_gather_is_named_and_scoped(topo, one_chip, mosaic):
     assert "/loader_gather/" in text
 
 
+def instructions(text):
+    """(name, result and operands as written) of each HLO instruction."""
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = (.*)", line)
+        if found:
+            yield found.group(1), re.split(
+                r", (?:metadata|backend_config|frontend_attributes)=",
+                found.group(2))[0]
+
+
+@pytest.mark.parametrize("rows,sample_shape,dtype,out_dtype,batch", [
+    (1450000, (784,), "float32", "float32", 100),
+    (12288, (227, 227, 3), "bfloat16", "bfloat16", 256),
+    (50000, (32, 32, 3), "uint8", "float32", 128),
+], ids=["mnist_mlp_f32", "alexnet_bf16", "image_u8_to_f32"])
+def test_step_gather_has_no_op_over_the_table(
+        topo, one_chip, mosaic, rows, sample_shape, dtype, out_dtype,
+        batch):
+    """The per-step program on the row store, at both cells' sizes:
+    only the parameter and the kernel see the row count as a leading
+    dimension (the rule the benchmark's ``data_device_ms_per_step``
+    reader applies to a trace), and the store arrives in the layout the
+    kernel's ``operand_layout_constraints`` names — no copy between."""
+    import jax
+    import jax.numpy as jnp
+    dtype = jnp.dtype(dtype)
+    shape = gather.store_shape(
+        rows, int(numpy.prod(sample_shape)), dtype)
+    assert shape[0] == rows and shape[1] * shape[2] >= numpy.prod(
+        sample_shape)
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),
+             jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)]
+    text = gather.gather_minibatch.trace(
+        *avals, out_dtype=numpy.dtype(out_dtype),
+        sample_shape=sample_shape).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "HloModule jit_gather_minibatch" in text
+    over_table = [name for name, rest in instructions(text)
+                  if re.match(r"\(?\w+\[%d[,\]]" % rows, rest)]
+    assert len(over_table) == 1, over_table  # the parameter
+    kernel, = [(name, rest) for name, rest in instructions(text)
+               if "tpu_custom_call" in rest]
+    assert re.match(r"^%veles_gather_rows(\.\d+)?$", kernel[0])
+    assert over_table[0] + ")" in kernel[1], kernel[1]  # its operand
+    wanted = re.search(
+        r"operand_layout_constraints=\{.*?(\w+\[%d,[\d,]*\])\{([\d,]+)\}"
+        % rows, kernel[1])
+    assert wanted and wanted.group(2) == "2,1,0", kernel[1]
+    entry = re.search(
+        r"entry_computation_layout=\{\(%s\{([\d,]+)[:}]"
+        % re.escape(wanted.group(1)), text)
+    assert entry and entry.group(1) == wanted.group(2), text[:400]
+
+
 def test_scopes_leave_loss_and_gradients_bit_identical(monkeypatch):
     """CPU, interpreter kernels: the same step traced with
     ``jax.named_scope`` a no-op gives the same bits."""

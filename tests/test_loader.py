@@ -9,6 +9,7 @@ from veles_tpu.dummy import DummyWorkflow
 from veles_tpu.loader import (
     FullBatchLoader, FullBatchLoaderMSE, TEST, VALID, TRAIN)
 from veles_tpu.normalization import NormalizerRegistry
+from veles_tpu.ops import gather as gather_module
 
 
 # ---------------------------------------------------------------- normalizers
@@ -154,6 +155,121 @@ def test_loader_device_gather_parity(cpu_device):
             host.minibatch_labels.mem[:host.minibatch_size])
 
 
+class RowsLoader(FullBatchLoader):
+    """Rows handed in; ``how`` says which way they reach
+    ``original_data``: through ``create_originals`` (a window on the
+    store's own buffer) or assigned as a plain ndarray (one host copy)."""
+
+    def __init__(self, workflow, rows=None, how="create", **kwargs):
+        self._rows, self._how = rows, how
+        super(RowsLoader, self).__init__(workflow, **kwargs)
+
+    def load_data(self):
+        self.class_lengths[:] = [0, 20, len(self._rows) - 20]
+        self._calc_class_end_offsets()
+        if self._how == "create":
+            self.create_originals(self._rows.shape[1:])
+            self.original_data.mem[:] = self._rows
+        else:
+            self.original_data = self._rows.copy()
+            self.original_labels = [None] * len(self._rows)
+        self.original_labels[:] = [i % 5 for i in range(len(self._rows))]
+
+
+def rows_loader(device, rows, how, dtype):
+    from veles_tpu.prng import RandomGenerator
+    loader = RowsLoader(
+        DummyWorkflow(), rows=rows, how=how, minibatch_size=16,
+        dtype=dtype, prng=RandomGenerator("rows_loader", seed=77))
+    loader.initialize(device=device)
+    return loader
+
+
+@pytest.mark.parametrize("how,shape,stored,dtype", [
+    ("create", (67, 784), "float32", "float32"),
+    ("ndarray", (67, 784), "float32", "float32"),
+    ("create", (67, 10), "float32", "float32"),
+    ("ndarray", (67, 10), "float32", "float32"),
+    ("create", (67, 9, 7, 3), "bfloat16", "bfloat16"),
+    ("ndarray", (67, 9, 7, 3), "bfloat16", "bfloat16"),
+    # create_originals allocates in the loader's own dtype
+    ("ndarray", (67, 6, 5), "uint8", "float32"),
+])
+def test_device_minibatches_are_the_rows_bit_for_bit(
+        cpu_device, how, shape, stored, dtype):
+    """The device path serves ``rows[idx]`` in the loader's dtype, bit
+    for bit what the host path (and so the parent's gather) serves for
+    the same seed: widths off 128, a short last minibatch with its tail
+    zeroed (20 and 47 rows in batches of 16), a narrower stored type."""
+    import jax.numpy as jnp
+    rng = numpy.random.RandomState(3)
+    rows = (rng.rand(*shape) * 200).astype(jnp.dtype(stored))
+    dev = rows_loader(cpu_device, rows, how, jnp.dtype(dtype))
+    host = rows_loader(None, rows, how, jnp.dtype(dtype))
+    assert dev._use_device_path() and not host._use_device_path()
+    assert dev.original_data.shape == shape
+    assert dev.original_data.mem.tobytes() == rows.tobytes()
+    # the device holds the table once, as the store the host buffer is
+    store = dev._stores_["data"]
+    assert gather_module.host_store_of(dev.original_data.mem).shape \
+        == store.shape
+    assert dev.original_data.device is None
+    short = 0
+    for _ in range(7):  # 2 validation + 3 train minibatches, and again
+        dev.run()
+        host.run()
+        count = dev.minibatch_size
+        assert count == host.minibatch_size
+        short += count < 16
+        idx = dev.minibatch_indices.mem[:count]
+        numpy.testing.assert_array_equal(
+            idx, host.minibatch_indices.mem[:count])
+        dev.minibatch_data.map_read()
+        got = dev.minibatch_data.mem
+        assert got.dtype == jnp.dtype(dtype)
+        want = rows[idx].astype(jnp.dtype(dtype))
+        assert got[:count].tobytes() == want.tobytes()
+        assert got[:count].tobytes() == \
+            host.minibatch_data.mem[:count].tobytes()
+        assert not numpy.asarray(got[count:], numpy.float32).any()
+        dev.minibatch_labels.map_read()
+        numpy.testing.assert_array_equal(
+            dev.minibatch_labels.mem[:count], idx % 5)
+        assert (dev.minibatch_labels.mem[count:] == -1).all()
+    assert short >= 2
+
+
+def test_store_is_derived_state_rebuilt_by_initialize(cpu_device):
+    """Not pickled; ``original_data`` pickles as the plain rows; a
+    restored loader's ``initialize`` builds the store again."""
+    import pickle
+    from veles_tpu.observe.metrics import registry
+    rows = numpy.random.RandomState(4).rand(67, 10).astype(numpy.float32)
+    loader = rows_loader(cpu_device, rows, "create", numpy.float32)
+    assert registry.peek("loader.store_s").count >= 2  # data, labels
+    assert registry.peek("loader.store_bytes").value == sum(
+        store.nbytes for store in loader._stores_.values())
+    assert loader._stores_["data"].shape == (67, 1, 128)
+    state = loader.__getstate__()
+    assert not [key for key in state if "store" in key]
+    kept = pickle.loads(pickle.dumps(loader.original_data))
+    assert kept.mem.flags.c_contiguous and kept.shape == (67, 10)
+    assert kept.mem.tobytes() == rows.tobytes()
+    # a restored loader: plain rows, no store, until it initializes
+    loader.original_data = kept
+    loader._stores_ = {}
+    loader._how = "kept"
+    loader.load_data = lambda: None
+    loader.initialize(device=cpu_device)
+    assert loader._stores_["data"].shape == (67, 1, 128)
+    assert gather_module.host_store_of(loader.original_data.mem) is not None
+    loader.run()
+    loader.minibatch_data.map_read()
+    idx = loader.minibatch_indices.mem[:loader.minibatch_size]
+    assert loader.minibatch_data.mem[:len(idx)].tobytes() == \
+        rows[idx].tobytes()
+
+
 def test_loader_train_shuffled_between_epochs():
     loader = make_loader(device=None)
     first = None
@@ -247,14 +363,20 @@ class SyntheticMSELoader(FullBatchLoaderMSE):
             self.original_data.mem @ rng.rand(8, 3)).astype(numpy.float32)
 
 
-def test_mse_loader_targets(cpu_device):
+@pytest.mark.parametrize("batch", [16, 24])
+def test_mse_loader_targets(cpu_device, batch):
+    """Targets 3 wide go through the row store too, bit for bit; at
+    batch 24 the 16 validation rows are a short minibatch."""
     wf = DummyWorkflow()
-    loader = SyntheticMSELoader(wf, minibatch_size=16)
+    loader = SyntheticMSELoader(wf, minibatch_size=batch)
     loader.initialize(device=cpu_device)
-    loader.run()
-    loader.minibatch_targets.map_read()
-    idx = loader.minibatch_indices.mem[:16]
-    loader.original_targets.map_read()
-    numpy.testing.assert_allclose(
-        loader.minibatch_targets.mem[:16],
-        loader.original_targets.mem[idx], rtol=1e-5)
+    assert loader._stores_["targets"].shape == (80, 1, 128)
+    for _ in range(3):
+        loader.run()
+        count = loader.minibatch_size
+        loader.minibatch_targets.map_read()
+        idx = loader.minibatch_indices.mem[:count]
+        loader.original_targets.map_read()
+        assert loader.minibatch_targets.mem[:count].tobytes() == \
+            loader.original_targets.mem[idx].tobytes()
+        assert not loader.minibatch_targets.mem[count:].any()

@@ -120,6 +120,80 @@ class TestGather:
         numpy.testing.assert_array_equal(out, data[idx])
 
 
+    @pytest.mark.parametrize("shape,dtype,out_dtype,store", [
+        ((40, 784), "float32", None, (40, 1, 896)),
+        ((5, 154587), "bfloat16", None, (5, 1216, 128)),
+        ((30, 10), "float32", None, (30, 1, 128)),
+        ((20, 300), "bfloat16", None, (20, 16, 128)),
+        ((33, 5, 7, 3), "uint8", "float32", (33, 32, 128)),
+        ((9, 4097), "uint8", None, (9, 64, 128)),
+        ((17,), "int32", None, (17, 1, 128)),
+    ], ids=["f32_784", "bf16_154587", "f32_10", "bf16_300", "u8_to_f32",
+            "u8_4097", "i32_scalar_rows"])
+    def test_row_store_parity(self, shape, dtype, out_dtype, store):
+        """Widths off 128 through the ONE path: the store built on the
+        host (what FullBatchLoader uploads) and the raw-array
+        composition give ``data[idx]`` bit for bit."""
+        from veles_tpu.ops import gather
+        dtype = jnp.dtype(dtype)
+        data = (RS.rand(*shape) * 200).astype(dtype)
+        idx = RS.randint(0, shape[0], 7).astype(numpy.int32)
+        want = data[idx].astype(out_dtype or dtype)
+        assert gather.store_shape(
+            shape[0], int(numpy.prod(shape[1:])), dtype) == store
+        buf = gather.build_store(data)
+        assert buf.shape == store and buf.dtype == dtype
+        for got in (
+                gather_minibatch(jnp.asarray(buf), jnp.asarray(idx),
+                                 out_dtype=out_dtype,
+                                 sample_shape=shape[1:]),
+                gather_minibatch(jnp.asarray(data), jnp.asarray(idx),
+                                 out_dtype=out_dtype)):
+            got = numpy.asarray(got)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert got.tobytes() == want.tobytes()
+
+    def test_host_store_window_is_the_store(self):
+        """What is written through the ``(N,) + sample_shape`` window —
+        also through its ``reshape(N, -1)``, as the normalizers and the
+        benchmark's dataset write — lands in the store, pad zero."""
+        from veles_tpu.ops import gather
+        buf, view = gather.host_store(6, (5, 7, 3), numpy.float32)
+        assert view.shape == (6, 5, 7, 3) and buf.shape == (6, 1, 128)
+        assert gather.host_store_of(view) is buf
+        flat = view.reshape(6, -1)
+        assert numpy.shares_memory(flat, buf)
+        flat[:] = numpy.arange(6 * 105).reshape(6, 105)
+        numpy.testing.assert_array_equal(
+            buf.reshape(6, 128)[:, :105],
+            numpy.arange(6 * 105).reshape(6, 105))
+        assert not buf.reshape(6, 128)[:, 105:].any()
+        # a plain array, a copy and a row slice are not a store's window
+        assert gather.host_store_of(numpy.zeros((6, 5, 7, 3))) is None
+        assert gather.host_store_of(view.copy()) is None
+        assert gather.host_store_of(view[1:]) is None
+
+    @pytest.mark.parametrize("count", [1000, 128, 5])
+    def test_gather_labels_from_store(self, count):
+        from veles_tpu.ops import gather, gather_labels
+        labels = RS.randint(0, 10, count).astype(numpy.int32)
+        idx = numpy.array([0, count - 1, count // 2, 0], numpy.int32)
+        store = gather.build_label_store(labels)
+        assert store.shape == (-(-count // 128), 1, 128)
+        for table in (store, labels):
+            got = numpy.asarray(gather_labels(jnp.asarray(table),
+                                              jnp.asarray(idx)))
+            numpy.testing.assert_array_equal(got, labels[idx])
+
+    def test_gather_clamps_indices_into_the_table(self):
+        """A DMA from a row that is not there would fault the chip."""
+        data = RS.rand(10, 12).astype(numpy.float32)
+        idx = numpy.array([-3, 0, 9, 10, 1 << 30], numpy.int32)
+        out = numpy.asarray(gather_minibatch(jnp.asarray(data),
+                                             jnp.asarray(idx)))
+        numpy.testing.assert_array_equal(out, data[[0, 0, 9, 9, 9]])
+
+
 class TestNormalize:
     def test_mean_disp(self):
         x = (RS.rand(30, 50) * 255).astype(numpy.uint8)

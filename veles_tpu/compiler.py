@@ -809,6 +809,23 @@ def _tail_schedule(order, batch, what):
     return order, sizes, n_steps, n
 
 
+def _epoch_gathers(dataset, targets, loss):
+    """(idx -> x, idx -> y) for an epoch scan's body.  The row stores
+    (ops/gather.py) are built HERE, once an epoch and outside the scan:
+    a pass over each table, which the body must not repeat per step."""
+    from veles_tpu.ops import gather
+
+    def rows(table):
+        return functools.partial(
+            gather.gather_rows, gather.build_store(table),
+            sample_shape=table.shape[1:])
+
+    if loss == "softmax":
+        return rows(dataset), functools.partial(
+            gather.gather_labels, gather.build_label_store(targets))
+    return rows(dataset), rows(targets)
+
+
 def build_train_epoch(plans, batch, loss="softmax", donate=True,
                       compiler_options=None):
     """Compile fn(state, dataset, targets, order, key=None) ->
@@ -835,28 +852,25 @@ def build_train_epoch(plans, batch, loss="softmax", donate=True,
     import jax
     import jax.numpy as jnp
 
-    from veles_tpu.ops.gather import gather_labels, gather_minibatch
-
     step = _build_step_fn(plans, loss)
 
     def epoch(state, dataset, targets, order, key=None):
         order, sizes, n_steps, n = _tail_schedule(
             order, batch, "build_train_epoch")
         sizes = sizes.astype(jnp.float32)  # step's batch_size arg
+        gather_x, gather_y = _epoch_gathers(dataset, targets, loss)
 
         def body(carry, scans):
             st = carry
             i, size = scans
             idx = jax.lax.dynamic_slice(order, (i * batch,), (batch,))
-            x = gather_minibatch(dataset, idx)
+            x = gather_x(idx)
+            y = gather_y(idx)
             if loss == "softmax":
-                y = gather_labels(targets, idx)
                 # padded slots -> sentinel label: excluded from the CE
                 # sum, n_err, and gradients by the loss's valid mask
+                # (mse loss masks rows >= batch_size itself)
                 y = jnp.where(jnp.arange(batch) < size, y, -1)
-            else:
-                # mse loss masks rows >= batch_size itself
-                y = gather_minibatch(targets, idx)
             k = None if key is None else jax.random.fold_in(key, i)
             st, m = step(st, x, y, size, k)
             return st, m
@@ -899,27 +913,26 @@ def build_eval_epoch(plans, batch, loss="softmax",
     import jax
     import jax.numpy as jnp
 
-    from veles_tpu.ops.gather import gather_labels, gather_minibatch
-
     def epoch(params, dataset, targets, order):
         order, sizes, n_steps, _ = _tail_schedule(
             order, batch, "build_eval_epoch")
+        gather_x, gather_y = _epoch_gathers(dataset, targets, loss)
 
         def body(carry, scans):
             total, count = carry
             i, size = scans
             idx = jax.lax.dynamic_slice(order, (i * batch,), (batch,))
-            x = gather_minibatch(dataset, idx)
+            x = gather_x(idx)
             out = _forward_for_loss(plans, params, x)
             slot = jnp.arange(batch) < size
             if loss == "softmax":
-                y = gather_labels(targets, idx)
+                y = gather_y(idx)
                 valid = (y >= 0) & slot
                 pred = jnp.argmax(out, axis=-1)
                 m = jnp.sum((pred != y) & valid).astype(jnp.int32)
                 c = jnp.sum(valid).astype(jnp.int32)
             else:
-                t = gather_minibatch(targets, idx)
+                t = gather_y(idx)
                 diff = (out.reshape(out.shape[0], -1)
                         - t.reshape(t.shape[0], -1))
                 diff = diff * slot[:, None].astype(diff.dtype)

@@ -8,13 +8,23 @@ index window, zero-padding of short minibatches, labels mapped to ints up
 front.
 
 TPU redesign (reference's GPU path was a per-step __global gather kernel,
-ocl/fullbatch_loader.cl:5-50): the dataset is `device_put` once into HBM;
-each serve step runs ops.gather.gather_minibatch — a Pallas kernel whose
-scalar-prefetched index window routes per-sample DMAs — and adopts the
-result as the device-side minibatch with NO host round-trip
-(Array.set_device_array).  On the numpy backend the same contract runs
-through the host path, which is what the test base uses for parity
-checks.
+ocl/fullbatch_loader.cl:5-50): at `initialize` the dataset goes into HBM
+once, as a ROW STORE (ops/gather.py: every row whole memory tiles, so a
+row is one contiguous run of HBM).  The host buffer `create_originals`
+allocates IS that store, and `original_data.mem` a strided
+`(N,) + sample_shape` window on it, so `load_data()` and the normalizer
+write store layout with no copy and the upload is the buffer as it is; a
+dataset assigned as a plain ndarray takes one host copy.  Each serve step
+runs ops.gather.gather_minibatch on the store — one Pallas kernel whose
+scalar-prefetched index window routes a DMA per sample, then ops over
+the gathered rows only — and adopts the result as the device-side
+minibatch with NO host round-trip (Array.set_device_array).  The stores
+(data, labels, MSE targets) are derived state: not pickled, rebuilt by
+`initialize`, which reads the host rows as they are then — rows written
+later reach the device at the next `initialize`.  `original_data` itself
+never goes to the device: the table is there once.  On the numpy backend
+the same contract runs through the host path, which is what the test
+base uses for parity checks.
 """
 
 import numpy
@@ -27,6 +37,7 @@ from veles_tpu.memory import Array
 from veles_tpu.observe.metrics import registry as _registry
 from veles_tpu.observe.trace import tracer as _tracer
 from veles_tpu import ops
+from veles_tpu.ops import gather
 
 __all__ = ["FullBatchLoader", "FullBatchLoaderMSE"]
 
@@ -81,9 +92,14 @@ class FullBatchLoader(Loader):
         # trailing-underscore attrs are not pickled; the mapped labels
         # are rebuilt from original_labels by _map_original_labels()
         self._mapped_original_labels_ = Array()
+        # the device row stores by what they hold ("data", "labels",
+        # "targets"): derived state, rebuilt by initialize
+        self._stores_ = {}
         # the device path of fill_indices: the upload of the index
         # window and the dispatch of the two gather programs
         self._m_gather_ = _registry.histogram("loader.gather_s")
+        # building and uploading the stores, once an initialize
+        self._m_store_ = _registry.histogram("loader.store_s")
 
     @property
     def shape(self):
@@ -92,9 +108,15 @@ class FullBatchLoader(Loader):
         return self.original_data.shape[1:]
 
     def create_originals(self, dshape, labels=True):
-        """Allocate original_data (+labels) for load_data() to fill."""
-        self.original_data.mem = numpy.zeros(
-            (self.total_samples,) + tuple(dshape), self.dtype)
+        """Allocate original_data (+labels) for load_data() to fill: on
+        the device path a window on a host buffer in row-store layout,
+        which ``_store_rows`` uploads as it is."""
+        if self._use_device_path():
+            self.original_data.set_host_view(gather.host_store(
+                self.total_samples, dshape, self.dtype)[1])
+        else:
+            self.original_data.mem = numpy.zeros(
+                (self.total_samples,) + tuple(dshape), self.dtype)
         if labels:
             self._mapped_original_labels_.mem = numpy.zeros(
                 self.total_samples, Loader.LABEL_DTYPE)
@@ -102,16 +124,19 @@ class FullBatchLoader(Loader):
 
     def initialize(self, device=None, **kwargs):
         self.device = device
+        # the device lets go of the last initialize's table first
+        self._stores_ = {}
         result = super(FullBatchLoader, self).initialize(**kwargs)
         self.analyze_original_dataset()
         self._map_original_labels()
         if self._use_device_path():
             # one-time HBM residency; per-step gathers read from here
-            self.original_data.initialize(self.device)
-            self.original_data.unmap()
+            self._store_rows("data", self.original_data)
             if self.has_labels:
-                self._mapped_original_labels_.initialize(self.device)
-                self._mapped_original_labels_.unmap()
+                self._mapped_original_labels_.map_read()
+                self._store_rows(
+                    "labels", self._mapped_original_labels_,
+                    build=gather.build_label_store)
             self.shuffled_indices.initialize(self.device)
         return result
 
@@ -119,6 +144,26 @@ class FullBatchLoader(Loader):
         return (self.on_device and self.device is not None and
                 not isinstance(self.device, NumpyDevice) and
                 self.device.exists)
+
+    def _store_rows(self, name, array, build=None):
+        """Upload ``array``'s rows as the row store ``name`` (the upload
+        is asynchronous: the span times the host's part).  Rows that
+        ``create_originals`` made are in store layout already; any other
+        take one host copy, after which ``array.mem`` is the window on
+        that copy and the host, too, holds the rows once."""
+        with _tracer.scope("loader.store", cat="loader",
+                           hist=self._m_store_):
+            if build is not None:
+                buf = build(array.mem)
+            else:
+                buf = gather.host_store_of(array.mem)
+                if buf is None:
+                    buf = gather.build_store(array.mem)
+                    array.set_host_view(gather.rows_of(
+                        buf, array.mem.shape[1:]))
+            self._stores_[name] = self.device.put(buf)
+        _registry.gauge("loader.store_bytes").set(
+            sum(store.nbytes for store in self._stores_.values()))
 
     def create_minibatch_data(self):
         self.minibatch_data.mem = numpy.zeros(
@@ -210,13 +255,14 @@ class FullBatchLoader(Loader):
                            hist=self._m_gather_):
             idx_dev = self.device.put(window)
             data = ops.gather_minibatch(
-                self.original_data.devmem, idx_dev, out_dtype=self.dtype)
+                self._stores_["data"], idx_dev, out_dtype=self.dtype,
+                sample_shape=self.shape)
             if count < self.max_minibatch_size:
                 data = self._zero_tail(data, count)
             self.minibatch_data.set_device_array(data, self.device)
             if self.has_labels:
-                labels = ops.gather_labels(
-                    self._mapped_original_labels_.devmem, idx_dev)
+                labels = ops.gather_labels(self._stores_["labels"],
+                                           idx_dev)
                 if count < self.max_minibatch_size:
                     labels = self._mask_tail_labels(labels, count)
                 self.minibatch_labels.set_device_array(labels,
@@ -280,8 +326,7 @@ class FullBatchLoaderMSE(LoaderMSEMixin, FullBatchLoader):
             self.target_normalizer.analyze(self.original_targets.mem)
         self.target_normalizer.normalize(self.original_targets.mem)
         if self._use_device_path():
-            self.original_targets.initialize(self.device)
-            self.original_targets.unmap()
+            self._store_rows("targets", self.original_targets)
         return result
 
     def fill_indices(self, start_offset, count):
@@ -293,7 +338,8 @@ class FullBatchLoaderMSE(LoaderMSEMixin, FullBatchLoader):
         window[:count] = self.minibatch_indices.mem[:count]
         idx_dev = self.device.put(window)
         targets = ops.gather_minibatch(
-            self.original_targets.devmem, idx_dev, out_dtype=self.dtype)
+            self._stores_["targets"], idx_dev, out_dtype=self.dtype,
+            sample_shape=self.original_targets.shape[1:])
         if count < self.max_minibatch_size:
             targets = self._zero_tail(targets, count)
         self.minibatch_targets.set_device_array(targets, self.device)

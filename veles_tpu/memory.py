@@ -99,7 +99,14 @@ class Array(Pickleable):
         if value is None:
             self.reset()
             return
-        self._mem = numpy.ascontiguousarray(value)
+        self.set_host_view(numpy.ascontiguousarray(value))
+
+    def set_host_view(self, view):
+        """Take ``view`` as the host buffer AS IT IS: a strided window
+        on a larger buffer stays a window on it (``mem = view`` would
+        copy it contiguous).  FullBatchLoader's originals are such
+        windows on the row store the device holds."""
+        self._mem = view
         # a wholesale buffer swap invalidates the staging slots (their
         # shape/identity no longer matches); re-staged lazily
         self._stage_bufs_ = None
