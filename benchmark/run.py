@@ -117,8 +117,14 @@ def result_line(manifest, ctx, result, devices):
             raise RuntimeError("the traced run holds no whole train step")
         values = read_layer_metrics(manifest, cell_name, result["layers"])
     else:
-        values = {m["name"]: result["metrics"][m["name"]]
-                  for m in cell_metrics(manifest, "end_to_end", cell_name)}
+        names = [m["name"] for m in
+                 cell_metrics(manifest, "end_to_end", cell_name)]
+        missing = [n for n in names if n not in result["metrics"]]
+        if missing:
+            raise RuntimeError(
+                "this run of %s gave no %s (the runner said why): no "
+                "result" % (cell_name, ", ".join(missing)))
+        values = {name: result["metrics"][name] for name in names}
     line = {"correct": bool(result["correct"]),
             "attempted": int(result["attempted"]),
             "failed": int(result["failed"]),
@@ -128,6 +134,8 @@ def result_line(manifest, ctx, result, devices):
     if ctx.trace:
         from benchmark import reduce_trace
         line["breakdown"] = reduce_trace.breakdown(trace)
+    # last in the line: every number compared for `correct`, [it, limit]
+    line["compared"] = result["compared"]
     return line
 
 
@@ -171,8 +179,12 @@ def main(argv=None):
         device_kind=devices[0].device_kind,
         device=Device(backend="tpu"))
     result = runner.run(ctx)
-    print(json.dumps(result_line(manifest, ctx, result, devices)),
-          flush=True)
+    line = result_line(manifest, ctx, result, devices)
+    for name, (number, limit) in line["compared"].items():
+        sys.stderr.write("benchmark: compared %s %r limit %r\n"
+                         % (name, number, limit))
+    sys.stderr.flush()
+    print(json.dumps(line), flush=True)
     return 0
 
 

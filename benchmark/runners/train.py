@@ -16,8 +16,10 @@ readers read): unit timers and the registry as deltas over the window,
 the reduced trace, the step count, the configuration and the traffic.
 """
 
+import collections
 import errno
 import importlib
+import math
 import os
 import pickle
 import shutil
@@ -35,6 +37,11 @@ from benchmark.datasets import SeededDataset
 #: train steps averaged at each end of the run for "the loss fell"
 LOSS_STEPS = 20
 TRAIN_STEP_MODULE = "jit_step"
+#: samples a run needs beyond its 95th percentile (choosing-metrics, 1)
+MIN_BEYOND_P95 = 10
+#: train steps of the window whose loss and ``finite`` flag the runner
+#: holds before one program on the device counts the failed among them
+CHECK_CHUNK = 64
 
 
 def reference_of(config):
@@ -109,12 +116,27 @@ def file_cap_allows(directory, nbytes):
     return True
 
 
+def count_failed(losses, flags):
+    """How many of these steps have a loss that is not finite or a
+    ``finite`` flag that is false: one device scalar."""
+    import jax.numpy as jnp
+    fine = jnp.stack(flags) & jnp.isfinite(jnp.stack(losses))
+    return jnp.sum(~fine)
+
+
 class WindowUnit(Unit):
-    """Runs after the fused trainer on every minibatch.  Keeps each
-    train step's lazy device scalars (no host sync on the step path),
-    opens the window after ``warmup_steps`` train steps, and closes it
-    ``seconds`` later.  The device is waited for at the two edges of the
-    window and nowhere inside an untraced one."""
+    """Runs after the fused trainer on every minibatch.  Opens the
+    window after ``warmup_steps`` train steps and closes it ``seconds``
+    later; the device is waited for at the two edges and nowhere inside
+    an untraced window.  Every train step of the window is checked, as
+    before PR 28, but its loss and ``finite`` flag are not kept until
+    the end: every ``CHECK_CHUNK`` steps one jitted call counts the
+    failed among them on the device, and the runner keeps that count.
+    Every step's pair kept over a 30 s window (11,500 pairs) slowed the
+    MNIST cell's step by 3 % by the window's end, and a copy of each to
+    the host cost 235 us a step (PERF.md section 6, PR 28).  The loss of
+    the first and of the last ``LOSS_STEPS`` train steps is kept for
+    "the loss fell"."""
 
     hide_from_registry = True
 
@@ -125,8 +147,20 @@ class WindowUnit(Unit):
         self.process_started = kwargs["process_started"]
         #: (directory, first step of the window to trace, whole steps)
         self.trace_plan = kwargs.get("trace_plan")
-        self.losses, self.finite = [], []
+        self.train_steps = 0
+        self.first_losses = []
+        self.last_losses = collections.deque(maxlen=LOSS_STEPS)
         self.stamps = []
+        import jax
+        self._count_failed_ = jax.jit(count_failed)  # not pickled
+        #: (losses, flags) of the window's newest steps, not yet counted
+        self.pending = ([], [])
+        #: one device scalar a chunk: the failed steps in it
+        self.failed_in_chunks = []
+        self.check_seconds = 0.0
+        #: (clock, train steps stamped, this process's CPU seconds),
+        #: about once a second
+        self.cpu_marks = []
         self.eval_steps = 0
         self.opened_at_step = None
         self.open = self.close = None
@@ -150,26 +184,64 @@ class WindowUnit(Unit):
         is_train = sw.loader.minibatch_class == TRAIN
         if is_train:
             trainer = sw.fused_trainer
-            self.losses.append(trainer.last_loss)
-            self.finite.append(trainer.last_step_finite)
+            self.train_steps += 1
+            if len(self.first_losses) < LOSS_STEPS:
+                self.first_losses.append(trainer.last_loss)
+            self.last_losses.append(trainer.last_loss)
         if self.close is not None:
             return
         if self.open is None:
-            if is_train and len(self.losses) >= self.warmup_steps:
-                self.opened_at_step = len(self.losses)
+            if is_train and self.train_steps >= self.warmup_steps:
+                self.opened_at_step = self.train_steps
+                # compiles the chunk's program (or finds it in the
+                # cache) inside set-up
+                self._count_failed_(
+                    (trainer.last_loss,) * CHECK_CHUNK,
+                    (trainer.last_step_finite,) * CHECK_CHUNK)
                 self.open = self._edge()
                 self.setup_s = self.open["clock"] - self.process_started
+                self.cpu_marks.append((self.open["clock"], 0,
+                                       time.process_time()))
             return
         now = time.perf_counter()
         if is_train:
             self.stamps.append(now)
+            self._watch_step(trainer)
             if self.trace_plan is not None:
                 self._drive_trace()
         else:
             self.eval_steps += 1
-        if now - self.open["clock"] >= self.seconds:
+        closing = now - self.open["clock"] >= self.seconds
+        if closing or now - self.cpu_marks[-1][0] >= 1.0:
+            self.cpu_marks.append((now, len(self.stamps),
+                                   time.process_time()))
+        if closing:
             self.close = self._edge()
             sw.decision.complete <<= True
+
+    def _watch_step(self, trainer):
+        """Holds this step's loss and flag; every ``CHECK_CHUNK``-th
+        step hands the chunk to the device to count its failed steps."""
+        started = time.perf_counter()
+        losses, flags = self.pending
+        losses.append(trainer.last_loss)
+        flags.append(trainer.last_step_finite)
+        if len(losses) == CHECK_CHUNK:
+            self.failed_in_chunks.append(
+                self._count_failed_(tuple(losses), tuple(flags)))
+            self.pending = ([], [])
+        self.check_seconds += time.perf_counter() - started
+
+    def failed_steps(self):
+        """The failed steps of the window: the chunks' counts, and the
+        steps since the last chunk read one by one.  Called after the
+        window has closed."""
+        import jax
+        losses, flags = jax.device_get(self.pending)
+        rest = sum(not (bool(flag) and math.isfinite(float(loss)))
+                   for loss, flag in zip(losses, flags))
+        return rest + sum(
+            int(count) for count in jax.device_get(self.failed_in_chunks))
 
     def _drive_trace(self):
         import jax
@@ -300,49 +372,138 @@ def judge(ctx, sw, window):
             setup["seconds"], seconds, steps, window.eval_steps,
             window.opened_at_step)
     problems = []
+    #: every number compared, beside its limit: {name: [number, limit]}
+    compared = {"compiles_in_window": [compiles["count"], 0]}
     if compiles["count"]:
         problems.append("%d compile request(s) inside the window"
                         % compiles["count"])
 
-    losses = numpy.asarray(jax.device_get(window.losses), numpy.float64)
-    finite = numpy.asarray(jax.device_get(window.finite), bool)
-    in_window = slice(window.opened_at_step,
-                      window.opened_at_step + steps)
-    bad = ~(finite[in_window] & numpy.isfinite(losses[in_window]))
-    failed = max(int(bad.sum()), int(sw.fused_trainer.skip_count))
+    first, last = (
+        numpy.asarray(jax.device_get(list(losses)), numpy.float64)
+        for losses in (window.first_losses, window.last_losses))
+    # every step of the window, read by the runner itself, or the
+    # program's own count over the whole run if that is more
+    failed = max(window.failed_steps(), int(sw.fused_trainer.skip_count))
+    compared["failed_steps"] = [failed, 0]
     if failed:
         problems.append("%d skipped or non-finite step(s)" % failed)
-    head, tail = losses[:LOSS_STEPS].mean(), losses[-LOSS_STEPS:].mean()
+    head, tail = first.mean(), last.mean()
     ctx.say("  mean loss of the first %d train steps from initialisation "
             "%.4f, of the last %d %.4f", LOSS_STEPS, head, LOSS_STEPS,
             tail)
+    compared["loss_last_below_first"] = [float(tail), float(head)]
     if not tail < head:
         problems.append("the loss did not fall: %.4f -> %.4f"
                         % (head, tail))
-    problems += against_reference(ctx, sw)
+    diff, off = against_reference(ctx, sw)
+    compared["output_rel_diff"] = [
+        diff, ctx.config["reference"]["max_rel_diff"]]
+    problems += off
 
-    marks = numpy.asarray([opened["clock"]] + window.stamps)[::stride]
-    spans = numpy.diff(marks) * 1e3 / stride
-    check(len(spans) > 0, "no step interval in a window of %d steps",
-          steps)
-    ctx.say("  %d step-interval samples (every %d step(s)); median "
-            "%.4f ms, p95 %.4f ms, max %.4f ms", len(spans), stride,
-            numpy.median(spans), numpy.percentile(spans, 95), spans.max())
+    ctx.say("  the window: %d train + %d eval steps, %d save(s), %.3f s",
+            steps, window.eval_steps,
+            delta(closed["registry"], opened["registry"]).get(
+                "snapshot.exports", 0), seconds)
+    ctx.say("  checking each step's loss and flag took the host %.1f us a "
+            "step (%d chunk(s) of %d)", window.check_seconds * 1e6 / max(
+                steps, 1), len(window.failed_in_chunks), CHECK_CHUNK)
+    metrics = window_metrics(ctx.say, opened["clock"], closed["clock"],
+                             window.stamps, batch, stride)
+    metrics["setup_s"] = window.setup_s
+    cpu_anatomy(ctx.say, window.cpu_marks, batch)
     for problem in problems:
         ctx.say("  NOT CORRECT: %s", problem)
-    return {
-        "correct": not problems, "attempted": steps, "failed": failed,
-        "metrics": {
-            "train_images_per_s": steps * batch / seconds,
-            # linear interpolation between closest ranks
-            "train_step_ms_p95": float(numpy.percentile(spans, 95)),
-            "setup_s": window.setup_s,
-        }}
+    return {"correct": not problems, "attempted": steps, "failed": failed,
+            "metrics": metrics, "compared": compared}
+
+
+def window_metrics(say, opened_clock, closed_clock, stamps, batch, stride):
+    """The end-to-end metrics the stamps give, and the lines that place
+    an off run.  ``train_step_ms_p95`` is left out where fewer than
+    ``MIN_BEYOND_P95`` samples lie beyond the percentile: a cell that
+    reports it then gets no result line."""
+    steps = len(stamps)
+    rates, longest = window_anatomy(opened_clock, stamps, batch)
+    say("  images/s in each whole second: %s",
+        " ".join("%d" % rate for rate in rates))
+    say("  the five longest step intervals (ms @ train step of the "
+        "window): %s", ", ".join("%.3f @ %d" % pair for pair in longest))
+    spans = step_intervals(opened_clock, stamps, stride)
+    check(len(spans) > 0, "no step interval in a window of %d steps",
+          steps)
+    p95, _, beyond = interval_p95(spans)
+    say("  %d step-interval samples (every %d step(s)); median %.4f ms, "
+        "p95 %.4f ms (%d beyond it), max %.4f ms", len(spans), stride,
+        numpy.median(spans), p95, beyond, spans.max())
+    metrics = {"train_images_per_s":
+               steps * batch / (closed_clock - opened_clock)}
+    if beyond >= MIN_BEYOND_P95:
+        metrics["train_step_ms_p95"] = p95
+    else:
+        say("  no train_step_ms_p95: %d sample(s) beyond the percentile, "
+            "and a percentile wants %d", beyond, MIN_BEYOND_P95)
+    return metrics
+
+
+def step_intervals(opened_clock, stamps, stride):
+    """ms a step between every ``stride``-th train-step completion,
+    from the window's opening edge on: many samples, not long ones
+    (``time.perf_counter`` resolves far below a step)."""
+    marks = numpy.asarray([opened_clock] + list(stamps))[::stride]
+    return numpy.diff(marks) * 1e3 / stride
+
+
+def interval_p95(spans):
+    """(95th percentile by linear interpolation between closest ranks,
+    samples, samples beyond the percentile)."""
+    if not len(spans):
+        return float("nan"), 0, 0
+    p95 = float(numpy.percentile(spans, 95))
+    return p95, len(spans), int((spans > p95).sum())
+
+
+def window_anatomy(opened_clock, stamps, batch):
+    """What places an off run: (train images completed in each whole
+    second of the window, the five longest single-step intervals with
+    the window's train step each ended on)."""
+    since = numpy.asarray(stamps) - opened_clock
+    whole = int(since[-1]) if len(since) else 0
+    rates = numpy.bincount(since.astype(int), minlength=whole)[:whole]
+    single = step_intervals(opened_clock, stamps, 1)
+    longest = numpy.argsort(single)[::-1][:5]
+    return ((rates * batch).tolist(),
+            [(float(single[i]), int(i) + 1) for i in longest])
+
+
+def cpu_anatomy(say, marks, batch):
+    """Whose a stall is, as far as a process can see: the CPU time of
+    all its threads (the TPU runtime's spin, so it is more than one
+    core) over the window, and over each stretch between two marks
+    (about a second; a stall makes it longer) that ran more than 2 %
+    under the median stretch's rate, the five slowest of them.  A
+    stretch in which every thread stood still lacks the stall's length
+    in CPU seconds on each core: the process was not running, whatever
+    its Python was about to do."""
+    if len(marks) < 2:
+        return
+    say("  the process used %.2f s of CPU over the window's %.2f s",
+        marks[-1][2] - marks[0][2], marks[-1][0] - marks[0][0])
+    pairs = list(zip(marks, marks[1:]))
+    rates = [(b[1] - a[1]) * batch / (b[0] - a[0]) for a, b in pairs]
+    median = numpy.median(rates)
+    slow = sorted((i for i, rate in enumerate(rates)
+                   if rate < 0.98 * median), key=rates.__getitem__)
+    for i in slow[:5]:
+        a, b = pairs[i]
+        say("  slow stretch: train steps %d-%d in %.3f s, %d images/s "
+            "(median stretch %d), %.2f s of CPU", a[1], b[1], b[0] - a[0],
+            rates[i], median, b[2] - a[2])
 
 
 def against_reference(ctx, sw):
     """One validation minibatch through the program's forward, against
-    the plain float32 reference on the same weights."""
+    the plain float32 reference on the same weights: (the largest
+    difference relative to the reference's largest output, problems)."""
     import jax
 
     from veles_tpu.compiler import build_forward
@@ -378,7 +539,7 @@ def against_reference(ctx, sw):
     if not numpy.isfinite(got).all() or diff > tolerance["max_rel_diff"]:
         problems.append("output off the reference by %.3g (tolerance %g)"
                         % (diff, tolerance["max_rel_diff"]))
-    return problems
+    return diff, problems
 
 
 def layer_context(ctx, sw, window, trace_dir):

@@ -14,6 +14,7 @@ import os
 import re
 import shutil
 import sys
+import time
 import types
 
 import numpy
@@ -227,11 +228,14 @@ def toy_context(chips, seed=20260928, seconds=0.4, **traffic):
 
 @pytest.mark.parametrize("chips", [1, 4])
 def test_train_runner_yields_every_declared_metric(_settings_put_back,
-                                                   chips):
+                                                   monkeypatch, chips):
     """The runner the chip runs, through the product's normal path: one
     device by the default entry (auto-fuse, Prefetcher), four virtual
     devices over ``auto_mesh("data")``.  The trace-read metrics are fed
-    the recorded trace: a CPU has no device plane."""
+    the recorded trace: a CPU has no device plane.  A toy window holds
+    too few samples for a percentile: the floor is lifted here and
+    tested on its own below."""
+    monkeypatch.setattr(train_runner, "MIN_BEYOND_P95", 0)
     ctx = toy_context(chips)
     result = train_runner.run(ctx)
     assert result["correct"], ctx.lines
@@ -251,16 +255,21 @@ def test_train_runner_yields_every_declared_metric(_settings_put_back,
     layers["trace"] = reduce_trace.reduce(TRACE)
     layers["dataset_rows"] = 12288
     traced = bench_run.read_layer_metrics(MANIFEST, CELLS[0], layers)
-    assert set(traced) == set(PER_LAYER) - (
-        set() if chips == 1 else {"pipeline_wait_us_per_step.train"})
+    assert set(traced) == {m["name"] for m in bench_run.cell_metrics(
+        MANIFEST, "per_layer", CELLS[0])} - (
+            set() if chips == 1 else {"pipeline_wait_us_per_step.train"})
     assert all(numpy.isfinite(v) and v >= 0 for v in traced.values())
     assert traced["step_peak_pct.train"] <= 100
     # the one save of the run fell into set-up, whatever the seed
     assert layers["registry_whole_run"]["snapshot.exports"] == 1
     assert layers["registry"].get("snapshot.exports", 0) == 0
     line = bench_run.result_line(MANIFEST, ctx, result, ctx.devices)
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    assert set(line["compared"]) == {
+        "compiles_in_window", "failed_steps", "loss_last_below_first",
+        "output_rel_diff"}
+    assert all(len(pair) == 2 for pair in line["compared"].values())
     assert set(line["metrics"]) == {m["name"]
                                     for m in MANIFEST["end_to_end"]}
     assert line["device"]["count"] == chips
@@ -289,6 +298,119 @@ def test_a_compile_inside_the_window_is_not_correct(_settings_put_back,
                for line in ctx.lines), ctx.lines
 
 
+def test_the_window_keeps_few_device_scalars_alive(_settings_put_back,
+                                                   monkeypatch):
+    """Thousands of live device scalars slow the program's step (PERF.md
+    section 6, PR 28): the window checks EVERY step's loss and flag, but
+    holds at most a chunk of them, whatever the number of steps -- one
+    count a chunk, compiled before the window opens -- and keeps the
+    loss of the first and of the last ``LOSS_STEPS`` train steps."""
+    seen = {"most": 0}
+    plain_judge = train_runner.judge
+    plain_watch = train_runner.WindowUnit._watch_step
+
+    def judge(ctx, sw, window):
+        seen["window"], seen["trainer"] = window, sw.fused_trainer
+        return plain_judge(ctx, sw, window)
+
+    def watch_step(self, trainer):
+        plain_watch(self, trainer)
+        seen["most"] = max(seen["most"], len(self.pending[0]))
+
+    monkeypatch.setattr(train_runner, "judge", judge)
+    monkeypatch.setattr(train_runner.WindowUnit, "_watch_step", watch_step)
+    monkeypatch.setattr(train_runner, "CHECK_CHUNK", 8)
+    ctx = toy_context(1, seconds=0.3)
+    result = train_runner.run(ctx)
+    assert result["correct"], ctx.lines  # so: no compile in the window
+    window = seen["window"]
+    kept = train_runner.LOSS_STEPS
+    assert window.train_steps - window.opened_at_step >= \
+        result["attempted"] > 2 * kept
+    assert len(window.first_losses) == len(window.last_losses) == kept
+    assert not hasattr(window, "losses") and not hasattr(window, "finite")
+    # every step of the window is in a chunk's count or still pending
+    assert seen["most"] == 7
+    assert 8 * len(window.failed_in_chunks) + len(window.pending[0]) == \
+        result["attempted"]
+    assert result["failed"] == int(seen["trainer"].skip_count) == 0
+    assert result["compared"]["failed_steps"] == [0, 0]
+    head, tail = result["compared"]["loss_last_below_first"][::-1]
+    assert head > tail > 0
+    assert any("checking each step's loss and flag took the host" in line
+               for line in ctx.lines), ctx.lines
+
+
+@pytest.mark.parametrize("fault, planted_at", [
+    ("nan_loss_flag_true", 2 * train_runner.LOSS_STEPS + 5),
+    ("flag_false_loss_finite", 2 * train_runner.LOSS_STEPS + 5),
+    ("nan_loss_flag_true", -1)])  # the window's last step: in no chunk
+def test_one_bad_step_in_mid_window_is_not_correct(
+        _settings_put_back, monkeypatch, fault, planted_at):
+    """The runner reads every step of the window itself: one step in
+    the middle whose loss is NaN while its ``finite`` flag says true --
+    or whose flag is false while the program's counter did not count --
+    is a failed step and a run that is not correct, though the 40
+    losses kept for "the loss fell" and the program's ``skip_count``
+    show nothing."""
+    plain_run = train_runner.WindowUnit.run
+    monkeypatch.setattr(train_runner, "CHECK_CHUNK", 16)
+
+    def run_with_one_bad_step(self):
+        trainer = self.workflow.fused_trainer
+        if self.workflow.loader.minibatch_class == train_runner.TRAIN:
+            if not hasattr(self, "bad"):
+                # made in set-up from the step's own scalars, so that
+                # nothing compiles for it inside the window
+                self.bad = {
+                    "nan_loss_flag_true": (
+                        "last_loss",
+                        trainer.last_loss * numpy.float32("nan")),
+                    "flag_false_loss_finite": (
+                        "last_step_finite", ~trainer.last_step_finite),
+                }[fault]
+            due = len(self.stamps) == planted_at if planted_at >= 0 else (
+                self.open is not None and time.perf_counter()
+                - self.open["clock"] >= self.seconds)
+            if due and self.bad is not None:
+                setattr(trainer, *self.bad)
+                self.bad = None
+        plain_run(self)
+
+    monkeypatch.setattr(train_runner.WindowUnit, "run",
+                        run_with_one_bad_step)
+    ctx = toy_context(1, seconds=0.4)
+    result = train_runner.run(ctx)
+    assert result["attempted"] > planted_at + train_runner.LOSS_STEPS
+    assert not result["correct"] and result["failed"] == 1
+    assert result["compared"]["failed_steps"] == [1, 0]
+    if planted_at >= 0:
+        assert all(numpy.isfinite(
+            result["compared"]["loss_last_below_first"]))
+    assert "  NOT CORRECT: 1 skipped or non-finite step(s)" in ctx.lines
+    assert not any("compile request(s) inside" in line
+                   for line in ctx.lines), ctx.lines
+
+
+def test_a_step_the_program_skipped_is_a_failed_step(_settings_put_back,
+                                                     monkeypatch):
+    """``failed`` is also the program's own count of non-finite steps,
+    where that is more than the runner read itself: one more on the
+    counter is one failed step and a run that is not correct."""
+    plain_judge = train_runner.judge
+
+    def judge(ctx, sw, window):
+        sw.fused_trainer.skip_count = sw.fused_trainer.skip_count + 1
+        return plain_judge(ctx, sw, window)
+
+    monkeypatch.setattr(train_runner, "judge", judge)
+    ctx = toy_context(1, seconds=0.1)
+    result = train_runner.run(ctx)
+    assert not result["correct"] and result["failed"] == 1
+    assert any("1 skipped or non-finite step(s)" in line
+               for line in ctx.lines), ctx.lines
+
+
 def test_same_seed_same_inputs_and_weights(_settings_put_back):
     """Data and initial weights are functions of --seed."""
     def first_losses(seed):
@@ -301,6 +423,118 @@ def test_same_seed_same_inputs_and_weights(_settings_put_back):
         first_losses(big).split("of the last")[0]
     assert first_losses(big).split("of the last")[0] != \
         first_losses(7).split("of the last")[0]
+
+
+# -- the step interval's percentile --------------------------------------------
+
+#: the per-step values of 401 samples; by linear interpolation between
+#: closest ranks the 95th percentile is rank 380 of 400: 2.95 exactly
+TAIL_VALUES = numpy.linspace(2.0, 3.0, 401)
+
+
+def seeded_stamps(stride, values=TAIL_VALUES, seed=20261001):
+    """(opening edge, stamps): every ``stride``-th completion lies a
+    sample's ``stride * value`` ms after the last, the samples in a
+    seeded order, the steps inside a sample at seeded uneven places --
+    so only the stride the stamps were built for reads the values."""
+    rng = numpy.random.RandomState(seed)
+    samples = rng.permutation(values) * stride / 1e3
+    inside = rng.uniform(0.2, 1.8, (len(samples), stride))
+    steps = inside / inside.sum(1, keepdims=True) * samples[:, None]
+    opened = 1234.5
+    return opened, (opened + numpy.cumsum(steps.ravel())).tolist()
+
+
+@pytest.mark.parametrize("stride", [1, 5, 20, 50, 100])
+def test_interval_p95_reads_the_known_tail_per_step(stride):
+    opened, stamps = seeded_stamps(stride)
+    spans = train_runner.step_intervals(opened, stamps, stride)
+    p95, samples, beyond = train_runner.interval_p95(spans)
+    assert samples == 401 and beyond == 20
+    assert p95 == pytest.approx(2.95, rel=1e-9)
+    assert numpy.median(spans) == pytest.approx(2.5, rel=1e-9)
+    # a window that ends inside a sample drops the part, not the sample
+    spans = train_runner.step_intervals(opened, stamps[:-1], stride)
+    assert len(spans) == (401 if stride == 1 else 400) - (stride == 1)
+    if stride > 1:  # another stride reads other samples, not these values
+        other = train_runner.interval_p95(
+            train_runner.step_intervals(opened, stamps, 1))[0]
+        assert abs(other - 2.95) > 0.05
+
+
+@pytest.mark.parametrize("stride", [1, 5, 20, 50, 100])
+def test_a_window_with_under_ten_samples_beyond_gives_no_p95(stride):
+    """180 samples leave 9 beyond the percentile: the runner leaves the
+    metric out and says why (and ``run.py`` gives a cell that reports
+    it no line: below); 201 leave 10, and the metric is there."""
+    def metrics_of(samples):
+        opened, stamps = seeded_stamps(
+            stride, numpy.linspace(2.0, 3.0, samples))
+        lines = []
+        return lines, train_runner.window_metrics(
+            lambda fmt, *args: lines.append(fmt % args), opened,
+            stamps[-1], stamps, 100, stride)
+
+    lines, short = metrics_of(180)
+    assert set(short) == {"train_images_per_s"}
+    assert short["train_images_per_s"] == pytest.approx(
+        100 / 2.5e-3, rel=1e-9)
+    assert any("no train_step_ms_p95: 9 sample(s) beyond" in line
+               for line in lines), lines
+    lines, enough = metrics_of(201)
+    assert enough["train_step_ms_p95"] == pytest.approx(2.95, rel=1e-9)
+    assert any("201 step-interval samples (every %d step(s))" % stride
+               in line and "(10 beyond it)" in line for line in lines)
+
+
+@pytest.mark.parametrize("cell_name, metrics, missing", [
+    (CELLS[0], {"setup_s": 1.0}, "train_images_per_s"),
+    ("mnist_mlp_train_b100",
+     {"setup_s": 1.0, "train_images_per_s": 2.0}, "train_step_ms_p95"),
+])
+def test_a_run_that_lacks_an_end_to_end_metric_gets_no_line(
+        cell_name, metrics, missing):
+    import jax
+    ctx = types.SimpleNamespace(cell={"name": cell_name}, trace=False)
+    result = {"correct": True, "attempted": 9, "failed": 0,
+              "metrics": metrics, "compared": {},
+              "layers": {"trace": None}}
+    with pytest.raises(RuntimeError, match="gave no " + missing):
+        bench_run.result_line(MANIFEST, ctx, result, jax.devices()[:1])
+
+
+#: ms a train step, where a cell reports ``train_step_ms_p95`` (my chip
+#: runs, PR 28: PERF.md section 5)
+RECORDED_STEP_MS = {"mnist_mlp_train_b100": 2.6}
+
+
+@pytest.mark.parametrize("cell_name", bench_run.find(
+    MANIFEST["end_to_end"], "train_step_ms_p95", "metric")["workloads"])
+def test_the_traffic_files_stride_leaves_ten_samples_beyond(cell_name):
+    """At the cell's recorded step time a window of ``run_seconds``
+    holds, at the traffic file's stride, at least twice the ten samples
+    beyond the 95th percentile that a run needs."""
+    _, _, traffic = bench_run.load_cell(MANIFEST, cell_name)
+    steps = MANIFEST["run_seconds"] * 1e3 / RECORDED_STEP_MS[cell_name]
+    samples = int(steps // traffic["interval_stride"])
+    beyond = samples - 1 - int(0.95 * (samples - 1))
+    assert beyond >= 2 * train_runner.MIN_BEYOND_P95, (samples, beyond)
+
+
+def test_cpu_anatomy_places_a_stall_in_which_the_process_stood_still():
+    marks, lines = [], []
+    clock = steps = cpu = 0.0
+    for i in range(11):  # ten stretches of 400 steps; the sixth lasts 3 s
+        marks.append((clock, int(steps), cpu))
+        clock += 3.0 if i == 5 else 1.0
+        cpu += 2.5  # and gets no more CPU than the others: 2 s frozen
+        steps += 400
+    train_runner.cpu_anatomy(
+        lambda fmt, *args: lines.append(fmt % args), marks, 100)
+    assert lines == [
+        "  the process used 25.00 s of CPU over the window's 12.00 s",
+        "  slow stretch: train steps 2000-2400 in 3.000 s, 13333 images/s "
+        "(median stretch 40000), 2.50 s of CPU"]
 
 
 # -- a cell dropped in as files ----------------------------------------------
