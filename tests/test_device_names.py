@@ -135,6 +135,31 @@ def test_pool_bwd_is_named_at_alexnet_pool1(topo, one_chip, mosaic):
     assert pool_bwd.KERNEL_NAME in op_name
 
 
+def test_causal_latent_attention_kernels_at_the_decoders_widths(
+        topo, one_chip, mosaic):
+    """The decoder cell's attention: 8,192 tokens, keys 192 wide against
+    values 128 wide, causal, bfloat16 products, at the tiles
+    ``blocks=None`` takes; forward and both backward kernels pass
+    Mosaic and keep their names through ``custom_vjp``."""
+    import jax
+    import jax.numpy as jnp
+
+    from veles_tpu.ops import attention
+    q = aval((4, 8192, 192), jnp.bfloat16)
+    v = aval((4, 8192, 128), jnp.bfloat16)
+
+    def loss_grads(q, k, v):
+        return jax.grad(lambda *a: attention.flash_attention(
+            *a, causal=True, product_dtype=jnp.bfloat16).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    calls = mosaic_calls(compiled_text(loss_grads, one_chip, q, q, v))
+    kernels = sorted(name.lstrip("%").split(".")[0] for name in calls)
+    assert kernels == sorted((attention.FWD_KERNEL_NAME,
+                              attention.DQ_KERNEL_NAME,
+                              attention.DKV_KERNEL_NAME)), calls
+
+
 def toy_step(batch=8):
     import jax
     plans, state, _ = zoo.build_plans_and_state(TOY_CNN, TOY_INPUT,

@@ -113,7 +113,7 @@ def layer_scope(index, plan):
 
 
 def _forward_for_loss(plans, params, x, key=None, remat=False,
-                      layer_fn=None, fold_offset=0):
+                      layer_fn=None, fold_offset=0, aux=None):
     """Forward pass; returns (pre-softmax logits | final output).
 
     ``key``: dropout rng; None (inference / keyless step) makes dropout
@@ -132,6 +132,10 @@ def _forward_for_loss(plans, params, x, key=None, remat=False,
     ``fold_offset`` shifts the dropout key-fold index — a caller
     walking a SLICE of a larger model (the pipeline step's tail) must
     key dropout on the global layer index to match the fused step.
+
+    ``aux``: a list that collects ``(layer index, {name: array})`` from
+    the layers whose class has ``apply_with_aux`` (a routed layer's
+    per-expert load); None leaves them out.
     """
     from veles_tpu.models.all2all import All2All, All2AllSoftmax
     from veles_tpu.models.dropout import DropoutForward
@@ -161,10 +165,25 @@ def _forward_for_loss(plans, params, x, key=None, remat=False,
                         h.shape, plan.static.get("dropout_ratio", 0.5),
                         h.dtype)
                     h = h * mask
+            elif aux is not None and hasattr(plan.forward_cls,
+                                             "apply_with_aux"):
+                h, extra = layer(functools.partial(
+                    plan.forward_cls.apply_with_aux, **plan.static))(p, h)
+                if extra:
+                    aux.append((i + fold_offset, extra))
             else:
                 h = layer(functools.partial(
                     plan.forward_cls.apply, **plan.static))(p, h)
     return h
+
+
+def _stack_layer_aux(collected):
+    """[(layer, {name: array})] -> {name: array stacked over the layers
+    that gave it, in layer order}: what the step adds to its metrics."""
+    import jax.numpy as jnp
+    names = sorted({name for _, extra in collected for name in extra})
+    return {name: jnp.stack([extra[name] for _, extra in collected
+                             if name in extra]) for name in names}
 
 
 def _chain_grad_barriers(grads):
@@ -256,17 +275,25 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
     hypers = [p.hyper_full() for p in plans]
 
     def loss_fn(params, x, target, batch_size, key):
+        collected = []
         if forward_fn is not None:
             out = forward_fn(params, x, key, bwd_remat)
         else:
             out = _forward_for_loss(plans, params, x, key,
-                                    remat=bwd_remat)
+                                    remat=bwd_remat, aux=collected)
         with jax.named_scope("loss"):
-            return loss_of(out, target, batch_size)
+            value, metric = loss_of(out, target, batch_size)
+        return value, (metric, _stack_layer_aux(collected))
 
     def loss_of(out, target, batch_size):
         if loss == "softmax":
             labels = target
+            if out.ndim == 3:
+                # (B, T, V) logits against (B, T) next tokens: every
+                # position is a sample, the mean is over tokens
+                batch_size = batch_size * out.shape[1]
+                out = out.reshape(-1, out.shape[-1])
+                labels = labels.reshape(-1)
             valid = labels >= 0
             safe = jnp.where(valid, labels, 0)
             logp = jax.nn.log_softmax(out)
@@ -290,10 +317,10 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
         return jnp.sum(diff * diff) / batch_size, mse_sum
 
     def step(state, x, target, batch_size, step_key=None,
-             grad_poison=None, loss_poison=None):
+             grad_poison=None, loss_poison=None, step_count=None):
         params = [{"weights": s["weights"], "bias": s["bias"]}
                   for s in state]
-        (loss_value, aux), grads = jax.value_and_grad(
+        (loss_value, (aux, layer_aux)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, x, target, batch_size,
                                    step_key)
         # chaos nan-injection (docs/health.md): the poisons are traced
@@ -320,6 +347,8 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
         if metric_sync is not None:
             loss_value = metric_sync(loss_value)
             aux = metric_sync(aux)
+            layer_aux = {name: metric_sync(value)
+                         for name, value in layer_aux.items()}
 
         # numerics guard: one all-isfinite reduction over the loss and
         # the global grad-norm.  A single inf/nan anywhere in the
@@ -344,7 +373,7 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
             step_finite = jnp.isfinite(loss_value) & jnp.isfinite(grad_norm)
 
             new_state = new_state if zero_update is not None else \
-                _apply_solver(plans, hypers, state, grads)
+                _apply_solver(plans, hypers, state, grads, step_count)
             # a non-finite update is SKIPPED, not applied: every state leaf
             # falls back to its pre-step value, so one poisoned minibatch
             # leaves params (and solver accumulators) bit-identical to
@@ -358,40 +387,51 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
             metrics = {"loss": loss_value,
                        "n_err": jnp.zeros((), jnp.int32),
                        "mse_sum": aux}
+        # what the layers count for themselves (a routed layer's
+        # per-expert load), stacked over layers: lazy like the rest
+        metrics.update(layer_aux)
         metrics["grad_norm"] = grad_norm
         metrics["finite"] = step_finite
         metrics["skipped"] = (~step_finite).astype(jnp.int32)
         return new_state, metrics
 
-    def _apply_solver(plans, hypers, state, grads):
+    def _apply_solver(plans, hypers, state, grads, step_count=None):
+        def solver_grad(plan, decay, hyper, grad, param):
+            """(the gradient the solver takes, its extra keywords):
+            adamw decays the parameter, not the gradient, and wants the
+            count of this step (1 for the first)."""
+            grad = grad.astype(param.dtype)
+            if plan.solver == "adamw":
+                return grad, {"step": step_count, "decay": decay}
+            return GradientDescentBase.regularized(
+                grad, param, decay, hyper["l1_vs_l2"]), {}
+
         new_state = []
         for plan, hyper, s, g in zip(plans, hypers, state, grads):
             if s["weights"] is None:  # param-less layer (pooling, ...)
                 new_state.append(dict(s))
                 continue
             W = s["weights"]
-            gw = GradientDescentBase.regularized(
-                g["weights"].astype(W.dtype), W,
-                hyper["weights_decay"], hyper["l1_vs_l2"])
+            gw, extra = solver_grad(plan, hyper["weights_decay"],
+                                    hyper, g["weights"], W)
             new_w, acc_w, acc2_w = GradientDescentBase.solver_update(
                 plan.solver, W, gw, s["accum_weights"],
                 s["accum2_weights"], hyper["learning_rate"],
                 hyper["gradient_moment"], hyper["adadelta_rho"],
-                hyper["solver_epsilon"])
+                hyper["solver_epsilon"], **extra)
             entry = {"weights": new_w, "accum_weights": acc_w,
                      "accum2_weights": acc2_w,
                      "bias": s["bias"], "accum_bias": s["accum_bias"],
                      "accum2_bias": s["accum2_bias"]}
             if plan.include_bias and s["bias"] is not None:
                 b = s["bias"]
-                gb = GradientDescentBase.regularized(
-                    g["bias"].astype(b.dtype), b,
-                    hyper["weights_decay_bias"], hyper["l1_vs_l2"])
+                gb, extra = solver_grad(plan, hyper["weights_decay_bias"],
+                                        hyper, g["bias"], b)
                 new_b, acc_b, acc2_b = GradientDescentBase.solver_update(
                     plan.solver, b, gb, s["accum_bias"], s["accum2_bias"],
                     hyper["learning_rate_bias"],
                     hyper["gradient_moment_bias"], hyper["adadelta_rho"],
-                    hyper["solver_epsilon"])
+                    hyper["solver_epsilon"], **extra)
                 entry.update({"bias": new_b, "accum_bias": acc_b,
                               "accum2_bias": acc2_b})
             new_state.append(entry)
@@ -437,7 +477,13 @@ def build_train_step(plans, loss="softmax", mesh=None, data_axis="data",
     optional ``grad_poison`` / ``loss_poison`` keyword scalars are the
     chaos harness's in-graph nan-injection hooks (None costs nothing).
     batch_size is a traced scalar so
-    short minibatches don't retrigger compilation.
+    short minibatches don't retrigger compilation.  (B, T, V) logits
+    against (B, T) integer targets are the next-token loss: the mean and
+    ``n_err`` are over tokens.  The ``adamw`` solver wants the optional
+    ``step_count`` keyword (this step's number, from 1; a traced scalar),
+    which only the single-device step takes: the shard_map builders do
+    not pass it on yet.  Layers with ``apply_with_aux`` add their own
+    counters to the metrics, stacked over layers (``moe_load`` ...).
     ``compiler_options``: per-program XLA options (see
     :func:`step_compiler_options` for the tuned per-chip set).
 

@@ -13,6 +13,7 @@ from veles_tpu.loader.base import (  # noqa: F401
     Loader, LoaderMSEMixin, LoaderError, TEST, VALID, TRAIN, CLASS_NAME)
 from veles_tpu.loader.fullbatch import (  # noqa: F401
     FullBatchLoader, FullBatchLoaderMSE)
+from veles_tpu.loader.tokens import TokenRowLoader  # noqa: F401
 from veles_tpu.loader.audio import AudioFileLoader  # noqa: F401
 from veles_tpu.loader.hdfs import (  # noqa: F401
     HdfsTextLoader, WebHdfsClient)

@@ -244,13 +244,7 @@ class FullBatchLoader(Loader):
         if not self._use_device_path():
             return super(FullBatchLoader, self).fill_indices(
                 start_offset, count)
-        self.shuffled_indices.map_read()
-        window = numpy.full(
-            self.max_minibatch_size, 0, Loader.INDEX_DTYPE)
-        window[:count] = \
-            self.shuffled_indices.mem[start_offset:start_offset + count]
-        self.minibatch_indices.mem[:count] = window[:count]
-        self.minibatch_indices.mem[count:] = -1
+        window = self._index_window(start_offset, count)
         with _tracer.scope("loader.gather", cat="loader",
                            hist=self._m_gather_):
             idx_dev = self.device.put(window)
@@ -268,6 +262,18 @@ class FullBatchLoader(Loader):
                 self.minibatch_labels.set_device_array(labels,
                                                        self.device)
         return True
+
+    def _index_window(self, start_offset, count):
+        """The minibatch's ``count`` shuffled indices in a window of the
+        full minibatch size (0 past ``count``: a row that exists), and
+        the same in ``minibatch_indices`` (-1 past ``count``)."""
+        self.shuffled_indices.map_read()
+        window = numpy.zeros(self.max_minibatch_size, Loader.INDEX_DTYPE)
+        window[:count] = \
+            self.shuffled_indices.mem[start_offset:start_offset + count]
+        self.minibatch_indices.mem[:count] = window[:count]
+        self.minibatch_indices.mem[count:] = -1
+        return window
 
     @staticmethod
     def _zero_tail(data, count):

@@ -301,6 +301,9 @@ class DecisionGD(DecisionBase):
         length = self.class_lengths[class_index]
         if length == 0:
             return None
+        # a sample may carry many targets (a row of next tokens): the
+        # rate is over targets, as the errors are counted
+        length *= getattr(self.evaluator, "targets_per_sample", 1)
         # forces the device sync (once per finished class, not per
         # minibatch) and normalizes to a plain float for logs/JSON
         with self._sync():

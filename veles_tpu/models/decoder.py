@@ -1,0 +1,602 @@
+"""Causal decoder units: token embedding, a latent-attention (MLA) layer
+with a gated SiLU feed-forward or a routed expert layer, and the output
+head — the DeepSeek-V3 layer family (docs/model_layer.md "Decoder
+units").
+
+Built on the contracts of ``transformer.py``: the math is in pure
+functions and ``apply(params, x, **static)`` class methods; a layer's
+many matrices pack into the ONE ``(weights, bias)`` pair every unit has
+— one flat float32 vector each, static offsets (:func:`layer_layout`) —
+so ``compiler.py``, the snapshotter and ``parallel/`` see a layer like
+any other.  ``weights`` holds the matrices (decayed by the solver),
+``bias`` what is not decayed: the RMSNorm gains and the router's
+correction bias, which takes no gradient.  The state is float32 whatever
+``root.common.engine.precision_type`` says (``STATE_DTYPE``); that
+setting is the dtype of the operands and activations, and every product
+accumulates in float32.
+
+The layer, ``h`` the residual stream (config.json keys of the family in
+brackets)::
+
+    a = rms_norm(h)
+    q = a W_q                      -> heads x (nope | rope)
+    [c | k_rope] = a W_kva         -> kv_rank + rope   (kv_lora_rank)
+    [k_nope | v] = rms_norm(c) W_kvb -> heads x (nope + v_head)
+    rotary on q_rope per head and on the one k_rope all heads share,
+    adjacent pairs (rope_interleave)
+    h += causal_softmax((q_nope.k_nope + q_rope.k_rope) / sqrt(nope+rope)) v W_o
+    m = rms_norm(h)
+    dense:  h += (silu(m W_g) * m W_u) W_d
+    routed: p = sigmoid(m W_r); the top_k largest of p + b;
+            w_i = p_i / sum_chosen p * routed_scale
+            h += sum_i w_i Expert_i(m) + Shared(m)
+
+**The share.**  A routed layer is told which experts it holds
+(``first_expert``, ``experts_held``): it routes over ALL ``experts``,
+sorts the step's token-expert assignments by expert, keeps those of its
+own experts in a buffer, and runs grouped products
+(``lax.ragged_dot``) over them.  What the absent experts would add is
+left out — that partial result goes on — and nothing stands in for the
+chips that hold them or for the exchange.  The buffer holds the most a
+step can send: tokens x min(top_k, experts held) rows, so nothing is
+ever dropped (the grouped products pay for the rows that are filled, the
+elementwise passes for the whole buffer).  A layer built with a smaller
+``capacity`` (no factory or configuration sets one) DROPS the
+assignments that do not fit and counts them (``moe_dropped``): the layer
+never drops one silently.
+
+**Initialisation.**  Normal, ``weights_stddev`` every matrix but those
+that write into the residual stream (``w_o`` and every ``*_down``),
+which take ``out_stddev`` (default: the same).  With one std everywhere
+the first layer's attention output — an average of values over the
+prefix, so nearly the same vector at every position — outweighs the
+embedding four to one, every token looks alike to the router and all
+choose the same experts; the scaled form (std / sqrt(2 x layers), as
+GPT-2 and Megatron-LM initialise these projections) keeps tokens apart.
+"""
+
+import functools
+
+import numpy
+
+from veles_tpu.models.transformer import _GDAutodiff, _SequenceUnit
+
+__all__ = ["DecoderEmbedding", "DecoderLayer", "DecoderHead",
+           "GDDecoderEmbedding", "GDDecoderLayer", "GDDecoderHead",
+           "rms_norm", "rotary", "latent_attention",
+           "gated_ffn", "routed_experts", "layer_layout", "unpack",
+           "decoder_layer"]
+
+#: ``jax.named_scope`` names inside each ``l<k>_DecoderLayer``
+SCOPE_ATTENTION = "attention"
+SCOPE_ROUTER = "router"
+SCOPE_ROUTED = "routed_experts"
+SCOPE_SHARED = "shared_experts"
+SCOPE_FFN = "dense_ffn"
+
+
+# -- pure math ---------------------------------------------------------------
+
+
+def rms_norm(x, gain, eps=1e-6):
+    """x / sqrt(mean(x^2) + eps) * gain over the last axis, float32
+    statistics, in x's dtype."""
+    import jax.numpy as jnp
+    from jax import lax
+    xf = x.astype(jnp.float32)
+    scale = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                      + eps)
+    return (xf * scale * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def rotary(x, theta):
+    """Rotary positions on ADJACENT pairs of the last axis: pair ``i``
+    of position ``t`` turns by ``t * theta ** (-2 i / width)``.  ``x``
+    is (B, T, ..., width); positions count from 0 along axis 1."""
+    import jax.numpy as jnp
+    width = x.shape[-1]
+    t = x.shape[1]
+    inv = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)       # (T, width)
+    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)
+    shape = (1, t) + (1,) * (x.ndim - 3) + (width,)
+    xf = x.astype(jnp.float32)
+    # the pair's other element: (a, b) -> (-b, a)
+    even = (jnp.arange(width) % 2 == 0)
+    other = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                      jnp.roll(xf, 1, axis=-1))
+    return (xf * cos.reshape(shape) + other * sin.reshape(shape)).astype(
+        x.dtype)
+
+
+def _dense(x, w):
+    import jax.numpy as jnp
+    return jnp.einsum("...f,fg->...g", x, w,
+                      preferred_element_type=jnp.float32)
+
+
+def _attend(q, k, v, scale, pallas_bwd):
+    """(B*H, T, .) causal attention through the flash kernels or the
+    stock reference, per the VELES_PALLAS_BWD contract."""
+    import jax.numpy as jnp
+
+    from veles_tpu.ops.attention import (attention_reference,
+                                         flash_attention)
+    if pallas_bwd is None:
+        from veles_tpu.ops.common import pallas_bwd_enabled
+        pallas_bwd = pallas_bwd_enabled()
+    if not pallas_bwd:
+        return attention_reference(q, k, v, scale=scale, causal=True)
+    narrow = q.dtype if q.dtype == jnp.bfloat16 else None
+    return flash_attention(q, k, v, scale=scale, causal=True,
+                           product_dtype=narrow)
+
+
+def latent_attention(a, w, *, heads, qk_nope, qk_rope, v_head, kv_rank,
+                     kv_gain, theta, eps, pallas_bwd=None):
+    """The MLA sub-layer over normalised ``a`` (B, T, D), before the
+    residual add.  ``w`` holds ``w_q``, ``w_kva``, ``w_kvb``, ``w_o``."""
+    import jax.numpy as jnp
+    b, t, _ = a.shape
+    dtype = a.dtype
+    q = _dense(a, w["w_q"]).astype(dtype).reshape(
+        b, t, heads, qk_nope + qk_rope)
+    kva = _dense(a, w["w_kva"]).astype(dtype)
+    c = rms_norm(kva[..., :kv_rank], kv_gain, eps)
+    k_rope = rotary(kva[..., kv_rank:], theta)           # (B, T, rope)
+    kv = _dense(c, w["w_kvb"]).astype(dtype).reshape(
+        b, t, heads, qk_nope + v_head)
+    q = jnp.concatenate(
+        [q[..., :qk_nope], rotary(q[..., qk_nope:], theta)], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :qk_nope],
+         jnp.broadcast_to(k_rope[:, :, None, :], (b, t, heads, qk_rope))],
+        axis=-1)
+    v = kv[..., qk_nope:]
+
+    def fold(x):  # (B, T, H, w) -> (B*H, T, w)
+        return x.transpose(0, 2, 1, 3).reshape(b * heads, t, x.shape[-1])
+
+    o = _attend(fold(q), fold(k), fold(v),
+                1.0 / float(numpy.sqrt(qk_nope + qk_rope)), pallas_bwd)
+    o = o.reshape(b, heads, t, v_head).transpose(0, 2, 1, 3).reshape(
+        b, t, heads * v_head)
+    return _dense(o, w["w_o"]).astype(dtype)
+
+
+def gated_ffn(m, w_gate, w_up, w_down):
+    """(silu(m W_g) * m W_u) W_d, float32 accumulation, in m's dtype."""
+    import jax
+    gate = _dense(m, w_gate)
+    up = _dense(m, w_up)
+    return _dense((jax.nn.silu(gate) * up).astype(m.dtype),
+                  w_down).astype(m.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _row_moves():
+    """(dispatch, combine): ``dispatch(src, token_of, pos, valid)`` is
+    ``src[token_of]`` (C, D); ``combine(rows, token_of, pos, valid)`` is
+    ``sum_k rows[pos[:, k]]`` over the valid slots (N, D).  Each is the
+    other's transpose, and both are written as gathers: the kept
+    assignments are a permutation, which XLA's scatter cannot know."""
+    import jax
+    import jax.numpy as jnp
+
+    def gather_sum(rows, pos, valid):
+        total = None
+        for j in range(pos.shape[1]):
+            picked = jnp.where(valid[:, j, None],
+                               rows[jnp.where(valid[:, j], pos[:, j], 0)],
+                               0).astype(jnp.float32)
+            total = picked if total is None else total + picked
+        return total.astype(rows.dtype)
+
+    @jax.custom_vjp
+    def dispatch(src, token_of, pos, valid):
+        return src[token_of]
+
+    def dispatch_fwd(src, token_of, pos, valid):
+        return src[token_of], (token_of, pos, valid)
+
+    def dispatch_bwd(res, g):
+        token_of, pos, valid = res
+        return gather_sum(g, pos, valid), None, None, None
+
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+
+    @jax.custom_vjp
+    def combine(rows, token_of, pos, valid):
+        return gather_sum(rows, pos, valid)
+
+    def combine_fwd(rows, token_of, pos, valid):
+        return gather_sum(rows, pos, valid), (token_of, pos, valid)
+
+    def combine_bwd(res, g):
+        token_of, pos, valid = res
+        return g[token_of], None, None, None
+
+    combine.defvjp(combine_fwd, combine_bwd)
+    return dispatch, combine
+
+
+def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
+                   capacity):
+    """The held experts' part of ``sum_i w_i Expert_i(m)``.
+
+    ``m`` (N, D) tokens, ``idx`` (N, K) chosen experts out of all,
+    ``weights`` (N, K) float32; ``e_gate``/``e_up`` (E_held, D, F) and
+    ``e_down`` (E_held, F, D) the experts ``first_expert ..
+    first_expert + E_held - 1``.  Assignments are sorted by expert and
+    those of the held experts fill a ``capacity``-row buffer (None: the
+    N x min(K, E_held) rows a step can send at most); grouped products
+    run over it.  Returns (out (N, D), aux) with aux
+    ``moe_load`` (E_held,) tokens routed to each held expert,
+    ``moe_assignments`` their sum and ``moe_dropped`` how many did not
+    fit the buffer."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    n, k = idx.shape
+    held = e_gate.shape[0]
+    if capacity is None:
+        capacity = n * min(k, held)
+    dispatch, combine = _row_moves()
+    local = idx - first_expert
+    is_held = (local >= 0) & (local < held)
+    # one key an assignment; the experts not held come last.  A counting
+    # sort (the keys are few): an assignment's row is its expert's first
+    # row plus how many of that expert's came before it — a running
+    # count, no sort (the chip's compiler takes 20 s a sort)
+    key = jnp.where(is_held, local, held).reshape(-1).astype(jnp.int32)
+    mine = jnp.arange(held + 1, dtype=jnp.int32)[:, None] == key[None, :]
+    before = jnp.cumsum(mine, axis=1, dtype=jnp.int32)
+    count = before[:, -1]                    # tokens an expert gets
+    first = jnp.cumsum(count) - count
+    pos = jnp.sum(jnp.where(mine, before - 1 + first[:, None], 0),
+                  axis=0)                           # assignment -> row
+    order = jnp.zeros_like(pos).at[pos].set(
+        jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+    load = count[:held]
+    kept = jnp.sum(load)
+    reach = jnp.minimum(jnp.cumsum(load), capacity)
+    sizes = jnp.diff(reach, prepend=0).astype(jnp.int32)
+    slot = order[:capacity]                         # row -> assignment
+    token_of = (slot // k).astype(jnp.int32)
+    row_valid = jnp.arange(capacity) < jnp.minimum(kept, capacity)
+    valid = (is_held.reshape(-1) & (pos < capacity)).reshape(n, k)
+    pos = pos.reshape(n, k)
+
+    xs = dispatch(m, token_of, pos, valid)          # (C, D)
+    hidden = (jax.nn.silu(lax.ragged_dot(
+        xs, e_gate, sizes, preferred_element_type=jnp.float32))
+        * lax.ragged_dot(xs, e_up, sizes,
+                         preferred_element_type=jnp.float32))
+    y = lax.ragged_dot(hidden.astype(m.dtype), e_down, sizes,
+                       preferred_element_type=jnp.float32)
+    w_row = weights.reshape(-1)[slot]
+    y = jnp.where(row_valid[:, None], y * w_row[:, None], 0.0)
+    out = combine(y.astype(m.dtype), token_of, pos, valid)
+    aux = {"moe_load": load, "moe_assignments": kept,
+           "moe_dropped": jnp.maximum(kept - capacity, 0)}
+    return out, aux
+
+
+# -- the packed layer --------------------------------------------------------
+
+
+def layer_layout(d, *, heads, qk_nope, qk_rope, v_head, kv_rank, ffn=None,
+                 experts=None, experts_held=None, expert_width=None,
+                 shared_width=None, **_):
+    """((name, shape) of the packed ``weights``, of the packed
+    ``bias``): the ONE definition the unit's initialiser and the apply
+    read.  ``ffn`` makes the layer dense; else it is routed."""
+    weights = [("w_q", (d, heads * (qk_nope + qk_rope))),
+               ("w_kva", (d, kv_rank + qk_rope)),
+               ("w_kvb", (kv_rank, heads * (qk_nope + v_head))),
+               ("w_o", (heads * v_head, d))]
+    bias = [("attn_gain", (d,)), ("kv_gain", (kv_rank,)),
+            ("ffn_gain", (d,))]
+    if ffn:
+        weights += [("w_gate", (d, ffn)), ("w_up", (d, ffn)),
+                    ("w_down", (ffn, d))]
+    else:
+        weights += [("w_router", (d, experts)),
+                    ("e_gate", (experts_held, d, expert_width)),
+                    ("e_up", (experts_held, d, expert_width)),
+                    ("e_down", (experts_held, expert_width, d)),
+                    ("s_gate", (d, shared_width)),
+                    ("s_up", (d, shared_width)),
+                    ("s_down", (shared_width, d))]
+        bias += [("router_bias", (experts,))]
+    return weights, bias
+
+
+def _size(shape):
+    return int(numpy.prod(shape))
+
+
+#: pieces kept float32 whatever the operands' dtype: the router scores
+#: in float32 (a rounded score would move a token between experts, not
+#: its output by a rounding), as the family's own code does
+FLOAT32_PIECES = ("w_router",)
+
+
+@functools.lru_cache(maxsize=None)
+def _unpacker(layout):
+    """vec -> tuple of pieces, each cast to its dtype, whose gradient is
+    ONE concatenate of the pieces' (autodiff's sum of padded slices
+    would pass over the vector once a piece)."""
+    import jax
+    import jax.numpy as jnp
+
+    def pieces(vec):
+        out, offset = [], 0
+        for _, shape, dtype in layout:
+            size = _size(shape)
+            out.append(vec[offset:offset + size].reshape(shape).astype(
+                dtype))
+            offset += size
+        return tuple(out)
+
+    @jax.custom_vjp
+    def unpack(vec):
+        return pieces(vec)
+
+    def fwd(vec):
+        return pieces(vec), None
+
+    def bwd(_, grads):
+        return (jnp.concatenate(
+            [g.astype(jnp.float32).ravel() for g in grads]),)
+
+    unpack.defvjp(fwd, bwd)
+    return unpack
+
+
+def unpack(vec, layout, dtype):
+    """Packed flat float32 ``vec`` -> {name: array}: of ``dtype``, the
+    ``FLOAT32_PIECES`` of float32."""
+    layout = tuple(
+        (name, tuple(shape), "float32" if name in FLOAT32_PIECES
+         else numpy.dtype(dtype).name) for name, shape in layout)
+    pieces = _unpacker(layout)(vec)
+    return {entry[0]: piece for entry, piece in zip(layout, pieces)}
+
+
+def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope,
+                  qk_rope, v_head, kv_rank, ffn=None, experts=None,
+                  experts_held=None, first_expert=0, top_k=None,
+                  expert_width=None, shared_width=None, routed_scale=1.0,
+                  capacity=None, theta=1e6, eps=1e-6, pallas_bwd=None):
+    """One layer over packed params: (h, aux).  ``aux`` is empty for a
+    dense layer."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    dims = dict(heads=heads, qk_nope=qk_nope, qk_rope=qk_rope,
+                v_head=v_head, kv_rank=kv_rank, ffn=ffn, experts=experts,
+                experts_held=experts_held, expert_width=expert_width,
+                shared_width=shared_width)
+    w_layout, b_layout = layer_layout(h.shape[-1], **dims)
+    w = unpack(weights, w_layout, compute_dtype)
+    g = unpack(bias, b_layout, jnp.float32)
+    h = h.astype(compute_dtype)
+    with jax.named_scope(SCOPE_ATTENTION):
+        h = h + latent_attention(
+            rms_norm(h, g["attn_gain"], eps), w, heads=heads,
+            qk_nope=qk_nope, qk_rope=qk_rope, v_head=v_head,
+            kv_rank=kv_rank, kv_gain=g["kv_gain"], theta=theta, eps=eps,
+            pallas_bwd=pallas_bwd)
+    m = rms_norm(h, g["ffn_gain"], eps)
+    if ffn:
+        with jax.named_scope(SCOPE_FFN):
+            return h + gated_ffn(m, w["w_gate"], w["w_up"],
+                                 w["w_down"]), {}
+    b, t, d = m.shape
+    tokens = m.reshape(b * t, d)
+    with jax.named_scope(SCOPE_ROUTER):
+        # float32 scores from the float32 router (FLOAT32_PIECES)
+        p = jax.nn.sigmoid(jnp.dot(
+            tokens.astype(jnp.float32), w["w_router"],
+            precision=lax.Precision.HIGHEST))
+        from veles_tpu.parallel.moe import top_k_route
+        _, idx = top_k_route(
+            p + lax.stop_gradient(g["router_bias"]), top_k)
+        chosen = jnp.take_along_axis(p, idx, axis=-1)
+        gate = chosen / jnp.sum(chosen, axis=-1, keepdims=True) \
+            * routed_scale
+    with jax.named_scope(SCOPE_ROUTED):
+        routed, aux = routed_experts(
+            tokens, idx, gate, w["e_gate"], w["e_up"], w["e_down"],
+            first_expert=first_expert, capacity=capacity)
+    with jax.named_scope(SCOPE_SHARED):
+        shared = gated_ffn(m, w["s_gate"], w["s_up"], w["s_down"])
+    return h + routed.reshape(b, t, d) + shared, aux
+
+
+# -- units -------------------------------------------------------------------
+
+
+def _compute_dtype():
+    from veles_tpu.config import precision_dtype
+    return precision_dtype().name
+
+
+class _DecoderUnit(_SequenceUnit):
+    """State is float32 whatever the engine's precision says; that
+    setting is the operands' dtype (``compute_dtype``)."""
+
+    STATE_DTYPE = numpy.float32
+
+    def __init__(self, workflow, **kwargs):
+        kwargs.setdefault("weights_filling", "gaussian")
+        kwargs.setdefault("weights_stddev", 0.02)
+        super(_DecoderUnit, self).__init__(workflow, **kwargs)
+        self.eps = kwargs.get("eps", 1e-6)
+
+    def _gaussian(self, shape, stddev=None):
+        arr = numpy.zeros(shape, numpy.float32)
+        self.fill_array(arr, self.weights_filling,
+                        stddev or self.weights_stddev, shape[0])
+        return arr
+
+
+class DecoderEmbedding(_DecoderUnit):
+    """Token ids (B, T) -> (B, T, width): ``weights`` is the (vocab,
+    width) table, no bias."""
+
+    MAPPING = "decoder_embedding"
+
+    def __init__(self, workflow, **kwargs):
+        kwargs["include_bias"] = False
+        super(DecoderEmbedding, self).__init__(workflow, **kwargs)
+        self.vocab = int(kwargs["vocab"])
+        self.width = int(kwargs["width"])
+
+    def static_config(self):
+        return {"compute_dtype": _compute_dtype()}
+
+    def create_params(self):
+        shape = tuple(self.input.shape)
+        if len(shape) != 2:
+            raise ValueError("%s expects (batch, tokens) ids, got %s"
+                             % (type(self).__name__, (shape,)))
+        self._ensure_output(shape + (self.width,))
+        if not self.weights:
+            self.weights.mem = self._gaussian((self.vocab, self.width))
+
+    @classmethod
+    def apply(cls, params, x, *, compute_dtype="float32"):
+        import jax.numpy as jnp
+        return jnp.take(params["weights"], x, axis=0).astype(compute_dtype)
+
+
+class DecoderLayer(_DecoderUnit):
+    """One MLA layer, dense or routed, packed (:func:`layer_layout`)."""
+
+    MAPPING = "decoder_layer"
+    DIMS = ("heads", "qk_nope", "qk_rope", "v_head", "kv_rank", "ffn",
+            "experts", "experts_held", "first_expert", "top_k",
+            "expert_width", "shared_width", "routed_scale", "capacity",
+            "theta")
+
+    #: the pieces that write into the residual stream (``out_stddev``)
+    RESIDUAL_WRITERS = ("w_o", "w_down", "e_down", "s_down")
+    #: registry names of the counters ``apply_with_aux`` emits: the
+    #: trainer publishes a scalar a layer as ``<name>`` and a vector a
+    #: layer as ``<name>.l<layer>.e<element>``
+    AUX_COUNTERS = {"moe_assignments": "moe.assignments",
+                    "moe_dropped": "moe.dropped_assignments",
+                    "moe_load": "moe.load"}
+
+    def __init__(self, workflow, **kwargs):
+        super(DecoderLayer, self).__init__(workflow, **kwargs)
+        self.dims = {name: kwargs[name] for name in self.DIMS
+                     if kwargs.get(name) is not None}
+        self.router_bias_stddev = kwargs.get("router_bias_stddev", 0.0)
+        self.out_stddev = kwargs.get("out_stddev")
+
+    def static_config(self):
+        return dict(self.dims, eps=self.eps,
+                    compute_dtype=_compute_dtype())
+
+    def create_params(self):
+        shape = self._seq_shape()
+        self._ensure_output(shape)
+        if self.weights:
+            return  # restored from a snapshot
+        w_layout, b_layout = layer_layout(shape[-1], **self.dims)
+        self.weights.mem = numpy.concatenate(
+            [self._gaussian(piece, self.out_stddev
+                            if name in self.RESIDUAL_WRITERS
+                            else None).ravel()
+             for name, piece in w_layout])
+        pieces = []
+        for name, piece in b_layout:
+            if name == "router_bias":
+                value = numpy.zeros(piece, numpy.float32)
+                if self.router_bias_stddev:
+                    self.prng.fill_normal(value, 0.0,
+                                          self.router_bias_stddev)
+            else:
+                value = numpy.ones(piece, numpy.float32)
+            pieces.append(value)
+        self.bias.mem = numpy.concatenate(pieces)
+
+    @classmethod
+    def apply_with_aux(cls, params, x, **static):
+        return decoder_layer(x, params["weights"], params["bias"],
+                             **static)
+
+    @classmethod
+    def apply(cls, params, x, **static):
+        return cls.apply_with_aux(params, x, **static)[0]
+
+
+class DecoderHead(_DecoderUnit):
+    """rms_norm, then float32 logits over the vocabulary rows held:
+    ``weights`` (width, vocab), ``bias`` the norm's gain."""
+
+    MAPPING = "decoder_head"
+
+    def __init__(self, workflow, **kwargs):
+        super(DecoderHead, self).__init__(workflow, **kwargs)
+        self.vocab = int(kwargs["vocab"])
+
+    def static_config(self):
+        return {"eps": self.eps, "compute_dtype": _compute_dtype()}
+
+    def create_params(self):
+        shape = self._seq_shape()
+        self._ensure_output(shape[:2] + (self.vocab,))
+        if not self.weights:
+            self.weights.mem = self._gaussian((shape[-1], self.vocab))
+            self.bias.mem = numpy.ones((shape[-1],), numpy.float32)
+
+    @classmethod
+    def apply(cls, params, x, *, eps=1e-6, compute_dtype="float32"):
+        h = rms_norm(x.astype(compute_dtype), params["bias"], eps)
+        return _dense(h, params["weights"].astype(compute_dtype))
+
+
+# -- gradient-descent units (the per-unit debug path) ------------------------
+
+
+class _GDDecoder(_GDAutodiff):
+    """Stock vjp over the forward's apply; the static config is the
+    forward unit's (linked by the workflow as ``forward_unit``)."""
+
+    MAPPING = None
+
+    def __init__(self, workflow, **kwargs):
+        super(_GDDecoder, self).__init__(workflow, **kwargs)
+        self._static = {k: v for k, v in kwargs.items()
+                        if k in DecoderLayer.DIMS and v is not None}
+        self.eps = kwargs.get("eps", 1e-6)
+
+
+class GDDecoderEmbedding(_GDDecoder):
+    MAPPING = "decoder_embedding"
+    FORWARD_CLS = DecoderEmbedding
+
+    def backward_static(self):
+        return {"compute_dtype": _compute_dtype()}
+
+
+class GDDecoderLayer(_GDDecoder):
+    MAPPING = "decoder_layer"
+    FORWARD_CLS = DecoderLayer
+
+    def backward_static(self):
+        return dict(self._static, eps=self.eps,
+                    compute_dtype=_compute_dtype())
+
+
+class GDDecoderHead(_GDDecoder):
+    MAPPING = "decoder_head"
+    FORWARD_CLS = DecoderHead
+
+    def backward_static(self):
+        return {"eps": self.eps, "compute_dtype": _compute_dtype()}

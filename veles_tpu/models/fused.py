@@ -24,6 +24,15 @@ from veles_tpu.units import Unit
 
 __all__ = ["FusedTrainer", "fuse_standard_workflow"]
 
+#: the step's own metrics; any other key is a layer's counter
+STEP_METRICS = frozenset(("loss", "n_err", "mse_sum", "grad_norm",
+                          "finite", "skipped"))
+
+#: the share of the device's memory above which the step's backward
+#: recomputes its layers (FusedTrainer._backward_should_recompute): what
+#: is left is for the step's temporaries and the compiler's own buffers
+REMAT_ABOVE = 0.7
+
 
 class FusedTrainer(Unit):
     """Wraps compiler.build_train_step over a StandardWorkflow's
@@ -67,6 +76,13 @@ class FusedTrainer(Unit):
         self.mse_sum = 0.0
         self.n_samples = 0
         self.last_loss = None
+        #: targets a sample carries: 1 for a class label, T for a row of
+        #: T next tokens (the decision's error rate is over targets)
+        self.targets_per_sample = 1
+        #: what the layers count for themselves (compiler.py's
+        #: ``apply_with_aux`` metrics, e.g. ``moe_load``), summed over
+        #: the train steps since the last publish: lazy device arrays
+        self.layer_counters = {}
 
     def init_unpickled(self):
         super(FusedTrainer, self).init_unpickled()
@@ -86,6 +102,7 @@ class FusedTrainer(Unit):
             "step.eval_dispatch_s")
         self._m_steps_ = _registry.counter("train.steps")
         self._m_samples_ = _registry.counter("train.samples")
+        self._m_tokens_ = _registry.counter("train.tokens")
         #: XLA cost-model FLOPs of one compiled step (None until the
         #: first step ran; 0.0 when cost analysis is unavailable)
         self._step_flops_ = None
@@ -169,7 +186,15 @@ class FusedTrainer(Unit):
         else:
             self._step_fn = build_train_step(
                 plans, loss=self.loss, donate=True,
+                bwd_remat=self._backward_should_recompute(plans),
                 compiler_options=step_compiler_options())
+        #: adamw's bias correction wants the step's number
+        self._counts_steps = any(p.solver == "adamw" for p in plans)
+        #: the layers' own metric names, known after the first step
+        self._layer_metrics = None
+        if self.loss == "softmax":
+            self.targets_per_sample = int(numpy.prod(
+                self.sw.loader.minibatch_labels.shape[1:]))
         forward = build_forward(plans)
 
         # eval metrics fused INTO the forward dispatch: one async call
@@ -204,6 +229,58 @@ class FusedTrainer(Unit):
         # is the recompile storm the watcher warns about
         _xla.watch(self._step_fn, "fused.step")
         _xla.watch(self._eval_metrics, "fused.eval")
+
+    def _backward_should_recompute(self, plans):
+        """Whether the step's backward should recompute each layer's
+        forward (``bwd_remat``) instead of holding its activations:
+        decided from what can be observed — the bytes autodiff would
+        save for the backward at this minibatch's shape (an abstract
+        trace, nothing runs), beside what the device already holds and
+        one more copy of the parameters for their gradients, against
+        the device's memory.  A device that does not report its memory
+        (the CPU) keeps the activations."""
+        import jax
+
+        from veles_tpu.compiler import _forward_for_loss
+        from veles_tpu.observe import xla_introspect as _xla
+        memory = _xla.device_memory_gauges()
+        limit = memory.get("xla.mem.bytes_limit.d0")
+        if not limit:
+            return False
+        in_use = memory.get("xla.mem.bytes_in_use.d0", 0)
+        loader = self.sw.loader
+        x = loader.minibatch_data
+        x = jax.ShapeDtypeStruct(x.shape, x.dtype)
+        params = [{k: None if s[k] is None else
+                   jax.ShapeDtypeStruct(s[k].shape, s[k].dtype)
+                   for k in ("weights", "bias")}
+                  for s in self._abstract_state()]
+
+        def saved(p, x_):
+            return jax.vjp(lambda q: _forward_for_loss(plans, q, x_), p)[1]
+
+        def nbytes(tree):
+            return sum(leaf.size * leaf.dtype.itemsize
+                       for leaf in jax.tree_util.tree_leaves(tree))
+
+        param_bytes = nbytes(params)
+        # the residuals hold the parameters too: they are there already
+        held = max(0, nbytes(jax.eval_shape(saved, params, x))
+                   - param_bytes)
+        need = in_use + param_bytes + held
+        recompute = need > REMAT_ABOVE * limit
+        self.info("backward: %.2f GB of activations to hold, %.2f GB in "
+                  "use, %.2f GB of gradients, device %.2f GB: %s",
+                  held / 1e9, in_use / 1e9,
+                  param_bytes / 1e9, limit / 1e9,
+                  "each layer is recomputed in the backward" if recompute
+                  else "activations are kept")
+        return recompute
+
+    def _abstract_state(self):
+        return [{"weights": fwd.weights if fwd.weights else None,
+                 "bias": fwd.bias if fwd.bias and fwd.include_bias
+                 else None} for fwd in self.sw.forwards]
 
     def _publish_step_flops(self, x, target, batch_size, key, poisons):
         """XLA's own cost model for ONE fused step, from abstract
@@ -338,6 +415,40 @@ class FusedTrainer(Unit):
             self._state = None
             self._comm_published_ = False
         self._compress_skips_seen_ = skips
+        self.publish_layer_counters()
+
+    def publish_layer_counters(self):
+        """Read the layers' counters (one wait for the device) and add
+        them to the registry under the names their layer class declares
+        (``AUX_COUNTERS``: {the step's metric: the registry's name}): a
+        scalar a layer adds up into the counter ``<name>``, a vector a
+        layer into ``<name>.l<i>.e<j>`` (element ``j`` of the ``i``-th
+        layer that counts it).  Called where the decision already syncs
+        (``on_health_sync``, a train class's end) and by whoever wants
+        the counts sooner (the benchmark, at its window's edges).
+        Returns what it added."""
+        import jax
+        counters = getattr(self, "layer_counters", None)
+        if not counters:  # also a trainer from before the counters
+            return {}
+        self.layer_counters = {}
+        names = {}
+        for plan in self._plans or ():
+            names.update(getattr(plan.forward_cls, "AUX_COUNTERS", {}))
+        added = {name: numpy.asarray(value)
+                 for name, value in jax.device_get(counters).items()}
+        for name, value in added.items():
+            target = names.get(name)
+            if target is None:
+                continue
+            if value.ndim < 2:  # one scalar a layer
+                _registry.counter(target).inc(int(value.sum()))
+                continue
+            for layer, row in enumerate(value):
+                for element, count in enumerate(row):
+                    _registry.counter("%s.l%d.e%d" % (
+                        target, layer, element)).inc(int(count))
+        return added
 
     def sync(self):
         """Write the fused state back into the unit Arrays (on demand:
@@ -401,6 +512,8 @@ class FusedTrainer(Unit):
             self._publish_comm(span.elapsed)
         self._m_steps_.inc()
         self._m_samples_.inc(self.n_samples)
+        if getattr(self, "targets_per_sample", 1) > 1:
+            self._m_tokens_.inc(self.n_samples * self.targets_per_sample)
         profiler_step()
 
     def _train_step(self, x, target, batch_size):
@@ -423,6 +536,8 @@ class FusedTrainer(Unit):
                     poisons[kwarg] = numpy.float32(
                         numpy.nan if fault.param is None
                         else fault.param)
+        if self._counts_steps:  # one more traced scalar of the step
+            poisons["step_count"] = numpy.int32(self._iteration)
         # the call of the compiled program ALONE; in a profiler trace
         # the step annotation groups the device's ops by train step
         with step_annotation("train_step", self._iteration), \
@@ -446,6 +561,11 @@ class FusedTrainer(Unit):
                                    metrics["skipped"])
         self.consecutive_skips = lazy_consec(
             self.consecutive_skips, metrics["skipped"])
+        if self._layer_metrics is None:  # the first step since compile
+            self._layer_metrics = tuple(metrics.keys() - STEP_METRICS)
+        for name in self._layer_metrics:
+            self.layer_counters[name] = lazy_add(
+                self.layer_counters.get(name, 0), metrics[name])
         # mse_sum from the step's aux metric matches EvaluatorMSE's
         # definition (per-feature mean, summed over samples); the
         # scalar loss is SSE/batch over ALL elements and would
@@ -513,6 +633,7 @@ class FusedTrainer(Unit):
         state["_prefetcher"] = None
         # concretize lazy device metrics for the pickle
         state["n_err"] = int(self.n_err)
+        state["layer_counters"] = {}
         state["mse_sum"] = float(self.mse_sum)
         if self.last_loss is not None:
             state["last_loss"] = float(self.last_loss)

@@ -42,6 +42,12 @@ class ForwardBase(Unit):
         layout (the reference stored (fan_out, fan_in)).
     """
 
+    #: dtype of the unit's parameters (and so of its solver state); None
+    #: follows ``root.common.engine.precision_type``.  A unit that keeps
+    #: float32 state under lower-precision operands names it here and
+    #: casts inside ``apply``
+    STATE_DTYPE = None
+
     def __init__(self, workflow, **kwargs):
         super(ForwardBase, self).__init__(workflow, **kwargs)
         self.input = None  # linked from loader/previous unit (Array)
@@ -88,7 +94,7 @@ class ForwardBase(Unit):
         self.device = device
         super(ForwardBase, self).initialize(**kwargs)
         self.create_params()
-        dtype = precision_dtype()
+        dtype = numpy.dtype(self.STATE_DTYPE or precision_dtype())
         for arr in self.param_arrays():
             if arr:
                 if arr.dtype != dtype:
@@ -214,8 +220,9 @@ class GradientDescentBase(Unit):
     """Backward + parameter update for one forward unit.
 
     kwargs: learning_rate, learning_rate_bias, weights_decay (L2/L1 per
-    l1_vs_l2 blend), gradient_moment (momentum), solver
-    ("momentum" | "adagrad" | "adadelta"), adadelta_rho, solver_epsilon.
+    l1_vs_l2 blend), gradient_moment (momentum; adamw's beta1), solver
+    ("momentum" | "adagrad" | "adadelta" | "adamw"), adadelta_rho
+    (adamw's beta2), solver_epsilon.
 
     Reference-parity semantics: err_output is dL/d(output) arriving from
     the NEXT unit (or the evaluator); run() produces err_input =
@@ -276,7 +283,7 @@ class GradientDescentBase(Unit):
         pairs = [(self.accum_weights, self.weights),
                  (self.accum_bias,
                   self.bias if self.include_bias else None)]
-        if self.solver == "adadelta":
+        if self.solver in ("adadelta", "adamw"):
             pairs += [(self.accum2_weights, self.weights),
                       (self.accum2_bias,
                        self.bias if self.include_bias else None)]
@@ -347,7 +354,7 @@ class GradientDescentBase(Unit):
 
     @staticmethod
     def solver_update(solver, param, grad, accum, accum2, lr, moment,
-                      rho, eps):
+                      rho, eps, step=None, decay=0.0):
         """One solver step; returns (new_param, new_accum, new_accum2).
 
         momentum:  v = moment*v + lr*g;            p -= v
@@ -355,8 +362,15 @@ class GradientDescentBase(Unit):
         adadelta:  a  = rho*a + (1-rho)*g*g
                    d  = g*sqrt(a2+eps)/sqrt(a+eps); p -= lr*d
                    a2 = rho*a2 + (1-rho)*d*d
+        adamw:     m  = moment*m + (1-moment)*g;  v = rho*v + (1-rho)*g*g
+                   p -= lr*(m/(1-moment^t) / (sqrt(v/(1-rho^t))+eps)
+                            + decay*p)
         (manualrst_veles_algorithms.rst solver list: SGD+momentum /
-        AdaGrad / AdaDelta.)
+        AdaGrad / AdaDelta; AdamW is Loshchilov & Hutter 2019.)  adamw
+        keeps its two moments in ``accum``/``accum2``, takes ``step``
+        (t: 1 for the first step, a traced scalar) and decays the
+        parameter by ``decay`` itself: its callers do not fold the
+        decay into ``grad``.
         """
         import jax.numpy as jnp
         if solver == "momentum":
@@ -370,6 +384,19 @@ class GradientDescentBase(Unit):
             d = grad * jnp.sqrt(accum2 + eps) / jnp.sqrt(a + eps)
             a2 = rho * accum2 + (1.0 - rho) * d * d
             return param - lr * d, a, a2
+        if solver == "adamw":
+            if step is None:
+                raise ValueError(
+                    "the adamw solver needs the step count for its bias "
+                    "correction, and this train step was built without "
+                    "one (the shard_map builders do not pass it)")
+            t = jnp.asarray(step, jnp.float32)
+            m = moment * accum + (1.0 - moment) * grad
+            v = rho * accum2 + (1.0 - rho) * grad * grad
+            m_hat = m / (1.0 - moment ** t)
+            v_hat = v / (1.0 - rho ** t)
+            return (param - lr * (m_hat / (jnp.sqrt(v_hat) + eps)
+                                  + decay * param), m, v)
         raise ValueError("unknown solver %r" % solver)
 
     # -- the pure backward --------------------------------------------------

@@ -32,23 +32,24 @@ def _build_mapping(module, base):
 
 def forward_mapping():
     from veles_tpu.models import (
-        activation, conv, deconv, dropout, pooling, rnn, transformer)
+        activation, conv, decoder, deconv, dropout, pooling, rnn,
+        transformer)
     from veles_tpu.models.nn_units import ForwardBase
     mapping = {}
     for module in (all2all, conv, pooling, dropout, activation, deconv,
-                   rnn, transformer):
+                   rnn, transformer, decoder):
         mapping.update(_build_mapping(module, ForwardBase))
     return mapping
 
 
 def gd_mapping():
     from veles_tpu.models import (
-        activation, deconv, dropout, gd_conv, gd_pooling, rnn,
+        activation, decoder, deconv, dropout, gd_conv, gd_pooling, rnn,
         transformer)
     from veles_tpu.models.nn_units import GradientDescentBase
     mapping = {}
     for module in (gd_module, gd_conv, gd_pooling, dropout, activation,
-                   deconv, rnn, transformer):
+                   deconv, rnn, transformer, decoder):
         mapping.update(_build_mapping(module, GradientDescentBase))
     return mapping
 

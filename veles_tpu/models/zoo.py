@@ -10,7 +10,7 @@ that the compiler lowers onto the MXU.
 
 __all__ = ["alexnet_layers", "vgg_layers", "mnist_mlp_layers",
            "autoencoder_layers", "transformer_layers",
-           "build_plans_and_state"]
+           "mla_moe_decoder_layers", "build_plans_and_state"]
 
 
 def build_plans_and_state(specs, input_shape, seed=0):
@@ -149,6 +149,46 @@ def transformer_layers(blocks=2, heads=2, hidden=None, classes=10,
             for _ in range(blocks)]
     spec.append({"type": "softmax", "output_sample_shape": classes,
                  "learning_rate": lr, "gradient_moment": moment})
+    return spec
+
+
+def mla_moe_decoder_layers(vocab, width, layers, heads, qk_nope, qk_rope,
+                           v_head, kv_rank, ffn, experts, experts_held,
+                           top_k, expert_width, shared_width,
+                           dense_layers=1, first_expert=0,
+                           routed_scale=1.0, theta=1e6, eps=1e-6,
+                           lr=1e-4, beta1=0.9, beta2=0.95, adam_eps=1e-8,
+                           decay=0.1, init_std=0.02, out_init_std=None,
+                           router_bias_std=0.0):
+    """A causal decoder of the DeepSeek-V3 layer family
+    (models/decoder.py): token embedding, ``layers`` latent-attention
+    layers — the first ``dense_layers`` with a gated feed-forward ``ffn``
+    wide, the rest routed over ``experts`` of which this program holds
+    ``experts_held`` from ``first_expert`` on, ``top_k`` a token, beside a
+    shared expert ``shared_width`` wide — and the output head over the
+    ``vocab`` rows held.  ``out_init_std`` is the std of the matrices
+    that write into the residual stream (None: ``init_std``, like the
+    rest).  Trained with AdamW; the loader serves (B, T) ids and (B, T)
+    next ids (``loader.TokenRowLoader``)."""
+    solver = {"solver": "adamw", "learning_rate": lr,
+              "gradient_moment": beta1, "adadelta_rho": beta2,
+              "solver_epsilon": adam_eps, "weights_decay": decay,
+              "weights_decay_bias": 0.0, "weights_stddev": init_std,
+              "eps": eps}
+    attention = {"heads": heads, "qk_nope": qk_nope, "qk_rope": qk_rope,
+                 "v_head": v_head, "kv_rank": kv_rank, "theta": theta}
+    routed = {"experts": experts, "experts_held": experts_held,
+              "first_expert": first_expert, "top_k": top_k,
+              "expert_width": expert_width, "shared_width": shared_width,
+              "routed_scale": routed_scale,
+              "router_bias_stddev": router_bias_std}
+    spec = [dict(solver, type="decoder_embedding", vocab=vocab,
+                 width=width)]
+    for index in range(layers):
+        body = {"ffn": ffn} if index < dense_layers else routed
+        spec.append(dict(solver, type="decoder_layer",
+                         out_stddev=out_init_std, **attention, **body))
+    spec.append(dict(solver, type="decoder_head", vocab=vocab))
     return spec
 
 

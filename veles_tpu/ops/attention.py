@@ -33,6 +33,23 @@ conv-VJP, built to the same contracts:
 - ``blocks=None`` consults the ``attention`` ScheduleCache family
   (tune/spec.py) exactly like matmul's consult — tiles change the
   SCHEDULE, never the math.
+- **Causal** (``causal=True``): key ``j`` counts for query ``i`` only
+  where ``j <= i``.  All three kernels skip the tiles that lie wholly
+  above the diagonal — the body runs under ``pl.when`` and the block
+  index of the skipped side is clamped to the last tile needed, so a
+  skipped grid step fetches nothing — and mask the tiles the diagonal
+  crosses with the same finite floor.
+- **Keys wider than values** (latent attention: 192-wide ``q``/``k``
+  against 128-wide ``v``): ``q``/``k``/``dq``/``dk`` tiles carry the
+  key width, ``v``/``out``/``do``/``dv`` tiles and the output
+  accumulator the value width, each padded to whole lanes by itself.
+- ``product_dtype`` (None keeps the v2 behaviour): the dtype EVERY
+  product's operands are rounded to, the probability and cotangent
+  tiles included.  With bfloat16 ``q``/``k``/``v`` the v2 kernels hand
+  the MXU float32 probability tiles, which costs the level's float32
+  product (three passes at level 0); ``product_dtype=bfloat16`` is the
+  usual mixed-precision contract — bfloat16 operands, float32
+  accumulation — in one pass.
 
 The ``VELES_PALLAS_BWD`` contract (docs/kernels.md): the model layer
 (models/transformer.py) routes to :func:`flash_attention` only when the
@@ -68,10 +85,16 @@ DKV_KERNEL_NAME = "veles_flash_dkv"
 
 #: bump when the kernel's algorithm changes: tuned schedules in the
 #: cache are only valid for the algorithm they were measured on
-#: (v2: the backward reads (m, l) row statistics, not a logsumexp)
-ATTENTION_KERNEL_VERSION = 2
+#: (v2: the backward reads (m, l) row statistics, not a logsumexp;
+#: v3: causal tile skipping, key width apart from value width,
+#: ``product_dtype`` — the non-causal equal-width float32 program is
+#: v2's, op for op)
+ATTENTION_KERNEL_VERSION = 3
 
 _DEFAULT_BLOCKS = (256, 256)  # (bq, bk)
+#: causal sequences of a thousand tokens and more: a (256, 256) tile is
+#: a quarter of a microsecond of MXU work, about what a grid step costs
+_CAUSAL_LONG_BLOCKS = (512, 512)
 
 #: finite -inf stand-in for score masking: exp(-1e30 - m) underflows to
 #: an exact 0.0 for any realistic row max m, while (-1e30) - (-1e30)
@@ -85,18 +108,45 @@ def _col_ids(bq, bk):
     return jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
 
 
+def _row_ids(bq, bk):
+    return jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+
+
+def _masked_scores(s, i, kk, *, bq, bk, t_real, causal):
+    """Padded key columns — and, causal, the keys after each query —
+    to the finite floor, never -inf."""
+    col = kk * bk + _col_ids(*s.shape)
+    keep = col < t_real
+    if causal:
+        keep = keep & (col <= i * bq + _row_ids(*s.shape))
+    return jnp.where(keep, s, _MASK_FLOOR)
+
+
+def _when_needed(causal, i, kk, bq, bk):
+    """Decorator running a kernel body only where (q tile ``i``, k tile
+    ``kk``) holds a pair with key <= query; always, when not causal."""
+    if not causal:
+        return lambda body: body()
+    return pl.when(kk * bk < (i + 1) * bq)
+
+
+def _narrow(x, product_dtype):
+    return x if product_dtype is None else x.astype(product_dtype)
+
+
 # -- forward kernel ----------------------------------------------------------
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
-                acc_ref, m_ref, l_ref, *, n_k, scale, t_real, bk,
-                precision_level):
+                acc_ref, m_ref, l_ref, *, n_k, scale, t_real, bq, bk,
+                precision_level, causal, product_dtype):
     """One (b, i, kk) grid step of the online-softmax forward.
 
-    ``acc_ref`` (bq, dh) f32 carries the running unnormalized output;
-    ``m_ref``/``l_ref`` (bq, 128) carry the running row max and row
-    sum, lane-broadcast so the scratch tiles stay MXU-shaped.
+    ``acc_ref`` (bq, value width) f32 carries the running unnormalized
+    output; ``m_ref``/``l_ref`` (bq, 128) carry the running row max and
+    row sum, lane-broadcast so the scratch tiles stay MXU-shaped.
     """
+    i = pl.program_id(1)
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -105,22 +155,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         m_ref[:] = jnp.full_like(m_ref, _MASK_FLOOR)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0]
-    s = mxu_partial_dot(q, k_ref[0].T, precision_level) * scale
-    # mask padded key columns to the finite floor, never -inf
-    col = kk * bk + _col_ids(*s.shape)
-    s = jnp.where(col < t_real, s, _MASK_FLOOR)
+    @_when_needed(causal, i, kk, bq, bk)
+    def _tile():
+        q = q_ref[0]
+        s = mxu_partial_dot(q, k_ref[0].T, precision_level) * scale
+        s = _masked_scores(s, i, kk, bq=bq, bk=bk, t_real=t_real,
+                           causal=causal)
 
-    m_prev = m_ref[:, :1]                      # (bq, 1)
-    s_max = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
-    m_new = jnp.maximum(m_prev, s_max)
-    p = jnp.exp(s - m_new)                     # (bq, bk) f32
-    alpha = jnp.exp(m_prev - m_new)            # (bq, 1)
-    l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + mxu_partial_dot(
-        p, v_ref[0], precision_level)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_prev = m_ref[:, :1]                      # (bq, 1)
+        s_max = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
+        m_new = jnp.maximum(m_prev, s_max)
+        p = jnp.exp(s - m_new)                     # (bq, bk) f32
+        alpha = jnp.exp(m_prev - m_new)            # (bq, 1)
+        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + mxu_partial_dot(
+            _narrow(p, product_dtype), v_ref[0], precision_level)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(kk == n_k - 1)
     def _store():
@@ -133,46 +184,70 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         l_out_ref[0] = jnp.broadcast_to(l_safe, l_out_ref.shape[1:])
 
 
+def _last_k(causal, bq, bk):
+    """Block index of the k side for grid step (i, kk): causal, a
+    skipped step names the last tile the q tile needs, which is the
+    block already there, so nothing is fetched for it."""
+    if not causal:
+        return lambda i, kk: kk
+    return lambda i, kk: jnp.minimum(kk, ((i + 1) * bq - 1) // bk)
+
+
+def _first_q(causal, bq, bk):
+    """The same for the q side of the dk/dv kernel's (kk, i) steps."""
+    if not causal:
+        return lambda kk, i: i
+    return lambda kk, i: jnp.maximum(i, (kk * bk) // bq)
+
+
 @functools.partial(
     jax.jit, static_argnames=("scale", "precision_level", "blocks",
-                              "interpret"))
-def _flash_fwd_jit(q, k, v, scale, precision_level, blocks, interpret):
-    """(out, (m, l)): the tiled forward.  q/k/v are (B, T, dh); the
-    row statistics come back (B, Tq_padded, 128) f32 each,
-    lane-broadcast (the backward kernels read the same layout)."""
-    b, t, dh = q.shape
+                              "interpret", "causal", "product_dtype"))
+def _flash_fwd_jit(q, k, v, scale, precision_level, blocks, interpret,
+                   causal=False, product_dtype=None):
+    """(out, (m, l)): the tiled forward.  q/k are (B, T, key width), v
+    (B, T, value width); the row statistics come back (B, Tq_padded,
+    128) f32 each, lane-broadcast (the backward kernels read the same
+    layout)."""
+    b, t, _ = q.shape
+    dv = v.shape[-1]
     bq, bk = _clamped_blocks(blocks, t)
     qp = pad_to(q, (None, bq, 128))
     kp = pad_to(k, (None, bk, 128))
     vp = pad_to(v, (None, bk, 128))
     _, tq, dhp = qp.shape
+    dvp = vp.shape[-1]
     tk = kp.shape[1]
     n_k = tk // bk
     grid = (b, tq // bq, n_k)
+    k_at = _last_k(causal, bq, bk)
 
     out, row_max, row_sum = pl.pallas_call(
         functools.partial(_fwd_kernel, n_k=n_k, scale=scale,
-                          t_real=t, bk=bk,
-                          precision_level=precision_level),
+                          t_real=t, bq=bq, bk=bk,
+                          precision_level=precision_level,
+                          causal=causal, product_dtype=product_dtype),
         name=FWD_KERNEL_NAME,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bb, i, kk: (bb, kk, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bb, i, kk: (bb, kk, 0)),
+            pl.BlockSpec((1, bk, dhp),
+                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
+            pl.BlockSpec((1, bk, dvp),
+                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
+            pl.BlockSpec((1, bq, dvp), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda bb, i, kk: (bb, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, tq, dhp), q.dtype),
+            jax.ShapeDtypeStruct((b, tq, dvp), q.dtype),
             jax.ShapeDtypeStruct((b, tq, 128), jnp.float32),
             jax.ShapeDtypeStruct((b, tq, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, dhp), jnp.float32),
+            pltpu.VMEM((bq, dvp), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
@@ -180,7 +255,7 @@ def _flash_fwd_jit(q, k, v, scale, precision_level, blocks, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)
-    return unpad(out, (b, t, dh)), (row_max, row_sum)
+    return unpad(out, (b, t, dv)), (row_max, row_sum)
 
 
 # -- backward kernels --------------------------------------------------------
@@ -192,27 +267,39 @@ def _probabilities(s, m_ref, l_ref):
     return jnp.exp(s - m_ref[0][:, :1]) * (1.0 / l_ref[0][:, :1])
 
 
+def _cotangent(do_ref, product_dtype):
+    """The output cotangent tile as a product operand: float32 as in
+    v2, or as it is stored where the products are narrowed."""
+    if product_dtype is None:
+        return do_ref[0].astype(jnp.float32)
+    return do_ref[0].astype(product_dtype)
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
-                   dq_ref, acc_ref, *, n_k, scale, t_real, bk,
-                   precision_level):
+                   dq_ref, acc_ref, *, n_k, scale, t_real, bq, bk,
+                   precision_level, causal, product_dtype):
     """dq for one q-tile, accumulated over k-tiles: the probability
     tile is recomputed from the saved row statistics
     (recompute-over-store), then ds = p * (dp - delta) and
     dq += ds @ k * scale."""
+    i = pl.program_id(1)
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    s = mxu_partial_dot(q_ref[0], k_ref[0].T, precision_level) * scale
-    col = kk * bk + _col_ids(*s.shape)
-    s = jnp.where(col < t_real, s, _MASK_FLOOR)
-    p = _probabilities(s, m_ref, l_ref)
-    dp = mxu_partial_dot(do_ref[0].astype(jnp.float32), v_ref[0].T,
-                         precision_level)
-    ds = p * (dp - delta_ref[0][:, :1]) * scale
-    acc_ref[:] += mxu_partial_dot(ds, k_ref[0], precision_level)
+    @_when_needed(causal, i, kk, bq, bk)
+    def _tile():
+        s = mxu_partial_dot(q_ref[0], k_ref[0].T, precision_level) * scale
+        s = _masked_scores(s, i, kk, bq=bq, bk=bk, t_real=t_real,
+                           causal=causal)
+        p = _probabilities(s, m_ref, l_ref)
+        dp = mxu_partial_dot(_cotangent(do_ref, product_dtype),
+                             v_ref[0].T, precision_level)
+        ds = p * (dp - delta_ref[0][:, :1]) * scale
+        acc_ref[:] += mxu_partial_dot(_narrow(ds, product_dtype),
+                                      k_ref[0], precision_level)
 
     @pl.when(kk == n_k - 1)
     def _store():
@@ -221,7 +308,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                    *, n_q, scale, t_real, bk, precision_level):
+                    *, n_q, scale, t_real, bq, bk, precision_level,
+                    causal, product_dtype):
     """dk/dv for one k-tile, accumulated over q-tiles.  Padded key
     columns are masked to exact-zero probabilities, so their dk/dv
     rows come out 0 and the unpad slices them away."""
@@ -233,15 +321,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     kk = pl.program_id(1)
-    s = mxu_partial_dot(q_ref[0], k_ref[0].T, precision_level) * scale
-    col = kk * bk + _col_ids(*s.shape)
-    s = jnp.where(col < t_real, s, _MASK_FLOOR)
-    p = _probabilities(s, m_ref, l_ref)
-    do = do_ref[0].astype(jnp.float32)
-    dv_acc_ref[:] += mxu_partial_dot(p.T, do, precision_level)
-    dp = mxu_partial_dot(do, v_ref[0].T, precision_level)
-    ds = p * (dp - delta_ref[0][:, :1]) * scale
-    dk_acc_ref[:] += mxu_partial_dot(ds.T, q_ref[0], precision_level)
+
+    @_when_needed(causal, qq, kk, bq, bk)
+    def _tile():
+        s = mxu_partial_dot(q_ref[0], k_ref[0].T, precision_level) * scale
+        s = _masked_scores(s, qq, kk, bq=bq, bk=bk, t_real=t_real,
+                           causal=causal)
+        p = _probabilities(s, m_ref, l_ref)
+        do = _cotangent(do_ref, product_dtype)
+        dv_acc_ref[:] += mxu_partial_dot(_narrow(p, product_dtype).T, do,
+                                         precision_level)
+        dp = mxu_partial_dot(do, v_ref[0].T, precision_level)
+        ds = p * (dp - delta_ref[0][:, :1]) * scale
+        dk_acc_ref[:] += mxu_partial_dot(_narrow(ds, product_dtype).T,
+                                         q_ref[0], precision_level)
 
     @pl.when(qq == n_q - 1)
     def _store():
@@ -251,14 +344,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
 
 @functools.partial(
     jax.jit, static_argnames=("scale", "precision_level", "blocks",
-                              "interpret"))
+                              "interpret", "causal", "product_dtype"))
 def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
-                   blocks, interpret):
+                   blocks, interpret, causal=False, product_dtype=None):
     """(dq, dk, dv) via the two tiled backward kernels.  ``delta`` =
     rowsum(do * out) is the standard flash-backward precompute — one
     elementwise pass, kept outside the kernels like conv-VJP keeps its
     dgrad as a lax conv."""
     b, t, dh = q.shape
+    dv_width = v.shape[-1]
     row_max, row_sum = stats
     bq, bk = _clamped_blocks(blocks, t)
     qp = pad_to(q, (None, bq, 128))
@@ -270,20 +364,26 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
     delta = pad_to(jnp.broadcast_to(delta, (b, t, 128)), (None, bq,
                                                           None))
     _, tq, dhp = qp.shape
+    dvp = vp.shape[-1]
     tk = kp.shape[1]
     n_q, n_k = tq // bq, tk // bk
+    k_at = _last_k(causal, bq, bk)
+    q_at = _first_q(causal, bq, bk)
+    static = dict(scale=scale, t_real=t, bq=bq, bk=bk,
+                  precision_level=precision_level, causal=causal,
+                  product_dtype=product_dtype)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, n_k=n_k, scale=scale,
-                          t_real=t, bk=bk,
-                          precision_level=precision_level),
+        functools.partial(_bwd_dq_kernel, n_k=n_k, **static),
         name=DQ_KERNEL_NAME,
         grid=(b, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bb, i, kk: (bb, kk, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bb, i, kk: (bb, kk, 0)),
-            pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
+            pl.BlockSpec((1, bk, dhp),
+                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
+            pl.BlockSpec((1, bk, dvp),
+                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
+            pl.BlockSpec((1, bq, dvp), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda bb, i, kk: (bb, i, 0)),
@@ -298,31 +398,34 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
     )(qp, kp, vp, dop, row_max, row_sum, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, n_q=n_q, scale=scale,
-                          t_real=t, bk=bk,
-                          precision_level=precision_level),
+        functools.partial(_bwd_dkv_kernel, n_q=n_q, **static),
         name=DKV_KERNEL_NAME,
         grid=(b, n_k, n_q),
         in_specs=[
-            pl.BlockSpec((1, bq, dhp), lambda bb, kk, i: (bb, i, 0)),
+            pl.BlockSpec((1, bq, dhp),
+                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
             pl.BlockSpec((1, bk, dhp), lambda bb, kk, i: (bb, kk, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bb, kk, i: (bb, kk, 0)),
-            pl.BlockSpec((1, bq, dhp), lambda bb, kk, i: (bb, i, 0)),
-            pl.BlockSpec((1, bq, 128), lambda bb, kk, i: (bb, i, 0)),
-            pl.BlockSpec((1, bq, 128), lambda bb, kk, i: (bb, i, 0)),
-            pl.BlockSpec((1, bq, 128), lambda bb, kk, i: (bb, i, 0)),
+            pl.BlockSpec((1, bk, dvp), lambda bb, kk, i: (bb, kk, 0)),
+            pl.BlockSpec((1, bq, dvp),
+                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
+            pl.BlockSpec((1, bq, 128),
+                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
+            pl.BlockSpec((1, bq, 128),
+                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
+            pl.BlockSpec((1, bq, 128),
+                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, dhp), lambda bb, kk, i: (bb, kk, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bb, kk, i: (bb, kk, 0)),
+            pl.BlockSpec((1, bk, dvp), lambda bb, kk, i: (bb, kk, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, tk, dhp), q.dtype),
-            jax.ShapeDtypeStruct((b, tk, dhp), q.dtype),
+            jax.ShapeDtypeStruct((b, tk, dvp), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, dhp), jnp.float32),
-            pltpu.VMEM((bk, dhp), jnp.float32),
+            pltpu.VMEM((bk, dvp), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -330,72 +433,91 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
     )(qp, kp, vp, dop, row_max, row_sum, delta)
 
     return (unpad(dq, (b, t, dh)), unpad(dk, (b, t, dh)),
-            unpad(dv, (b, t, dh)))
+            unpad(dv, (b, t, dv_width)))
 
 
 # -- the custom_vjp entry ----------------------------------------------------
 
 
 @functools.lru_cache(maxsize=None)
-def _flash_fn(scale, precision_level, blocks):
+def _flash_fn(scale, precision_level, blocks, causal=False,
+              product_dtype=None):
     """Per-static-config custom_vjp, cached so jit tracing sees one
-    stable callable per (scale, level, schedule) — the conv_act
+    stable callable per (scale, level, schedule, form) — the conv_act
     pattern."""
+    form = dict(causal=causal, product_dtype=product_dtype)
 
     @jax.custom_vjp
     def f(q, k, v):
         out, _ = _flash_fwd_jit(q, k, v, scale, precision_level,
-                                blocks, interpret_for(q, k, v))
+                                blocks, interpret_for(q, k, v), **form)
         return out
 
     def fwd(q, k, v):
         out, stats = _flash_fwd_jit(q, k, v, scale, precision_level,
-                                    blocks, interpret_for(q, k, v))
+                                    blocks, interpret_for(q, k, v),
+                                    **form)
         return out, (q, k, v, out, stats)
 
     def bwd(res, do):
         q, k, v, out, stats = res
         return _flash_bwd_jit(q, k, v, out, stats, do, scale,
                               precision_level, blocks,
-                              interpret_for(q, k, v))
+                              interpret_for(q, k, v), **form)
 
     f.defvjp(fwd, bwd)
     return f
 
 
 def flash_attention(q, k, v, scale=None, precision_level=0,
-                    blocks=None):
+                    blocks=None, causal=False, product_dtype=None):
     """Tiled online-softmax attention with the Pallas backward
-    attached: ``softmax(q @ k^T * scale) @ v`` over (B, T, dh)
-    operands (B = batch x heads; the model layer folds heads in).
+    attached: ``softmax(q @ k^T * scale) @ v`` over (B, T, key width)
+    ``q``/``k`` and (B, T, value width) ``v`` (B = batch x heads; the
+    model layer folds heads in); ``causal=True`` keeps key ``j`` for
+    query ``i`` only where ``j <= i``.
 
     ``precision_level`` follows the matmul ladder for every product
-    step (docs/kernels.md); ``blocks=None`` consults the ``attention``
-    schedule-cache family before the static ``_DEFAULT_BLOCKS``.
+    step (docs/kernels.md); ``product_dtype`` rounds every product's
+    operands, probability and cotangent tiles included (module
+    docstring).  ``blocks=None`` consults the ``attention``
+    schedule-cache family before the static default.
     """
-    if q.ndim != 3 or k.shape != q.shape or v.shape != q.shape:
-        raise ValueError("flash_attention expects matching (B, T, dh) "
-                         "operands, got %s %s %s" %
+    if (q.ndim != 3 or k.shape != q.shape or v.ndim != 3
+            or v.shape[:2] != q.shape[:2]):
+        raise ValueError("flash_attention expects (B, T, dk) q and k "
+                         "and a (B, T, dv) v, got %s %s %s" %
                          (q.shape, k.shape, v.shape))
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    plain = not causal and v.shape == q.shape
     if blocks is None:
-        blocks = _tuned_blocks(q, precision_level) or _DEFAULT_BLOCKS
-    out = _flash_fn(float(scale), int(precision_level),
-                    tuple(blocks))(q, k, v)
+        # the tuned schedules were measured on the plain form
+        blocks = (plain and _tuned_blocks(q, precision_level)) or (
+            _CAUSAL_LONG_BLOCKS if causal and q.shape[1] >= 1024
+            else _DEFAULT_BLOCKS)
+    if product_dtype is not None:
+        product_dtype = jnp.dtype(product_dtype).name
+    # the plain form keeps the call (and the cache key) it had in v2
+    form = (bool(causal), product_dtype) if causal or product_dtype else ()
+    out = _flash_fn(float(scale), int(precision_level), tuple(blocks),
+                    *form)(q, k, v)
     if _common.DEBUG_NONFINITE and not isinstance(out, jax.core.Tracer):
         _debug_check(q, k, v, out, precision_level)
     return out
 
 
-def attention_reference(q, k, v, scale=None, precision_level=1):
+def attention_reference(q, k, v, scale=None, precision_level=1,
+                        causal=False):
     """Stock softmax attention in the kernel's exact op order — the
     ``VELES_PALLAS_BWD=0`` fallback (plain jnp, stock autodiff) AND
     the parity oracle: on shapes that fit one (bq, bk) tile the flash
     kernel executes this sequence verbatim AT THE SAME LEVEL, so the
     two agree within a few ULP there (module docstring); multi-tile
     shapes differ only by the online rescale's accumulation order
-    (ULP-bounded, tests/test_transformer.py).
+    (ULP-bounded, tests/test_transformer.py).  ``v`` may be narrower
+    or wider than ``q``/``k``; ``causal=True`` floors the keys after
+    each query as the kernels do.
 
     The DEFAULT level is 1 (true-f32 HIGHEST products): stock model-
     layer math is full f32 everywhere else in the zoo (the gd units'
@@ -416,6 +538,9 @@ def attention_reference(q, k, v, scale=None, precision_level=1):
 
     def one(qb, kb, vb):
         s = mxu_partial_dot(qb, kb.T, precision_level) * scale
+        if causal:
+            s = jnp.where(_col_ids(*s.shape) <= _row_ids(*s.shape), s,
+                          _MASK_FLOOR)
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)

@@ -9,6 +9,15 @@ combine is a single psum over ICI.  This dense-dispatch formulation is
 EXACT (no capacity-factor token dropping) and keeps the collective
 pattern trivial; a capacity-based all_to_all dispatch path is the
 documented follow-up for sparse regimes.
+
+What ``models/decoder.py``'s routed layer does that this one does not:
+it is TOLD which experts it holds and computes only their part, sorts
+the step's token-expert assignments by expert and runs grouped products
+(``lax.ragged_dot``) over the kept ones instead of every expert on every
+token, scores with a sigmoid and a correction bias and renormalises over
+the chosen, and carries a shared expert.  It has no exchange: one
+program, one share.  The two layers share the top-k selection
+(:func:`top_k_route`, here): there is no second one in the tree.
 """
 
 import jax
@@ -19,7 +28,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from veles_tpu.parallel.mesh import shard_map
 
 __all__ = ["moe_apply", "moe_reference", "init_moe_params",
-           "shard_moe_params"]
+           "shard_moe_params", "top_k_route"]
+
+
+def top_k_route(scores, k):
+    """The ``k`` largest of ``scores`` along the last axis: (values,
+    indices).  The tree's one top-k selection: the gate here and
+    ``models/decoder.py``'s routed layer both call it."""
+    return lax.top_k(scores, k)
 
 
 def init_moe_params(rng, n_experts, features, hidden, out_features):
@@ -48,7 +64,7 @@ def _gate_weights(params, x, top_k):
     n_experts = logits.shape[-1]
     if top_k >= n_experts:
         return jax.nn.softmax(logits, axis=-1)
-    top_vals, _ = lax.top_k(logits, top_k)
+    top_vals, _ = top_k_route(logits, top_k)
     threshold = top_vals[..., -1:]
     masked = jnp.where(logits >= threshold, logits, -jnp.inf)
     return jax.nn.softmax(masked, axis=-1)
