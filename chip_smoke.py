@@ -175,8 +175,7 @@ def _step_avals(sw):
 
     loader = sw.loader
     if trainer.mesh is not None:
-        x = trainer._stage_sharded(loader.minibatch_data)
-        labels = trainer._stage_sharded(loader.minibatch_labels)
+        x, labels = trainer._stage(loader)
     else:
         x, labels = loader.minibatch_data.mem, loader.minibatch_labels.mem
     args = [jax.tree.map(aval, trainer._state,
@@ -367,6 +366,7 @@ def _check_spread(sw, lowered, chips):
     per bucket."""
     import jax
 
+    from veles_tpu.observe.metrics import registry
     from veles_tpu.parallel.bucketed import plan_buckets
     from veles_tpu.parallel.analysis import parse_collective_ops
 
@@ -375,18 +375,35 @@ def _check_spread(sw, lowered, chips):
                 if s["weights"] is not None)
     check(len(leaf.sharding.device_set) == chips,
           "state sits on %d device(s)", len(leaf.sharding.device_set))
-    x = trainer._stage_sharded(sw.loader.minibatch_data)
+
+    def counted(name):
+        metric = registry.peek(name)
+        return 0 if metric is None else metric.value
+
+    x = trainer._stage(sw.loader)[0]
     homes = {shard.device for shard in x.addressable_shards}
     check(len(homes) == chips and
           x.addressable_shards[0].data.shape[0] * chips == x.shape[0],
           "batch shards sit on %d device(s)", len(homes))
+    # the resident dataset lives on the mesh, a chip its own rows, and
+    # the minibatch is gathered there: nothing of it through the host
+    for name, store in sw.loader._stores_.items():
+        held = {shard.data.shape[0] for shard in store.addressable_shards}
+        check(len(store.sharding.device_set) == chips and
+              held == {store.shape[0] // chips},
+              "store %r: %s rows a chip on %d device(s)", name, held,
+              len(store.sharding.device_set))
+    check(counted("step.host_staged_bytes") == 0 and
+          counted("loader.mesh_gathers") > 0,
+          "%d bytes of minibatches went through the host, %d mesh "
+          "gathers", counted("step.host_staged_bytes"),
+          counted("loader.mesh_gathers"))
     # XLA:CPU keeps no per-device memory statistics; every TPU does
     stats = [d.memory_stats() for d in jax.local_devices()]
     in_use = [s["bytes_in_use"] for s in stats if s]
     check(len(in_use) == chips or jax.default_backend() == "cpu",
           "no memory statistics on %s", jax.local_devices())
-    # every chip holds at least its replica of the state (chip 0 also
-    # holds the HBM-resident dataset: the loader sits on one device)
+    # every chip holds at least its replica of the state
     state_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
         trainer._state))
     check(not in_use or min(in_use) >= state_bytes,

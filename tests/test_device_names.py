@@ -229,6 +229,49 @@ def instructions(text):
                 found.group(2))[0]
 
 
+def test_mesh_gather_runs_the_kernel_on_each_chips_shard(topo, mosaic):
+    """The data-parallel cell's gather (global batch 1,024 from 12,288
+    AlexNet rows over the four chips of a v5e host): one program in
+    which each chip runs ``veles_gather_rows`` on ITS 3,072 rows, one
+    reduce-scatter leaves it its 256 rows of the window, and no chip
+    sees the table whole."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(numpy.array(topo.devices), ("data",))
+    rows, sample_shape, batch = 12288, (227, 227, 3), 1024
+    shape = gather.store_shape(
+        rows, int(numpy.prod(sample_shape)), jnp.bfloat16)
+    whole = NamedSharding(mesh, PartitionSpec())
+    compiled = gather.mesh_gather_minibatch.trace(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=NamedSharding(
+            mesh, PartitionSpec("data"))),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole),
+        mesh=mesh, data_axis="data", out_dtype=numpy.dtype(jnp.bfloat16),
+        sample_shape=sample_shape).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_mesh_gather_minibatch" in text
+    (name, op_name), = mosaic_calls(text).items()
+    assert re.match(r"^%veles_gather_rows(\.\d+)?$", name), name
+    assert "/loader_gather/" in op_name
+    lines = dict(instructions(text))
+    assert "bf16[3072,1216,128]" in lines[name], lines[name]
+    assert not [rest for rest in lines.values()
+                if re.match(r"\(?\w+\[%d[,\]]" % rows, rest)]
+    exchanges = [rest for rest in lines.values()
+                 if re.search(r" (reduce-scatter|all-reduce|all-gather|"
+                              r"all-to-all|collective-permute)"
+                              r"(-start)?\(", rest)]
+    assert len(exchanges) == 1 and re.match(
+        r"bf16\[256,1216,128\]\S* reduce-scatter\(", exchanges[0]), exchanges
+    assert re.search(r"ENTRY .*-> bf16\[256,227,227,3\]", text)
+    # a chip's quarter of the 3.83 GB store, not the store
+    held = compiled.memory_analysis().argument_size_in_bytes
+    assert held < rows // 4 * 1216 * 128 * 2 + (1 << 20), held
+
+
 @pytest.mark.parametrize("rows,sample_shape,dtype,out_dtype,batch", [
     (1450000, (784,), "float32", "float32", 100),
     (12288, (227, 227, 3), "bfloat16", "bfloat16", 256),

@@ -122,9 +122,15 @@ class Loader(Unit):
             "normalization_parameters", {})
         self._normalizer = None
         self.prng = kwargs.get("prng", prng.get())
+        #: the axes of the mesh the minibatches are consumed on and the
+        #: one the batch is split over (lay_over_mesh): None on one chip
+        self.mesh_axes = None
+        self.data_axis = None
 
     def init_unpickled(self):
         super(Loader, self).init_unpickled()
+        # the live Mesh of mesh_axes (device handles: not pickled)
+        self._mesh_ = None
         self._minibatch_offset_ = 0
         self._minibatch_size_ = 0
         self.pending_minibatches_ = defaultdict(list)
@@ -193,6 +199,17 @@ class Loader(Unit):
             if legacy in state and backing not in state:
                 state[backing] = state.pop(legacy)
         super(Loader, self).__setstate__(state)
+
+    def lay_over_mesh(self, mesh, data_axis):
+        """The trainer consumes the minibatches over ``mesh``, split
+        over ``data_axis`` (``fuse_standard_workflow`` says so before
+        ``initialize``).  A loader that keeps its dataset on the device
+        then keeps it, and serves, over that axis
+        (``FullBatchLoader``); any other serves as ever and the trainer
+        stages.  A pickle carries the axes, as the trainer's does."""
+        self._mesh_ = mesh
+        self.mesh_axes = dict(mesh.shape)
+        self.data_axis = data_axis
 
     # -- the ILoader contract ---------------------------------------------
 

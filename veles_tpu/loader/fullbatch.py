@@ -25,6 +25,14 @@ later reach the device at the next `initialize`.  `original_data` itself
 never goes to the device: the table is there once.  On the numpy backend
 the same contract runs through the host path, which is what the test
 base uses for parity checks.
+
+Under a mesh (the trainer was fused with one: `lay_over_mesh`) the same
+stores are laid over its data axis by rows, each chip holding
+`ceil(N / chips)` of them and none the table, and the minibatch is
+gathered onto the mesh already split as the data-parallel step takes it
+(ops/gather.py, "the gather under a mesh"): the shuffle stays global, the
+rows and their order are the one-chip gather's, and nothing of a
+minibatch passes through the host.
 """
 
 import numpy
@@ -95,11 +103,16 @@ class FullBatchLoader(Loader):
         # the device row stores by what they hold ("data", "labels",
         # "targets"): derived state, rebuilt by initialize
         self._stores_ = {}
+        # what a step gathers, in store order: (store, its rows' shape
+        # or None for labels, the minibatch Array that adopts them)
+        self._served_ = []
         # the device path of fill_indices: the upload of the index
         # window and the dispatch of the two gather programs
         self._m_gather_ = _registry.histogram("loader.gather_s")
         # building and uploading the stores, once an initialize
         self._m_store_ = _registry.histogram("loader.store_s")
+        # minibatches gathered over a mesh (0 on one chip)
+        self._m_mesh_gathers_ = _registry.counter("loader.mesh_gathers")
 
     @property
     def shape(self):
@@ -126,17 +139,27 @@ class FullBatchLoader(Loader):
         self.device = device
         # the device lets go of the last initialize's table first
         self._stores_ = {}
+        self._served_ = []
+        axes = getattr(self, "mesh_axes", None)
+        if axes and self._mesh_ is None:  # unpickled
+            from veles_tpu.parallel.mesh import restore_mesh
+            self._mesh_ = restore_mesh(axes, self.warning)
+        if self._mesh_ is not None and self._mesh_.is_multi_process:
+            # every process's loader serves its own rows there, and the
+            # trainer stitches them (parallel.shard_host_batch)
+            self._mesh_ = None
         result = super(FullBatchLoader, self).initialize(**kwargs)
         self.analyze_original_dataset()
         self._map_original_labels()
         if self._use_device_path():
             # one-time HBM residency; per-step gathers read from here
-            self._store_rows("data", self.original_data)
+            self._store_rows("data", self.original_data,
+                             self.minibatch_data)
             if self.has_labels:
                 self._mapped_original_labels_.map_read()
                 self._store_rows(
                     "labels", self._mapped_original_labels_,
-                    build=gather.build_label_store)
+                    self.minibatch_labels, build=gather.build_label_store)
             self.shuffled_indices.initialize(self.device)
         return result
 
@@ -145,23 +168,28 @@ class FullBatchLoader(Loader):
                 not isinstance(self.device, NumpyDevice) and
                 self.device.exists)
 
-    def _store_rows(self, name, array, build=None):
-        """Upload ``array``'s rows as the row store ``name`` (the upload
-        is asynchronous: the span times the host's part).  Rows that
-        ``create_originals`` made are in store layout already; any other
-        take one host copy, after which ``array.mem`` is the window on
-        that copy and the host, too, holds the rows once."""
+    def _store_rows(self, name, array, minibatch, build=None):
+        """Upload ``array``'s rows as the row store ``name``, from which
+        every step gathers ``minibatch`` (the upload is asynchronous:
+        the span times the host's part).  Rows that ``create_originals``
+        made are in store layout already; any other take one host copy,
+        after which ``array.mem`` is the window on that copy and the
+        host, too, holds the rows once.  Under a mesh the store goes
+        over its data axis, each chip its own rows."""
         with _tracer.scope("loader.store", cat="loader",
                            hist=self._m_store_):
             if build is not None:
-                buf = build(array.mem)
+                buf, sample_shape = build(array.mem), None
             else:
+                sample_shape = array.mem.shape[1:]
                 buf = gather.host_store_of(array.mem)
                 if buf is None:
                     buf = gather.build_store(array.mem)
-                    array.set_host_view(gather.rows_of(
-                        buf, array.mem.shape[1:]))
-            self._stores_[name] = self.device.put(buf)
+                    array.set_host_view(gather.rows_of(buf, sample_shape))
+            self._stores_[name] = (
+                self.device.put(buf) if self._mesh_ is None else
+                gather.shard_store(buf, self._mesh_, self.data_axis))
+        self._served_.append((name, sample_shape, minibatch))
         _registry.gauge("loader.store_bytes").set(
             sum(store.nbytes for store in self._stores_.values()))
 
@@ -247,21 +275,45 @@ class FullBatchLoader(Loader):
         window = self._index_window(start_offset, count)
         with _tracer.scope("loader.gather", cat="loader",
                            hist=self._m_gather_):
-            idx_dev = self.device.put(window)
-            data = ops.gather_minibatch(
-                self._stores_["data"], idx_dev, out_dtype=self.dtype,
-                sample_shape=self.shape)
-            if count < self.max_minibatch_size:
-                data = self._zero_tail(data, count)
-            self.minibatch_data.set_device_array(data, self.device)
-            if self.has_labels:
-                labels = ops.gather_labels(self._stores_["labels"],
-                                           idx_dev)
-                if count < self.max_minibatch_size:
-                    labels = self._mask_tail_labels(labels, count)
-                self.minibatch_labels.set_device_array(labels,
-                                                       self.device)
+            for (_, _, minibatch), rows in zip(
+                    self._served_, self._gather(window, count)):
+                minibatch.set_device_array(rows, self.device)
         return True
+
+    def _gather(self, window, count):
+        """The ``window``'s rows out of every store, in store order, as
+        device arrays: the rows from ``count`` on zero, their labels -1.
+        On one chip the two gather programs on the loader's device;
+        under a mesh the same gather over its data axis, each chip left
+        with its part of the window (ops/gather.py): the arrays carry
+        the batch sharding the trainer's step takes."""
+        mesh = self._mesh_
+        short = count < self.max_minibatch_size
+        if mesh is None:
+            idx_dev = self.device.put(window)
+        else:
+            from veles_tpu.parallel.api import replicate
+            self._m_mesh_gathers_.inc()
+            over = dict(mesh=mesh, data_axis=self.data_axis)
+            window, count = replicate(mesh, (window, numpy.int32(count)))
+        for name, sample_shape, _ in self._served_:
+            store = self._stores_[name]
+            if mesh is not None:
+                yield (gather.mesh_gather_labels(
+                           store, window, count, **over)
+                       if sample_shape is None else
+                       gather.mesh_gather_minibatch(
+                           store, window, count, out_dtype=self.dtype,
+                           sample_shape=sample_shape, **over))
+            elif sample_shape is None:
+                labels = ops.gather_labels(store, idx_dev)
+                yield (self._mask_tail_labels(labels, count) if short
+                       else labels)
+            else:
+                rows = ops.gather_minibatch(
+                    store, idx_dev, out_dtype=self.dtype,
+                    sample_shape=sample_shape)
+                yield self._zero_tail(rows, count) if short else rows
 
     def _index_window(self, start_offset, count):
         """The minibatch's ``count`` shuffled indices in a window of the
@@ -332,24 +384,9 @@ class FullBatchLoaderMSE(LoaderMSEMixin, FullBatchLoader):
             self.target_normalizer.analyze(self.original_targets.mem)
         self.target_normalizer.normalize(self.original_targets.mem)
         if self._use_device_path():
-            self._store_rows("targets", self.original_targets)
+            self._store_rows("targets", self.original_targets,
+                             self.minibatch_targets)
         return result
-
-    def fill_indices(self, start_offset, count):
-        filled = super(FullBatchLoaderMSE, self).fill_indices(
-            start_offset, count)
-        if not filled:
-            return False
-        window = numpy.zeros(self.max_minibatch_size, Loader.INDEX_DTYPE)
-        window[:count] = self.minibatch_indices.mem[:count]
-        idx_dev = self.device.put(window)
-        targets = ops.gather_minibatch(
-            self._stores_["targets"], idx_dev, out_dtype=self.dtype,
-            sample_shape=self.original_targets.shape[1:])
-        if count < self.max_minibatch_size:
-            targets = self._zero_tail(targets, count)
-        self.minibatch_targets.set_device_array(targets, self.device)
-        return True
 
     def fill_minibatch(self):
         super(FullBatchLoaderMSE, self).fill_minibatch()

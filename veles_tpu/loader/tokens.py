@@ -72,9 +72,8 @@ class TokenRowLoader(FullBatchLoader):
         window = self._index_window(start_offset, count)
         with _tracer.scope("loader.gather", cat="loader",
                            hist=self._m_gather_):
-            rows = ops.gather_minibatch(
-                self._stores_["data"], self.device.put(window),
-                out_dtype=self.dtype, sample_shape=self.shape)
+            # every row as it is: split_rows masks the tail itself
+            rows, = self._gather(window, self.max_minibatch_size)
             data, targets = split_rows(rows, numpy.int32(count))
             self.minibatch_data.set_device_array(data, self.device)
             self.minibatch_labels.set_device_array(targets, self.device)

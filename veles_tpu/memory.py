@@ -132,6 +132,16 @@ class Array(Pickleable):
                 self._state_ = _HOST_DIRTY
         return self.devmem
 
+    def resident(self):
+        """The device buffer where it holds what the Array holds — a
+        result adopted by ``set_device_array`` or an upload the host has
+        not written since — else None.  Moves nothing: no upload as
+        ``devmem`` makes, no fetch."""
+        with self._lock_:
+            if self._state_ in (_DEVICE_DIRTY, _IN_SYNC):
+                return self._devmem_
+        return None
+
     def __bool__(self):
         return self._mem is not None and self._mem.size > 0
 

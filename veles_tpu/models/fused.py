@@ -97,6 +97,10 @@ class FusedTrainer(Unit):
         # left of step.train_s is the span's self time (the dropout
         # key, the lazy skip counters, the metrics bookkeeping)
         self._m_stage_ = _registry.histogram("step.stage_s")
+        # bytes of minibatches that _stage_sharded took through the
+        # host on their way to the mesh (0 where the loader gathers
+        # onto the mesh, and on one chip)
+        self._m_host_staged_ = _registry.counter("step.host_staged_bytes")
         self._m_dispatch_ = _registry.histogram("step.dispatch_s")
         self._m_eval_dispatch_ = _registry.histogram(
             "step.eval_dispatch_s")
@@ -114,31 +118,12 @@ class FusedTrainer(Unit):
         self._compress_skips_seen_ = 0
 
     def _restore_mesh(self):
-        """Rebuild the SPMD mesh after unpickling (a Mesh holds live
-        device handles, so snapshots carry its AXES instead): same
-        shape when the host still has the devices; a single-axis
-        (pure-DP) mesh re-spans whatever devices exist now; a
-        multi-axis shape that no longer fits fails LOUDLY rather than
-        silently degrading to a single-device step."""
+        """Rebuild the SPMD mesh after unpickling (snapshots carry its
+        AXES: parallel.mesh.restore_mesh)."""
         axes = getattr(self, "_spmd_axes_", None)
-        if not axes or self.mesh is not None:
-            return
-        from veles_tpu.parallel import auto_mesh, make_mesh
-        try:
-            self.mesh = make_mesh(dict(axes))
-        except ValueError as exc:
-            if len(axes) == 1:
-                self.mesh = auto_mesh(next(iter(axes)))
-                self.warning(
-                    "resumed SPMD mesh %s does not fit this host "
-                    "(%s); re-spanning the data axis over %d devices",
-                    dict(axes), exc,
-                    self.mesh.shape[next(iter(axes))])
-            else:
-                raise ValueError(
-                    "cannot rebuild the resumed SPMD mesh %s on this "
-                    "host: %s — re-fuse with an explicit mesh"
-                    % (dict(axes), exc))
+        if axes and self.mesh is None:
+            from veles_tpu.parallel.mesh import restore_mesh
+            self.mesh = restore_mesh(axes, self.warning)
 
     def initialize(self, device=None, **kwargs):
         self.device = device
@@ -341,8 +326,10 @@ class FusedTrainer(Unit):
             _xla.set_fwd_flops(fwd_flops)
 
     def _stage_sharded(self, arr):
-        """Stage one minibatch Array onto the mesh, leading dim over
-        ``data_axis``.  Multi-host processes stitch their local slice
+        """Stage one minibatch Array onto the mesh THROUGH THE HOST,
+        leading dim over ``data_axis``: for a minibatch that is not on
+        the mesh already (``_stage``), and the only place that reads one
+        back.  Multi-host processes stitch their local slice
         (parallel.shard_host_batch); single-process meshes device_put
         the full batch.  The host buffer is COPIED first: XLA:CPU's
         device_put adopts host memory zero-copy, and the loader refills
@@ -350,6 +337,7 @@ class FusedTrainer(Unit):
         from veles_tpu.parallel.api import shard_host_batch
         arr.map_read()
         host = numpy.array(arr.mem)
+        self._m_host_staged_.inc(host.nbytes)
         if host.shape[0] % self.mesh.shape[self.data_axis]:
             raise ValueError(
                 "minibatch rows %d not divisible by mesh axis %r=%d"
@@ -460,13 +448,17 @@ class FusedTrainer(Unit):
     _sync_state_to_units = sync
 
     def _stage(self, loader):
-        """(x, target) on the device: sharded over the mesh, the
-        Prefetcher's already-transferred arrays, or the loader's."""
+        """(x, target) on the device: split over the mesh, the
+        Prefetcher's already-transferred arrays, or the loader's.  A
+        loader that gathers onto the mesh (``Loader.lay_over_mesh``)
+        hands over arrays that are split as the step takes them, and
+        they pass as they are; any other minibatch goes through the
+        host."""
         targets = (loader.minibatch_labels if self.loss == "softmax"
                    else loader.minibatch_targets)
         if self.mesh is not None:
-            return (self._stage_sharded(loader.minibatch_data),
-                    self._stage_sharded(targets))
+            return tuple(self._on_mesh(arr)
+                         for arr in (loader.minibatch_data, targets))
         prefetched = (self._prefetcher.current
                       if self._prefetcher is not None else None)
         if prefetched is not None:
@@ -477,6 +469,14 @@ class FusedTrainer(Unit):
                 else prefetched.targets)
         return (loader.minibatch_data.device_array(self.device),
                 targets.device_array(self.device))
+
+    def _on_mesh(self, arr):
+        from veles_tpu.parallel.api import batch_sharding
+        held = arr.resident()
+        if held is not None and held.sharding.is_equivalent_to(
+                batch_sharding(self.mesh, self.data_axis), held.ndim):
+            return held
+        return self._stage_sharded(arr)
 
     def run(self):
         loader = self.sw.loader
@@ -663,7 +663,10 @@ def fuse_standard_workflow(sw, dropout_seed=0, pipeline=False,
     ``--grad-compress``).  With a mesh the master-slave protocol
     carries CONTROL records only — per-step gradients ride ICI — so
     the workflow flips to the single-traversal inline update
-    validation (docs/distributed.md, ``Workflow.update_validation``).
+    validation (docs/distributed.md, ``Workflow.update_validation``),
+    and the loader is told the mesh: a dataset resident on the device
+    then lives, and is gathered, over ``data_axis``
+    (``Loader.lay_over_mesh``).
     """
     from veles_tpu.config import root
     train_cfg = root.common.train
@@ -679,6 +682,7 @@ def fuse_standard_workflow(sw, dropout_seed=0, pipeline=False,
                            grad_compress=grad_compress)
     if mesh is not None:
         sw.update_validation = "inline"
+        sw.loader.lay_over_mesh(mesh, data_axis)
     # detach the old chain from control flow
     for unit in sw.forwards + [sw.evaluator] + sw.gds:
         unit.unlink_all()

@@ -18,6 +18,14 @@ is one Pallas kernel, ``veles_gather_rows``: the shuffled indices are
 scalar-prefetched into SMEM and every row goes HBM -> HBM by its own
 DMA, a window of them in flight.  The program around it touches the
 ``B`` gathered rows only: slice off the pad, cast, reshape.
+
+Under a mesh the same two halves are laid over its data axis
+(:func:`shard_store`, :func:`mesh_gather_minibatch`,
+:func:`mesh_gather_labels`): chip ``k`` of ``n`` holds rows
+``[k R, (k + 1) R)``, ``R = ceil(N / n)``, runs the same kernel on its
+own shard for the rows of the index window that it owns, and one
+reduce-scatter leaves it with its ``B / n`` rows of the window, in the
+window's order: the batch sharding the data-parallel step takes.
 """
 
 import functools
@@ -25,12 +33,15 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import NamedSharding, PartitionSpec
 
 from veles_tpu.ops.common import ceil_mult, interpret_for
 
 __all__ = ["gather_minibatch", "gather_labels", "gather_rows",
+           "mesh_gather_minibatch", "mesh_gather_labels", "shard_store",
            "store_shape", "build_store", "build_label_store", "host_store",
            "host_store_of", "rows_of"]
 
@@ -128,6 +139,29 @@ def build_label_store(labels):
         padded // LANES, LANES))
 
 
+def shard_store(buf, mesh, data_axis):
+    """The numpy store ``buf`` as ONE array laid over ``data_axis`` by
+    rows: chip ``k`` of ``n`` holds rows ``[k R, (k + 1) R)``,
+    ``R = ceil(N / n)``, the last shard padded with zero rows that no
+    index names.  Every chip is sent its own rows straight from the
+    host buffer: the table is never whole on one chip, and the host
+    makes no copy of it (but the last shard's, where it is padded)."""
+    chips = mesh.shape[data_axis]
+    shard_rows = -(-len(buf) // chips)
+
+    def shard(index):
+        rows = buf[index[0]]
+        pad = shard_rows - len(rows)
+        if pad:
+            rows = numpy.concatenate(
+                [rows, numpy.zeros((pad,) + rows.shape[1:], rows.dtype)])
+        return rows
+
+    return jax.make_array_from_callback(
+        (chips * shard_rows,) + buf.shape[1:],
+        NamedSharding(mesh, PartitionSpec(data_axis)), shard)
+
+
 # -- the gather --------------------------------------------------------------
 
 def _gather_kernel(idx_ref, store_ref, out_ref, sems):
@@ -155,14 +189,9 @@ def _gather_kernel(idx_ref, store_ref, out_ref, sems):
     jax.lax.fori_loop(max(0, batch - DMA_WINDOW), batch, drain, 0)
 
 
-def gather_rows(store, indices, sample_shape, out_dtype=None):
-    """store x (B,) -> ``(B,) + sample_shape``: the kernel over the
-    store as it is, then ops over the B gathered rows.  Traceable (the
-    epoch scans call it in their body).  Indices are clamped into range:
-    a DMA from a row that is not there would fault the chip."""
-    batch = indices.shape[0]
-    indices = jnp.clip(indices.astype(jnp.int32), 0, store.shape[0] - 1)
-    sample_shape = tuple(sample_shape)
+def _kernel_rows(store, indices):
+    """store x (B,) int32 indices, each in range -> (B, S, L): the
+    kernel alone."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(),
@@ -170,17 +199,33 @@ def gather_rows(store, indices, sample_shape, out_dtype=None):
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA((DMA_WINDOW,))],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _gather_kernel,
         name=KERNEL_NAME,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch,) + store.shape[1:],
-                                       store.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (indices.shape[0],) + store.shape[1:], store.dtype),
         interpret=interpret_for(store),
     )(indices, store)
-    out = out.reshape(batch, -1)[:, :_width(sample_shape)]
-    return out.astype(out_dtype or store.dtype).reshape(
+
+
+def _samples(rows, sample_shape, out_dtype):
+    """(B, S, L) gathered rows -> ``(B,) + sample_shape``: slice off
+    the pad, cast, reshape."""
+    batch = rows.shape[0]
+    out = rows.reshape(batch, -1)[:, :_width(sample_shape)]
+    return out.astype(out_dtype or rows.dtype).reshape(
         (batch,) + sample_shape)
+
+
+def gather_rows(store, indices, sample_shape, out_dtype=None):
+    """store x (B,) -> ``(B,) + sample_shape``: the kernel over the
+    store as it is, then ops over the B gathered rows.  Traceable (the
+    epoch scans call it in their body).  Indices are clamped into range:
+    a DMA from a row that is not there would fault the chip."""
+    indices = jnp.clip(indices.astype(jnp.int32), 0, store.shape[0] - 1)
+    return _samples(_kernel_rows(store, indices), tuple(sample_shape),
+                    out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "sample_shape"))
@@ -208,6 +253,85 @@ def gather_labels(labels, indices):
         if labels.ndim == 1:
             labels = build_label_store(labels)
         indices = indices.astype(jnp.int32)
-        rows = gather_rows(labels, indices // LANES, (LANES,))
-        return jnp.take_along_axis(
-            rows, (indices % LANES)[:, None], axis=1)[:, 0]
+        return _lanes(gather_rows(labels, indices // LANES, (LANES,)),
+                      indices)
+
+
+def _lanes(rows, indices):
+    """Label ``indices[i]`` out of row ``i`` of the (B, 128) label rows."""
+    return jnp.take_along_axis(
+        rows, (indices % LANES)[:, None], axis=1)[:, 0]
+
+
+# -- the gather under a mesh --------------------------------------------------
+
+def _own_rows(shard, rows, count, data_axis, pick=None):
+    """Inside a ``shard_map`` over ``data_axis``: this chip's ``shard``
+    of a :func:`shard_store` x the window's (B,) store rows -> this
+    chip's ``B / n`` rows of the window, in the window's order, zero
+    from the window's ``count``-th on.  Every chip runs the kernel over
+    the whole window, on row 0 where the row is another chip's or past
+    ``count``, zeroes those, and a reduce-scatter adds the chips'
+    windows up: each row is one chip's row plus zeros, so no value
+    changes.  ``pick`` maps the (B, S, L) gathered rows to what is
+    exchanged."""
+    local = rows - lax.axis_index(data_axis) * shard.shape[0]
+    owned = ((jnp.arange(rows.shape[0]) < count) & (local >= 0) &
+             (local < shard.shape[0]))
+    got = _kernel_rows(shard, jnp.where(owned, local, 0))
+    if pick is not None:
+        got = pick(got)
+    owned = owned.reshape((-1,) + (1,) * (got.ndim - 1))
+    return lax.psum_scatter(jnp.where(owned, got, 0), data_axis,
+                            scatter_dimension=0, tiled=True)
+
+
+def _over_mesh(local_gather, mesh, data_axis, store, indices, count):
+    chips = mesh.shape[data_axis]
+    if indices.shape[0] % chips:
+        raise ValueError(
+            "minibatch rows %d not divisible by mesh axis %r=%d"
+            % (indices.shape[0], data_axis, chips))
+    whole = PartitionSpec()
+    return jax.shard_map(
+        local_gather, mesh=mesh,
+        in_specs=(PartitionSpec(data_axis), whole, whole),
+        out_specs=PartitionSpec(data_axis), check_vma=False)(
+            store, indices.astype(jnp.int32), count)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "data_axis", "out_dtype", "sample_shape"))
+def mesh_gather_minibatch(store, indices, count, *, mesh, data_axis,
+                          out_dtype=None, sample_shape):
+    """:func:`gather_minibatch` where ``store`` is a
+    :func:`shard_store` over ``data_axis``: the same rows in the same
+    order, split over the axis as ``parallel.api.batch_sharding`` splits
+    a batch; the rows from ``count`` on are zero.  The ops over the
+    gathered rows run on each chip's ``B / n``."""
+    sample_shape = tuple(sample_shape)
+
+    def local_gather(shard, indices, count):
+        return _samples(_own_rows(shard, indices, count, data_axis),
+                        sample_shape, out_dtype)
+
+    with jax.named_scope(SCOPE):
+        return _over_mesh(local_gather, mesh, data_axis, store, indices,
+                          count)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "data_axis"))
+def mesh_gather_labels(store, indices, count, *, mesh, data_axis):
+    """:func:`gather_labels` where ``store`` is the label store as a
+    :func:`shard_store`; the labels from ``count`` on are -1."""
+    def local_gather(shard, indices, count):
+        labels = _own_rows(
+            shard, indices // LANES, count, data_axis,
+            pick=lambda rows: _lanes(rows.reshape(-1, LANES), indices))
+        part = labels.shape[0]
+        mine = lax.axis_index(data_axis) * part + jnp.arange(part)
+        return jnp.where(mine < count, labels, -1)
+
+    with jax.named_scope(SCOPE):
+        return _over_mesh(local_gather, mesh, data_axis, store, indices,
+                          count)

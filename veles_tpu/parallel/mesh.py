@@ -13,7 +13,8 @@ import jax
 from jax import shard_map
 from jax.sharding import Mesh
 
-__all__ = ["make_mesh", "auto_mesh", "shard_map", "zero_slot_table",
+__all__ = ["make_mesh", "auto_mesh", "restore_mesh", "shard_map",
+           "zero_slot_table",
            "zero_state", "unzero_state", "MeshManager", "mesh_snapshot"]
 
 
@@ -47,6 +48,29 @@ def auto_mesh(data_axis="data", devices=None):
     tensor-level strategy (parameter-server DP, SURVEY.md section 2.6)."""
     devices = list(devices if devices is not None else jax.devices())
     return make_mesh({data_axis: len(devices)}, devices)
+
+
+def restore_mesh(axes, warn):
+    """The mesh a pickle's ``axes`` (``dict(mesh.shape)``: a Mesh holds
+    live device handles, so snapshots carry its axes instead) stand
+    for, on the devices this host has now: the same shape where it
+    fits; a single-axis (pure-DP) mesh re-spans whatever devices exist,
+    with a word to ``warn``; a multi-axis shape that no longer fits
+    fails LOUDLY rather than degrade to a single-device run."""
+    try:
+        return make_mesh(dict(axes))
+    except ValueError as exc:
+        if len(axes) != 1:
+            raise ValueError(
+                "cannot rebuild the resumed SPMD mesh %s on this "
+                "host: %s — re-fuse with an explicit mesh"
+                % (dict(axes), exc))
+        axis = next(iter(axes))
+        mesh = auto_mesh(axis)
+        warn("resumed SPMD mesh %s does not fit this host (%s); "
+             "re-spanning the data axis over %d devices",
+             dict(axes), exc, mesh.shape[axis])
+        return mesh
 
 
 # -- ZeRO-1 state layout (docs/distributed.md, "Elastic mesh contract") ---
