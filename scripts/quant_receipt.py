@@ -17,9 +17,8 @@ receipt records, per model:
 - **CPU latency rows** for both engines, honestly labeled: on CPU the
   int8 kernels execute through the Pallas INTERPRETER, so the int8
   leg's wall time measures the interpreter and carries no speedup
-  claim — the TPU row (``bench.py quant_ab``, interleaved
-  pass-filtered slopes against the int8 peak) is the real-hardware
-  receipt the ROADMAP ledger tracks;
+  claim — the 8-bit rate on a TPU is not measured (no cell of the
+  benchmark serves; ROADMAP R2);
 - warm-restart **compile receipts** for the quantized digests.
 
 A compact ``quant_ab`` block is also folded into BENCH_serve.json so
@@ -221,10 +220,9 @@ def main():
             "cpu_latency_ms rows are CPU-interpreter machinery "
             "evidence only: the int8 Pallas kernels run through the "
             "Pallas interpreter on CPU, so the int8 leg measures the "
-            "interpreter, not the MXU's 8-bit rate.  The TPU speedup "
-            "row is bench.py quant_ab (interleaved pass-filtered "
-            "slopes, int8-vs-bf16 peak context) — pending a "
-            "real-TPU run (ROADMAP real-hardware receipts ledger)."),
+            "interpreter, not the MXU's 8-bit rate.  The 8-bit rate "
+            "on a TPU is not measured: no cell of the benchmark "
+            "serves (ROADMAP R2)."),
         "wall_s": round(time.time() - t0, 1),
     }
     out = os.path.join(os.path.dirname(os.path.dirname(

@@ -5,9 +5,9 @@ Two parts, internally consistent (round-2 verdict: bytes and step time
 must describe the SAME network):
 
 1. COLLECTIVE BYTES: lowers the data-parallel train step of the FULL
-   AlexNet (227 px, 1000 classes — the exact model bench.py times on
-   the real chip) over 2..64 virtual devices and sums the all-reduce
-   payload the optimized HLO actually issues.  Since PR 6 this covers
+   AlexNet (227 px, 1000 classes — the model of the benchmark's
+   ``alexnet_train_*`` cells) over 2..64 virtual devices and sums the
+   all-reduce payload the optimized HLO issues.  Since PR 6 this covers
    BOTH planes: the flat pjit-annotation step (one fused ~250 MB
    all-reduce) and the SPMD bucketed step
    (compiler.build_train_step(grad_bucket_mb=...)), whose optimized
@@ -24,8 +24,9 @@ must describe the SAME network):
    to the measured bucket granularity; the last bucket plus per-bucket
    hop latency stay exposed.  The old no-overlap projection is kept in
    the report as "projection_no_overlap" for comparison.  Combined
-   with the single-chip step time measured by bench.py on the real
-   chip, this yields projected efficiency at 8/16/32/64 chips plus a
+   with a single-chip step time from a chip run (``--step-seconds``,
+   from PERF_LEDGER.jsonl), this yields projected efficiency at
+   8/16/32/64 chips plus a
    bandwidth/latency sensitivity table.
 
    Model constants (documented, overridable by flags): v5e ICI
@@ -260,8 +261,9 @@ def main():
     parser.add_argument("--out", default=os.path.join(REPO,
                                                       "SCALING.json"))
     parser.add_argument("--per-device-batch", type=int, default=128,
-                        help="matches the bench.py single-chip batch "
-                             "so t_step and t_comm describe one run")
+                        help="the single-chip batch t_step was "
+                             "measured at, so t_step and t_comm "
+                             "describe one run")
     parser.add_argument("--size", type=int, default=227)
     parser.add_argument("--classes", type=int, default=1000)
     parser.add_argument("--counts", default="2,4,8,16,32,64")

@@ -665,34 +665,3 @@ def test_bwd_snapshot_attribution(monkeypatch):
         t2.observe(0.002)
         e2.observe(0.004)
     assert xla.bwd_snapshot(reg2) is None
-
-
-def test_bench_bwd_ab_smoke():
-    """The compile-only A/B harness runs on CPU: both legs compile,
-    forward losses bit-identical, states within the kernel band."""
-    import bench
-
-    res = bench.bench_bwd_ab(small=True)
-    assert res["loss_bit_identical"] is True
-    assert res["parity_ok"] is True
-    assert res["state_max_rel_diff"] < 1e-4
-    # CPU leg carries compile+parity only — no timing claims
-    assert "note" in res or "speedup" in res
-
-
-def test_spread_filters_jitter_passes():
-    """bench._spread / _filter_passes: the published median discards
-    non-positive (jitter-dominated) passes, records passes_used and
-    the raw per-pass slopes."""
-    from bench import _filter_passes, _spread
-
-    samples = [0.016, 0.017, -0.038, 0.016, 0.018]
-    spread = _spread(samples)
-    assert spread["passes"] == 5
-    assert spread["passes_used"] == 4
-    assert spread["median"] == pytest.approx(0.0165)
-    assert spread["min"] == pytest.approx(-0.038)  # raw extremes kept
-    assert spread["slopes"] == [pytest.approx(s) for s in samples]
-    # all passes jitter-dominated: raw list returned, caller's floor
-    # (not the filter) rejects the measurement
-    assert _filter_passes([-1.0, -2.0]) == [-1.0, -2.0]

@@ -580,15 +580,3 @@ def test_peak_tables_and_step_dtype(monkeypatch):
             xi.set_step_dtype("fp4")
     finally:
         xi.set_step_dtype(prev)
-
-
-def test_bench_quant_ab_smoke():
-    """The bench section's CPU mode: parity + receipts, green."""
-    from bench import bench_quant_ab
-
-    result = bench_quant_ab(True)
-    assert result["pallas_bitexact"] is True
-    assert result["top1_delta_pct"] <= 5.0
-    assert result["digests"]["f32"] != result["digests"]["int8"]
-    assert result["compiles"]["int8"] >= 1
-    assert "note" in result  # CPU rows never claim a speedup

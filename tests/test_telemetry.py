@@ -1,5 +1,5 @@
-"""Fleet telemetry plane (veles_tpu/observe/timeseries.py, alerts.py,
-baseline.py; docs/observability.md "Fleet telemetry"): series-ring
+"""Fleet telemetry plane (veles_tpu/observe/timeseries.py, alerts.py;
+docs/observability.md "Fleet telemetry"): series-ring
 bucket semantics (counter deltas/rates over ACTUAL elapsed time,
 gauge last-write, mergeable log-binned latency digests), the
 take_chunk ship cursor and FleetTelemetry's seq-dedup'd
@@ -9,15 +9,13 @@ sum, gauges max, digests merge bin-wise), NTP probe offset estimation
 slow must both burn; thin windows abstain), EMA spike rules,
 edge-triggered alert lifecycle with the flight-recorder + tail-
 exemplar evidence dump, heartbeat schema v2/v3 validation and the
-JSONL digest, the perf-baseline regression gate, and the
-``observe fleet`` / ``observe regress`` CLI round-trips."""
+JSONL digest, and the ``observe fleet`` CLI round-trip."""
 
 import json
 import math
 
 import pytest
 
-from veles_tpu.observe import baseline
 from veles_tpu.observe.alerts import (AlertManager, BurnRateRule,
                                       EmaSpikeRule, default_rules,
                                       rule_from_spec)
@@ -410,82 +408,6 @@ def test_summarize_heartbeats_mixed_schemas(tmp_path):
     assert digest["alerts_fired"] == ["slo_burn.interactive"]
 
 
-# -- perf-regression sentinel -----------------------------------------------
-
-
-def _write_baseline(path, metrics):
-    path.write_text(json.dumps(
-        {"schema": 1, "source": "test", "metrics": metrics}))
-    return str(path)
-
-
-def test_baseline_gate_directions_and_tolerance(tmp_path):
-    """``direction`` names which way is BETTER: a higher-is-better
-    metric fails by dropping past tolerance, a lower-is-better one by
-    rising; in-tolerance drift and improvements pass."""
-    base = _write_baseline(tmp_path / "PERF_BASELINE.json", {
-        "tflops": {"value": 100.0, "direction": "higher",
-                   "tolerance_pct": 10.0},
-        "p99_ms": {"value": 20.0, "direction": "lower",
-                   "tolerance_pct": 10.0}})
-    ok, report = baseline.gate({"tflops": 95.0, "p99_ms": 21.0},
-                               baseline_path=base)
-    assert ok and report["status"] == "ok"
-    ok, report = baseline.gate({"tflops": 85.0, "p99_ms": 19.0},
-                               baseline_path=base)
-    assert not ok and report["regressed"] == ["tflops"]
-    ok, report = baseline.gate({"tflops": 120.0, "p99_ms": 26.0},
-                               baseline_path=base)
-    assert not ok and report["regressed"] == ["p99_ms"]
-    statuses = {r["metric"]: r["status"] for r in report["results"]}
-    assert statuses["tflops"] == "improved"
-    assert any("REGRESSED" in line
-               for line in baseline.render_report(report))
-
-
-def test_baseline_gate_missing_metric_and_no_baseline(tmp_path):
-    """A baselined metric the run did not cover reports ``missing``
-    without failing; a missing baseline passes as ``no_baseline`` —
-    first runs must never be red."""
-    base = _write_baseline(tmp_path / "PERF_BASELINE.json", {
-        "tflops": {"value": 100.0, "direction": "higher"}})
-    ok, report = baseline.gate({"other": 1.0}, baseline_path=base)
-    assert ok
-    assert report["results"][0]["status"] == "missing"
-    ok, report = baseline.gate(
-        {"tflops": 1.0}, baseline_path=str(tmp_path / "absent.json"))
-    assert ok and report["status"] == "no_baseline"
-    assert baseline.load_baseline(str(tmp_path / "absent.json")) is None
-
-
-def test_baseline_headline_metric_folding(tmp_path):
-    """The compact record's headline {metric, value} pair is folded
-    in under its own metric name (bench.py's last line shape)."""
-    base = _write_baseline(tmp_path / "PERF_BASELINE.json", {
-        "bf16_tflops": {"value": 100.0, "direction": "higher",
-                        "tolerance_pct": 10.0}})
-    ok, _ = baseline.gate({"metric": "bf16_tflops", "value": 99.0},
-                          baseline_path=base)
-    assert ok
-    ok, report = baseline.gate({"metric": "bf16_tflops", "value": 50.0},
-                               baseline_path=base)
-    assert not ok and report["regressed"] == ["bf16_tflops"]
-
-
-def test_steady_state_rates_filters_warmup(tmp_path):
-    """Heartbeat-derived rates follow the measure.py filter-passes
-    discipline: warmup/drain zero-rate buckets measure the idle
-    machine, not the program."""
-
-    def bucket(rate):
-        return {"counters": {"req": {"delta": rate, "rate": rate}}}
-
-    rates = baseline.steady_state_rates(
-        [bucket(0.0), bucket(0.0)] +
-        [bucket(r) for r in (95.0, 100.0, 105.0, 98.0, 102.0)])
-    assert 90.0 <= rates["req.rate"] <= 110.0
-
-
 # -- CLI --------------------------------------------------------------------
 
 
@@ -511,23 +433,3 @@ def test_observe_fleet_cli_round_trip(tmp_path, capsys):
     # human rendering exercises the same rollup
     assert main(["fleet", str(a), str(b)]) == 0
     assert "fleet rollup" in capsys.readouterr().out
-
-
-def test_observe_regress_cli_exit_codes(tmp_path, capsys):
-    """``observe regress`` is the sentinel's CLI front: exit 0 on a
-    clean record, exit 1 naming the regressed metric."""
-    from veles_tpu.observe.__main__ import main
-    base = _write_baseline(tmp_path / "PERF_BASELINE.json", {
-        "tflops": {"value": 100.0, "direction": "higher",
-                   "tolerance_pct": 10.0}})
-    good, bad = tmp_path / "good.json", tmp_path / "bad.json"
-    good.write_text(json.dumps({"tflops": 101.0}))
-    bad.write_text(json.dumps({"tflops": 70.0}))
-    assert main(["regress", str(good), "--baseline", base]) == 0
-    assert "perf gate: ok" in capsys.readouterr().out
-    assert main(["regress", str(bad), "--baseline", base]) == 1
-    assert "REGRESSED" in capsys.readouterr().out
-    assert main(["regress", str(bad), "--baseline", base,
-                 "--json"]) == 1
-    assert json.loads(capsys.readouterr().out)["regressed"] == \
-        ["tflops"]

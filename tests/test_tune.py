@@ -287,15 +287,23 @@ def test_planted_entry_serves_pool_bwd_bit_equal(monkeypatch):
 # -- measurement discipline ---------------------------------------------------
 
 
-def test_filter_passes_is_the_shared_definition():
-    """bench.py's _filter_passes IS tune.measure.filter_passes — one
-    jitter policy, no drift."""
-    import bench
+@pytest.mark.parametrize("samples,kept", [
+    pytest.param([-1.0, 2.0, 3.0], [2.0, 3.0], id="tuner"),
+    # five chain slopes of a backward A/B, one swamped by host jitter
+    pytest.param([0.016, 0.017, -0.038, 0.016, 0.018],
+                 [0.016, 0.017, 0.016, 0.018], id="bwd_slopes"),
+])
+def test_filter_passes_discards_never_clamps(samples, kept):
+    """tune.measure.filter_passes is the one jitter policy: a
+    non-positive pass is dropped, not clamped to a floor, so the
+    median runs over the passes that measured the program."""
     from veles_tpu.tune.measure import filter_passes
-    assert bench._filter_passes is filter_passes
-    assert filter_passes([-1.0, 2.0, 3.0]) == [2.0, 3.0]
-    # all-jitter: raw list unchanged, caller's floor rejects
-    assert filter_passes([-1.0, -2.0]) == [-1.0, -2.0]
+    assert filter_passes(samples) == kept
+    assert numpy.median(filter_passes(samples)) == \
+        pytest.approx(numpy.median(kept))
+    # all-jitter: raw list unchanged, the caller's floor rejects
+    noise = [-abs(s) for s in samples]
+    assert filter_passes(noise) == noise
 
 
 def test_rank_positive_majority_discipline():

@@ -255,7 +255,8 @@ def test_rbm_reduces_reconstruction_error(cpu_device):
 
 def test_alexnet_vgg_fused_step_tiny():
     """Full AlexNet/VGG specs compile + execute one fused train step on
-    scaled-down input (the real shapes run in bench.py on TPU)."""
+    scaled-down input (the real shapes run in the benchmark's
+    ``alexnet_train_*`` cells on the chip)."""
     from veles_tpu.compiler import build_train_step
     from veles_tpu.models.zoo import (
         alexnet_layers, build_plans_and_state, vgg_layers)

@@ -883,8 +883,9 @@ def build_train_epoch(plans, batch, loss="softmax", donate=True,
     dispatch-bound model (small MLPs) per-step cost collapses to pure
     compute.  The per-step path remains the product default because
     the decision unit gates per minibatch; this is the turbo path for
-    epoch-granular control (and what bench.py reports as mnist
-    ``scan_*`` rows).
+    epoch-granular control (no Workflow reaches it:
+    ``examples/digits_turbo.py`` and ``tests/test_epoch.py`` are its
+    callers).
 
     ``targets``: int labels (softmax) or a float target array indexed
     like the dataset (mse).  ``order`` (int32 (N,)) defines epoch

@@ -2,10 +2,10 @@
 (manualrst_veles_algorithms.rst:157 names AlexNet & VGG as the
 reference models).
 
-Each builder returns a ``layers`` list for StandardWorkflow; the specs
-are also what bench.py's images/sec measurement compiles through the
-fused train step.  bf16-friendly: all the FLOPs sit in conv/fc layers
-that the compiler lowers onto the MXU.
+Each builder returns a ``layers`` list for StandardWorkflow; the
+benchmark's cells (``benchmark/configs/``) build the same specs.
+bf16-friendly: all the FLOPs sit in conv/fc layers that the compiler
+lowers onto the MXU.
 """
 
 __all__ = ["alexnet_layers", "vgg_layers", "mnist_mlp_layers",
@@ -15,8 +15,9 @@ __all__ = ["alexnet_layers", "vgg_layers", "mnist_mlp_layers",
 
 def build_plans_and_state(specs, input_shape, seed=0):
     """Compile LayerPlans + an initial fused-step state for a spec list
-    WITHOUT building the unit graph (used by bench.py and the graft
-    entry, where no loader exists).  input_shape excludes batch."""
+    WITHOUT building the unit graph (used by the graft entry, the
+    tuner's walk and the receipt scripts, where no loader exists).
+    input_shape excludes batch."""
     import numpy
 
     from veles_tpu.compiler import LayerPlan

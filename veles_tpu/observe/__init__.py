@@ -51,11 +51,7 @@ The fleet telemetry plane (PR 20) adds the decisions layer:
   (``FleetTelemetry``; ``python -m veles_tpu.observe fleet``);
 - :mod:`veles_tpu.observe.alerts` — declarative multi-window
   burn-rate + EMA-spike alert rules over those series,
-  edge-triggered with flight + exemplar evidence dumps;
-- :mod:`veles_tpu.observe.baseline` — the perf-regression sentinel:
-  bench compact records + steady-state rates vs the committed
-  ``PERF_BASELINE.json`` (``bench.py --gate``; ``python -m
-  veles_tpu.observe regress``).
+  edge-triggered with flight + exemplar evidence dumps.
 
 Everything here is stdlib-only and import-light, so hot modules
 (units, pipeline_input, compiler-adjacent code) can import it without
@@ -66,8 +62,6 @@ from veles_tpu.observe.alerts import (ALERTS_SCHEMA_VERSION,
                                       AlertManager, BurnRateRule,
                                       EmaSpikeRule, alerts,
                                       default_rules)
-from veles_tpu.observe.baseline import (gate, load_baseline,
-                                        steady_state_rates)
 from veles_tpu.observe.cluster import (TraceCollector, estimate_offset,
                                        probe_sample)
 from veles_tpu.observe.flight import (FLIGHT_SCHEMA_VERSION,
@@ -115,5 +109,4 @@ __all__ = [
     "SERIES_SCHEMA_VERSION",
     "AlertManager", "BurnRateRule", "EmaSpikeRule", "alerts",
     "default_rules", "ALERTS_SCHEMA_VERSION",
-    "gate", "load_baseline", "steady_state_rates",
 ]

@@ -21,10 +21,6 @@ Commands:
   into offset-corrected fleet rollups and print the per-metric table;
   ``--rules`` evaluates the stock serve alert rules over the rollup
   (docs/observability.md "Fleet telemetry").
-- ``regress record.json [--baseline PERF_BASELINE.json] [trace...]``
-  — the perf-regression sentinel: compare a compact bench record
-  against the committed baseline; exits 1 naming the regressed
-  metric (and the dominant tail segment when traces are given).
 """
 
 import argparse
@@ -95,17 +91,6 @@ def main(argv=None):
                     help="evaluate the stock serve alert rules over "
                          "the rollup")
     pf.add_argument("--json", action="store_true")
-
-    pg = sub.add_parser(
-        "regress", help="perf-regression gate vs PERF_BASELINE.json")
-    pg.add_argument("record", metavar="RECORD_JSON",
-                    help="compact bench record (bench.py's last "
-                         "line, saved as JSON)")
-    pg.add_argument("traces", nargs="*", metavar="TRACE_OR_FLIGHT",
-                    help="optional traces/flight dumps; a failing "
-                         "gate then names the dominant tail segment")
-    pg.add_argument("--baseline", default=None)
-    pg.add_argument("--json", action="store_true")
 
     args = parser.parse_args(argv)
     if args.command == "merge":
@@ -200,24 +185,6 @@ def main(argv=None):
                 record["alert"], record["state"],
                 record.get("reason", "")))
         return 0
-    if args.command == "regress":
-        import json
-        from veles_tpu.observe import baseline
-        with open(args.record) as fh:
-            record = json.load(fh)
-        analysis = None
-        if args.traces:
-            from veles_tpu.observe import requests as reqtrace
-            analysis = reqtrace.analyze_files(args.traces)
-        ok, report = baseline.gate(record,
-                                   baseline_path=args.baseline,
-                                   analysis=analysis)
-        if args.json:
-            print(json.dumps(report, indent=2, sort_keys=True))
-        else:
-            for line in baseline.render_report(report):
-                print(line)
-        return 0 if ok else 1
     return 1
 
 

@@ -26,10 +26,11 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
 def percentiles(samples, ps=(50, 95, 99)):
     """Nearest-rank percentiles of a sequence as ``{"p50": ...}``.
 
-    Plain-Python so import-light callers (bench.py's slope spreads, the
-    histogram snapshots) share ONE definition; on tiny sample sets the
-    nearest-rank convention degrades gracefully (p95/p99 of 5 samples
-    are both the max) instead of inventing interpolated values.
+    Plain-Python so import-light callers (the histogram snapshots,
+    the serve tier's latency windows) share ONE definition; on tiny
+    sample sets the nearest-rank convention degrades gracefully
+    (p95/p99 of 5 samples are both the max) instead of inventing
+    interpolated values.
     """
     if not samples:
         return {}
@@ -203,7 +204,7 @@ class MetricsRegistry(object):
         return out
 
     def reset(self):
-        """Drop every metric (tests / bench A-B legs start clean)."""
+        """Drop every metric (tests and soak legs start clean)."""
         with self._lock:
             self._metrics.clear()
 

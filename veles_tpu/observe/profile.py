@@ -14,7 +14,7 @@ Two run-scoped services on top of the tracer/registry:
   snapshot, health counters, epoch/metrics from the decision unit, and
   samples/sec throughput derived from the ``train.samples`` counter
   delta.  web_status.py surfaces the same health block in its status
-  posts; bench.py and offline tools consume the file.
+  posts; offline tools (``observe summary``) consume the file.
 """
 
 import json

@@ -14,10 +14,9 @@ per-request substrate (docs/observability.md "Request tracing"):
 - **Segment marks.**  Serve components stamp cheap ``perf_counter``
   marks on their existing request objects as ``(segment, start,
   dur)`` tuples — the canonical taxonomy is :data:`SEGMENTS`.  Marks
-  are on for EVERY request while :data:`enabled` (the
-  ``VELES_REQTRACE=0`` kill switch exists for the bench.py
-  ``trace_overhead`` A/B), because tail exemplars need the timeline
-  of requests that only turn out slow at completion.
+  are on for EVERY request while :data:`enabled` (``VELES_REQTRACE=0``
+  is the kill switch), because tail exemplars need the timeline of
+  requests that only turn out slow at completion.
 - **Sampled span emission.**  Full request-track spans go to the
   :mod:`veles_tpu.observe.trace` tracer only for *sampled* requests.
   Sampling is DETERMINISTIC in the id (crc32 hash, no RNG) so the two
@@ -74,8 +73,7 @@ LEG_SPAN = SEGMENT_PREFIX + "leg"
 _TRUTHY = ("1", "true", "on", "yes")
 
 # Kill switch for the whole per-request path: marks, exemplars, span
-# emission.  bench.py trace_overhead flips this module attribute for
-# its stamps-on vs fully-off A/B.
+# emission.
 enabled = os.environ.get("VELES_REQTRACE", "1").strip().lower() \
     in _TRUTHY
 

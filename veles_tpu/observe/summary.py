@@ -8,15 +8,13 @@ number that says where time is actually spent, not merely enclosed)
 plus the last value of every counter track.
 
 ``python -m veles_tpu.observe summary <trace.json|flight.json>`` is
-the CLI; :func:`digest_line` is the one-liner bench.py appends to its
-output when ``VELES_TRACE`` is set.
+the CLI.
 """
 
 import json
 
 __all__ = ["load", "summarize", "summarize_trace", "summarize_flight",
-           "summarize_heartbeats", "render", "digest_line",
-           "request_digest_line"]
+           "summarize_heartbeats", "render", "request_digest_line"]
 
 
 def load(path):
@@ -269,8 +267,8 @@ def render(summary, out=None):
 def request_digest_line(doc, top=3):
     """One line of per-request-segment attribution when the document
     carries request-scoped spans or exemplars (observe/requests.py);
-    None otherwise — ``observe summary`` and :func:`digest_line`
-    append it so CI logs show WHERE request time went."""
+    None otherwise — ``observe summary`` appends it so CI logs show
+    WHERE request time went."""
     from veles_tpu.observe import requests as reqtrace
     records, counts = reqtrace.extract_requests(doc)
     if not records:
@@ -282,22 +280,3 @@ def request_digest_line(doc, top=3):
                       for name, row in segs)
     return "request segments: %d requests, %d legs; %s" % (
         report["requests"], report["legs"], parts or "no segments")
-
-
-def digest_line(doc, top=3):
-    """One line: the global top-N spans by self time — what bench.py
-    appends to CI logs when VELES_TRACE is set."""
-    summary = summarize(doc, top=top)
-    merged = {}
-    for rows in summary["tracks"].values():
-        for row in rows:
-            entry = merged.setdefault(row["name"], [0.0, 0])
-            entry[0] += row["self_s"]
-            entry[1] += row["count"]
-    ranked = sorted(merged.items(), key=lambda kv: -kv[1][0])[:top]
-    spans = ", ".join("%s %.3fs x%d" % (name, s, c)
-                      for name, (s, c) in ranked) or "no spans"
-    line = "trace digest: %d events; top self-time: %s" % (
-        summary["events"], spans)
-    req = request_digest_line(doc, top=top)
-    return line if req is None else "%s; %s" % (line, req)

@@ -10,7 +10,7 @@ The pieces (docs/kernels.md, "Autotuning"):
   builders;
 - ``tune.measure`` — the ONE timing discipline (pass filtering,
   positive-majority ranking, interleaved round-robin sampling) shared
-  with bench.py and ``autotune_matmul``;
+  with ``autotune_matmul``;
 - ``tune.costmodel`` — the deterministic learned cost model (boosted
   stumps over hand-built features, pure numpy) trained on the
   ``measurements.jsonl`` sidecar, with its leave-one-spec-out trust

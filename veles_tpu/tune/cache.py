@@ -566,8 +566,7 @@ def provenance(op, shape, dtype, precision_level, extra=None):
     """"tuned" when a cache entry would ACTUALLY serve this spec —
     same structural validation as the kernels' consult, so an entry
     the consult rejects (and serves statically) is never attributed as
-    tuned — else "static".  The MFU-attribution annotation (scripts/
-    mfu_breakdown.py); no counters, no recording."""
+    tuned — else "static".  No counters, no recording."""
     try:
         digest, _ = schedule_key(op, shape, dtype, precision_level,
                                  device_kind(), extra)

@@ -1,9 +1,8 @@
 """The ONE timing-measurement discipline for schedule ranking.
 
 Every published kernel-schedule ranking — the GA autotuner's fitness,
-``ops/matmul.py``'s curated candidate sweep, bench.py's A/B medians —
-runs through these helpers, so the jitter policy can never drift
-between the tuner and the benchmarks:
+``ops/matmul.py``'s curated candidate sweep — runs through these
+helpers, so the jitter policy can never drift between the tuners:
 
 - **Pass filtering** (``filter_passes``): a non-positive chain slope
   means host jitter exceeded the whole chain delta for that pass — it
