@@ -29,6 +29,8 @@ from veles_tpu.loader.tokens import TokenRowLoader  # noqa: E402
 from veles_tpu.models import decoder, fused, zoo  # noqa: E402
 from veles_tpu.models.nn_units import GradientDescentBase  # noqa: E402
 from veles_tpu.models.nn_workflow import StandardWorkflow  # noqa: E402
+from veles_tpu.observe.metrics import registry  # noqa: E402
+from veles_tpu.ops.attention import KEPT_NAMES  # noqa: E402
 
 VOCAB, T = 96, 32
 ARGUMENTS = dict(
@@ -383,6 +385,48 @@ def test_recompute_is_decided_from_the_devices_memory(_precision,
                         lambda *a: [Told(1 << 30, used=(1 << 30) - 4096)])
     assert trainer._backward_should_recompute(plans) is True
     assert 0 < fused.REMAT_ABOVE < 1
+    # the CPU's layers run the stock attention, which names nothing: a
+    # recomputed layer keeps nothing
+    gauge = registry.peek("step.kept_residual_bytes")
+    assert gauge.value == 0
+    # through the flash kernels a layer names its attention's output and
+    # two floats a row (ops/attention.KEPT_NAMES): 3 layers of 4 rows x
+    # 4 heads x 32 tokens x (16 wide + 2) float32
+    for plan in plans:
+        if plan.forward_cls is decoder.DecoderLayer:
+            plan.static["pallas_bwd"] = True
+    named = 3 * 4 * 4 * T * (16 + 2) * 4
+    state = sum(a.nbytes for s in extract_state(sw)
+                for a in (s["weights"], s["bias"]) if a is not None)
+
+    def told(room, used=0):
+        """A device with ``room`` bytes under the line once the state's
+        gradients are counted."""
+        limit = int((state + used + room) / fused.REMAT_ABOVE) + 1
+        monkeypatch.setattr(jax, "local_devices",
+                            lambda *a: [Told(limit, used)])
+        return trainer._backward_should_recompute(plans)
+
+    # the activations do not fit, what the layers named does
+    assert told(named + 4096) == KEPT_NAMES
+    assert "recomputed in the backward but for" in seen[-1]
+    assert "%.2f GB" % (named / 1e9) in seen[-1]
+    assert gauge.value == named
+    assert told(named) == KEPT_NAMES and told(named, used=1 << 20) \
+        == KEPT_NAMES
+    # neither fits: the bare checkpoint, as before
+    assert told(named - 4096) is True
+    assert seen[-1].endswith("each layer is recomputed in the backward")
+    assert gauge.value == 0
+    # all of it fits
+    assert told(1 << 30) is False and gauge.value == 0
+    assert "activations are kept" in seen[-1]
+    # the CPU, which does not say
+    gauge.set(named)
+    cpu = jax.devices("cpu")
+    monkeypatch.setattr(jax, "local_devices", lambda *a: cpu)
+    assert trainer._backward_should_recompute(plans) is False
+    assert gauge.value == 0
 
 
 def test_recomputed_backward_gives_the_same_step(_precision):

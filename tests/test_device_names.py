@@ -341,3 +341,41 @@ def test_scopes_leave_loss_and_gradients_bit_identical(monkeypatch):
     for a, b in zip(leaves_a, leaves_b):
         assert numpy.asarray(a).tobytes() == numpy.asarray(b).tobytes()
     assert numpy.isfinite(scoped[1]["loss"]) and scoped[1]["loss"] > 0
+
+
+@pytest.mark.parametrize("keeps, forwards", [("nothing", 2), ("names", 1)])
+def test_a_recomputed_decoder_layer_runs_the_flash_forward_once(
+        topo, one_chip, mosaic, keeps, forwards):
+    """A dense decoder layer at the decoder cell's attention widths,
+    2,048 tokens, bfloat16, under its checkpoint as the fused step wraps
+    it: the bare one replays ``veles_flash_fwd`` in the backward, the
+    one that keeps what the kernel named (``attention.KEPT_NAMES``)
+    does not, and the compiler drops the replay — not only the jaxpr
+    (tests/test_checkpoint_keeps.py)."""
+    import jax
+    import jax.numpy as jnp
+
+    from veles_tpu.models import decoder
+    from veles_tpu.ops import attention
+    width, dims = 2048, dict(heads=32, qk_nope=128, qk_rope=64, v_head=128,
+                             kv_rank=512, ffn=1024)
+    plan = compiler.LayerPlan(decoder.DecoderLayer, static=dict(
+        dims, theta=1e6, eps=1e-6, compute_dtype="bfloat16"))
+    params = [{name: aval((sum(decoder._size(shape)
+                               for _, shape in layout),), "float32")
+               for name, layout in zip(
+                   ("weights", "bias"),
+                   decoder.layer_layout(width, **dims))}]
+    remat = attention.KEPT_NAMES if keeps == "names" else True
+
+    def grads(params, h):
+        # the loss too, as the step returns it: the forward pass stays
+        return jax.value_and_grad(lambda p: compiler._forward_for_loss(
+            [plan], p, h, remat=remat).astype(jnp.float32).sum())(params)
+
+    calls = mosaic_calls(compiled_text(
+        grads, one_chip, params, aval((1, 2048, width), jnp.bfloat16)))
+    kernels = sorted(name.lstrip("%").split(".")[0] for name in calls)
+    assert kernels == sorted(
+        [attention.FWD_KERNEL_NAME] * forwards
+        + [attention.DQ_KERNEL_NAME, attention.DKV_KERNEL_NAME]), calls

@@ -125,6 +125,11 @@ def _forward_for_loss(plans, params, x, key=None, remat=False,
     backward-decongestion set (docs/kernels.md).  Recomputation replays
     identical ops, so gradients stay bit-identical; it trades MXU time
     for activation HBM pressure and is off by default.
+    ``remat=KEPT_NAMES`` (any tuple of ``checkpoint_name`` names)
+    recomputes the same way but keeps what the layer's ops gave those
+    names — the flash forward's output and row statistics — so the
+    ops that made them are not run again; a layer that names nothing
+    lowers as under ``True``.
 
     ``layer_fn(i, plan, p, h, key)``: optional per-layer override hook
     (the model-parallel builders swap a sharded apply in for specific
@@ -142,7 +147,13 @@ def _forward_for_loss(plans, params, x, key=None, remat=False,
     import jax
 
     def layer(fn):
-        return jax.checkpoint(fn) if remat else fn
+        if not remat:
+            return fn
+        if remat is True:
+            return jax.checkpoint(fn)
+        return jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.save_only_these_names(
+                *remat))
 
     h = x
     for i, (plan, p) in enumerate(zip(plans, params)):
@@ -248,7 +259,9 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
     follow the VELES_PALLAS_BWD knob) threads the per-layer gradients
     through an optimization_barrier chain in backward production order
     — a pure scheduling hint, bit-identical results; ``bwd_remat``
-    checkpoints each layer's forward to cut activation pressure.
+    checkpoints each layer's forward to cut activation pressure (True:
+    the layer whole; a tuple of ``checkpoint_name`` names: but for what
+    its ops gave those names, :func:`_forward_for_loss`).
 
     Model-parallel hooks (parallel/tensor.py, parallel/pipeline.py):
     ``forward_fn(params, x, key, remat)`` replaces the stock layer walk

@@ -101,6 +101,12 @@ class FusedTrainer(Unit):
         # host on their way to the mesh (0 where the loader gathers
         # onto the mesh, and on one chip)
         self._m_host_staged_ = _registry.counter("step.host_staged_bytes")
+        # bytes a recomputed backward keeps of its layers' kernels
+        # (_backward_should_recompute; 0 where it keeps every
+        # activation, or none, and under a mesh, which never recomputes)
+        self._m_kept_residual_ = _registry.gauge(
+            "step.kept_residual_bytes")
+        self._m_kept_residual_.set(0)
         self._m_dispatch_ = _registry.histogram("step.dispatch_s")
         self._m_eval_dispatch_ = _registry.histogram(
             "step.eval_dispatch_s")
@@ -216,21 +222,28 @@ class FusedTrainer(Unit):
         _xla.watch(self._eval_metrics, "fused.eval")
 
     def _backward_should_recompute(self, plans):
-        """Whether the step's backward should recompute each layer's
-        forward (``bwd_remat``) instead of holding its activations:
-        decided from what can be observed — the bytes autodiff would
-        save for the backward at this minibatch's shape (an abstract
-        trace, nothing runs), beside what the device already holds and
-        one more copy of the parameters for their gradients, against
-        the device's memory.  A device that does not report its memory
-        (the CPU) keeps the activations."""
+        """What the step's backward holds of the forward, as
+        ``build_train_step``'s ``bwd_remat``: False, every activation;
+        ``KEPT_NAMES``, each layer recomputed but for what its kernels
+        named (ops/attention.py: the flash forward's output and row
+        statistics, so that the kernel runs once a layer); True, each
+        layer recomputed whole.  Decided from what can be observed — the
+        bytes autodiff would save for the backward at this minibatch's
+        shape (abstract traces, nothing runs), beside what the device
+        already holds and one more copy of the parameters for their
+        gradients, against ``REMAT_ABOVE`` of the device's memory: the
+        first of the three that fits, and the last where the layers name
+        nothing.  A device that does not report its memory (the CPU)
+        keeps the activations."""
         import jax
 
         from veles_tpu.compiler import _forward_for_loss
         from veles_tpu.observe import xla_introspect as _xla
+        from veles_tpu.ops.attention import KEPT_NAMES
         memory = _xla.device_memory_gauges()
         limit = memory.get("xla.mem.bytes_limit.d0")
         if not limit:
+            self._m_kept_residual_.set(0)
             return False
         in_use = memory.get("xla.mem.bytes_in_use.d0", 0)
         loader = self.sw.loader
@@ -241,26 +254,40 @@ class FusedTrainer(Unit):
                    for k in ("weights", "bias")}
                   for s in self._abstract_state()]
 
-        def saved(p, x_):
-            return jax.vjp(lambda q: _forward_for_loss(plans, q, x_), p)[1]
-
         def nbytes(tree):
             return sum(leaf.size * leaf.dtype.itemsize
                        for leaf in jax.tree_util.tree_leaves(tree))
 
+        def saved_bytes(remat):
+            """What the backward holds of the forward under ``remat``
+            (the parameters among it)."""
+            def saved(p, x_):
+                return jax.vjp(lambda q: _forward_for_loss(
+                    plans, q, x_, remat=remat), p)[1]
+            return nbytes(jax.eval_shape(saved, params, x))
+
         param_bytes = nbytes(params)
         # the residuals hold the parameters too: they are there already
-        held = max(0, nbytes(jax.eval_shape(saved, params, x))
-                   - param_bytes)
-        need = in_use + param_bytes + held
-        recompute = need > REMAT_ABOVE * limit
+        held = max(0, saved_bytes(False) - param_bytes)
+        room = REMAT_ABOVE * limit - in_use - param_bytes
+        remat, kept, what = False, 0, "activations are kept"
+        if held > room:
+            # what the named values add to a recomputed layer's inputs
+            kept = saved_bytes(KEPT_NAMES) - saved_bytes(True)
+            if 0 < kept <= room:
+                remat = KEPT_NAMES
+                what = ("each layer is recomputed in the backward but "
+                        "for %.2f GB that its kernels named, which are "
+                        "kept" % (kept / 1e9))
+            else:
+                remat, kept = True, 0
+                what = "each layer is recomputed in the backward"
+        self._m_kept_residual_.set(kept)
         self.info("backward: %.2f GB of activations to hold, %.2f GB in "
                   "use, %.2f GB of gradients, device %.2f GB: %s",
                   held / 1e9, in_use / 1e9,
-                  param_bytes / 1e9, limit / 1e9,
-                  "each layer is recomputed in the backward" if recompute
-                  else "activations are kept")
-        return recompute
+                  param_bytes / 1e9, limit / 1e9, what)
+        return remat
 
     def _abstract_state(self):
         return [{"weights": fwd.weights if fwd.weights else None,

@@ -68,6 +68,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -82,6 +83,16 @@ __all__ = ["flash_attention", "attention_reference",
 FWD_KERNEL_NAME = "veles_flash_fwd"
 DQ_KERNEL_NAME = "veles_flash_dq"
 DKV_KERNEL_NAME = "veles_flash_dkv"
+
+#: what the ``fwd`` rule names (``jax.ad_checkpoint.checkpoint_name``)
+#: of the forward kernel's results: the output, and the row max and row
+#: sum as one float32 a row.  They are all the backward reads of the
+#: forward kernel, so a layer's ``jax.checkpoint`` whose policy saves
+#: these names (compiler._forward_for_loss) recomputes the layer
+#: without calling the forward kernel again; outside such a policy a
+#: name is an identity
+KEPT_OUT, KEPT_ROW_MAX, KEPT_ROW_SUM = KEPT_NAMES = (
+    "veles_flash_out", "veles_flash_row_max", "veles_flash_row_sum")
 
 #: bump when the kernel's algorithm changes: tuned schedules in the
 #: cache are only valid for the algorithm they were measured on
@@ -353,7 +364,10 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
     dgrad as a lax conv."""
     b, t, dh = q.shape
     dv_width = v.shape[-1]
-    row_max, row_sum = stats
+    # (B, Tq_padded) each, as the ``fwd`` rule keeps them: back to the
+    # lane-broadcast layout the two kernels read
+    row_max, row_sum = (
+        jnp.broadcast_to(s[:, :, None], s.shape + (128,)) for s in stats)
     bq, bk = _clamped_blocks(blocks, t)
     qp = pad_to(q, (None, bq, 128))
     kp = pad_to(k, (None, bk, 128))
@@ -454,9 +468,18 @@ def _flash_fn(scale, precision_level, blocks, causal=False,
         return out
 
     def fwd(q, k, v):
-        out, stats = _flash_fwd_jit(q, k, v, scale, precision_level,
-                                    blocks, interpret_for(q, k, v),
-                                    **form)
+        out, (row_max, row_sum) = _flash_fwd_jit(
+            q, k, v, scale, precision_level, blocks,
+            interpret_for(q, k, v), **form)
+        # every lane of a row's statistic is the same float: one a row
+        # is kept ((B, Tq), which the chip does not tile back to 128
+        # lanes as it would (B, Tq, 1)).  The primal output and every
+        # residual below descend from the NAMED values only; one that
+        # descended from the kernel's raw result would bring the kernel
+        # back into a recomputed layer
+        out = checkpoint_name(out, KEPT_OUT)
+        stats = (checkpoint_name(row_max[:, :, 0], KEPT_ROW_MAX),
+                 checkpoint_name(row_sum[:, :, 0], KEPT_ROW_SUM))
         return out, (q, k, v, out, stats)
 
     def bwd(res, do):
