@@ -160,6 +160,44 @@ def test_causal_latent_attention_kernels_at_the_decoders_widths(
                               attention.DKV_KERNEL_NAME)), calls
 
 
+@pytest.mark.parametrize("window, names", [
+    (2048, ("WIN_FWD_KERNEL_NAME", "WIN_DQ_KERNEL_NAME",
+            "WIN_DKV_KERNEL_NAME")),
+    (None, ("FWD_KERNEL_NAME", "DQ_KERNEL_NAME", "DKV_KERNEL_NAME"))])
+def test_grouped_window_attention_kernels_at_the_second_decoders_widths(
+        topo, one_chip, mosaic, window, names):
+    """The ``trinity_mini`` configuration's attention: 8,192 tokens, 32
+    query heads on 4 KV heads 128 wide, bfloat16 products, under the
+    2,048-token window (band-only grids, the group an inner axis of the
+    dk/dv grid) and without it: the three kernels pass Mosaic under
+    their own names, and K and V reach them with 4 rows — never repeated
+    to the 32 query heads."""
+    import jax
+    import jax.numpy as jnp
+
+    from veles_tpu.ops import attention
+    q = aval((32, 8192, 128), jnp.bfloat16)
+    k = aval((4, 8192, 128), jnp.bfloat16)
+
+    def loss_grads(q, k, v):
+        return jax.grad(lambda *a: attention.flash_attention(
+            *a, causal=True, window=window,
+            product_dtype=jnp.bfloat16).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = compiled_text(loss_grads, one_chip, q, k, k)
+    kernels = sorted(name.lstrip("%").split(".")[0]
+                     for name in mosaic_calls(text))
+    assert kernels == sorted(getattr(attention, name) for name in names)
+    layouts = [line.split("operand_layout_constraints=")[1].split(
+        "frontend_attributes")[0] for line in text.splitlines()
+        if "operand_layout_constraints=" in line and "veles_flash" in line]
+    assert len(layouts) == 3, text[:2000]
+    for layout in layouts:
+        shapes = re.findall(r"bf16\[([0-9,]*)\]", layout)
+        assert shapes[:3] == ["32,8192,128", "4,8192,128", "4,8192,128"]
+
+
 def toy_step(batch=8):
     import jax
     plans, state, _ = zoo.build_plans_and_state(TOY_CNN, TOY_INPUT,

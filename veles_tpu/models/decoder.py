@@ -1,7 +1,12 @@
-"""Causal decoder units: token embedding, a latent-attention (MLA) layer
-with a gated SiLU feed-forward or a routed expert layer, and the output
-head — the DeepSeek-V3 layer family (docs/model_layer.md "Decoder
-units").
+"""Causal decoder units: token embedding, one layer made of parts — a
+latent-attention (MLA) or a grouped-query attention sub-layer, norms
+before each sub-layer or around it, a gated SiLU feed-forward or a
+routed expert layer — and the output head (docs/model_layer.md "Decoder
+units").  The parts are chosen by the layer's own dims (``kv_rank``
+makes the attention latent, ``kv_heads`` grouped; ``post_norms`` puts a
+norm after each sub-layer too; ``ffn`` makes the feed-forward dense),
+never by a model's name: the DeepSeek-V3 family is one choice of them,
+the window/full grouped-query family with sandwich norms another.
 
 Built on the contracts of ``transformer.py``: the math is in pure
 functions and ``apply(params, x, **static)`` class methods; a layer's
@@ -15,8 +20,8 @@ correction bias, which takes no gradient.  The state is float32 whatever
 setting is the dtype of the operands and activations, and every product
 accumulates in float32.
 
-The layer, ``h`` the residual stream (config.json keys of the family in
-brackets)::
+The layer with latent attention, ``h`` the residual stream (config.json
+keys of the DeepSeek-V3 family in brackets)::
 
     a = rms_norm(h)
     q = a W_q                      -> heads x (nope | rope)
@@ -30,6 +35,21 @@ brackets)::
     routed: p = sigmoid(m W_r); the top_k largest of p + b;
             w_i = p_i / sum_chosen p * routed_scale
             h += sum_i w_i Expert_i(m) + Shared(m)
+
+The grouped-query attention sub-layer (:func:`grouped_attention`), in
+place of the first five lines::
+
+    q = a W_q -> heads x head_width;  k, v = a W_k, a W_v -> kv_heads x
+    head_width;  z = a W_z -> heads x head_width  (the output gate)
+    q, k = rms_norm(q), rms_norm(k)  over each head, one gain each
+    rotary on q and k, the pairing (i, i + head_width / 2), where the
+    layer has ``rope``; no position signal where it has not
+    query head n reads KV head n // (heads / kv_heads); key j counts for
+    query i where j <= i and, with ``window``, i - j < window
+    h += [rms_norm](softmax(q.k / sqrt(head_width)) v * sigmoid(z)) W_o
+
+and with ``post_norms`` each sub-layer's output is normalised before it
+is added (``h += rms_norm(f)``: the sandwich placement).
 
 **The share.**  A routed layer is told which experts it holds
 (``first_expert``, ``experts_held``): it routes over ALL ``experts``,
@@ -53,6 +73,9 @@ prefix, so nearly the same vector at every position — outweighs the
 embedding four to one, every token looks alike to the router and all
 choose the same experts; the scaled form (std / sqrt(2 x layers), as
 GPT-2 and Megatron-LM initialise these projections) keeps tokens apart.
+Under ``post_norms`` a norm follows each of those matrices, so their
+std does not reach the stream; what does is the post-norms' gains, which
+start from ``post_gain`` (default 1, as every other gain).
 """
 
 import functools
@@ -63,7 +86,7 @@ from veles_tpu.models.transformer import _GDAutodiff, _SequenceUnit
 
 __all__ = ["DecoderEmbedding", "DecoderLayer", "DecoderHead",
            "GDDecoderEmbedding", "GDDecoderLayer", "GDDecoderHead",
-           "rms_norm", "rotary", "latent_attention",
+           "rms_norm", "rotary", "latent_attention", "grouped_attention",
            "gated_ffn", "routed_experts", "layer_layout", "unpack",
            "decoder_layer"]
 
@@ -89,23 +112,31 @@ def rms_norm(x, gain, eps=1e-6):
     return (xf * scale * gain.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary(x, theta):
-    """Rotary positions on ADJACENT pairs of the last axis: pair ``i``
-    of position ``t`` turns by ``t * theta ** (-2 i / width)``.  ``x``
-    is (B, T, ..., width); positions count from 0 along axis 1."""
+def rotary(x, theta, halves=False):
+    """Rotary positions on pairs of the last axis: pair ``i`` of
+    position ``t`` turns by ``t * theta ** (-2 i / width)``.  The pairs
+    are ADJACENT elements (2i, 2i + 1), or with ``halves`` element ``i``
+    and element ``i + width / 2`` (the rotate-half pairing).  ``x`` is
+    (B, T, ..., width); positions count from 0 along axis 1."""
     import jax.numpy as jnp
     width = x.shape[-1]
     t = x.shape[1]
     inv = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)       # (T, width)
-    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)
     shape = (1, t) + (1,) * (x.ndim - 3) + (width,)
     xf = x.astype(jnp.float32)
-    # the pair's other element: (a, b) -> (-b, a)
-    even = (jnp.arange(width) % 2 == 0)
-    other = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
-                      jnp.roll(xf, 1, axis=-1))
+    if halves:
+        cos = jnp.tile(jnp.cos(angle), 2)              # (T, width)
+        sin = jnp.tile(jnp.sin(angle), 2)
+        a, b = xf[..., :width // 2], xf[..., width // 2:]
+        other = jnp.concatenate([-b, a], axis=-1)
+    else:
+        cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)   # (T, width)
+        sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)
+        # the pair's other element: (a, b) -> (-b, a)
+        even = (jnp.arange(width) % 2 == 0)
+        other = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                          jnp.roll(xf, 1, axis=-1))
     return (xf * cos.reshape(shape) + other * sin.reshape(shape)).astype(
         x.dtype)
 
@@ -116,9 +147,11 @@ def _dense(x, w):
                       preferred_element_type=jnp.float32)
 
 
-def _attend(q, k, v, scale, pallas_bwd):
-    """(B*H, T, .) causal attention through the flash kernels or the
-    stock reference, per the VELES_PALLAS_BWD contract."""
+def _attend(q, k, v, scale, pallas_bwd, window=None):
+    """(B*H, T, .) causal attention — ``k``/``v`` (B*H_kv, T, .) where
+    the heads are grouped, ``window`` keys back where given — through
+    the flash kernels or the stock reference, per the VELES_PALLAS_BWD
+    contract."""
     import jax.numpy as jnp
 
     from veles_tpu.ops.attention import (attention_reference,
@@ -127,10 +160,17 @@ def _attend(q, k, v, scale, pallas_bwd):
         from veles_tpu.ops.common import pallas_bwd_enabled
         pallas_bwd = pallas_bwd_enabled()
     if not pallas_bwd:
-        return attention_reference(q, k, v, scale=scale, causal=True)
+        return attention_reference(q, k, v, scale=scale, causal=True,
+                                   window=window)
     narrow = q.dtype if q.dtype == jnp.bfloat16 else None
     return flash_attention(q, k, v, scale=scale, causal=True,
-                           product_dtype=narrow)
+                           product_dtype=narrow, window=window)
+
+
+def _fold_heads(x):
+    """(B, T, H, w) -> (B*H, T, w)."""
+    b, t, heads, width = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * heads, t, width)
 
 
 def latent_attention(a, w, *, heads, qk_nope, qk_rope, v_head, kv_rank,
@@ -154,14 +194,41 @@ def latent_attention(a, w, *, heads, qk_nope, qk_rope, v_head, kv_rank,
          jnp.broadcast_to(k_rope[:, :, None, :], (b, t, heads, qk_rope))],
         axis=-1)
     v = kv[..., qk_nope:]
-
-    def fold(x):  # (B, T, H, w) -> (B*H, T, w)
-        return x.transpose(0, 2, 1, 3).reshape(b * heads, t, x.shape[-1])
-
-    o = _attend(fold(q), fold(k), fold(v),
+    o = _attend(_fold_heads(q), _fold_heads(k), _fold_heads(v),
                 1.0 / float(numpy.sqrt(qk_nope + qk_rope)), pallas_bwd)
     o = o.reshape(b, heads, t, v_head).transpose(0, 2, 1, 3).reshape(
         b, t, heads * v_head)
+    return _dense(o, w["w_o"]).astype(dtype)
+
+
+def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
+                      theta, eps, q_gain, k_gain, pallas_bwd=None):
+    """The grouped-query sub-layer over normalised ``a`` (B, T, D),
+    before the residual add: ``heads`` query heads read ``kv_heads``
+    key/value heads (never repeated: the kernels index them), each
+    head's q and k RMS-normalised (one ``head_width`` gain each, shared
+    by the heads), rotary in the rotate-half pairing where ``rope``,
+    keys ``window`` back where given, and a sigmoid gate on the output
+    before ``w_o``.  ``w`` holds ``w_q``, ``w_k``, ``w_v``, ``w_z``,
+    ``w_o``."""
+    import jax
+    import jax.numpy as jnp
+    b, t, _ = a.shape
+    dtype = a.dtype
+    q = _dense(a, w["w_q"]).astype(dtype).reshape(b, t, heads, head_width)
+    k = _dense(a, w["w_k"]).astype(dtype).reshape(
+        b, t, kv_heads, head_width)
+    v = _dense(a, w["w_v"]).astype(dtype).reshape(
+        b, t, kv_heads, head_width)
+    z = _dense(a, w["w_z"])
+    q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
+    if rope:
+        q, k = rotary(q, theta, halves=True), rotary(k, theta, halves=True)
+    o = _attend(_fold_heads(q), _fold_heads(k), _fold_heads(v),
+                1.0 / float(numpy.sqrt(head_width)), pallas_bwd, window)
+    o = o.reshape(b, heads, t, head_width).transpose(0, 2, 1, 3).reshape(
+        b, t, heads * head_width)
+    o = (o.astype(jnp.float32) * jax.nn.sigmoid(z)).astype(dtype)
     return _dense(o, w["w_o"]).astype(dtype)
 
 
@@ -286,18 +353,35 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
 # -- the packed layer --------------------------------------------------------
 
 
-def layer_layout(d, *, heads, qk_nope, qk_rope, v_head, kv_rank, ffn=None,
-                 experts=None, experts_held=None, expert_width=None,
-                 shared_width=None, **_):
+def layer_layout(d, *, heads, qk_nope=None, qk_rope=None, v_head=None,
+                 kv_rank=None, kv_heads=None, head_width=None,
+                 post_norms=False, ffn=None, experts=None,
+                 experts_held=None, expert_width=None, shared_width=None,
+                 **_):
     """((name, shape) of the packed ``weights``, of the packed
     ``bias``): the ONE definition the unit's initialiser and the apply
-    read.  ``ffn`` makes the layer dense; else it is routed."""
-    weights = [("w_q", (d, heads * (qk_nope + qk_rope))),
-               ("w_kva", (d, kv_rank + qk_rope)),
-               ("w_kvb", (kv_rank, heads * (qk_nope + v_head))),
-               ("w_o", (heads * v_head, d))]
-    bias = [("attn_gain", (d,)), ("kv_gain", (kv_rank,)),
-            ("ffn_gain", (d,))]
+    read.  ``kv_rank`` makes the attention latent, ``kv_heads``
+    grouped; ``post_norms`` adds the gains of a norm after each
+    sub-layer; ``ffn`` makes the layer dense, else it is routed."""
+    if kv_rank:
+        weights = [("w_q", (d, heads * (qk_nope + qk_rope))),
+                   ("w_kva", (d, kv_rank + qk_rope)),
+                   ("w_kvb", (kv_rank, heads * (qk_nope + v_head))),
+                   ("w_o", (heads * v_head, d))]
+        bias = [("attn_gain", (d,)), ("kv_gain", (kv_rank,))]
+    else:
+        weights = [("w_q", (d, heads * head_width)),
+                   ("w_k", (d, kv_heads * head_width)),
+                   ("w_v", (d, kv_heads * head_width)),
+                   ("w_z", (d, heads * head_width)),
+                   ("w_o", (heads * head_width, d))]
+        bias = [("attn_gain", (d,)), ("q_gain", (head_width,)),
+                ("k_gain", (head_width,))]
+    if post_norms:
+        bias += [("post_attn_gain", (d,))]
+    bias += [("ffn_gain", (d,))]
+    if post_norms:
+        bias += [("post_ffn_gain", (d,))]
     if ffn:
         weights += [("w_gate", (d, ffn)), ("w_up", (d, ffn)),
                     ("w_down", (ffn, d))]
@@ -365,35 +449,52 @@ def unpack(vec, layout, dtype):
     return {entry[0]: piece for entry, piece in zip(layout, pieces)}
 
 
-def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope,
-                  qk_rope, v_head, kv_rank, ffn=None, experts=None,
+def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope=None,
+                  qk_rope=None, v_head=None, kv_rank=None, kv_heads=None,
+                  head_width=None, window=None, rope=True,
+                  post_norms=False, ffn=None, experts=None,
                   experts_held=None, first_expert=0, top_k=None,
                   expert_width=None, shared_width=None, routed_scale=1.0,
-                  capacity=None, theta=1e6, eps=1e-6, pallas_bwd=None):
+                  route_eps=0.0, capacity=None, theta=1e6, eps=1e-6,
+                  pallas_bwd=None):
     """One layer over packed params: (h, aux).  ``aux`` is empty for a
     dense layer."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     dims = dict(heads=heads, qk_nope=qk_nope, qk_rope=qk_rope,
-                v_head=v_head, kv_rank=kv_rank, ffn=ffn, experts=experts,
-                experts_held=experts_held, expert_width=expert_width,
-                shared_width=shared_width)
+                v_head=v_head, kv_rank=kv_rank, kv_heads=kv_heads,
+                head_width=head_width, post_norms=post_norms, ffn=ffn,
+                experts=experts, experts_held=experts_held,
+                expert_width=expert_width, shared_width=shared_width)
     w_layout, b_layout = layer_layout(h.shape[-1], **dims)
     w = unpack(weights, w_layout, compute_dtype)
     g = unpack(bias, b_layout, jnp.float32)
     h = h.astype(compute_dtype)
+
+    def added(h, f, gain):
+        """The residual stream after a sub-layer's output ``f``."""
+        return h + (rms_norm(f, g[gain], eps) if post_norms else f)
+
     with jax.named_scope(SCOPE_ATTENTION):
-        h = h + latent_attention(
-            rms_norm(h, g["attn_gain"], eps), w, heads=heads,
-            qk_nope=qk_nope, qk_rope=qk_rope, v_head=v_head,
-            kv_rank=kv_rank, kv_gain=g["kv_gain"], theta=theta, eps=eps,
-            pallas_bwd=pallas_bwd)
+        a = rms_norm(h, g["attn_gain"], eps)
+        if kv_rank:
+            attended = latent_attention(
+                a, w, heads=heads, qk_nope=qk_nope, qk_rope=qk_rope,
+                v_head=v_head, kv_rank=kv_rank, kv_gain=g["kv_gain"],
+                theta=theta, eps=eps, pallas_bwd=pallas_bwd)
+        else:
+            attended = grouped_attention(
+                a, w, heads=heads, kv_heads=kv_heads,
+                head_width=head_width, window=window, rope=rope,
+                theta=theta, eps=eps, q_gain=g["q_gain"],
+                k_gain=g["k_gain"], pallas_bwd=pallas_bwd)
+        h = added(h, attended, "post_attn_gain")
     m = rms_norm(h, g["ffn_gain"], eps)
     if ffn:
         with jax.named_scope(SCOPE_FFN):
-            return h + gated_ffn(m, w["w_gate"], w["w_up"],
-                                 w["w_down"]), {}
+            return added(h, gated_ffn(m, w["w_gate"], w["w_up"],
+                                      w["w_down"]), "post_ffn_gain"), {}
     b, t, d = m.shape
     tokens = m.reshape(b * t, d)
     with jax.named_scope(SCOPE_ROUTER):
@@ -405,14 +506,20 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope,
         _, idx = top_k_route(
             p + lax.stop_gradient(g["router_bias"]), top_k)
         chosen = jnp.take_along_axis(p, idx, axis=-1)
-        gate = chosen / jnp.sum(chosen, axis=-1, keepdims=True) \
-            * routed_scale
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        if route_eps:
+            total = total + route_eps
+        gate = chosen / total * routed_scale
     with jax.named_scope(SCOPE_ROUTED):
         routed, aux = routed_experts(
             tokens, idx, gate, w["e_gate"], w["e_up"], w["e_down"],
             first_expert=first_expert, capacity=capacity)
     with jax.named_scope(SCOPE_SHARED):
         shared = gated_ffn(m, w["s_gate"], w["s_up"], w["s_down"])
+    if post_norms:
+        return added(h, routed.reshape(b, t, d) + shared,
+                     "post_ffn_gain"), aux
+    # pre-norm only: the sum in the order it always had (the same bits)
     return h + routed.reshape(b, t, d) + shared, aux
 
 
@@ -443,9 +550,17 @@ class _DecoderUnit(_SequenceUnit):
         return arr
 
 
+def _embedding_static(scale):
+    static = {"compute_dtype": _compute_dtype()}
+    if scale != 1.0:
+        static["scale"] = scale
+    return static
+
+
 class DecoderEmbedding(_DecoderUnit):
     """Token ids (B, T) -> (B, T, width): ``weights`` is the (vocab,
-    width) table, no bias."""
+    width) table, no bias; ``scale`` multiplies the rows (an input
+    multiplier such as sqrt(width))."""
 
     MAPPING = "decoder_embedding"
 
@@ -454,9 +569,10 @@ class DecoderEmbedding(_DecoderUnit):
         super(DecoderEmbedding, self).__init__(workflow, **kwargs)
         self.vocab = int(kwargs["vocab"])
         self.width = int(kwargs["width"])
+        self.scale = float(kwargs.get("scale", 1.0))
 
     def static_config(self):
-        return {"compute_dtype": _compute_dtype()}
+        return _embedding_static(self.scale)
 
     def create_params(self):
         shape = tuple(self.input.shape)
@@ -468,19 +584,26 @@ class DecoderEmbedding(_DecoderUnit):
             self.weights.mem = self._gaussian((self.vocab, self.width))
 
     @classmethod
-    def apply(cls, params, x, *, compute_dtype="float32"):
+    def apply(cls, params, x, *, compute_dtype="float32", scale=1.0):
         import jax.numpy as jnp
-        return jnp.take(params["weights"], x, axis=0).astype(compute_dtype)
+        rows = jnp.take(params["weights"], x, axis=0)
+        if scale != 1.0:
+            rows = rows * scale
+        return rows.astype(compute_dtype)
 
 
 class DecoderLayer(_DecoderUnit):
-    """One MLA layer, dense or routed, packed (:func:`layer_layout`)."""
+    """One layer — latent or grouped attention, norms before or around
+    each sub-layer, dense or routed — packed (:func:`layer_layout`)."""
 
     MAPPING = "decoder_layer"
-    DIMS = ("heads", "qk_nope", "qk_rope", "v_head", "kv_rank", "ffn",
+    DIMS = ("heads", "qk_nope", "qk_rope", "v_head", "kv_rank", "kv_heads",
+            "head_width", "window", "rope", "post_norms", "ffn",
             "experts", "experts_held", "first_expert", "top_k",
-            "expert_width", "shared_width", "routed_scale", "capacity",
-            "theta")
+            "expert_width", "shared_width", "routed_scale", "route_eps",
+            "capacity", "theta")
+    #: the gains of the norms AFTER a sub-layer (``post_gain``)
+    POST_GAINS = ("post_attn_gain", "post_ffn_gain")
 
     #: the pieces that write into the residual stream (``out_stddev``)
     RESIDUAL_WRITERS = ("w_o", "w_down", "e_down", "s_down")
@@ -497,6 +620,7 @@ class DecoderLayer(_DecoderUnit):
                      if kwargs.get(name) is not None}
         self.router_bias_stddev = kwargs.get("router_bias_stddev", 0.0)
         self.out_stddev = kwargs.get("out_stddev")
+        self.post_gain = kwargs.get("post_gain", 1.0)
 
     def static_config(self):
         return dict(self.dims, eps=self.eps,
@@ -521,7 +645,9 @@ class DecoderLayer(_DecoderUnit):
                     self.prng.fill_normal(value, 0.0,
                                           self.router_bias_stddev)
             else:
-                value = numpy.ones(piece, numpy.float32)
+                value = numpy.full(
+                    piece, self.post_gain if name in self.POST_GAINS
+                    else 1.0, numpy.float32)
             pieces.append(value)
         self.bias.mem = numpy.concatenate(pieces)
 
@@ -581,8 +707,12 @@ class GDDecoderEmbedding(_GDDecoder):
     MAPPING = "decoder_embedding"
     FORWARD_CLS = DecoderEmbedding
 
+    def __init__(self, workflow, **kwargs):
+        super(GDDecoderEmbedding, self).__init__(workflow, **kwargs)
+        self.scale = float(kwargs.get("scale", 1.0))
+
     def backward_static(self):
-        return {"compute_dtype": _compute_dtype()}
+        return _embedding_static(self.scale)
 
 
 class GDDecoderLayer(_GDDecoder):

@@ -193,6 +193,54 @@ def mla_moe_decoder_layers(vocab, width, layers, heads, qk_nope, qk_rope,
     return spec
 
 
+def gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
+                           head_width, window, ffn, experts, experts_held,
+                           top_k, expert_width, shared_width,
+                           dense_layers=1, first_expert=0,
+                           routed_scale=1.0, route_eps=0.0, theta=1e4,
+                           eps=1e-5, embed_scale=1.0, lr=1e-4, beta1=0.9,
+                           beta2=0.95, adam_eps=1e-8, decay=0.1,
+                           init_std=0.02, router_bias_std=0.0,
+                           post_norm_gain=1.0):
+    """A causal decoder of grouped-query attention layers with a norm
+    before and after each sub-layer (models/decoder.py): token embedding
+    times ``embed_scale``, one layer for each entry of ``layer_types`` —
+    ``"window"`` (keys at most ``window`` back, rotary positions) or
+    ``"full"`` (every earlier key, no position signal) — ``heads`` query
+    heads reading ``kv_heads`` key/value heads ``head_width`` wide, the
+    first ``dense_layers`` with a gated feed-forward ``ffn`` wide, the
+    rest routed as :func:`mla_moe_decoder_layers` routes, and the output
+    head over the ``vocab`` rows held.  ``post_norm_gain`` is the value
+    the gains of the norms after a sub-layer start from.  Solver,
+    initialisation and loader as there."""
+    solver = {"solver": "adamw", "learning_rate": lr,
+              "gradient_moment": beta1, "adadelta_rho": beta2,
+              "solver_epsilon": adam_eps, "weights_decay": decay,
+              "weights_decay_bias": 0.0, "weights_stddev": init_std,
+              "eps": eps}
+    routed = {"experts": experts, "experts_held": experts_held,
+              "first_expert": first_expert, "top_k": top_k,
+              "expert_width": expert_width, "shared_width": shared_width,
+              "routed_scale": routed_scale, "route_eps": route_eps,
+              "router_bias_stddev": router_bias_std}
+    spec = [dict(solver, type="decoder_embedding", vocab=vocab,
+                 width=width, scale=embed_scale)]
+    for index, kind in enumerate(layer_types):
+        if kind not in ("window", "full"):
+            raise ValueError("layer_types holds \"window\" or \"full\", "
+                             "got %r" % (kind,))
+        windowed = kind == "window"
+        body = {"ffn": ffn} if index < dense_layers else routed
+        spec.append(dict(
+            solver, type="decoder_layer", heads=heads,
+            kv_heads=kv_heads, head_width=head_width,
+            window=window if windowed else None, rope=windowed,
+            theta=theta, post_norms=True, post_gain=post_norm_gain,
+            **body))
+    spec.append(dict(solver, type="decoder_head", vocab=vocab))
+    return spec
+
+
 def mnist_mlp_layers(hidden=100, classes=10, lr=0.1, moment=0.9):
     """BASELINE config 1: the 784-hidden-10 fully-connected net."""
     return [

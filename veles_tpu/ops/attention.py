@@ -43,6 +43,22 @@ conv-VJP, built to the same contracts:
   against 128-wide ``v``): ``q``/``k``/``dq``/``dk`` tiles carry the
   key width, ``v``/``out``/``do``/``dv`` tiles and the output
   accumulator the value width, each padded to whole lanes by itself.
+- **Grouped key/value heads** (``k``/``v`` with fewer rows than ``q``:
+  (B x H_kv, T, .) against (B x H, T, .)): query head ``n`` reads the
+  tiles of KV head ``n // group`` through the block index maps — K and
+  V are never repeated to the query heads in HBM, forward or backward —
+  and the dk/dv kernel's grid runs over the KV heads with the group as
+  an inner axis, so a group's query heads sum into one float32
+  accumulator and each dk/dv tile is written once.
+- **A window** (``window=W``, causal only): key ``j`` counts for query
+  ``i`` only where ``0 <= i - j < W``.  The windowed form's grid spans
+  the band alone — ``W / bk + 1`` key steps a query tile (and as many
+  query steps a key tile in the dk/dv kernel), the block index offset
+  by the first tile the band crosses — so its cost grows with T x W,
+  not T x T; the few steps a tile at the sequence's start does not
+  need run nothing and fetch nothing, as in the causal form.  These
+  kernels carry their own names (``veles_flash_win_*``).  A window of T
+  or more is the causal form, and runs as it.
 - ``product_dtype`` (None keeps the v2 behaviour): the dtype EVERY
   product's operands are rounded to, the probability and cotangent
   tiles included.  With bfloat16 ``q``/``k``/``v`` the v2 kernels hand
@@ -83,6 +99,10 @@ __all__ = ["flash_attention", "attention_reference",
 FWD_KERNEL_NAME = "veles_flash_fwd"
 DQ_KERNEL_NAME = "veles_flash_dq"
 DKV_KERNEL_NAME = "veles_flash_dkv"
+#: the same three under a window, whose grid spans the band only
+WIN_FWD_KERNEL_NAME = "veles_flash_win_fwd"
+WIN_DQ_KERNEL_NAME = "veles_flash_win_dq"
+WIN_DKV_KERNEL_NAME = "veles_flash_win_dkv"
 
 #: what the ``fwd`` rule names (``jax.ad_checkpoint.checkpoint_name``)
 #: of the forward kernel's results: the output, and the row max and row
@@ -99,8 +119,9 @@ KEPT_OUT, KEPT_ROW_MAX, KEPT_ROW_SUM = KEPT_NAMES = (
 #: (v2: the backward reads (m, l) row statistics, not a logsumexp;
 #: v3: causal tile skipping, key width apart from value width,
 #: ``product_dtype`` — the non-causal equal-width float32 program is
-#: v2's, op for op)
-ATTENTION_KERNEL_VERSION = 3
+#: v2's, op for op; v4: grouped key/value heads and a window with a
+#: band-only grid — a call with neither lowers to v3's program)
+ATTENTION_KERNEL_VERSION = 4
 
 _DEFAULT_BLOCKS = (256, 256)  # (bq, bk)
 #: causal sequences of a thousand tokens and more: a (256, 256) tile is
@@ -123,22 +144,37 @@ def _row_ids(bq, bk):
     return jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
 
 
-def _masked_scores(s, i, kk, *, bq, bk, t_real, causal):
-    """Padded key columns — and, causal, the keys after each query —
-    to the finite floor, never -inf."""
+def _masked_scores(s, i, kk, *, bq, bk, t_real, causal, window=None):
+    """Padded key columns — and, causal, the keys after each query and,
+    under a window, those ``window`` and more before it — to the finite
+    floor, never -inf."""
     col = kk * bk + _col_ids(*s.shape)
     keep = col < t_real
     if causal:
-        keep = keep & (col <= i * bq + _row_ids(*s.shape))
+        row = i * bq + _row_ids(*s.shape)
+        keep = keep & (col <= row)
+        if window is not None:
+            keep = keep & (row - col < window)
     return jnp.where(keep, s, _MASK_FLOOR)
 
 
-def _when_needed(causal, i, kk, bq, bk):
+def _when_needed(causal, i, kk, bq, bk, window=None, t_real=None):
     """Decorator running a kernel body only where (q tile ``i``, k tile
-    ``kk``) holds a pair with key <= query; always, when not causal."""
+    ``kk``) holds a pair with key <= query (and, under a window, one
+    with query - key < window, among the sequence's own tiles: a band
+    step may name a tile past its end); always, when not causal."""
     if not causal:
         return lambda body: body()
-    return pl.when(kk * bk < (i + 1) * bq)
+    needed = kk * bk < (i + 1) * bq
+    if window is not None:
+        needed = (needed & (i * bq < (kk + 1) * bk + window - 1)
+                  & (jnp.maximum(i * bq, kk * bk) < t_real))
+    return pl.when(needed)
+
+
+def _first_k(i, bq, bk, window):
+    """The first key tile q tile ``i``'s band crosses."""
+    return jnp.maximum(i * bq - (window - 1), 0) // bk
 
 
 def _narrow(x, product_dtype):
@@ -150,28 +186,33 @@ def _narrow(x, product_dtype):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                 acc_ref, m_ref, l_ref, *, n_k, scale, t_real, bq, bk,
-                precision_level, causal, product_dtype):
-    """One (b, i, kk) grid step of the online-softmax forward.
+                precision_level, causal, product_dtype, window=None):
+    """One (b, i, step) grid step of the online-softmax forward: step
+    ``kk`` of ``n_k`` is key tile ``kk``, or under a window the
+    ``kk``-th tile of q tile ``i``'s band.
 
     ``acc_ref`` (bq, value width) f32 carries the running unnormalized
     output; ``m_ref``/``l_ref`` (bq, 128) carry the running row max and
     row sum, lane-broadcast so the scratch tiles stay MXU-shaped.
     """
     i = pl.program_id(1)
-    kk = pl.program_id(2)
+    step = kk = pl.program_id(2)
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _MASK_FLOOR)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    @_when_needed(causal, i, kk, bq, bk)
+    if window is not None:
+        kk = step + _first_k(i, bq, bk, window)
+
+    @_when_needed(causal, i, kk, bq, bk, window, t_real)
     def _tile():
         q = q_ref[0]
         s = mxu_partial_dot(q, k_ref[0].T, precision_level) * scale
         s = _masked_scores(s, i, kk, bq=bq, bk=bk, t_real=t_real,
-                           causal=causal)
+                           causal=causal, window=window)
 
         m_prev = m_ref[:, :1]                      # (bq, 1)
         s_max = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
@@ -184,7 +225,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _store():
         l_fin = l_ref[:, :1]
         # fully-masked (padded) q rows have l == 0; divide by 1 so the
@@ -211,15 +252,56 @@ def _first_q(causal, bq, bk):
     return lambda kk, i: jnp.maximum(i, (kk * bk) // bq)
 
 
+def _band_k(bq, bk, window, t):
+    """Block index of the k side for step ``kk`` of q tile ``i``'s
+    band; a step past the band's end (a tile at the sequence's start
+    has a shorter one) names the band's last tile again."""
+    def at(i, kk):
+        return jnp.minimum(_first_k(i, bq, bk, window) + kk, jnp.minimum(
+            ((i + 1) * bq - 1) // bk, (t - 1) // bk))
+    return at
+
+
+def _band_q(bq, bk, window, t):
+    """The same for the q side of the dk/dv kernel: step ``i`` of the
+    q tiles that k tile ``kk``'s keys are in the window of."""
+    def at(kk, i):
+        return jnp.minimum((kk * bk) // bq + i, jnp.minimum(
+            ((kk + 1) * bk + window - 2) // bq, (t - 1) // bq))
+    return at
+
+
+def _band_steps(t, bq, bk, window):
+    """(key steps a q tile, q steps a k tile) of the windowed grids:
+    the most tiles of the other side any tile's band crosses —
+    ``window / bk + 1`` where the tiles are square and divide it."""
+    n_q, n_k = -(-t // bq), -(-t // bk)
+    k_steps = max(min(((i + 1) * bq - 1) // bk, n_k - 1)
+                  - max(i * bq - window + 1, 0) // bk + 1
+                  for i in range(n_q))
+    q_steps = max(min(((kk + 1) * bk + window - 2) // bq, n_q - 1)
+                  - (kk * bk) // bq + 1 for kk in range(n_k))
+    return k_steps, q_steps
+
+
+def _kv_head(group):
+    """Row of ``k``/``v`` that row ``bb`` of ``q`` reads: with
+    ``group`` query heads a KV head, head ``n`` reads ``n // group``."""
+    if group == 1:
+        return lambda bb: bb
+    return lambda bb: bb // group
+
+
 @functools.partial(
     jax.jit, static_argnames=("scale", "precision_level", "blocks",
-                              "interpret", "causal", "product_dtype"))
+                              "interpret", "causal", "product_dtype",
+                              "window"))
 def _flash_fwd_jit(q, k, v, scale, precision_level, blocks, interpret,
-                   causal=False, product_dtype=None):
-    """(out, (m, l)): the tiled forward.  q/k are (B, T, key width), v
-    (B, T, value width); the row statistics come back (B, Tq_padded,
-    128) f32 each, lane-broadcast (the backward kernels read the same
-    layout)."""
+                   causal=False, product_dtype=None, window=None):
+    """(out, (m, l)): the tiled forward.  q is (B, T, key width), k
+    (B / group, T, key width), v (B / group, T, value width); the row
+    statistics come back (B, Tq_padded, 128) f32 each, lane-broadcast
+    (the backward kernels read the same layout)."""
     b, t, _ = q.shape
     dv = v.shape[-1]
     bq, bk = _clamped_blocks(blocks, t)
@@ -230,22 +312,27 @@ def _flash_fwd_jit(q, k, v, scale, precision_level, blocks, interpret,
     dvp = vp.shape[-1]
     tk = kp.shape[1]
     n_k = tk // bk
-    grid = (b, tq // bq, n_k)
     k_at = _last_k(causal, bq, bk)
+    if window is not None:
+        n_k = _band_steps(t, bq, bk, window)[0]
+        k_at = _band_k(bq, bk, window, t)
+    grid = (b, tq // bq, n_k)
+    kv = _kv_head(b // k.shape[0])
 
     out, row_max, row_sum = pl.pallas_call(
         functools.partial(_fwd_kernel, n_k=n_k, scale=scale,
                           t_real=t, bq=bq, bk=bk,
                           precision_level=precision_level,
-                          causal=causal, product_dtype=product_dtype),
-        name=FWD_KERNEL_NAME,
+                          causal=causal, product_dtype=product_dtype,
+                          window=window),
+        name=FWD_KERNEL_NAME if window is None else WIN_FWD_KERNEL_NAME,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bk, dhp),
-                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
+                         lambda bb, i, kk: (kv(bb), k_at(i, kk), 0)),
             pl.BlockSpec((1, bk, dvp),
-                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
+                         lambda bb, i, kk: (kv(bb), k_at(i, kk), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dvp), lambda bb, i, kk: (bb, i, 0)),
@@ -288,23 +375,27 @@ def _cotangent(do_ref, product_dtype):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
                    dq_ref, acc_ref, *, n_k, scale, t_real, bq, bk,
-                   precision_level, causal, product_dtype):
-    """dq for one q-tile, accumulated over k-tiles: the probability
-    tile is recomputed from the saved row statistics
+                   precision_level, causal, product_dtype, window=None):
+    """dq for one q-tile, accumulated over k-tiles (under a window,
+    over the tiles of its band, as the forward walks them): the
+    probability tile is recomputed from the saved row statistics
     (recompute-over-store), then ds = p * (dp - delta) and
     dq += ds @ k * scale."""
     i = pl.program_id(1)
-    kk = pl.program_id(2)
+    step = kk = pl.program_id(2)
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @_when_needed(causal, i, kk, bq, bk)
+    if window is not None:
+        kk = step + _first_k(i, bq, bk, window)
+
+    @_when_needed(causal, i, kk, bq, bk, window, t_real)
     def _tile():
         s = mxu_partial_dot(q_ref[0], k_ref[0].T, precision_level) * scale
         s = _masked_scores(s, i, kk, bq=bq, bk=bk, t_real=t_real,
-                           causal=causal)
+                           causal=causal, window=window)
         p = _probabilities(s, m_ref, l_ref)
         dp = mxu_partial_dot(_cotangent(do_ref, product_dtype),
                              v_ref[0].T, precision_level)
@@ -312,7 +403,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
         acc_ref[:] += mxu_partial_dot(_narrow(ds, product_dtype),
                                       k_ref[0], precision_level)
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _store():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -320,24 +411,34 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
                     *, n_q, scale, t_real, bq, bk, precision_level,
-                    causal, product_dtype):
-    """dk/dv for one k-tile, accumulated over q-tiles.  Padded key
-    columns are masked to exact-zero probabilities, so their dk/dv
-    rows come out 0 and the unpad slices them away."""
-    qq = pl.program_id(2)
+                    causal, product_dtype, window=None, group=1):
+    """dk/dv for one k-tile, accumulated over q-tiles (under a window,
+    over the q tiles whose band holds it) and, with grouped heads
+    (grid (KV head, k tile, query head of the group, q step)), over the
+    group's query heads.  Padded key columns are masked to exact-zero
+    probabilities, so their dk/dv rows come out 0 and the unpad slices
+    them away."""
+    step = qq = pl.program_id(2 if group == 1 else 3)
+    head = None if group == 1 else pl.program_id(2)
 
-    @pl.when(qq == 0)
+    def at(step_, head_):
+        here = step == step_
+        return here if head is None else here & (head == head_)
+
+    @pl.when(at(0, 0))
     def _init():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     kk = pl.program_id(1)
+    if window is not None:
+        qq = step + (kk * bk) // bq
 
-    @_when_needed(causal, qq, kk, bq, bk)
+    @_when_needed(causal, qq, kk, bq, bk, window, t_real)
     def _tile():
         s = mxu_partial_dot(q_ref[0], k_ref[0].T, precision_level) * scale
         s = _masked_scores(s, qq, kk, bq=bq, bk=bk, t_real=t_real,
-                           causal=causal)
+                           causal=causal, window=window)
         p = _probabilities(s, m_ref, l_ref)
         do = _cotangent(do_ref, product_dtype)
         dv_acc_ref[:] += mxu_partial_dot(_narrow(p, product_dtype).T, do,
@@ -347,7 +448,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
         dk_acc_ref[:] += mxu_partial_dot(_narrow(ds, product_dtype).T,
                                          q_ref[0], precision_level)
 
-    @pl.when(qq == n_q - 1)
+    @pl.when(at(n_q - 1, group - 1))
     def _store():
         dk_ref[0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
@@ -355,14 +456,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
 
 @functools.partial(
     jax.jit, static_argnames=("scale", "precision_level", "blocks",
-                              "interpret", "causal", "product_dtype"))
+                              "interpret", "causal", "product_dtype",
+                              "window"))
 def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
-                   blocks, interpret, causal=False, product_dtype=None):
+                   blocks, interpret, causal=False, product_dtype=None,
+                   window=None):
     """(dq, dk, dv) via the two tiled backward kernels.  ``delta`` =
     rowsum(do * out) is the standard flash-backward precompute — one
     elementwise pass, kept outside the kernels like conv-VJP keeps its
     dgrad as a lax conv."""
     b, t, dh = q.shape
+    b_kv = k.shape[0]
+    group = b // b_kv
     dv_width = v.shape[-1]
     # (B, Tq_padded) each, as the ``fwd`` rule keeps them: back to the
     # lane-broadcast layout the two kernels read
@@ -381,22 +486,28 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
     dvp = vp.shape[-1]
     tk = kp.shape[1]
     n_q, n_k = tq // bq, tk // bk
+    k_steps, q_steps = n_k, n_q
     k_at = _last_k(causal, bq, bk)
     q_at = _first_q(causal, bq, bk)
     static = dict(scale=scale, t_real=t, bq=bq, bk=bk,
                   precision_level=precision_level, causal=causal,
-                  product_dtype=product_dtype)
+                  product_dtype=product_dtype, window=window)
+    if window is not None:
+        k_steps, q_steps = _band_steps(t, bq, bk, window)
+        k_at = _band_k(bq, bk, window, t)
+        q_at = _band_q(bq, bk, window, t)
+    kv = _kv_head(group)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, n_k=n_k, **static),
-        name=DQ_KERNEL_NAME,
-        grid=(b, n_q, n_k),
+        functools.partial(_bwd_dq_kernel, n_k=k_steps, **static),
+        name=DQ_KERNEL_NAME if window is None else WIN_DQ_KERNEL_NAME,
+        grid=(b, n_q, k_steps),
         in_specs=[
             pl.BlockSpec((1, bq, dhp), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bk, dhp),
-                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
+                         lambda bb, i, kk: (kv(bb), k_at(i, kk), 0)),
             pl.BlockSpec((1, bk, dvp),
-                         lambda bb, i, kk: (bb, k_at(i, kk), 0)),
+                         lambda bb, i, kk: (kv(bb), k_at(i, kk), 0)),
             pl.BlockSpec((1, bq, dvp), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda bb, i, kk: (bb, i, 0)),
             pl.BlockSpec((1, bq, 128), lambda bb, i, kk: (bb, i, 0)),
@@ -411,43 +522,55 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
         interpret=interpret,
     )(qp, kp, vp, dop, row_max, row_sum, delta)
 
+    # the dk/dv grid: (KV head, k tile, q step), and with grouped heads
+    # the group's query heads as one more inner axis that sums into the
+    # same accumulator (two index maps, so that the ungrouped one stays
+    # the one it was: no ``bb * 1 + 0`` in its program)
+    if group == 1:
+        def q_side(bb, kk, i):
+            return (bb, q_at(kk, i), 0)
+        inner, static_dkv = (q_steps,), static
+    else:
+        def q_side(bb, kk, head, i):
+            return (bb * group + head, q_at(kk, i), 0)
+        inner, static_dkv = (group, q_steps), dict(static, group=group)
+
+    def k_side(bb, kk, *_):
+        return (bb, kk, 0)
+
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, n_q=n_q, **static),
-        name=DKV_KERNEL_NAME,
-        grid=(b, n_k, n_q),
+        functools.partial(_bwd_dkv_kernel, n_q=q_steps, **static_dkv),
+        name=DKV_KERNEL_NAME if window is None else WIN_DKV_KERNEL_NAME,
+        grid=(b_kv, n_k) + inner,
         in_specs=[
-            pl.BlockSpec((1, bq, dhp),
-                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bb, kk, i: (bb, kk, 0)),
-            pl.BlockSpec((1, bk, dvp), lambda bb, kk, i: (bb, kk, 0)),
-            pl.BlockSpec((1, bq, dvp),
-                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
-            pl.BlockSpec((1, bq, 128),
-                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
-            pl.BlockSpec((1, bq, 128),
-                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
-            pl.BlockSpec((1, bq, 128),
-                         lambda bb, kk, i: (bb, q_at(kk, i), 0)),
+            pl.BlockSpec((1, bq, dhp), q_side),
+            pl.BlockSpec((1, bk, dhp), k_side),
+            pl.BlockSpec((1, bk, dvp), k_side),
+            pl.BlockSpec((1, bq, dvp), q_side),
+            pl.BlockSpec((1, bq, 128), q_side),
+            pl.BlockSpec((1, bq, 128), q_side),
+            pl.BlockSpec((1, bq, 128), q_side),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, dhp), lambda bb, kk, i: (bb, kk, 0)),
-            pl.BlockSpec((1, bk, dvp), lambda bb, kk, i: (bb, kk, 0)),
+            pl.BlockSpec((1, bk, dhp), k_side),
+            pl.BlockSpec((1, bk, dvp), k_side),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, tk, dhp), q.dtype),
-            jax.ShapeDtypeStruct((b, tk, dvp), q.dtype),
+            jax.ShapeDtypeStruct((b_kv, tk, dhp), q.dtype),
+            jax.ShapeDtypeStruct((b_kv, tk, dvp), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, dhp), jnp.float32),
             pltpu.VMEM((bk, dvp), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")
+            + ("arbitrary",) * len(inner)),
         interpret=interpret,
     )(qp, kp, vp, dop, row_max, row_sum, delta)
 
-    return (unpad(dq, (b, t, dh)), unpad(dk, (b, t, dh)),
-            unpad(dv, (b, t, dv_width)))
+    return (unpad(dq, (b, t, dh)), unpad(dk, (b_kv, t, dh)),
+            unpad(dv, (b_kv, t, dv_width)))
 
 
 # -- the custom_vjp entry ----------------------------------------------------
@@ -455,11 +578,11 @@ def _flash_bwd_jit(q, k, v, out, stats, do, scale, precision_level,
 
 @functools.lru_cache(maxsize=None)
 def _flash_fn(scale, precision_level, blocks, causal=False,
-              product_dtype=None):
+              product_dtype=None, window=None):
     """Per-static-config custom_vjp, cached so jit tracing sees one
     stable callable per (scale, level, schedule, form) — the conv_act
     pattern."""
-    form = dict(causal=causal, product_dtype=product_dtype)
+    form = dict(causal=causal, product_dtype=product_dtype, window=window)
 
     @jax.custom_vjp
     def f(q, k, v):
@@ -492,13 +615,30 @@ def _flash_fn(scale, precision_level, blocks, causal=False,
     return f
 
 
+def _check_shapes(q, k, v, causal, window):
+    if (q.ndim != 3 or k.ndim != 3 or k.shape[1:] != q.shape[1:]
+            or v.ndim != 3 or v.shape[:2] != k.shape[:2]
+            or not k.shape[0] or q.shape[0] % k.shape[0]):
+        raise ValueError("attention expects (B, T, dk) q and k and a "
+                         "(B, T, dv) v (k and v may have B / group rows: "
+                         "grouped heads), got %s %s %s" %
+                         (q.shape, k.shape, v.shape))
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window (%r) counts keys back from the query: "
+                         "it wants causal=True and at least 1" % (window,))
+
+
 def flash_attention(q, k, v, scale=None, precision_level=0,
-                    blocks=None, causal=False, product_dtype=None):
+                    blocks=None, causal=False, product_dtype=None,
+                    window=None):
     """Tiled online-softmax attention with the Pallas backward
     attached: ``softmax(q @ k^T * scale) @ v`` over (B, T, key width)
     ``q``/``k`` and (B, T, value width) ``v`` (B = batch x heads; the
     model layer folds heads in); ``causal=True`` keeps key ``j`` for
-    query ``i`` only where ``j <= i``.
+    query ``i`` only where ``j <= i``, and ``window=W`` with it only
+    where ``i - j < W``.  ``k``/``v`` with B / group rows are grouped
+    heads: row ``n`` of ``q`` reads row ``n // group`` (module
+    docstring).
 
     ``precision_level`` follows the matmul ladder for every product
     step (docs/kernels.md); ``product_dtype`` rounds every product's
@@ -506,14 +646,12 @@ def flash_attention(q, k, v, scale=None, precision_level=0,
     docstring).  ``blocks=None`` consults the ``attention``
     schedule-cache family before the static default.
     """
-    if (q.ndim != 3 or k.shape != q.shape or v.ndim != 3
-            or v.shape[:2] != q.shape[:2]):
-        raise ValueError("flash_attention expects (B, T, dk) q and k "
-                         "and a (B, T, dv) v, got %s %s %s" %
-                         (q.shape, k.shape, v.shape))
+    _check_shapes(q, k, v, causal, window)
+    if window is not None and window >= q.shape[1]:
+        window = None  # every earlier key is inside it: the causal form
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    plain = not causal and v.shape == q.shape
+    plain = not causal and v.shape == q.shape == k.shape
     if blocks is None:
         # the tuned schedules were measured on the plain form
         blocks = (plain and _tuned_blocks(q, precision_level)) or (
@@ -523,6 +661,8 @@ def flash_attention(q, k, v, scale=None, precision_level=0,
         product_dtype = jnp.dtype(product_dtype).name
     # the plain form keeps the call (and the cache key) it had in v2
     form = (bool(causal), product_dtype) if causal or product_dtype else ()
+    if window is not None:
+        form += (int(window),)
     out = _flash_fn(float(scale), int(precision_level), tuple(blocks),
                     *form)(q, k, v)
     if _common.DEBUG_NONFINITE and not isinstance(out, jax.core.Tracer):
@@ -531,7 +671,7 @@ def flash_attention(q, k, v, scale=None, precision_level=0,
 
 
 def attention_reference(q, k, v, scale=None, precision_level=1,
-                        causal=False):
+                        causal=False, window=None):
     """Stock softmax attention in the kernel's exact op order — the
     ``VELES_PALLAS_BWD=0`` fallback (plain jnp, stock autodiff) AND
     the parity oracle: on shapes that fit one (bq, bk) tile the flash
@@ -540,7 +680,10 @@ def attention_reference(q, k, v, scale=None, precision_level=1,
     shapes differ only by the online rescale's accumulation order
     (ULP-bounded, tests/test_transformer.py).  ``v`` may be narrower
     or wider than ``q``/``k``; ``causal=True`` floors the keys after
-    each query as the kernels do.
+    each query as the kernels do, ``window`` those that far and more
+    before it; ``k``/``v`` with B / group rows are grouped heads, each
+    read by ``group`` rows of ``q`` (indexed, so stock autodiff sums a
+    group's gradients).
 
     The DEFAULT level is 1 (true-f32 HIGHEST products): stock model-
     layer math is full f32 everywhere else in the zoo (the gd units'
@@ -556,14 +699,22 @@ def attention_reference(q, k, v, scale=None, precision_level=1,
     identical in the interpreter and under Mosaic on a v5e; level 1
     is within 5e-7).  Pass ``precision_level=0`` explicitly only to
     parity-test the kernel's level-0 op sequence."""
+    _check_shapes(q, k, v, causal, window)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    group = q.shape[0] // k.shape[0]
+    if group > 1:
+        of_head = jnp.arange(q.shape[0]) // group
+        k, v = k[of_head], v[of_head]
 
     def one(qb, kb, vb):
         s = mxu_partial_dot(qb, kb.T, precision_level) * scale
         if causal:
-            s = jnp.where(_col_ids(*s.shape) <= _row_ids(*s.shape), s,
-                          _MASK_FLOOR)
+            back = _row_ids(*s.shape) - _col_ids(*s.shape)
+            keep = back >= 0
+            if window is not None:
+                keep = keep & (back < window)
+            s = jnp.where(keep, s, _MASK_FLOOR)
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)
