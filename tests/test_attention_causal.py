@@ -41,7 +41,7 @@ def loss_of(fn):
 
 
 def test_version_is_bumped():
-    assert attention.ATTENTION_KERNEL_VERSION == 4
+    assert attention.ATTENTION_KERNEL_VERSION == 5
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

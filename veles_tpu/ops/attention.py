@@ -38,7 +38,21 @@ conv-VJP, built to the same contracts:
   above the diagonal — the body runs under ``pl.when`` and the block
   index of the skipped side is clamped to the last tile needed, so a
   skipped grid step fetches nothing — and mask the tiles the diagonal
-  crosses with the same finite floor.
+  or the band's edge crosses with the same finite floor.  The FORWARD
+  masks those and only those: a tile that lies wholly below the
+  diagonal (under a window wholly inside the band) and holds no padded
+  key is a WHOLE tile (:func:`_tile_classes`), every pair of it is
+  kept, and the forward hands its scores on as they are, the mask
+  under ``lax.cond(whole, keep, mask)`` — the select would return its
+  first operand, so the numbers are the masked body's bit for bit.
+  What that gains on the chip is NOT the mask's work (a v5e issues it
+  in slots the tile leaves spare: with no mask on any tile the three
+  kernels are 0.4 % faster) but the ``scf.if``'s boundary: the scores
+  leave a region as a value, so the product q k^T is finished before
+  the row reductions begin — 14 % of the forward at 8,192 tokens
+  (docs/kernels.md).  The backward kernels reduce nothing, gain
+  nothing from a boundary, and mask every needed tile as before.
+  :func:`tile_census` counts the classes a head's grid holds.
 - **Keys wider than values** (latent attention: 192-wide ``q``/``k``
   against 128-wide ``v``): ``q``/``k``/``dq``/``dk`` tiles carry the
   key width, ``v``/``out``/``do``/``dv`` tiles and the output
@@ -56,9 +70,11 @@ conv-VJP, built to the same contracts:
   query steps a key tile in the dk/dv kernel), the block index offset
   by the first tile the band crosses — so its cost grows with T x W,
   not T x T; the few steps a tile at the sequence's start does not
-  need run nothing and fetch nothing, as in the causal form.  These
-  kernels carry their own names (``veles_flash_win_*``).  A window of T
-  or more is the causal form, and runs as it.
+  need run nothing and fetch nothing, as in the causal form, and of
+  the band's tiles the forward masks the tiles the diagonal or the
+  band's edge crosses, and only those.  These kernels carry their own
+  names (``veles_flash_win_*``).  A window of T or more is the causal
+  form, and runs as it.
 - ``product_dtype`` (None keeps the v2 behaviour): the dtype EVERY
   product's operands are rounded to, the probability and cotangent
   tiles included.  With bfloat16 ``q``/``k``/``v`` the v2 kernels hand
@@ -92,7 +108,7 @@ from veles_tpu.ops import common as _common
 from veles_tpu.ops.common import (ceil_mult, interpret_for,
                                    mxu_partial_dot, pad_to, unpad)
 
-__all__ = ["flash_attention", "attention_reference",
+__all__ = ["flash_attention", "attention_reference", "tile_census",
            "ATTENTION_KERNEL_VERSION"]
 
 #: the kernels' names in compiled HLO and device traces (``%<name>``)
@@ -120,8 +136,10 @@ KEPT_OUT, KEPT_ROW_MAX, KEPT_ROW_SUM = KEPT_NAMES = (
 #: v3: causal tile skipping, key width apart from value width,
 #: ``product_dtype`` — the non-causal equal-width float32 program is
 #: v2's, op for op; v4: grouped key/value heads and a window with a
-#: band-only grid — a call with neither lowers to v3's program)
-ATTENTION_KERNEL_VERSION = 4
+#: band-only grid; v5: the causal and windowed FORWARD masks only the
+#: tiles a mask can change, under a ``lax.cond`` — the plain form's
+#: program is still v2's)
+ATTENTION_KERNEL_VERSION = 5
 
 _DEFAULT_BLOCKS = (256, 256)  # (bq, bk)
 #: causal sequences of a thousand tokens and more: a (256, 256) tile is
@@ -158,18 +176,76 @@ def _masked_scores(s, i, kk, *, bq, bk, t_real, causal, window=None):
     return jnp.where(keep, s, _MASK_FLOOR)
 
 
-def _when_needed(causal, i, kk, bq, bk, window=None, t_real=None):
-    """Decorator running a kernel body only where (q tile ``i``, k tile
-    ``kk``) holds a pair with key <= query (and, under a window, one
-    with query - key < window, among the sequence's own tiles: a band
-    step may name a tile past its end); always, when not causal."""
-    if not causal:
-        return lambda body: body()
+def _tile_classes(i, kk, bq, bk, window, t_real):
+    """(needed, whole) of (q tile ``i``, k tile ``kk``) under the
+    causal mask, from what a kernel can see of its place — Python ints
+    (:func:`tile_census`) or a grid step's scalars alike.
+
+    NEEDED is a tile that holds a pair with key <= query (and, under a
+    window, one with query - key < window, among the sequence's own
+    tiles: a band step may name a tile past its end).  A needed tile is
+    WHOLE where every pair of it is kept: its last column is at or
+    before its first row, under a window its last row is within
+    ``window`` of its first column, and it holds no padded key (which
+    the first already says of a causal tile; written out, so that the
+    class reads as what it is).  Padded QUERY rows want no mask: they
+    are sliced away.  Every other needed tile is an EDGE: the diagonal
+    or the window's far edge crosses it, or it holds padded keys."""
     needed = kk * bk < (i + 1) * bq
+    whole = ((kk + 1) * bk - 1 <= i * bq) & ((kk + 1) * bk <= t_real)
     if window is not None:
         needed = (needed & (i * bq < (kk + 1) * bk + window - 1)
-                  & (jnp.maximum(i * bq, kk * bk) < t_real))
-    return pl.when(needed)
+                  & (i * bq < t_real) & (kk * bk < t_real))
+        whole = whole & ((i + 1) * bq - 1 - kk * bk < window)
+    return needed, needed & whole
+
+
+def _when_needed(causal, i, kk, bq, bk, window=None, t_real=None):
+    """Decorator running a kernel body only where (q tile ``i``, k tile
+    ``kk``) is needed (:func:`_tile_classes`); always, when not
+    causal."""
+    if not causal:
+        return lambda body: body()
+    return pl.when(_tile_classes(i, kk, bq, bk, window, t_real)[0])
+
+
+def _scores_masked_on_an_edge(s, i, kk, *, bq, bk, t_real, causal,
+                              window=None):
+    """The forward's :func:`_masked_scores`: causal, the mask sits
+    under a ``lax.cond`` that hands a whole tile's ``s`` on as it is
+    (bit for bit what the select would return).  The gain is the
+    boundary's, not the mask's (docs/kernels.md): ``s`` leaves an
+    ``scf.if`` as a value, so q k^T is finished before the row maxima's
+    lane reductions begin.  It holds only while the branch that hands
+    ``s`` on is the TRUE one, on a predicate that is no negation:
+    Mosaic makes it the ``then`` of the ``scf.if`` (and turns a negated
+    predicate's branches around), and an ``else`` that yields ``s``
+    unchanged copies the tile — 24.0 against 21.3 ms a call at the
+    kanana cell's shape (tests/test_attention_tiles.py pins both)."""
+    place = dict(bq=bq, bk=bk, t_real=t_real, causal=causal, window=window)
+    if not causal:
+        return _masked_scores(s, i, kk, **place)
+    whole = _tile_classes(i, kk, bq, bk, window, t_real)[1]
+    return jax.lax.cond(
+        whole, lambda s: s, lambda s: _masked_scores(s, i, kk, **place), s)
+
+
+def tile_census(t, bq, bk, window=None):
+    """(whole, edge, skipped) grid steps of ONE head of the causal
+    (``window=None``) or windowed forward and dq kernels over ``t``
+    tokens in (``bq``, ``bk``) tiles: how often the forward hands a
+    tile's scores on as they are, masks them, and runs nothing —
+    static, so counted here with the kernels' own predicates rather
+    than sampled from a run."""
+    n_q, n_k = -(-t // bq), -(-t // bk)
+    k_steps = n_k if window is None else _band_steps(t, bq, bk, window)[0]
+    counts = [0, 0, 0]
+    for i in range(n_q):
+        first = 0 if window is None else max(i * bq - window + 1, 0) // bk
+        for kk in range(first, first + k_steps):
+            needed, whole = _tile_classes(i, kk, bq, bk, window, t)
+            counts[0 if whole else 1 if needed else 2] += 1
+    return tuple(counts)
 
 
 def _first_k(i, bq, bk, window):
@@ -211,8 +287,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
     def _tile():
         q = q_ref[0]
         s = mxu_partial_dot(q, k_ref[0].T, precision_level) * scale
-        s = _masked_scores(s, i, kk, bq=bq, bk=bk, t_real=t_real,
-                           causal=causal, window=window)
+        s = _scores_masked_on_an_edge(s, i, kk, bq=bq, bk=bk, t_real=t_real,
+                                      causal=causal, window=window)
 
         m_prev = m_ref[:, :1]                      # (bq, 1)
         s_max = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
