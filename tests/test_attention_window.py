@@ -268,3 +268,50 @@ def test_neither_group_nor_window_traces_to_the_program_it_was(form):
         text = program_text(fn, q, q, q)
     assert hashlib.sha256(text.encode()).hexdigest() == \
         PROGRAMS_BEFORE[form]
+
+
+@pytest.mark.parametrize("t, blocks", [(300, (104, 128)), (256, None)])
+def test_heads_narrower_than_a_lane_tile_32_on_8(t, blocks):
+    """64-wide heads, 32 query heads on 8 KV heads (groups of 4), the
+    full-causal form: each tile pads to 128 lanes by itself, and the
+    padding's lanes add nothing to the scores, the output or any of the
+    three gradients."""
+    q, k, v = operands(64 + t, 32, 4, t, 64, 64)
+    flash = lambda *a: flash_attention(  # noqa: E731
+        *a, precision_level=1, blocks=blocks, causal=True)
+    reference = lambda *a: attention_reference(  # noqa: E731
+        *a, precision_level=1, causal=True)
+    out, want = flash(q, k, v), reference(q, k, v)
+    assert out.shape == want.shape == (32, t, 64)
+    numpy.testing.assert_allclose(want, plain_softmax(q, k, v, None),
+                                  rtol=2e-5, atol=2e-6)
+    numpy.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
+    grads = jax.grad(loss_of(flash), argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(loss_of(reference), argnums=(0, 1, 2))(q, k, v)
+    for name, got, wanted, operand in zip(("dq", "dk", "dv"), grads,
+                                          wants, (q, k, v)):
+        assert got.shape == operand.shape, name  # dk, dv: the 8 KV heads'
+        numpy.testing.assert_allclose(got, wanted, rtol=1e-4, atol=2e-5,
+                                      err_msg=name)
+
+
+def test_narrow_heads_with_bfloat16_products():
+    """The cell's operand dtype at the narrow width: within a few
+    bfloat16 roundings of the float32 reference on the same operands."""
+    q, k, v = operands(23, 32, 4, 256, 64, 64, jnp.bfloat16)
+    flash = lambda *a: flash_attention(  # noqa: E731
+        *a, blocks=(128, 128), causal=True, product_dtype=jnp.bfloat16)
+    wide = tuple(a.astype(jnp.float32) for a in (q, k, v))
+    reference = lambda *a: attention_reference(  # noqa: E731
+        *a, precision_level=1, causal=True)
+    out = numpy.asarray(flash(q, k, v), numpy.float32)
+    want = numpy.asarray(reference(*wide))
+    assert numpy.abs(out - want).max() < 4 * BFLOAT16_EPS * numpy.abs(
+        want).max()
+    grads = jax.grad(loss_of(flash), argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(loss_of(reference), argnums=(0, 1, 2))(*wide)
+    for got, wanted in zip(grads, wants):
+        wanted = numpy.asarray(wanted)
+        assert numpy.abs(numpy.asarray(got, numpy.float32)
+                         - wanted).max() < 16 * BFLOAT16_EPS * numpy.abs(
+                             wanted).max()

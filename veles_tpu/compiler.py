@@ -112,6 +112,27 @@ def layer_scope(index, plan):
     return "l%d_%s" % (index, plan.forward_cls.__name__)
 
 
+def tied_plans(plans):
+    """{layer index: index of the layer whose ``weights`` it reads} for
+    the plans tied to another layer's parameters (``static["tied_to"]``:
+    a decoder head on the embedding's table)."""
+    return {i: plan.static["tied_to"] for i, plan in enumerate(plans)
+            if plan.static.get("tied_to") is not None}
+
+
+def refuse_tied_plans(plans, who):
+    """Raise for a model with tied layers: ``who`` walks a slice of the
+    layers, or updates them shard by shard, and would run the tied one
+    without the array it shares (or leave its own parameters out)."""
+    tied = tied_plans(plans)
+    if tied:
+        raise ValueError(
+            "%s cannot honour tied parameters (layer %s reads the "
+            "weights of layer %s): train this model with the "
+            "single-device or the data-parallel fused step, or untie it"
+            % (who, *next(iter(tied.items()))))
+
+
 def _forward_for_loss(plans, params, x, key=None, remat=False,
                       layer_fn=None, fold_offset=0, aux=None):
     """Forward pass; returns (pre-softmax logits | final output).
@@ -141,6 +162,12 @@ def _forward_for_loss(plans, params, x, key=None, remat=False,
     ``aux``: a list that collects ``(layer index, {name: array})`` from
     the layers whose class has ``apply_with_aux`` (a routed layer's
     per-expert load); None leaves them out.
+
+    A plan whose static config names ``tied_to`` (a decoder head tied to
+    the embedding's table) gets that layer's ``weights`` beside its own
+    parameters, as ``params["tied"]``: ONE array in ``params``, read
+    twice, so its gradient is autodiff's sum of both uses.  The walk
+    must then hold the whole model (:func:`refuse_tied_plans`).
     """
     from veles_tpu.models.all2all import All2All, All2AllSoftmax
     from veles_tpu.models.dropout import DropoutForward
@@ -157,6 +184,9 @@ def _forward_for_loss(plans, params, x, key=None, remat=False,
 
     h = x
     for i, (plan, p) in enumerate(zip(plans, params)):
+        tied_to = plan.static.get("tied_to")
+        if tied_to is not None:
+            p = dict(p, tied=params[tied_to]["weights"])
         # metadata only: the layer's forward ops AND their transposes in
         # the backward carry the scope in ``op_name``; instructions,
         # shapes and numerics are those of the unscoped program
@@ -421,22 +451,26 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
 
         new_state = []
         for plan, hyper, s, g in zip(plans, hypers, state, grads):
-            if s["weights"] is None:  # param-less layer (pooling, ...)
-                new_state.append(dict(s))
+            updates_bias = plan.include_bias and s["bias"] is not None
+            if s["weights"] is None and not updates_bias:
+                new_state.append(dict(s))  # param-less (pooling, ...)
                 continue
-            W = s["weights"]
-            gw, extra = solver_grad(plan, hyper["weights_decay"],
-                                    hyper, g["weights"], W)
-            new_w, acc_w, acc2_w = GradientDescentBase.solver_update(
-                plan.solver, W, gw, s["accum_weights"],
-                s["accum2_weights"], hyper["learning_rate"],
-                hyper["gradient_moment"], hyper["adadelta_rho"],
-                hyper["solver_epsilon"], **extra)
+            # a tied layer's matrix is another layer's: its gain alone
+            new_w, acc_w, acc2_w = None, None, None
+            if s["weights"] is not None:
+                W = s["weights"]
+                gw, extra = solver_grad(plan, hyper["weights_decay"],
+                                        hyper, g["weights"], W)
+                new_w, acc_w, acc2_w = GradientDescentBase.solver_update(
+                    plan.solver, W, gw, s["accum_weights"],
+                    s["accum2_weights"], hyper["learning_rate"],
+                    hyper["gradient_moment"], hyper["adadelta_rho"],
+                    hyper["solver_epsilon"], **extra)
             entry = {"weights": new_w, "accum_weights": acc_w,
                      "accum2_weights": acc2_w,
                      "bias": s["bias"], "accum_bias": s["accum_bias"],
                      "accum2_bias": s["accum2_bias"]}
-            if plan.include_bias and s["bias"] is not None:
+            if updates_bias:
                 b = s["bias"]
                 gb, extra = solver_grad(plan, hyper["weights_decay_bias"],
                                         hyper, g["bias"], b)
@@ -713,6 +747,8 @@ def _build_zero1_spmd_train_step(plans, loss, mesh, data_axis, n_shards,
     from veles_tpu.parallel import bucketed as _bucketed
     from veles_tpu.parallel.mesh import shard_map
 
+    refuse_tied_plans(plans, "the ZeRO-1 step (its update walks the "
+                      "layers that own a matrix)")
     n = mesh.shape[data_axis]
     m = int(n_shards)
     k = -(-m // n)  # device slots; table pads with the zero-row id m

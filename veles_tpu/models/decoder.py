@@ -1,12 +1,18 @@
 """Causal decoder units: token embedding, one layer made of parts — a
-latent-attention (MLA) or a grouped-query attention sub-layer, norms
-before each sub-layer or around it, a gated SiLU feed-forward or a
-routed expert layer — and the output head (docs/model_layer.md "Decoder
-units").  The parts are chosen by the layer's own dims (``kv_rank``
-makes the attention latent, ``kv_heads`` grouped; ``post_norms`` puts a
-norm after each sub-layer too; ``ffn`` makes the feed-forward dense),
-never by a model's name: the DeepSeek-V3 family is one choice of them,
-the window/full grouped-query family with sandwich norms another.
+token mixer that is latent attention (MLA), grouped-query attention or a
+gated short convolution, norms before each sub-layer or around it, a
+gated SiLU feed-forward or a routed expert layer — and the output head,
+with a matrix of its own or tied to the embedding's table
+(docs/model_layer.md "Decoder units").  The parts are chosen by the
+layer's own dims (``kv_rank`` makes the mixer latent attention,
+``kv_heads`` grouped-query attention, ``conv_taps`` a short convolution;
+``out_gate`` False takes the sigmoid gate off grouped attention's
+output; ``post_norms`` puts a norm after each sub-layer too; ``ffn``
+makes the feed-forward dense; a routed layer carries a shared expert
+where ``shared_width`` is not 0), never by a model's name: the
+DeepSeek-V3 family is one choice of them, the window/full grouped-query
+family with sandwich norms another, the hybrid of short convolutions and
+grouped-query attention a third.
 
 Built on the contracts of ``transformer.py``: the math is in pure
 functions and ``apply(params, x, **static)`` class methods; a layer's
@@ -34,19 +40,32 @@ keys of the DeepSeek-V3 family in brackets)::
     dense:  h += (silu(m W_g) * m W_u) W_d
     routed: p = sigmoid(m W_r); the top_k largest of p + b;
             w_i = p_i / sum_chosen p * routed_scale
-            h += sum_i w_i Expert_i(m) + Shared(m)
+            h += sum_i w_i Expert_i(m) [+ Shared(m)]
+
+``Shared`` is a part: a routed layer whose ``shared_width`` is 0 has
+none, and its ``s_*`` pieces and ``shared_experts`` scope leave with it.
 
 The grouped-query attention sub-layer (:func:`grouped_attention`), in
 place of the first five lines::
 
     q = a W_q -> heads x head_width;  k, v = a W_k, a W_v -> kv_heads x
-    head_width;  z = a W_z -> heads x head_width  (the output gate)
+    head_width;  z = a W_z -> heads x head_width  (the output gate, a
+    part: with ``out_gate`` False ``w_z`` leaves the layout)
     q, k = rms_norm(q), rms_norm(k)  over each head, one gain each
     rotary on q and k, the pairing (i, i + head_width / 2), where the
     layer has ``rope``; no position signal where it has not
     query head n reads KV head n // (heads / kv_heads); key j counts for
     query i where j <= i and, with ``window``, i - j < window
-    h += [rms_norm](softmax(q.k / sqrt(head_width)) v * sigmoid(z)) W_o
+    h += [rms_norm](softmax(q.k / sqrt(head_width)) v [* sigmoid(z)]) W_o
+
+The gated short convolution (:func:`short_conv`), the mixer of a layer
+with ``conv_taps`` = L, in place of attention::
+
+    [B | C | x] = a W_in              W_in (width, 3 width), no bias
+    u = B * x
+    c[t] = sum_{j < L} k[:, j] * u[t - (L - 1) + j],  u[t < 0] = 0
+                        (depthwise: one L-tap filter a channel, causal)
+    h += (C * c) W_out                W_out (width, width)
 
 and with ``post_norms`` each sub-layer's output is normalised before it
 is added (``h += rms_norm(f)``: the sandwich placement).
@@ -66,8 +85,10 @@ assignments that do not fit and counts them (``moe_dropped``): the layer
 never drops one silently.
 
 **Initialisation.**  Normal, ``weights_stddev`` every matrix but those
-that write into the residual stream (``w_o`` and every ``*_down``),
-which take ``out_stddev`` (default: the same).  With one std everywhere
+that write into the residual stream (``w_o``, ``w_out`` and every
+``*_down``), which take ``out_stddev`` (default: the same), and the
+short convolution's filter, uniform within 1 / sqrt(L) (what a
+depthwise ``Conv1d`` starts from).  With one std everywhere
 the first layer's attention output — an average of values over the
 prefix, so nearly the same vector at every position — outweighs the
 embedding four to one, every token looks alike to the router and all
@@ -87,11 +108,12 @@ from veles_tpu.models.transformer import _GDAutodiff, _SequenceUnit
 __all__ = ["DecoderEmbedding", "DecoderLayer", "DecoderHead",
            "GDDecoderEmbedding", "GDDecoderLayer", "GDDecoderHead",
            "rms_norm", "rotary", "latent_attention", "grouped_attention",
-           "gated_ffn", "routed_experts", "layer_layout", "unpack",
-           "decoder_layer"]
+           "short_conv", "gated_ffn", "routed_experts", "layer_layout",
+           "unpack", "decoder_layer"]
 
 #: ``jax.named_scope`` names inside each ``l<k>_DecoderLayer``
 SCOPE_ATTENTION = "attention"
+SCOPE_CONV = "short_conv"
 SCOPE_ROUTER = "router"
 SCOPE_ROUTED = "routed_experts"
 SCOPE_SHARED = "shared_experts"
@@ -202,15 +224,16 @@ def latent_attention(a, w, *, heads, qk_nope, qk_rope, v_head, kv_rank,
 
 
 def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
-                      theta, eps, q_gain, k_gain, pallas_bwd=None):
+                      theta, eps, q_gain, k_gain, out_gate=True,
+                      pallas_bwd=None):
     """The grouped-query sub-layer over normalised ``a`` (B, T, D),
     before the residual add: ``heads`` query heads read ``kv_heads``
     key/value heads (never repeated: the kernels index them), each
     head's q and k RMS-normalised (one ``head_width`` gain each, shared
     by the heads), rotary in the rotate-half pairing where ``rope``,
-    keys ``window`` back where given, and a sigmoid gate on the output
-    before ``w_o``.  ``w`` holds ``w_q``, ``w_k``, ``w_v``, ``w_z``,
-    ``w_o``."""
+    keys ``window`` back where given, and with ``out_gate`` a sigmoid
+    gate on the output before ``w_o``.  ``w`` holds ``w_q``, ``w_k``,
+    ``w_v``, ``w_o`` and, gated, ``w_z``."""
     import jax
     import jax.numpy as jnp
     b, t, _ = a.shape
@@ -220,7 +243,8 @@ def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
         b, t, kv_heads, head_width)
     v = _dense(a, w["w_v"]).astype(dtype).reshape(
         b, t, kv_heads, head_width)
-    z = _dense(a, w["w_z"])
+    if out_gate:
+        z = _dense(a, w["w_z"])
     q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
     if rope:
         q, k = rotary(q, theta, halves=True), rotary(k, theta, halves=True)
@@ -228,8 +252,32 @@ def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
                 1.0 / float(numpy.sqrt(head_width)), pallas_bwd, window)
     o = o.reshape(b, heads, t, head_width).transpose(0, 2, 1, 3).reshape(
         b, t, heads * head_width)
-    o = (o.astype(jnp.float32) * jax.nn.sigmoid(z)).astype(dtype)
+    if out_gate:
+        o = (o.astype(jnp.float32) * jax.nn.sigmoid(z)).astype(dtype)
     return _dense(o, w["w_o"]).astype(dtype)
+
+
+def short_conv(a, w_in, taps, w_out):
+    """The gated short convolution over normalised ``a`` (B, T, D),
+    before the residual add: ``[B | C | x] = a W_in``, the gated input
+    ``u = B * x``, a depthwise causal filter along the sequence —
+    ``c[t] = sum_j taps[:, j] * u[t - (L - 1) + j]`` with ``taps`` (D,
+    L) and nothing before position 0 — and ``(C * c) W_out``.  The
+    filter is L shifted multiply-adds in float32, which XLA fuses with
+    both gates into one pass over the (B, T, 3 D) projection."""
+    import jax.numpy as jnp
+    dtype = a.dtype
+    width, length = taps.shape
+    t = a.shape[1]
+    bcx = _dense(a, w_in).astype(dtype)
+    gate_in, gate_out, x = (bcx[..., :width], bcx[..., width:2 * width],
+                            bcx[..., 2 * width:])
+    u = jnp.pad((gate_in * x).astype(jnp.float32),
+                ((0, 0), (length - 1, 0), (0, 0)))
+    k = taps.astype(jnp.float32)
+    c = sum(k[:, j] * u[:, j:j + t] for j in range(length))
+    return _dense((gate_out.astype(jnp.float32) * c).astype(dtype),
+                  w_out).astype(dtype)
 
 
 def gated_ffn(m, w_gate, w_up, w_down):
@@ -353,17 +401,24 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
 # -- the packed layer --------------------------------------------------------
 
 
-def layer_layout(d, *, heads, qk_nope=None, qk_rope=None, v_head=None,
-                 kv_rank=None, kv_heads=None, head_width=None,
-                 post_norms=False, ffn=None, experts=None,
-                 experts_held=None, expert_width=None, shared_width=None,
-                 **_):
+def layer_layout(d, *, heads=None, qk_nope=None, qk_rope=None,
+                 v_head=None, kv_rank=None, kv_heads=None, head_width=None,
+                 out_gate=True, conv_taps=None, post_norms=False, ffn=None,
+                 experts=None, experts_held=None, expert_width=None,
+                 shared_width=None, **_):
     """((name, shape) of the packed ``weights``, of the packed
     ``bias``): the ONE definition the unit's initialiser and the apply
-    read.  ``kv_rank`` makes the attention latent, ``kv_heads``
-    grouped; ``post_norms`` adds the gains of a norm after each
-    sub-layer; ``ffn`` makes the layer dense, else it is routed."""
-    if kv_rank:
+    read.  ``conv_taps`` makes the mixer a short convolution,
+    ``kv_rank`` latent attention, ``kv_heads`` grouped attention (with
+    its output gate's ``w_z`` unless ``out_gate`` is False);
+    ``post_norms`` adds the gains of a norm after each sub-layer;
+    ``ffn`` makes the layer dense, else it is routed, beside a shared
+    expert where ``shared_width`` is not 0."""
+    if conv_taps:
+        weights = [("w_in", (d, 3 * d)), ("conv_k", (d, conv_taps)),
+                   ("w_out", (d, d))]
+        bias = [("conv_gain", (d,))]
+    elif kv_rank:
         weights = [("w_q", (d, heads * (qk_nope + qk_rope))),
                    ("w_kva", (d, kv_rank + qk_rope)),
                    ("w_kvb", (kv_rank, heads * (qk_nope + v_head))),
@@ -372,9 +427,10 @@ def layer_layout(d, *, heads, qk_nope=None, qk_rope=None, v_head=None,
     else:
         weights = [("w_q", (d, heads * head_width)),
                    ("w_k", (d, kv_heads * head_width)),
-                   ("w_v", (d, kv_heads * head_width)),
-                   ("w_z", (d, heads * head_width)),
-                   ("w_o", (heads * head_width, d))]
+                   ("w_v", (d, kv_heads * head_width))]
+        if out_gate:
+            weights += [("w_z", (d, heads * head_width))]
+        weights += [("w_o", (heads * head_width, d))]
         bias = [("attn_gain", (d,)), ("q_gain", (head_width,)),
                 ("k_gain", (head_width,))]
     if post_norms:
@@ -389,10 +445,11 @@ def layer_layout(d, *, heads, qk_nope=None, qk_rope=None, v_head=None,
         weights += [("w_router", (d, experts)),
                     ("e_gate", (experts_held, d, expert_width)),
                     ("e_up", (experts_held, d, expert_width)),
-                    ("e_down", (experts_held, expert_width, d)),
-                    ("s_gate", (d, shared_width)),
-                    ("s_up", (d, shared_width)),
-                    ("s_down", (shared_width, d))]
+                    ("e_down", (experts_held, expert_width, d))]
+        if shared_width:
+            weights += [("s_gate", (d, shared_width)),
+                        ("s_up", (d, shared_width)),
+                        ("s_down", (shared_width, d))]
         bias += [("router_bias", (experts,))]
     return weights, bias
 
@@ -449,10 +506,11 @@ def unpack(vec, layout, dtype):
     return {entry[0]: piece for entry, piece in zip(layout, pieces)}
 
 
-def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope=None,
-                  qk_rope=None, v_head=None, kv_rank=None, kv_heads=None,
-                  head_width=None, window=None, rope=True,
-                  post_norms=False, ffn=None, experts=None,
+def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
+                  qk_nope=None, qk_rope=None, v_head=None, kv_rank=None,
+                  kv_heads=None, head_width=None, window=None, rope=True,
+                  out_gate=True, conv_taps=None, post_norms=False,
+                  ffn=None, experts=None,
                   experts_held=None, first_expert=0, top_k=None,
                   expert_width=None, shared_width=None, routed_scale=1.0,
                   route_eps=0.0, capacity=None, theta=1e6, eps=1e-6,
@@ -464,7 +522,8 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope=None,
     from jax import lax
     dims = dict(heads=heads, qk_nope=qk_nope, qk_rope=qk_rope,
                 v_head=v_head, kv_rank=kv_rank, kv_heads=kv_heads,
-                head_width=head_width, post_norms=post_norms, ffn=ffn,
+                head_width=head_width, out_gate=out_gate,
+                conv_taps=conv_taps, post_norms=post_norms, ffn=ffn,
                 experts=experts, experts_held=experts_held,
                 expert_width=expert_width, shared_width=shared_width)
     w_layout, b_layout = layer_layout(h.shape[-1], **dims)
@@ -476,20 +535,27 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope=None,
         """The residual stream after a sub-layer's output ``f``."""
         return h + (rms_norm(f, g[gain], eps) if post_norms else f)
 
-    with jax.named_scope(SCOPE_ATTENTION):
-        a = rms_norm(h, g["attn_gain"], eps)
-        if kv_rank:
-            attended = latent_attention(
-                a, w, heads=heads, qk_nope=qk_nope, qk_rope=qk_rope,
-                v_head=v_head, kv_rank=kv_rank, kv_gain=g["kv_gain"],
-                theta=theta, eps=eps, pallas_bwd=pallas_bwd)
-        else:
-            attended = grouped_attention(
-                a, w, heads=heads, kv_heads=kv_heads,
-                head_width=head_width, window=window, rope=rope,
-                theta=theta, eps=eps, q_gain=g["q_gain"],
-                k_gain=g["k_gain"], pallas_bwd=pallas_bwd)
-        h = added(h, attended, "post_attn_gain")
+    if conv_taps:
+        with jax.named_scope(SCOPE_CONV):
+            mixed = short_conv(rms_norm(h, g["conv_gain"], eps),
+                               w["w_in"], w["conv_k"], w["w_out"])
+            h = added(h, mixed, "post_attn_gain")
+    else:
+        with jax.named_scope(SCOPE_ATTENTION):
+            a = rms_norm(h, g["attn_gain"], eps)
+            if kv_rank:
+                attended = latent_attention(
+                    a, w, heads=heads, qk_nope=qk_nope, qk_rope=qk_rope,
+                    v_head=v_head, kv_rank=kv_rank, kv_gain=g["kv_gain"],
+                    theta=theta, eps=eps, pallas_bwd=pallas_bwd)
+            else:
+                attended = grouped_attention(
+                    a, w, heads=heads, kv_heads=kv_heads,
+                    head_width=head_width, window=window, rope=rope,
+                    theta=theta, eps=eps, q_gain=g["q_gain"],
+                    k_gain=g["k_gain"], out_gate=out_gate,
+                    pallas_bwd=pallas_bwd)
+            h = added(h, attended, "post_attn_gain")
     m = rms_norm(h, g["ffn_gain"], eps)
     if ffn:
         with jax.named_scope(SCOPE_FFN):
@@ -514,13 +580,17 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads, qk_nope=None,
         routed, aux = routed_experts(
             tokens, idx, gate, w["e_gate"], w["e_up"], w["e_down"],
             first_expert=first_expert, capacity=capacity)
-    with jax.named_scope(SCOPE_SHARED):
-        shared = gated_ffn(m, w["s_gate"], w["s_up"], w["s_down"])
+    shared = None
+    if shared_width:
+        with jax.named_scope(SCOPE_SHARED):
+            shared = gated_ffn(m, w["s_gate"], w["s_up"], w["s_down"])
+    routed = routed.reshape(b, t, d)
+    if shared is None:
+        return added(h, routed, "post_ffn_gain"), aux
     if post_norms:
-        return added(h, routed.reshape(b, t, d) + shared,
-                     "post_ffn_gain"), aux
+        return added(h, routed + shared, "post_ffn_gain"), aux
     # pre-norm only: the sum in the order it always had (the same bits)
-    return h + routed.reshape(b, t, d) + shared, aux
+    return h + routed + shared, aux
 
 
 # -- units -------------------------------------------------------------------
@@ -593,12 +663,14 @@ class DecoderEmbedding(_DecoderUnit):
 
 
 class DecoderLayer(_DecoderUnit):
-    """One layer — latent or grouped attention, norms before or around
-    each sub-layer, dense or routed — packed (:func:`layer_layout`)."""
+    """One layer — a latent-attention, grouped-attention or
+    short-convolution mixer, norms before or around each sub-layer,
+    dense or routed — packed (:func:`layer_layout`)."""
 
     MAPPING = "decoder_layer"
     DIMS = ("heads", "qk_nope", "qk_rope", "v_head", "kv_rank", "kv_heads",
-            "head_width", "window", "rope", "post_norms", "ffn",
+            "head_width", "window", "rope", "out_gate", "conv_taps",
+            "post_norms", "ffn",
             "experts", "experts_held", "first_expert", "top_k",
             "expert_width", "shared_width", "routed_scale", "route_eps",
             "capacity", "theta")
@@ -606,7 +678,7 @@ class DecoderLayer(_DecoderUnit):
     POST_GAINS = ("post_attn_gain", "post_ffn_gain")
 
     #: the pieces that write into the residual stream (``out_stddev``)
-    RESIDUAL_WRITERS = ("w_o", "w_down", "e_down", "s_down")
+    RESIDUAL_WRITERS = ("w_o", "w_out", "w_down", "e_down", "s_down")
     #: registry names of the counters ``apply_with_aux`` emits: the
     #: trainer publishes a scalar a layer as ``<name>`` and a vector a
     #: layer as ``<name>.l<layer>.e<element>``
@@ -633,9 +705,7 @@ class DecoderLayer(_DecoderUnit):
             return  # restored from a snapshot
         w_layout, b_layout = layer_layout(shape[-1], **self.dims)
         self.weights.mem = numpy.concatenate(
-            [self._gaussian(piece, self.out_stddev
-                            if name in self.RESIDUAL_WRITERS
-                            else None).ravel()
+            [self._filled(name, piece).ravel()
              for name, piece in w_layout])
         pieces = []
         for name, piece in b_layout:
@@ -651,6 +721,17 @@ class DecoderLayer(_DecoderUnit):
             pieces.append(value)
         self.bias.mem = numpy.concatenate(pieces)
 
+    def _filled(self, name, shape):
+        """A piece of the packed weights as initialised."""
+        if name == "conv_k":
+            # a depthwise Conv1d's start: uniform within 1 / sqrt(taps)
+            taps = numpy.zeros(shape, numpy.float32)
+            bound = 1.0 / numpy.sqrt(shape[-1])
+            self.prng.fill(taps, -bound, bound)
+            return taps
+        return self._gaussian(shape, self.out_stddev
+                              if name in self.RESIDUAL_WRITERS else None)
+
     @classmethod
     def apply_with_aux(cls, params, x, **static):
         return decoder_layer(x, params["weights"], params["bias"],
@@ -663,28 +744,54 @@ class DecoderLayer(_DecoderUnit):
 
 class DecoderHead(_DecoderUnit):
     """rms_norm, then float32 logits over the vocabulary rows held:
-    ``weights`` (width, vocab), ``bias`` the norm's gain."""
+    ``weights`` (width, vocab), ``bias`` the norm's gain.  With
+    ``tied_to`` = the embedding's place in the model the head has no
+    matrix of its own: the logits are taken against that unit's (vocab,
+    width) table, which the fused step hands to ``apply`` as
+    ``params["tied"]`` (``compiler._forward_for_loss``) — one array in
+    the state, the moments and a snapshot, its gradient the sum of both
+    uses.  A tied head runs inside the fused step only."""
 
     MAPPING = "decoder_head"
 
     def __init__(self, workflow, **kwargs):
         super(DecoderHead, self).__init__(workflow, **kwargs)
         self.vocab = int(kwargs["vocab"])
+        self.tied_to = kwargs.get("tied_to")
 
     def static_config(self):
-        return {"eps": self.eps, "compute_dtype": _compute_dtype()}
+        static = {"eps": self.eps, "compute_dtype": _compute_dtype()}
+        if self.tied_to is not None:
+            static["tied_to"] = int(self.tied_to)
+        return static
 
     def create_params(self):
         shape = self._seq_shape()
         self._ensure_output(shape[:2] + (self.vocab,))
-        if not self.weights:
+        if self.bias:
+            return  # restored from a snapshot
+        if self.tied_to is None:
             self.weights.mem = self._gaussian((shape[-1], self.vocab))
-            self.bias.mem = numpy.ones((shape[-1],), numpy.float32)
+        self.bias.mem = numpy.ones((shape[-1],), numpy.float32)
+
+    def run(self):
+        if self.tied_to is not None:
+            raise RuntimeError(
+                "%s is tied to layer %d's table, which only the fused "
+                "step hands it: fuse the workflow (the per-unit path "
+                "runs an untied head)" % (self.name, self.tied_to))
+        super(DecoderHead, self).run()
 
     @classmethod
-    def apply(cls, params, x, *, eps=1e-6, compute_dtype="float32"):
+    def apply(cls, params, x, *, eps=1e-6, compute_dtype="float32",
+              tied_to=None):
+        import jax.numpy as jnp
         h = rms_norm(x.astype(compute_dtype), params["bias"], eps)
-        return _dense(h, params["weights"].astype(compute_dtype))
+        if tied_to is None:
+            return _dense(h, params["weights"].astype(compute_dtype))
+        return jnp.einsum("...f,vf->...v", h,
+                          params["tied"].astype(compute_dtype),
+                          preferred_element_type=jnp.float32)
 
 
 # -- gradient-descent units (the per-unit debug path) ------------------------

@@ -153,6 +153,16 @@ def transformer_layers(blocks=2, heads=2, hidden=None, classes=10,
     return spec
 
 
+def _adamw_spec(lr, beta1, beta2, adam_eps, decay, init_std, eps):
+    """What every unit of a decoder takes: AdamW (decay on the matrices
+    only), the initialisation's std and the norms' epsilon."""
+    return {"solver": "adamw", "learning_rate": lr,
+            "gradient_moment": beta1, "adadelta_rho": beta2,
+            "solver_epsilon": adam_eps, "weights_decay": decay,
+            "weights_decay_bias": 0.0, "weights_stddev": init_std,
+            "eps": eps}
+
+
 def mla_moe_decoder_layers(vocab, width, layers, heads, qk_nope, qk_rope,
                            v_head, kv_rank, ffn, experts, experts_held,
                            top_k, expert_width, shared_width,
@@ -171,11 +181,7 @@ def mla_moe_decoder_layers(vocab, width, layers, heads, qk_nope, qk_rope,
     that write into the residual stream (None: ``init_std``, like the
     rest).  Trained with AdamW; the loader serves (B, T) ids and (B, T)
     next ids (``loader.TokenRowLoader``)."""
-    solver = {"solver": "adamw", "learning_rate": lr,
-              "gradient_moment": beta1, "adadelta_rho": beta2,
-              "solver_epsilon": adam_eps, "weights_decay": decay,
-              "weights_decay_bias": 0.0, "weights_stddev": init_std,
-              "eps": eps}
+    solver = _adamw_spec(lr, beta1, beta2, adam_eps, decay, init_std, eps)
     attention = {"heads": heads, "qk_nope": qk_nope, "qk_rope": qk_rope,
                  "v_head": v_head, "kv_rank": kv_rank, "theta": theta}
     routed = {"experts": experts, "experts_held": experts_held,
@@ -191,6 +197,13 @@ def mla_moe_decoder_layers(vocab, width, layers, heads, qk_nope, qk_rope,
                          out_stddev=out_init_std, **attention, **body))
     spec.append(dict(solver, type="decoder_head", vocab=vocab))
     return spec
+
+
+def _layer_kind(kind, known):
+    if kind not in known:
+        raise ValueError("layer_types holds %s, got %r" % (
+            " or ".join("\"%s\"" % name for name in known), kind))
+    return kind
 
 
 def gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
@@ -213,11 +226,7 @@ def gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
     head over the ``vocab`` rows held.  ``post_norm_gain`` is the value
     the gains of the norms after a sub-layer start from.  Solver,
     initialisation and loader as there."""
-    solver = {"solver": "adamw", "learning_rate": lr,
-              "gradient_moment": beta1, "adadelta_rho": beta2,
-              "solver_epsilon": adam_eps, "weights_decay": decay,
-              "weights_decay_bias": 0.0, "weights_stddev": init_std,
-              "eps": eps}
+    solver = _adamw_spec(lr, beta1, beta2, adam_eps, decay, init_std, eps)
     routed = {"experts": experts, "experts_held": experts_held,
               "first_expert": first_expert, "top_k": top_k,
               "expert_width": expert_width, "shared_width": shared_width,
@@ -226,10 +235,7 @@ def gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
     spec = [dict(solver, type="decoder_embedding", vocab=vocab,
                  width=width, scale=embed_scale)]
     for index, kind in enumerate(layer_types):
-        if kind not in ("window", "full"):
-            raise ValueError("layer_types holds \"window\" or \"full\", "
-                             "got %r" % (kind,))
-        windowed = kind == "window"
+        windowed = _layer_kind(kind, ("window", "full")) == "window"
         body = {"ffn": ffn} if index < dense_layers else routed
         spec.append(dict(
             solver, type="decoder_layer", heads=heads,
@@ -238,6 +244,51 @@ def gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
             theta=theta, post_norms=True, post_gain=post_norm_gain,
             **body))
     spec.append(dict(solver, type="decoder_head", vocab=vocab))
+    return spec
+
+
+def conv_gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
+                                head_width, conv_taps, ffn, experts,
+                                experts_held, top_k, expert_width,
+                                dense_layers=1, first_expert=0,
+                                routed_scale=1.0, route_eps=0.0, theta=1e6,
+                                eps=1e-5, tied_head=True, lr=1e-4,
+                                beta1=0.9, beta2=0.95, adam_eps=1e-8,
+                                decay=0.1, init_std=0.02,
+                                out_init_std=None, router_bias_std=0.0):
+    """A causal decoder whose token mixer is a gated short convolution
+    in most layers (models/decoder.py): token embedding, one pre-norm
+    layer for each entry of ``layer_types`` — ``"conv"`` (a depthwise
+    causal filter of ``conv_taps`` taps between two gates) or
+    ``"attention"`` (``heads`` query heads reading ``kv_heads``
+    key/value heads ``head_width`` wide over every earlier key, rotary
+    positions, QK-norm, no output gate) — the first ``dense_layers``
+    with a gated feed-forward ``ffn`` wide, the rest routed as
+    :func:`mla_moe_decoder_layers` routes but with NO shared expert,
+    and the output head over the ``vocab`` rows held, tied to the
+    embedding's table unless ``tied_head`` is False.  Solver,
+    initialisation (``out_init_std`` for the matrices that write into
+    the residual stream) and loader as there."""
+    solver = _adamw_spec(lr, beta1, beta2, adam_eps, decay, init_std, eps)
+    mixers = {"conv": {"conv_taps": conv_taps},
+              "attention": {"heads": heads, "kv_heads": kv_heads,
+                            "head_width": head_width, "rope": True,
+                            "out_gate": False, "theta": theta}}
+    routed = {"experts": experts, "experts_held": experts_held,
+              "first_expert": first_expert, "top_k": top_k,
+              "expert_width": expert_width, "shared_width": 0,
+              "routed_scale": routed_scale, "route_eps": route_eps,
+              "router_bias_stddev": router_bias_std}
+    spec = [dict(solver, type="decoder_embedding", vocab=vocab,
+                 width=width)]
+    for index, kind in enumerate(layer_types):
+        body = {"ffn": ffn} if index < dense_layers else routed
+        spec.append(dict(solver, type="decoder_layer",
+                         out_stddev=out_init_std,
+                         **mixers[_layer_kind(kind, tuple(mixers))],
+                         **body))
+    head = {"tied_to": 0} if tied_head else {}
+    spec.append(dict(solver, type="decoder_head", vocab=vocab, **head))
     return spec
 
 

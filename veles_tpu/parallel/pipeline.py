@@ -237,6 +237,9 @@ def build_pipeline_train_step(plans, loss="softmax", mesh=None,
 
     if mesh is None:
         raise ValueError("build_pipeline_train_step needs a mesh")
+    _compiler.refuse_tied_plans(
+        plans, "the pipeline step (each stage walks a slice of the "
+        "layers)")
     start, stop = _stage_split(plans)
     n_stages = mesh.shape[axis]
     n_blocks = stop - start

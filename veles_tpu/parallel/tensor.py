@@ -358,6 +358,7 @@ def build_tp_train_step(plans, loss="softmax", mesh=None,
 
     if mesh is None:
         raise ValueError("build_tp_train_step needs a mesh")
+    _compiler.refuse_tied_plans(plans, "the tensor-parallel step")
     n = mesh.shape[model_axis]
     tp_flags = [_tp_plan(p) for p in plans]
     if not any(tp_flags):

@@ -87,9 +87,11 @@ def walk_forward(plans, params, x, layer_fn):
     arithmetic; dropout layers never reach it."""
     import jax
 
+    from veles_tpu.compiler import refuse_tied_plans
     from veles_tpu.models.all2all import All2AllSoftmax
     from veles_tpu.models.dropout import DropoutForward
 
+    refuse_tied_plans(plans, "the quantized forward")
     h = x
     for i, (plan, entry) in enumerate(zip(plans, params)):
         if issubclass(plan.forward_cls, DropoutForward):
