@@ -504,12 +504,18 @@ def test_builders_that_cannot_honour_the_tie_refuse_it(_precision,
 
 #: sha256 of the lowered train step of ``tests/test_decoder_gqa.py``'s
 #: toy (an UNTIED plan: window, window, full; gate, shared expert,
-#: sandwich norms) at the parent commit, written down before this file's
-#: program changes were made: the walk's new branch, the solver loop's
-#: new shape and the layer's new parts leave an untied plan's program
-#: text for text what it was
+#: sandwich norms).  PR 35 wrote down the parent's
+#: (``9a997158...c09c7``) to show that the walk's new branch, the solver
+#: loop's new shape and the layer's new parts left an untied plan's
+#: program text for text what it was.  PR 36 moved it: the toy has two
+#: routed layers, whose stretch from the tokens to the sum over a
+#: token's slots became ``decoder._expert_rows``' loops over the filled
+#: rows (the parent's text is 332,306 characters, this one 370,872: a
+#: loop body in each rule).  The programs without a routed layer did
+#: not move: the MNIST MLP's lowered step has the parent's sha256
+#: (``PERF.md`` section 6)
 UNTIED_STEP_DIGEST = (
-    "9a997158d5e88fd2a98ce95db1a33f8a4fca6b23f127137a403e5ba9758c09c7")
+    "cf3bef7fabb66696fcb1f8e6ff2b4aa400e5bb337dd986c0eda22ebd3d396ef9")
 
 
 def test_an_untied_plans_program_text_is_unchanged(_precision):

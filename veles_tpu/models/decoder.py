@@ -78,8 +78,10 @@ own experts in a buffer, and runs grouped products
 left out — that partial result goes on — and nothing stands in for the
 chips that hold them or for the exchange.  The buffer holds the most a
 step can send: tokens x min(top_k, experts held) rows, so nothing is
-ever dropped (the grouped products pay for the rows that are filled, the
-elementwise passes for the whole buffer).  A layer built with a smaller
+ever dropped, and a step pays for the rows it filled: everything between
+the sort and the sum over a token's slots walks them in chunks of
+``CHUNK`` rows and stops at the fill (:func:`_expert_rows`).  A layer
+built with a smaller
 ``capacity`` (no factory or configuration sets one) DROPS the
 assignments that do not fit and counts them (``moe_dropped``): the layer
 never drops one silently.
@@ -289,51 +291,151 @@ def gated_ffn(m, w_gate, w_up, w_down):
                   w_down).astype(m.dtype)
 
 
+#: rows of the routed buffer one step of :func:`routed_experts`' loops
+#: works over (a multiple of the 512-row tile; ``PERF.md`` section 6 has
+#: the chip readings that chose it).  A smaller buffer is one chunk.
+CHUNK = 8192
+
+
+def _gather_sum(rows, pos, valid):
+    """``sum_k rows[pos[:, k]]`` over the valid slots, (N, D): a gather a
+    slot (the kept assignments are a permutation, which XLA's scatter
+    cannot know), summed in float32."""
+    import jax.numpy as jnp
+    total = None
+    for j in range(pos.shape[1]):
+        picked = jnp.where(valid[:, j, None],
+                           rows[jnp.where(valid[:, j], pos[:, j], 0)],
+                           0).astype(jnp.float32)
+        total = picked if total is None else total + picked
+    return total.astype(rows.dtype)
+
+
 @functools.lru_cache(maxsize=None)
-def _row_moves():
-    """(dispatch, combine): ``dispatch(src, token_of, pos, valid)`` is
-    ``src[token_of]`` (C, D); ``combine(rows, token_of, pos, valid)`` is
-    ``sum_k rows[pos[:, k]]`` over the valid slots (N, D).  Each is the
-    other's transpose, and both are written as gathers: the kept
-    assignments are a permutation, which XLA's scatter cannot know."""
+def _expert_rows(chunk):
+    """``expert_rows(m, w_row, e_gate, e_up, e_down, token_of, pos, valid,
+    reach, filled)`` -> the (N, D) weighted sum of the held experts'
+    outputs: the stretch of :func:`routed_experts` from the tokens to
+    the sum, walked over the buffer's rows ``[0, filled)`` in chunks of
+    ``chunk`` rows and over no chunk beyond them.  Row ``r`` holds token
+    ``token_of[r]`` under weight ``w_row[r]``, expert ``e``'s rows end at
+    ``reach[e]``, and ``pos``/``valid`` (N, K) say which row each of a
+    token's slots went to.  The loops' bound is a device scalar, so they
+    lower to ``while`` and the backward is written, not derived: the
+    dispatch gather ``m[token_of]`` and the sum over a token's slots are
+    each the other's transpose, as gathers both ways."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
+    f32 = jnp.float32
 
-    def gather_sum(rows, pos, valid):
-        total = None
-        for j in range(pos.shape[1]):
-            picked = jnp.where(valid[:, j, None],
-                               rows[jnp.where(valid[:, j], pos[:, j], 0)],
-                               0).astype(jnp.float32)
-            total = picked if total is None else total + picked
-        return total.astype(rows.dtype)
+    def grouped(rows, experts, sizes):
+        return lax.ragged_dot(rows, experts, sizes,
+                              preferred_element_type=f32)
 
-    @jax.custom_vjp
-    def dispatch(src, token_of, pos, valid):
-        return src[token_of]
+    def back(g, experts, sizes):
+        """``grouped``'s transpose in its rows."""
+        return grouped(g, jnp.swapaxes(experts, 1, 2), sizes)
 
-    def dispatch_fwd(src, token_of, pos, valid):
-        return src[token_of], (token_of, pos, valid)
+    #: rows (R, A) x cotangents (R, B) -> (E, A, B): ``grouped``'s
+    #: transpose in the experts, in float32 for the carries
+    into_experts = lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(([0], [0]), ([], [])),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
-    def dispatch_bwd(res, g):
-        token_of, pos, valid = res
-        return gather_sum(g, pos, valid), None, None, None
+    def weight_grad(rows, g, sizes):
+        return lax.ragged_dot_general(rows, g, sizes, into_experts,
+                                      preferred_element_type=f32)
 
-    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+    def act(gate, up, dtype):
+        return (jax.nn.silu(gate) * up).astype(dtype)
 
-    @jax.custom_vjp
-    def combine(rows, token_of, pos, valid):
-        return gather_sum(rows, pos, valid)
+    def chunk_of(i, token_of, w_row, reach, filled):
+        """Chunk ``i``: (its first row, its rows' tokens and weights,
+        which of them are filled, the rows of it each expert owns)."""
+        r0 = i * chunk
+        tok = lax.dynamic_slice(token_of, (r0,), (chunk,))
+        w = lax.dynamic_slice(w_row, (r0,), (chunk,))
+        live = r0 + jnp.arange(chunk, dtype=jnp.int32) < filled
+        edge = jnp.clip(reach, r0, r0 + chunk)
+        sizes = edge - jnp.concatenate([r0[None], edge[:-1]])
+        return r0, tok, w[:, None], live[:, None], sizes
 
-    def combine_fwd(rows, token_of, pos, valid):
-        return gather_sum(rows, pos, valid), (token_of, pos, valid)
+    def trips(filled):
+        return (filled + chunk - 1) // chunk
 
-    def combine_bwd(res, g):
-        token_of, pos, valid = res
-        return g[token_of], None, None, None
+    def rows_in(m, tok, e_gate, e_up, sizes):
+        """A chunk's tokens and their two pre-activations."""
+        xs = m[tok]
+        return xs, grouped(xs, e_gate, sizes), grouped(xs, e_up, sizes)
 
-    combine.defvjp(combine_fwd, combine_bwd)
-    return dispatch, combine
+    def forward(m, w_row, e_gate, e_up, e_down, token_of, pos, valid,
+                reach, filled):
+        def step(i, y):
+            r0, tok, w, live, sizes = chunk_of(
+                i, token_of, w_row, reach, filled)
+            _, gate, up = rows_in(m, tok, e_gate, e_up, sizes)
+            y_c = grouped(act(gate, up, m.dtype), e_down, sizes)
+            return lax.dynamic_update_slice(
+                y, jnp.where(live, y_c * w, 0.0).astype(m.dtype), (r0, 0))
+
+        y = lax.fori_loop(0, trips(filled), step, jnp.zeros(
+            (token_of.shape[0], m.shape[1]), m.dtype))
+        return _gather_sum(y, pos, valid)
+
+    def fwd(*args):
+        # no row is kept for the backward, whose loop computes a chunk's
+        # ``xs`` and pre-activations again: under a layer's checkpoint
+        # this rule is the recomputation, and where the layer's output
+        # is only added to the stream its loop is then dead code (the
+        # chip, PERF.md section 6: cheaper than filling three more
+        # whole buffers, even where the loop stays)
+        return forward(*args), args
+
+    def bwd(res, g_out):
+        (m, w_row, e_gate, e_up, e_down, token_of, pos, valid, reach,
+         filled) = res
+        dtype = m.dtype
+
+        def step(i, carry):
+            g_xs, g_w, *sums = carry
+            r0, tok, w, live, sizes = chunk_of(
+                i, token_of, w_row, reach, filled)
+            xs, gate, up = rows_in(m, tok, e_gate, e_up, sizes)
+            hidden, act_back = jax.vjp(
+                lambda gate, up: act(gate, up, dtype), gate, up)
+            g_y = jnp.where(live, g_out[tok], 0)
+            # <g_y, hidden E_down> = <g_y E_down^T, hidden>: the weight's
+            # gradient without the down product's output
+            g_hidden = back(g_y, e_down, sizes)
+            g_w_c = jnp.where(live, jnp.sum(
+                g_hidden * hidden.astype(f32), axis=1, keepdims=True), 0)
+            pre = [g.astype(dtype)
+                   for g in act_back((g_hidden * w).astype(dtype))]
+            g_xs_c = jnp.where(live, back(pre[0], e_gate, sizes)
+                               + back(pre[1], e_up, sizes), 0)
+            grads = [weight_grad(xs, pre[0], sizes),
+                     weight_grad(xs, pre[1], sizes),
+                     weight_grad(hidden, (g_y.astype(f32) * w).astype(dtype),
+                                 sizes)]
+            return (lax.dynamic_update_slice(
+                        g_xs, g_xs_c.astype(dtype), (r0, 0)),
+                    lax.dynamic_update_slice(g_w, g_w_c[:, 0], (r0,)),
+                    *(total + g for total, g in zip(sums, grads)))
+
+        g_xs, g_w, g_gate, g_up, g_down = lax.fori_loop(
+            0, trips(filled), step, (
+                jnp.zeros((token_of.shape[0], m.shape[1]), dtype),
+                jnp.zeros(w_row.shape, f32),
+                *(jnp.zeros(e.shape, f32)
+                  for e in (e_gate, e_up, e_down))))
+        return (_gather_sum(g_xs, pos, valid), g_w.astype(w_row.dtype),
+                g_gate.astype(e_gate.dtype), g_up.astype(e_up.dtype),
+                g_down.astype(e_down.dtype), None, None, None, None, None)
+
+    expert_rows = jax.custom_vjp(forward)
+    expert_rows.defvjp(fwd, bwd)
+    return expert_rows
 
 
 def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
@@ -345,19 +447,18 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
     ``e_down`` (E_held, F, D) the experts ``first_expert ..
     first_expert + E_held - 1``.  Assignments are sorted by expert and
     those of the held experts fill a ``capacity``-row buffer (None: the
-    N x min(K, E_held) rows a step can send at most); grouped products
-    run over it.  Returns (out (N, D), aux) with aux
-    ``moe_load`` (E_held,) tokens routed to each held expert,
-    ``moe_assignments`` their sum and ``moe_dropped`` how many did not
-    fit the buffer."""
-    import jax
+    N x min(K, E_held) rows a step can send at most); the grouped
+    products and every pass over rows walk the rows a step FILLED, in
+    chunks of ``CHUNK`` (:func:`_expert_rows`).  Returns (out (N, D),
+    aux) with aux ``moe_load`` (E_held,) tokens routed to each held
+    expert, ``moe_assignments`` their sum, ``moe_dropped`` how many did
+    not fit the buffer and ``moe_visited_rows`` the rows of the chunks
+    walked."""
     import jax.numpy as jnp
-    from jax import lax
     n, k = idx.shape
     held = e_gate.shape[0]
     if capacity is None:
         capacity = n * min(k, held)
-    dispatch, combine = _row_moves()
     local = idx - first_expert
     is_held = (local >= 0) & (local < held)
     # one key an assignment; the experts not held come last.  A counting
@@ -375,26 +476,20 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
         jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
     load = count[:held]
     kept = jnp.sum(load)
+    filled = jnp.minimum(kept, capacity)
     reach = jnp.minimum(jnp.cumsum(load), capacity)
-    sizes = jnp.diff(reach, prepend=0).astype(jnp.int32)
-    slot = order[:capacity]                         # row -> assignment
+    # whole chunks: the rows past ``capacity`` are never filled
+    rows = min(CHUNK, capacity)
+    slot = jnp.pad(order[:capacity],                # row -> assignment
+                   (0, -capacity % rows))
     token_of = (slot // k).astype(jnp.int32)
-    row_valid = jnp.arange(capacity) < jnp.minimum(kept, capacity)
     valid = (is_held.reshape(-1) & (pos < capacity)).reshape(n, k)
-    pos = pos.reshape(n, k)
-
-    xs = dispatch(m, token_of, pos, valid)          # (C, D)
-    hidden = (jax.nn.silu(lax.ragged_dot(
-        xs, e_gate, sizes, preferred_element_type=jnp.float32))
-        * lax.ragged_dot(xs, e_up, sizes,
-                         preferred_element_type=jnp.float32))
-    y = lax.ragged_dot(hidden.astype(m.dtype), e_down, sizes,
-                       preferred_element_type=jnp.float32)
-    w_row = weights.reshape(-1)[slot]
-    y = jnp.where(row_valid[:, None], y * w_row[:, None], 0.0)
-    out = combine(y.astype(m.dtype), token_of, pos, valid)
+    out = _expert_rows(rows)(
+        m, weights.reshape(-1)[slot], e_gate, e_up, e_down, token_of,
+        pos.reshape(n, k), valid, reach, filled)
     aux = {"moe_load": load, "moe_assignments": kept,
-           "moe_dropped": jnp.maximum(kept - capacity, 0)}
+           "moe_dropped": jnp.maximum(kept - capacity, 0),
+           "moe_visited_rows": (filled + rows - 1) // rows * rows}
     return out, aux
 
 
@@ -684,7 +779,8 @@ class DecoderLayer(_DecoderUnit):
     #: layer as ``<name>.l<layer>.e<element>``
     AUX_COUNTERS = {"moe_assignments": "moe.assignments",
                     "moe_dropped": "moe.dropped_assignments",
-                    "moe_load": "moe.load"}
+                    "moe_load": "moe.load",
+                    "moe_visited_rows": "moe.visited_rows"}
 
     def __init__(self, workflow, **kwargs):
         super(DecoderLayer, self).__init__(workflow, **kwargs)
