@@ -1,0 +1,16 @@
+"""Fused step (device): device ms per traced train step in the leaf
+instructions under the step's ``update`` scope: the gradient norm and
+the finite guard, the solver's passes over parameters and moments, the
+skip-select (``benchmark/scope_metrics.py``)."""
+
+from benchmark import scope_metrics
+
+LAYER = "Fused step (device)"
+UNIT = "ms"
+MOVES = "train_images_per_s"
+SOURCE = "device_trace"
+
+
+def read(context):
+    return scope_metrics.ms_per_step_where(
+        context, lambda layer, part, phase: phase == "update")

@@ -56,6 +56,35 @@ STALE_BENCHMARK_TESTS = {
     "test_accepted_entries_keep_their_order_and_new_ones_follow":
         "asserts PR 29's metrics are last; PR 33's entries follow them "
         "(tests/benchmark/test_trinity_mini.py holds the rule as a prefix)",
+    # the same trap a cell deep: each compares its CELL's list of
+    # per-layer metrics with one PR's, which the next append that lists
+    # the cell ends (PR 37's twelve list five cells);
+    # tests/benchmark/test_scope_metrics.py runs each as it stands on the
+    # entries its PR knew, so all it asserts is still asserted
+    "tests/benchmark/test_train_lm.py::"
+    "test_lm_runner_yields_every_declared_metric":
+        "asserts the kanana cell reports PR 29's metrics only",
+    "tests/benchmark/test_trinity_mini.py::"
+    "test_each_reader_on_a_made_up_trace_and_registry":
+        "asserts the trinity cell reports PR 33's metrics only",
+    "tests/benchmark/test_lfm2.py::test_each_reader_on_a_made_up_trace":
+        "asserts the lfm2 cell reports PR 35's metrics only",
+    # and these want a made-up trace, after a whole toy run, to give
+    # their PR's readers something and no other reader of the cell
+    # anything: the twelve read any trace (what its program's table does
+    # not know is unattributed, ISSUE 37's rule)
+    "tests/benchmark/test_lfm2.py::"
+    "test_the_accepted_runner_at_toy_width_and_two_rows":
+        "asserts a trace gives the lfm2 cell PR 35's metrics only",
+    "tests/benchmark/test_trinity_mini.py::"
+    "test_the_runners_at_toy_width[train_lm-2]":
+        "asserts a trace gives the trinity cell PR 33's metrics only",
+    "tests/benchmark/test_trinity_mini.py::"
+    "test_the_runners_at_toy_width[train_lm-1]":
+        "asserts a trace gives the trinity cell PR 33's metrics only",
+    "tests/benchmark/test_trinity_mini.py::"
+    "test_the_runners_at_toy_width[train_lm_b1-1]":
+        "asserts a trace gives the trinity cell PR 33's metrics only",
 }
 
 

@@ -324,7 +324,7 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
         else:
             out = _forward_for_loss(plans, params, x, key,
                                     remat=bwd_remat, aux=collected)
-        with jax.named_scope("loss"):
+        with jax.named_scope(SCOPE_LOSS):
             value, metric = loss_of(out, target, batch_size)
         return value, (metric, _stack_layer_aux(collected))
 
@@ -386,7 +386,10 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
             # Poisons inject before the sync so a chaos fault on one
             # shard spreads (like a real bad chip) and the finiteness
             # guard below skips the step uniformly on every replica.
-            grads = grad_sync(grads)
+            # Under its own scope: the merge ends the backward, and a
+            # device trace must not book it to the guard that reads it.
+            with jax.named_scope(SCOPE_GRAD_SYNC):
+                grads = grad_sync(grads)
         if metric_sync is not None:
             loss_value = metric_sync(loss_value)
             aux = metric_sync(aux)
@@ -398,7 +401,7 @@ def _build_step_fn(plans, loss, grad_sync=None, metric_sync=None,
         # gradients makes the squared-sum non-finite, so isfinite of
         # the norm covers every leaf; both flags stay LAZY device
         # scalars riding the existing metrics result — no host sync
-        with jax.named_scope("update"):
+        with jax.named_scope(SCOPE_UPDATE):
             if zero_update is not None:
                 # ZeRO-1: reduce-scatter + sharded update + all-gather in
                 # one coupled unit; the grad-norm's squared-sum comes back
@@ -1045,3 +1048,16 @@ def build_eval_epoch(plans, batch, loss="softmax",
         return {name: total, "samples": count}
 
     return jax.jit(epoch, compiler_options=compiler_options or None)
+
+
+#: the step's own ``jax.named_scope`` names beside the layers', each with
+#: the phase of the step everything under it is (``xla_introspect.
+#: scope_of``); None: the loss is differentiated, its wrappers say.
+#: (At the END of the file: a Pallas kernel's serialized body holds the
+#: line numbers of the frames that called it, so a line added above
+#: ``_forward_for_loss`` changes the lowered step's text.)
+SCOPE_LOSS = "loss"
+SCOPE_GRAD_SYNC = "grad_sync"
+SCOPE_UPDATE = "update"
+STEP_SCOPES = {SCOPE_LOSS: None, SCOPE_GRAD_SYNC: "backward",
+               SCOPE_UPDATE: "update"}

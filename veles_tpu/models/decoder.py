@@ -837,6 +837,12 @@ class DecoderLayer(_DecoderUnit):
     def apply(cls, params, x, **static):
         return cls.apply_with_aux(params, x, **static)[0]
 
+    #: the ``jax.named_scope`` names of a layer's parts: what
+    #: ``xla_introspect.scope_of`` reads as the ``part`` of an
+    #: instruction under an ``l<k>_DecoderLayer`` scope
+    PART_SCOPES = (SCOPE_ATTENTION, SCOPE_CONV, SCOPE_ROUTER, SCOPE_ROUTED,
+                   SCOPE_SHARED, SCOPE_FFN)
+
 
 class DecoderHead(_DecoderUnit):
     """rms_norm, then float32 logits over the vocabulary rows held:

@@ -304,6 +304,7 @@ class FusedTrainer(Unit):
         per step)."""
         import jax
 
+        from veles_tpu.compiler import STEP_SCOPES
         from veles_tpu.observe import xla_introspect as _xla
         self._step_flops_ = 0.0
 
@@ -311,7 +312,13 @@ class FusedTrainer(Unit):
             if leaf is None:
                 return None
             if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
-                return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+                # a committed array's sharding is part of the lowering
+                # (and of the key jax caches it under): with it the
+                # description is the call's own
+                return jax.ShapeDtypeStruct(
+                    leaf.shape, leaf.dtype,
+                    sharding=leaf.sharding if getattr(
+                        leaf, "committed", False) else None)
             return leaf
 
         args = [jax.tree.map(aval, self._state,
@@ -325,6 +332,12 @@ class FusedTrainer(Unit):
         eval_args = [params, aval(x), aval(target)]
         if self.loss != "softmax":
             eval_args.append(aval(batch_size))
+        # shapes only: the table of what each instruction belongs to is
+        # built when somebody asks (xla_introspect.instruction_scopes)
+        _xla.describe("fused.step", args, kwargs, parts=sorted(
+            {part for plan in self._plans
+             for part in getattr(plan.forward_cls, "PART_SCOPES", ())}),
+            step_scopes=STEP_SCOPES)
         try:
             # pre-compile estimate ONLY: a .compile() here would
             # synchronously rebuild a step that takes minutes on a

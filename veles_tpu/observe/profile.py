@@ -94,7 +94,12 @@ class ProfilerHook(object):
         self.state = "tracing"
 
     def stop(self):
-        """Idempotent: stop tracing if the window is still open."""
+        """Idempotent: stop tracing if the window is still open, and
+        write ``device_scopes.json`` beside the trace it closes: which
+        scope each instruction of every described program (the fused
+        step) belongs to (``xla_introspect.instruction_scopes``), for
+        ``scripts/trace_scopes.py`` to divide the trace's device time
+        by (docs/observability.md)."""
         if self.state != "tracing":
             self.state = "done"
             return
@@ -103,7 +108,32 @@ class ProfilerHook(object):
             import jax
             jax.profiler.stop_trace()
         except Exception:
-            pass
+            return
+        self._write_device_scopes()
+
+    def _write_device_scopes(self):
+        import glob
+
+        from veles_tpu.observe import xla_introspect
+        programs = {}
+        for name in xla_introspect.described():
+            table = xla_introspect.instruction_scopes(name)
+            if table is not None:  # else the watcher said why
+                programs[name] = dict(xla_introspect.scope_names(name),
+                                      instructions=table)
+        if not programs:  # not a fused workflow: nothing to say
+            return None
+        traces = sorted(glob.glob(os.path.join(
+            self.logdir, "plugins", "profile", "*", "*.xplane.pb")))
+        path = os.path.join(
+            os.path.dirname(traces[-1]) if traces else self.logdir,
+            "device_scopes.json")
+        try:
+            with open(path, "w") as fout:
+                json.dump(programs, fout)
+        except OSError:
+            return None
+        return path
 
 
 _hook = None

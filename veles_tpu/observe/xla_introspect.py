@@ -23,11 +23,25 @@ the TPU-systems literature calls out as the ones that matter:
   MFU: on the CPU platform the gauge stays unpublished, and a chip
   whose ``device_kind`` is not in the table is an error.
 
+- **device time by the program's own scopes** — the device's
+  counterpart of ``tracer.scope``: every layer's ops run under
+  ``jax.named_scope`` (``l<k>_<Class>``, ``loss``, ``update``, a decoder
+  layer's parts), which XLA carries in each instruction's ``op_name``.
+  :func:`describe` keeps the SHAPES a watched program was called with
+  and the names of its scopes (none is written down here),
+  :func:`instruction_scopes` asks the compiled program for its
+  ``{instruction: op_name}`` table when somebody wants it (never on a
+  run's own path), :func:`scope_of` reads (layer, part, phase) out of an
+  ``op_name`` and :func:`device_seconds_by_scope` joins the table with a
+  profiler trace's seconds by instruction (docs/observability.md).
+
 Everything here imports jax lazily and is called OFF the step path
 (compile time, heartbeat thread, decision class end), preserving the
 observe-package invariant that telemetry never adds a host sync.
 """
 
+import re
+import sys
 import threading
 
 from veles_tpu.observe.metrics import percentiles
@@ -38,7 +52,9 @@ __all__ = ["CompileWatcher", "watcher", "ensure_installed", "watch",
            "set_fwd_flops", "set_step_dtype", "step_dtype",
            "peak_flops", "mfu_snapshot", "bwd_snapshot",
            "compile_snapshot", "compile_delta", "PEAKS",
-           "device_peaks"]
+           "device_peaks", "describe", "described", "instruction_scopes",
+           "scope_names", "instruction_key", "parse_instruction_scopes",
+           "scope_of", "device_seconds_by_scope"]
 
 #: THE peaks table: one row per chip, keyed by the exact
 #: ``device_kind`` string jax reports, with where the numbers come
@@ -87,6 +103,10 @@ class CompileWatcher(object):
         self.installed = False
         self._lock = threading.Lock()
         self._watched = {}  # name -> [fn, last_size, warned]
+        # name -> (abstract args, abstract kwargs, the names of its
+        # scopes): shapes only, no array is kept alive
+        self._described = {}
+        self._scopes = {}  # name -> {instruction key: op_name} | None
 
     # -- global compile accounting ----------------------------------------
 
@@ -131,11 +151,85 @@ class CompileWatcher(object):
             return False
         with self._lock:
             self._watched[name] = [fn, 0, False]
+            # another program: the old one's arguments and table go
+            self._described.pop(name, None)
+            self._scopes.pop(name, None)
         return True
 
     def unwatch(self, name):
         with self._lock:
             self._watched.pop(name, None)
+            self._described.pop(name, None)
+            self._scopes.pop(name, None)
+
+    # -- what each instruction of a watched program belongs to ------------
+
+    def describe(self, name, args, kwargs=None, parts=(),
+                 step_scopes=None):
+        """Keep the abstract arguments (``jax.ShapeDtypeStruct`` leaves:
+        shape, dtype and, where the real array was committed to a
+        device or a mesh, its sharding) the watched program ``name`` was
+        called with, and the names of its scopes as :func:`scope_of`
+        takes them: ``parts``, what its layers' classes name
+        (``DecoderLayer.PART_SCOPES``), and ``step_scopes``, the step's
+        own beside the layers' with the phase each stands for
+        (``compiler.STEP_SCOPES``).  Nothing is lowered or compiled
+        here; a new description drops the table of the old one."""
+        with self._lock:
+            self._described[name] = (
+                tuple(args), dict(kwargs or {}),
+                {"parts": list(parts),
+                 "step_scopes": dict(step_scopes or {})})
+            self._scopes.pop(name, None)
+
+    def described(self):
+        """The names of the programs that have a description."""
+        with self._lock:
+            return sorted(self._described)
+
+    def scope_names(self, name):
+        """``{"parts": [...], "step_scopes": {...}}`` as ``name`` was
+        described: the keywords of :func:`scope_of` and
+        :func:`device_seconds_by_scope`."""
+        with self._lock:
+            described = self._described.get(name)
+        return dict(described[2]) if described else {}
+
+    def instruction_scopes(self, name):
+        """``{instruction key: op_name}`` of the watched program's
+        optimised HLO (:func:`parse_instruction_scopes`), built on the
+        first call from ``fn.lower(*args, **kwargs).compile()`` at the
+        described arguments and kept; the text is dropped once parsed.
+        The description is the real call's, so jax hands back the
+        lowering and the executable it already holds: no compile
+        request at all, on the CPU and on the chip (a description that
+        misses the call's signature is a whole new compile: repair the
+        description).
+        None, and one warning line, for a program that is not watched or
+        not described, a jax without ``as_text`` or a compile that
+        fails: nothing here raises."""
+        with self._lock:
+            if name in self._scopes:
+                return self._scopes[name]
+            watched = self._watched.get(name)
+            described = self._described.get(name)
+        table = None
+        try:
+            if watched is None or described is None:
+                raise LookupError(
+                    "no program is watched and described under that name")
+            args, kwargs, names = described
+            text = watched[0].lower(*args, **kwargs).compile().as_text()
+            table = parse_instruction_scopes(text, names["step_scopes"])
+            del text
+        except Exception as exc:
+            import logging
+            logging.getLogger("xla").warning(
+                "no instruction scopes for %s: %s: %s", name,
+                type(exc).__name__, exc)
+        with self._lock:
+            self._scopes[name] = table
+        return table
 
     def poll(self, warn=None):
         """Refresh watched cache sizes; returns {name: size}.  Called
@@ -185,6 +279,22 @@ def watch(fn, name):
 
 def poll_recompiles():
     return watcher.poll()
+
+
+def describe(name, args, kwargs=None, parts=(), step_scopes=None):
+    return watcher.describe(name, args, kwargs, parts, step_scopes)
+
+
+def described():
+    return watcher.described()
+
+
+def instruction_scopes(name):
+    return watcher.instruction_scopes(name)
+
+
+def scope_names(name):
+    return watcher.scope_names(name)
 
 
 def compile_snapshot(reg=None):
@@ -433,4 +543,189 @@ def bwd_snapshot(reg=None):
         out["bwd_mfu_pct"] = round(
             100.0 * bwd_flops / bwd_s / peak, 3)
         reg.gauge("bwd.mfu_pct").set(out["bwd_mfu_pct"])
+    return out
+
+
+# -- device time by the program's own scopes ---------------------------------
+
+#: opcodes whose profiler event spans the events of a body that the same
+#: line also holds: left out of every sum, so nothing is counted twice
+CONTAINER_OPCODES = frozenset(("while", "conditional", "call"))
+
+_NOT_SHAPE = re.compile(r"\{[^{}]*\}|/\*[^*]*\*/\s*")
+_LAYER = re.compile(r"l\d+_(\w+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_REFERENCE = re.compile(r"%[\w.\-]+")
+_WRAPPER = re.compile(r"\b(?:jvp|transpose|vmap)\(")
+_HLO_LINE = re.compile(r"^\s*(ROOT\s+)?(%\S+ = .*)$")
+
+
+def _split_instruction(text):
+    """(name, result type as written, what follows it) of an HLO
+    instruction's text ``%name = <type> opcode(...)...``, or None."""
+    name, eq, rest = text.partition(" = ")
+    if not eq or not name.startswith("%"):
+        return None
+    if rest.startswith("("):  # a tuple: to its closing parenthesis
+        depth = 0
+        for end, char in enumerate(rest):
+            depth += (char == "(") - (char == ")")
+            if not depth:
+                break
+        end += 1
+    else:
+        end = rest.find(" ")
+        end = len(rest) if end < 0 else end
+    return name, rest[:end], rest[end:].lstrip()
+
+
+def instruction_key(text):
+    """``%fusion.421 f32[8192,25024]``: an instruction's name and result
+    shape, layouts dropped — the key the table of the program and the
+    events of a trace are joined on (names repeat across the programs of
+    one trace; a name with its shape hardly does).  None for a text that
+    is no instruction."""
+    found = _split_instruction(text)
+    return None if found is None else _key(found)
+
+
+def _key(found):
+    # layouts and the printer's /*index=5*/ marks are not the shape
+    return "%s %s" % (found[0], _NOT_SHAPE.sub("", found[1]))
+
+
+def _opcode(found):
+    # ``while`` of ``%while.15 = (...) while(...)``
+    return found[2].split("(", 1)[0].strip()
+
+
+def parse_instruction_scopes(hlo_text, step_scopes=None):
+    """{:func:`instruction_key`: ``op_name``} of every instruction of
+    every computation in an optimised HLO module's text — loop bodies
+    and the callers of fused computations among them.
+
+    An instruction whose own ``op_name`` names no scope inherits by the
+    program's structure alone: a fusion has its fused computation's
+    root's ``op_name``; an instruction of a called computation (a
+    loop's body, the expansion of a ``ragged_dot`` inside it) has that
+    of the instruction that calls it.  Nothing is guessed from users or
+    operands: what the compiler made outside every scope (a whole
+    vector's ``convert``, layout copies, zero fills) keeps the
+    ``op_name`` it had, ``""`` where it had none, and a join books it
+    under ``(None, None, None)``."""
+    def names_a_scope(op_name):
+        return scope_of(op_name, (), step_scopes)[0] is not None
+
+    computations, caller, root = _parse_computations(hlo_text)
+    resolved = {}  # instruction name -> the op_name it ends up with
+    table = {}
+    for name, instructions in reversed(computations):  # callers first
+        inherited = resolved.get(caller.get(name), "")
+        for inst, key, op_name, refs in instructions:
+            if not names_a_scope(op_name):
+                structural = [root[r] for r in refs if r in root]
+                op_name = next(filter(
+                    names_a_scope, structural + [inherited]), op_name)
+            resolved[inst] = op_name
+            # one string an op_name, however many instructions have it
+            table[key] = sys.intern(op_name)
+    return table
+
+
+def _parse_computations(hlo_text):
+    """([(computation, [(instruction name, key, own op_name, the names
+    its text refers to)])] in the text's order, {computation: the first
+    instruction that calls it}, {computation: its root's op_name})."""
+    computations, caller, root = [], {}, {}
+    current = None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and " = " not in line.split("(", 1)[0]:
+            head = line.split("(", 1)[0].split()  # [ENTRY] %name
+            current = (head[-1], []) if head else None
+            if current:
+                computations.append(current)
+            continue
+        match = _HLO_LINE.match(line)
+        if match is None or current is None:
+            continue
+        found = _split_instruction(match.group(2))
+        if found is None:
+            continue
+        named = _OP_NAME.search(line)
+        op_name = named.group(1) if named else ""
+        refs = _REFERENCE.findall(found[2].split(", metadata=", 1)[0])
+        current[1].append((found[0], _key(found), op_name, refs))
+        if match.group(1):
+            root[current[0]] = op_name
+    names = {name for name, _ in computations}
+    for _, instructions in computations:
+        for inst, _, _, refs in instructions:
+            for ref in refs:
+                if ref in names:
+                    caller.setdefault(ref, inst)
+    return computations, caller, root
+
+
+def scope_of(op_name, parts=(), step_scopes=None):
+    """(layer, part, phase) of an instruction's ``op_name``, a pure
+    function of the string.  ``layer``: the ``l<k>_<Class>`` component
+    (inside ``jvp(...)``/``transpose(...)`` wrappers too), or one of
+    ``step_scopes`` (``{scope: phase}``, the step's own scopes as its
+    builder names them: ``loss``, ``grad_sync``, ``update``), else None.
+    ``part``: the first component after the layer that is in ``parts``
+    (the names the layer's class gives its parts), else None.
+    ``phase``: what ``step_scopes`` says of its scope; where that is
+    None, and under a layer, ``recompute`` (a ``rematted_computation``
+    component: the forward replayed in the backward), ``backward`` (a
+    ``transpose(`` wrapper and not recomputed) or ``forward``; None
+    where there is no layer."""
+    step_scopes = step_scopes or {}
+    components = [
+        c if "(" in c else c.rstrip(")")  # jit(step) stays what it is
+        for c in _WRAPPER.sub("", op_name or "").split("/")]
+    layer = at = None
+    for index, component in enumerate(components):
+        if component in step_scopes or _LAYER.fullmatch(component):
+            layer, at = component, index
+            break
+    if layer is None:
+        return None, None, None
+    phase = step_scopes.get(layer)
+    if phase is not None:
+        return layer, None, phase
+    part = next((c for c in components[at + 1:] if c in parts), None)
+    if "rematted_computation" in components:
+        phase = "recompute"
+    elif "transpose(" in op_name:
+        phase = "backward"
+    else:
+        phase = "forward"
+    return layer, part, phase
+
+
+def layer_class(layer):
+    """``DecoderLayer`` of ``l3_DecoderLayer``; ``loss``, ``update`` and
+    None as they are."""
+    match = _LAYER.fullmatch(layer or "")
+    return match.group(1) if match else layer
+
+
+def device_seconds_by_scope(op_seconds, scopes, parts=(), step_scopes=None):
+    """{(layer class, part, phase): seconds} of a trace's
+    ``op_seconds`` ({event name = instruction text: seconds}) under the
+    table :func:`instruction_scopes` gave.  Leaves only: an event whose
+    opcode is in :data:`CONTAINER_OPCODES` spans its body's events and
+    is left out.  An event joins the table on :func:`instruction_key`;
+    what has no entry (another program's op) or an entry with no scope
+    adds to the key ``(None, None, None)``."""
+    out = {}
+    for text, seconds in op_seconds.items():
+        found = _split_instruction(text)
+        if found and _opcode(found) in CONTAINER_OPCODES:
+            continue
+        layer, part, phase = scope_of(
+            scopes.get(_key(found), "") if found else "", parts,
+            step_scopes)
+        key = (layer_class(layer), part, phase)
+        out[key] = out.get(key, 0.0) + seconds
     return out

@@ -91,31 +91,12 @@ class Prefetcher(Logger):
         # mid-run pickle (snapshotter) never observes a half-applied
         # serve mutating pending_minibatches_/failed_minibatches
         self._serve_mutex = threading.Lock()
-        self._reset_stats()
         # telemetry (docs/observability.md): per-stage histograms feed
         # the heartbeat/bench percentiles; resolved once, not per serve
         self._m_wait = _registry.histogram("pipeline.wait_s")
         self._m_fill = _registry.histogram("pipeline.fill_s")
         self._m_h2d = _registry.histogram("pipeline.h2d_s")
         _registry.gauge("pipeline.depth").set(self.depth)
-
-    def _reset_stats(self):
-        self._serves = self._applied = 0
-        self._timers_at_start = dict(self.loader.timers)
-
-    @property
-    def stats(self):
-        """This run's counts and stage seconds.  The seconds are the
-        loader's ``pipeline_*`` stage timers (the one place a stage's
-        time accumulates; ``print_stats`` shows them) since the worker
-        pool started."""
-        timers, base = self.loader.timers, self._timers_at_start
-        out = {"depth": self.depth, "serves": self._serves,
-               "applied": self._applied}
-        for stage in ("wait", "fill", "h2d"):
-            key = "pipeline_" + stage
-            out[stage + "_s"] = timers.get(key, 0.0) - base.get(key, 0.0)
-        return out
 
     def _stage_scope(self, stage, hist, **args):
         """One measurement of one stage for its histogram, the
@@ -142,7 +123,6 @@ class Prefetcher(Logger):
         self._inflight = 0
         self._results = queue.Queue()
         self.current = None
-        self._reset_stats()
         # staging slots are (re-)initialized lazily per serve in
         # _serve_one_locked, so a wholesale .mem swap is always healed
         self._pool = ThreadPool(minthreads=1, maxthreads=1,
@@ -218,7 +198,6 @@ class Prefetcher(Logger):
         self._inflight -= 1
         self._apply(item)
         self.current = item
-        self._applied += 1
 
     def _submit(self):
         pool = self._pool
@@ -326,5 +305,4 @@ class Prefetcher(Logger):
             targets = getattr(loader, "minibatch_targets", None)
             if targets is not None and bool(targets):
                 item.targets = targets.staged_capture(self.device)
-        self._serves += 1
         self._results.put(item)
