@@ -563,16 +563,24 @@ FLOAT32_PIECES = ("w_router",)
 def _unpacker(layout):
     """vec -> tuple of pieces, each cast to its dtype, whose gradient is
     ONE concatenate of the pieces' (autodiff's sum of padded slices
-    would pass over the vector once a piece)."""
+    would pass over the vector once a piece).  Each piece is cut from
+    the vector BEFORE it is reshaped or cast, behind a barrier: left to
+    itself the compiler casts the whole vector and moves each reshape
+    ahead of its slice, so that every distinct minor width costs a
+    relayout of the whole vector (a 32-wide float32 router piece padded
+    to 128 lanes: four times its bytes).  Cut first, a piece costs one
+    pass over its own bytes; slice, reshape and cast commute exactly,
+    so the pieces are the same bits."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
     def pieces(vec):
         out, offset = [], 0
         for _, shape, dtype in layout:
             size = _size(shape)
-            out.append(vec[offset:offset + size].reshape(shape).astype(
-                dtype))
+            piece = lax.optimization_barrier(vec[offset:offset + size])
+            out.append(piece.reshape(shape).astype(dtype))
             offset += size
         return tuple(out)
 
