@@ -552,12 +552,12 @@ def test_recompute_is_decided_from_the_devices_memory(_precision,
         return trainer._backward_should_recompute(plans)
 
     # the activations do not fit, what the layers named does
-    assert told(named + 4096) == KEPT_NAMES
+    assert told(named + 4096) == fused.kept_names()
     assert "recomputed in the backward but for" in seen[-1]
     assert "%.2f GB" % (named / 1e9) in seen[-1]
     assert gauge.value == named
-    assert told(named) == KEPT_NAMES and told(named, used=1 << 20) \
-        == KEPT_NAMES
+    assert told(named) == fused.kept_names() \
+        and told(named, used=1 << 20) == fused.kept_names()
     # neither fits: the bare checkpoint, as before
     assert told(named - 4096) is True
     assert seen[-1].endswith("each layer is recomputed in the backward")

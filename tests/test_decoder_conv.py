@@ -601,7 +601,7 @@ def test_the_recomputed_backward_keeps_one_attention_layers_results(
                for a in (s["weights"], s["bias"]) if a is not None)
     limit = int((held + named + 4096) / fused.REMAT_ABOVE) + 1
     monkeypatch.setattr(jax, "local_devices", lambda *a: [Told(limit)])
-    assert trainer._backward_should_recompute(plans) == KEPT_NAMES
+    assert trainer._backward_should_recompute(plans) == fused.kept_names()
     assert registry.peek("step.kept_residual_bytes").value == named
 
     kept = build_train_step(plans, donate=False)(

@@ -353,7 +353,7 @@ def test_recomputed_backward_keeps_both_kernel_forms_named_results(
                for a in (s["weights"], s["bias"]) if a is not None)
     limit = int((held + named + 4096) / fused.REMAT_ABOVE) + 1
     monkeypatch.setattr(jax, "local_devices", lambda *a: [Told(limit)])
-    assert trainer._backward_should_recompute(plans) == KEPT_NAMES
+    assert trainer._backward_should_recompute(plans) == fused.kept_names()
     assert "recomputed in the backward but for" in seen[-1]
     assert registry.peek("step.kept_residual_bytes").value == named
 

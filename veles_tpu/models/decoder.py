@@ -1,18 +1,22 @@
 """Causal decoder units: token embedding, one layer made of parts — a
-token mixer that is latent attention (MLA), grouped-query attention or a
+token mixer that is latent attention (MLA), grouped-query attention (over
+every earlier key, a window of them, or a learned selection of them) or a
 gated short convolution, norms before each sub-layer or around it, a
 gated SiLU feed-forward or a routed expert layer — and the output head,
 with a matrix of its own or tied to the embedding's table
 (docs/model_layer.md "Decoder units").  The parts are chosen by the
 layer's own dims (``kv_rank`` makes the mixer latent attention,
-``kv_heads`` grouped-query attention, ``conv_taps`` a short convolution;
+``kv_heads`` grouped-query attention, ``index_heads`` puts an indexer's
+selection in front of it, ``conv_taps`` makes it a short convolution;
 ``out_gate`` False takes the sigmoid gate off grouped attention's
 output; ``post_norms`` puts a norm after each sub-layer too; ``ffn``
 makes the feed-forward dense; a routed layer carries a shared expert
-where ``shared_width`` is not 0), never by a model's name: the
-DeepSeek-V3 family is one choice of them, the window/full grouped-query
-family with sandwich norms another, the hybrid of short convolutions and
-grouped-query attention a third.
+where ``shared_width`` is not 0 and routes by a sigmoid with a
+correction bias or, ``router`` "softmax", by a softmax), never by a
+model's name: the DeepSeek-V3 family is one choice of them, the
+window/full grouped-query family with sandwich norms another, the hybrid
+of short convolutions and grouped-query attention a third, grouped-query
+attention over a lightning indexer's selection a fourth.
 
 Built on the contracts of ``transformer.py``: the math is in pure
 functions and ``apply(params, x, **static)`` class methods; a layer's
@@ -20,11 +24,11 @@ many matrices pack into the ONE ``(weights, bias)`` pair every unit has
 — one flat float32 vector each, static offsets (:func:`layer_layout`) —
 so ``compiler.py``, the snapshotter and ``parallel/`` see a layer like
 any other.  ``weights`` holds the matrices (decayed by the solver),
-``bias`` what is not decayed: the RMSNorm gains and the router's
-correction bias, which takes no gradient.  The state is float32 whatever
-``root.common.engine.precision_type`` says (``STATE_DTYPE``); that
-setting is the dtype of the operands and activations, and every product
-accumulates in float32.
+``bias`` what is not decayed: the norms' gains (and the indexer's key
+norm's bias) and the router's correction bias, which takes no gradient.
+The state is float32 whatever ``root.common.engine.precision_type``
+says (``STATE_DTYPE``); that setting is the dtype of the operands and
+activations, and every product accumulates in float32.
 
 The layer with latent attention, ``h`` the residual stream (config.json
 keys of the DeepSeek-V3 family in brackets)::
@@ -40,6 +44,7 @@ keys of the DeepSeek-V3 family in brackets)::
     dense:  h += (silu(m W_g) * m W_u) W_d
     routed: p = sigmoid(m W_r); the top_k largest of p + b;
             w_i = p_i / sum_chosen p * routed_scale
+            (softmax: z = m W_r, the top_k largest z, w = softmax(z_chosen))
             h += sum_i w_i Expert_i(m) [+ Shared(m)]
 
 ``Shared`` is a part: a routed layer whose ``shared_width`` is 0 has
@@ -57,6 +62,17 @@ place of the first five lines::
     query head n reads KV head n // (heads / kv_heads); key j counts for
     query i where j <= i and, with ``window``, i - j < window
     h += [rms_norm](softmax(q.k / sqrt(head_width)) v [* sigmoid(z)]) W_o
+
+With ``index_heads`` key j counts for query i where j is in S[i], what a
+lightning indexer over stop_grad(a) keeps (:func:`key_selection`, the
+``attend`` that :func:`grouped_attention` is handed)::
+
+    qI = a W_iq -> index_heads x index_width;  kI = layer_norm(a W_ik)
+    wI = a W_iw / sqrt(index_heads index_width); rotary on the first half
+    I[i, j] = sum_n wI[i, n] relu(qI[i, n] . kI[j])  (j <= i, float32)
+    S[i] = {j <= i : I[i, j] >= the min(i + 1, index_topk)-th largest}
+    L_I = mean_i KL(mean_heads P[i, :] || softmax_{S[i]} I[i, :]), whose
+    gradient reaches the indexer's pieces alone
 
 The gated short convolution (:func:`short_conv`), the mixer of a layer
 with ``conv_taps`` = L, in place of attention::
@@ -98,7 +114,8 @@ choose the same experts; the scaled form (std / sqrt(2 x layers), as
 GPT-2 and Megatron-LM initialise these projections) keeps tokens apart.
 Under ``post_norms`` a norm follows each of those matrices, so their
 std does not reach the stream; what does is the post-norms' gains, which
-start from ``post_gain`` (default 1, as every other gain).
+start from ``post_gain`` (default 1, as every other gain).  The indexer's
+key norm starts as every norm does: gain 1, bias 0.
 """
 
 import functools
@@ -120,6 +137,7 @@ SCOPE_ROUTER = "router"
 SCOPE_ROUTED = "routed_experts"
 SCOPE_SHARED = "shared_experts"
 SCOPE_FFN = "dense_ffn"
+SCOPE_INDEXER = "indexer"
 
 
 # -- pure math ---------------------------------------------------------------
@@ -175,7 +193,9 @@ def _attend(q, k, v, scale, pallas_bwd, window=None):
     """(B*H, T, .) causal attention — ``k``/``v`` (B*H_kv, T, .) where
     the heads are grouped, ``window`` keys back where given — through
     the flash kernels or the stock reference, per the VELES_PALLAS_BWD
-    contract."""
+    contract.  Attention over a learned selection of keys is
+    :func:`key_selection`'s ``attend``, which :func:`grouped_attention`
+    calls in this one's place."""
     import jax.numpy as jnp
 
     from veles_tpu.ops.attention import (attention_reference,
@@ -227,7 +247,7 @@ def latent_attention(a, w, *, heads, qk_nope, qk_rope, v_head, kv_rank,
 
 def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
                       theta, eps, q_gain, k_gain, out_gate=True,
-                      pallas_bwd=None):
+                      pallas_bwd=None, attend=None):
     """The grouped-query sub-layer over normalised ``a`` (B, T, D),
     before the residual add: ``heads`` query heads read ``kv_heads``
     key/value heads (never repeated: the kernels index them), each
@@ -235,7 +255,9 @@ def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
     by the heads), rotary in the rotate-half pairing where ``rope``,
     keys ``window`` back where given, and with ``out_gate`` a sigmoid
     gate on the output before ``w_o``.  ``w`` holds ``w_q``, ``w_k``,
-    ``w_v``, ``w_o`` and, gated, ``w_z``."""
+    ``w_v``, ``w_o`` and, gated, ``w_z``.  ``attend(q, k, v, scale)``
+    over the folded heads, where given, takes :func:`_attend`'s place:
+    a learned selection's keys (:func:`key_selection`)."""
     import jax
     import jax.numpy as jnp
     b, t, _ = a.shape
@@ -250,13 +272,139 @@ def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
     q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
     if rope:
         q, k = rotary(q, theta, halves=True), rotary(k, theta, halves=True)
-    o = _attend(_fold_heads(q), _fold_heads(k), _fold_heads(v),
-                1.0 / float(numpy.sqrt(head_width)), pallas_bwd, window)
+    q, k, v = _fold_heads(q), _fold_heads(k), _fold_heads(v)
+    scale = 1.0 / float(numpy.sqrt(head_width))
+    if attend is None:
+        o = _attend(q, k, v, scale, pallas_bwd, window)
+    else:
+        o = attend(q, k, v, scale)
     o = o.reshape(b, heads, t, head_width).transpose(0, 2, 1, 3).reshape(
         b, t, heads * head_width)
     if out_gate:
         o = (o.astype(jnp.float32) * jax.nn.sigmoid(z)).astype(dtype)
     return _dense(o, w["w_o"]).astype(dtype)
+
+
+def _layer_norm(x, gain, bias, eps):
+    """(x - mean) / sqrt(var + eps) * gain + bias over the last axis, in
+    float32."""
+    import jax.numpy as jnp
+    x = x.astype(jnp.float32)
+    centred = x - jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+    return centred / jnp.sqrt(var + eps) * gain + bias
+
+
+def index_inputs(a, w, g, *, index_heads, index_width, theta, eps):
+    """The lightning indexer's operands over (B, T, D) ``a``: its queries
+    (B, T, index_heads, index_width) and its one key head (B, T,
+    index_width) in ``a``'s dtype, rotary on the first half of each
+    (the rotate-half pairing), and its head weights (B, T, index_heads)
+    float32, scaled by 1 / sqrt(index_heads x index_width)."""
+    import jax.numpy as jnp
+    b, t, _ = a.shape
+    half = index_width // 2
+    q = _dense(a, w["w_iq"]).astype(a.dtype).reshape(
+        b, t, index_heads, index_width)
+    k = _layer_norm(_dense(a, w["w_ik"]), g["index_k_gain"],
+                    g["index_k_bias"], eps).astype(a.dtype)
+    q = jnp.concatenate([rotary(q[..., :half], theta, halves=True),
+                         q[..., half:]], axis=-1)
+    k = jnp.concatenate([rotary(k[..., :half], theta, halves=True),
+                         k[..., half:]], axis=-1)
+    scale = 1.0 / float(numpy.sqrt(index_heads * index_width))
+    return q, k, _dense(a, w["w_iw"]) * scale
+
+
+@functools.lru_cache(maxsize=None)
+def _gradients_in():
+    """``attach(x, inputs, grads)`` -> ``x``, whose backward hands
+    ``grads`` to ``inputs`` (cast to their dtypes) whatever ``x``'s
+    cotangent: a loss term's gradient written out, applied where its
+    inputs are.  The gradients are named (``KEPT_INDEXER_GRADS``), so a
+    layer's checkpoint that keeps them does not compute them again."""
+    import jax
+    from jax.ad_checkpoint import checkpoint_name
+
+    from veles_tpu.ops.sparse_attention import KEPT_INDEXER_GRADS
+
+    @jax.custom_vjp
+    def attach(x, inputs, grads):
+        return x
+
+    def fwd(x, inputs, grads):
+        grads = tuple(checkpoint_name(g.astype(i.dtype), KEPT_INDEXER_GRADS)
+                      for g, i in zip(grads, inputs))
+        return x, grads
+
+    def bwd(grads, g_x):
+        return g_x, grads, None
+
+    attach.defvjp(fwd, bwd)
+    return attach
+
+
+def key_selection(a, w, g, *, index_heads, index_width, index_topk, theta,
+                  eps, pallas_bwd=None):
+    """A layer's learned selection of keys over normalised ``a`` (B, T,
+    D): ``(attend, trained)``.  The indexer (its own scope) reads ``a``
+    detached and keeps ``index_topk`` keys a query
+    (ops/sparse_attention.py).  ``attend(q, k, v, scale)`` is
+    :func:`grouped_attention`'s hook: the folded heads over the kept keys
+    alone, one selection for every head.  ``trained(attended)`` ->
+    ``(attended, aux)`` computes the indexer's loss L_I from what
+    ``attend`` saw and hands its gradient to the indexer's operands
+    (:func:`_gradients_in`), so that it trains the indexer's pieces and
+    nothing else; ``aux`` counts the kept pairs by query tile, the
+    (query tile, key tile) pairs that hold one beside those a causal
+    mask holds, and L_I.  Kernels where ``pallas_bwd`` (the knob as
+    :func:`_attend` reads it), else their plain ``jax.numpy``
+    definitions."""
+    import jax
+    from jax import lax
+
+    from veles_tpu.ops import sparse_attention as sparse
+    if pallas_bwd is None:
+        from veles_tpu.ops.common import pallas_bwd_enabled
+        pallas_bwd = pallas_bwd_enabled()
+    with jax.named_scope(SCOPE_INDEXER):
+        index = index_inputs(lax.stop_gradient(a), w, g,
+                             index_heads=index_heads,
+                             index_width=index_width, theta=theta, eps=eps)
+        if pallas_bwd:
+            selection = sparse.select(*index, index_topk)
+        else:
+            _, kept = sparse.select_reference(*index, index_topk)
+            selection = sparse.selection_of(kept)
+    seen = {}
+
+    def attend(q, k, v, scale):
+        seen.update(q=q, k=k, scale=scale)
+        if pallas_bwd:
+            o, seen["stats"] = sparse.attend(q, k, v, selection, scale)
+        else:
+            o, seen["probabilities"] = sparse.attend_reference(
+                q, k, v, kept, scale)
+        return o
+
+    def trained(attended):
+        with jax.named_scope(SCOPE_INDEXER):
+            if pallas_bwd:
+                kl, grads = sparse.indexer_loss(
+                    seen["q"], seen["k"], seen["stats"], *index, selection,
+                    seen["scale"])
+            else:
+                kl, grads = jax.value_and_grad(
+                    lambda *x: sparse.indexer_loss_reference(
+                        seen["probabilities"], kept, *x),
+                    argnums=(0, 1, 2))(
+                        *(lax.stop_gradient(x) for x in index))
+            attended = _gradients_in()(attended, index, grads)
+            aux = dict(sparse.counters(selection, a.shape[1]),
+                       indexer_kl=kl)
+        return attended, aux
+
+    return attend, trained
 
 
 def short_conv(a, w_in, taps, w_out):
@@ -500,15 +648,17 @@ def layer_layout(d, *, heads=None, qk_nope=None, qk_rope=None,
                  v_head=None, kv_rank=None, kv_heads=None, head_width=None,
                  out_gate=True, conv_taps=None, post_norms=False, ffn=None,
                  experts=None, experts_held=None, expert_width=None,
-                 shared_width=None, **_):
-    """((name, shape) of the packed ``weights``, of the packed
-    ``bias``): the ONE definition the unit's initialiser and the apply
-    read.  ``conv_taps`` makes the mixer a short convolution,
-    ``kv_rank`` latent attention, ``kv_heads`` grouped attention (with
-    its output gate's ``w_z`` unless ``out_gate`` is False);
-    ``post_norms`` adds the gains of a norm after each sub-layer;
-    ``ffn`` makes the layer dense, else it is routed, beside a shared
-    expert where ``shared_width`` is not 0."""
+                 shared_width=None, router="sigmoid", index_heads=None,
+                 index_width=None, **_):
+    """((name, shape) of the packed ``weights``, of the packed ``bias``):
+    the ONE definition the initialiser and the apply read.  ``conv_taps``
+    makes the mixer a short convolution, ``kv_rank`` latent attention,
+    ``kv_heads`` grouped attention (its gate's ``w_z`` unless
+    ``out_gate`` is False; an indexer's pieces where ``index_heads``);
+    ``post_norms`` adds the gains of a norm after each sub-layer; ``ffn``
+    makes the layer dense, else routed (a correction bias unless the
+    ``router`` is a softmax), beside a shared expert where
+    ``shared_width`` is not 0."""
     if conv_taps:
         weights = [("w_in", (d, 3 * d)), ("conv_k", (d, conv_taps)),
                    ("w_out", (d, d))]
@@ -528,6 +678,11 @@ def layer_layout(d, *, heads=None, qk_nope=None, qk_rope=None,
         weights += [("w_o", (heads * head_width, d))]
         bias = [("attn_gain", (d,)), ("q_gain", (head_width,)),
                 ("k_gain", (head_width,))]
+        if index_heads:
+            weights += [("w_iq", (d, index_heads * index_width)),
+                        ("w_ik", (d, index_width)), ("w_iw", (d, index_heads))]
+            bias += [("index_k_gain", (index_width,)),
+                     ("index_k_bias", (index_width,))]
     if post_norms:
         bias += [("post_attn_gain", (d,))]
     bias += [("ffn_gain", (d,))]
@@ -545,7 +700,8 @@ def layer_layout(d, *, heads=None, qk_nope=None, qk_rope=None,
             weights += [("s_gate", (d, shared_width)),
                         ("s_up", (d, shared_width)),
                         ("s_down", (shared_width, d))]
-        bias += [("router_bias", (experts,))]
+        if router != "softmax":
+            bias += [("router_bias", (experts,))]
     return weights, bias
 
 
@@ -613,13 +769,14 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
                   qk_nope=None, qk_rope=None, v_head=None, kv_rank=None,
                   kv_heads=None, head_width=None, window=None, rope=True,
                   out_gate=True, conv_taps=None, post_norms=False,
-                  ffn=None, experts=None,
+                  ffn=None, index_heads=None, index_width=None,
+                  index_topk=None, experts=None, router="sigmoid",
                   experts_held=None, first_expert=0, top_k=None,
                   expert_width=None, shared_width=None, routed_scale=1.0,
                   route_eps=0.0, capacity=None, theta=1e6, eps=1e-6,
                   pallas_bwd=None):
     """One layer over packed params: (h, aux).  ``aux`` is empty for a
-    dense layer."""
+    dense layer with no selection."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -627,18 +784,36 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
                 v_head=v_head, kv_rank=kv_rank, kv_heads=kv_heads,
                 head_width=head_width, out_gate=out_gate,
                 conv_taps=conv_taps, post_norms=post_norms, ffn=ffn,
-                experts=experts, experts_held=experts_held,
-                expert_width=expert_width, shared_width=shared_width)
+                experts=experts, experts_held=experts_held, router=router,
+                expert_width=expert_width, shared_width=shared_width,
+                index_heads=index_heads, index_width=index_width)
     w_layout, b_layout = layer_layout(h.shape[-1], **dims)
     w = unpack(weights, w_layout, compute_dtype)
     g = unpack(bias, b_layout, jnp.float32)
-    h = h.astype(compute_dtype)
+    h, extra = h.astype(compute_dtype), {}
 
     def added(h, f, gain):
         """The residual stream after a sub-layer's output ``f``."""
         return h + (rms_norm(f, g[gain], eps) if post_norms else f)
 
-    if conv_taps:
+    if index_heads:
+        # attention over the indexer's selection: the indexer's scope
+        # beside attention's, not inside it
+        with jax.named_scope(SCOPE_ATTENTION):
+            a = rms_norm(h, g["attn_gain"], eps)
+        attend, trained = key_selection(
+            a, w, g, index_heads=index_heads, index_width=index_width,
+            index_topk=index_topk, theta=theta, eps=eps,
+            pallas_bwd=pallas_bwd)
+        with jax.named_scope(SCOPE_ATTENTION):
+            attended = grouped_attention(
+                a, w, heads=heads, kv_heads=kv_heads, head_width=head_width,
+                window=None, rope=rope, theta=theta, eps=eps,
+                q_gain=g["q_gain"], k_gain=g["k_gain"], out_gate=out_gate,
+                pallas_bwd=pallas_bwd, attend=attend)
+        attended, extra = trained(attended)
+        h = added(h, attended, "post_attn_gain")
+    elif conv_taps:
         with jax.named_scope(SCOPE_CONV):
             mixed = short_conv(rms_norm(h, g["conv_gain"], eps),
                                w["w_in"], w["conv_k"], w["w_out"])
@@ -663,26 +838,34 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
     if ffn:
         with jax.named_scope(SCOPE_FFN):
             return added(h, gated_ffn(m, w["w_gate"], w["w_up"],
-                                      w["w_down"]), "post_ffn_gain"), {}
+                                      w["w_down"]), "post_ffn_gain"), extra
     b, t, d = m.shape
     tokens = m.reshape(b * t, d)
     with jax.named_scope(SCOPE_ROUTER):
         # float32 scores from the float32 router (FLOAT32_PIECES)
-        p = jax.nn.sigmoid(jnp.dot(
-            tokens.astype(jnp.float32), w["w_router"],
-            precision=lax.Precision.HIGHEST))
+        z = jnp.dot(tokens.astype(jnp.float32), w["w_router"],
+                    precision=lax.Precision.HIGHEST)
         from veles_tpu.parallel.moe import top_k_route
-        _, idx = top_k_route(
-            p + lax.stop_gradient(g["router_bias"]), top_k)
-        chosen = jnp.take_along_axis(p, idx, axis=-1)
-        total = jnp.sum(chosen, axis=-1, keepdims=True)
-        if route_eps:
-            total = total + route_eps
-        gate = chosen / total * routed_scale
+        if router == "softmax":
+            # the softmax over all, renormalised over the top_k largest,
+            # is the softmax over the chosen scores
+            _, idx = top_k_route(z, top_k)
+            gate = jax.nn.softmax(jnp.take_along_axis(z, idx, axis=-1),
+                                  axis=-1) * routed_scale
+        else:
+            p = jax.nn.sigmoid(z)
+            _, idx = top_k_route(
+                p + lax.stop_gradient(g["router_bias"]), top_k)
+            chosen = jnp.take_along_axis(p, idx, axis=-1)
+            total = jnp.sum(chosen, axis=-1, keepdims=True)
+            if route_eps:
+                total = total + route_eps
+            gate = chosen / total * routed_scale
     with jax.named_scope(SCOPE_ROUTED):
         routed, aux = routed_experts(
             tokens, idx, gate, w["e_gate"], w["e_up"], w["e_down"],
             first_expert=first_expert, capacity=capacity)
+        aux.update(extra)
     shared = None
     if shared_width:
         with jax.named_scope(SCOPE_SHARED):
@@ -766,15 +949,16 @@ class DecoderEmbedding(_DecoderUnit):
 
 
 class DecoderLayer(_DecoderUnit):
-    """One layer — a latent-attention, grouped-attention or
-    short-convolution mixer, norms before or around each sub-layer,
-    dense or routed — packed (:func:`layer_layout`)."""
+    """One layer — a latent-attention, grouped-attention (over a
+    selection where it has an indexer) or short-convolution mixer, norms
+    before or around each sub-layer, dense or routed — packed
+    (:func:`layer_layout`)."""
 
     MAPPING = "decoder_layer"
     DIMS = ("heads", "qk_nope", "qk_rope", "v_head", "kv_rank", "kv_heads",
             "head_width", "window", "rope", "out_gate", "conv_taps",
-            "post_norms", "ffn",
-            "experts", "experts_held", "first_expert", "top_k",
+            "post_norms", "ffn", "index_heads", "index_width", "index_topk",
+            "experts", "experts_held", "first_expert", "top_k", "router",
             "expert_width", "shared_width", "routed_scale", "route_eps",
             "capacity", "theta")
     #: the gains of the norms AFTER a sub-layer (``post_gain``)
@@ -784,11 +968,15 @@ class DecoderLayer(_DecoderUnit):
     RESIDUAL_WRITERS = ("w_o", "w_out", "w_down", "e_down", "s_down")
     #: registry names of the counters ``apply_with_aux`` emits: the
     #: trainer publishes a scalar a layer as ``<name>`` and a vector a
-    #: layer as ``<name>.l<layer>.e<element>``
+    #: layer as ``<name>.l<layer>.e<element>``; a float, as a gauge
     AUX_COUNTERS = {"moe_assignments": "moe.assignments",
                     "moe_dropped": "moe.dropped_assignments",
                     "moe_load": "moe.load",
-                    "moe_visited_rows": "moe.visited_rows"}
+                    "moe_visited_rows": "moe.visited_rows",
+                    "sparse_selected_pairs": "sparse.selected_pairs",
+                    "sparse_occupied_tiles": "sparse.occupied_tiles",
+                    "sparse_causal_tiles": "sparse.causal_tiles",
+                    "indexer_kl": "sparse.indexer_kl"}
 
     def __init__(self, workflow, **kwargs):
         super(DecoderLayer, self).__init__(workflow, **kwargs)
@@ -813,9 +1001,9 @@ class DecoderLayer(_DecoderUnit):
              for name, piece in w_layout])
         pieces = []
         for name, piece in b_layout:
-            if name == "router_bias":
+            if name in ("router_bias", "index_k_bias"):
                 value = numpy.zeros(piece, numpy.float32)
-                if self.router_bias_stddev:
+                if self.router_bias_stddev and name == "router_bias":
                     self.prng.fill_normal(value, 0.0,
                                           self.router_bias_stddev)
             else:
@@ -849,7 +1037,7 @@ class DecoderLayer(_DecoderUnit):
     #: ``xla_introspect.scope_of`` reads as the ``part`` of an
     #: instruction under an ``l<k>_DecoderLayer`` scope
     PART_SCOPES = (SCOPE_ATTENTION, SCOPE_CONV, SCOPE_ROUTER, SCOPE_ROUTED,
-                   SCOPE_SHARED, SCOPE_FFN)
+                   SCOPE_SHARED, SCOPE_FFN, SCOPE_INDEXER)
 
 
 class DecoderHead(_DecoderUnit):

@@ -34,6 +34,18 @@ STEP_METRICS = frozenset(("loss", "n_err", "mse_sum", "grad_norm",
 REMAT_ABOVE = 0.7
 
 
+def kept_names():
+    """What a recomputed layer keeps of its forward under the trainer's
+    ``bwd_remat``: the names the flash kernels give their output and row
+    statistics (ops/attention.py's ``KEPT_NAMES``, which the kernels of
+    attention over a selection give theirs too) and the indexer's loss
+    its gradients (ops/sparse_attention.py); a name no op gives is no
+    change to the program."""
+    from veles_tpu.ops.attention import KEPT_NAMES
+    from veles_tpu.ops.sparse_attention import KEPT_INDEXER_GRADS
+    return KEPT_NAMES + (KEPT_INDEXER_GRADS,)
+
+
 class FusedTrainer(Unit):
     """Wraps compiler.build_train_step over a StandardWorkflow's
     layers; exposes evaluator-compatible metrics (n_err / mse_sum) so
@@ -91,6 +103,8 @@ class FusedTrainer(Unit):
         # thread's dispatch wall time — the honest steady-state step
         # time under device backpressure, with zero extra host syncs
         self._m_train_step_ = _registry.histogram("step.train_s")
+        #: train steps in ``layer_counters`` since the last publish
+        self._counted_steps_ = 0
         self._m_eval_step_ = _registry.histogram("step.eval_s")
         # the step's anatomy on the host: picking or staging the
         # inputs, and the call of the compiled program alone; what is
@@ -224,9 +238,10 @@ class FusedTrainer(Unit):
     def _backward_should_recompute(self, plans):
         """What the step's backward holds of the forward, as
         ``build_train_step``'s ``bwd_remat``: False, every activation;
-        ``KEPT_NAMES``, each layer recomputed but for what its kernels
-        named (ops/attention.py: the flash forward's output and row
-        statistics, so that the kernel runs once a layer); True, each
+        :func:`kept_names`, each layer recomputed but for what its
+        kernels named (the flash forward's output and row statistics, so
+        that the kernel runs once a layer; the indexer's loss's
+        gradients); True, each
         layer recomputed whole.  Decided from what can be observed — the
         bytes autodiff would save for the backward at this minibatch's
         shape (abstract traces, nothing runs), beside what the device
@@ -239,7 +254,7 @@ class FusedTrainer(Unit):
 
         from veles_tpu.compiler import _forward_for_loss
         from veles_tpu.observe import xla_introspect as _xla
-        from veles_tpu.ops.attention import KEPT_NAMES
+        named = kept_names()
         memory = _xla.device_memory_gauges()
         limit = memory.get("xla.mem.bytes_limit.d0")
         if not limit:
@@ -273,9 +288,9 @@ class FusedTrainer(Unit):
         remat, kept, what = False, 0, "activations are kept"
         if held > room:
             # what the named values add to a recomputed layer's inputs
-            kept = saved_bytes(KEPT_NAMES) - saved_bytes(True)
+            kept = saved_bytes(named) - saved_bytes(True)
             if 0 < kept <= room:
-                remat = KEPT_NAMES
+                remat = named
                 what = ("each layer is recomputed in the backward but "
                         "for %.2f GB that its kernels named, which are "
                         "kept" % (kept / 1e9))
@@ -451,15 +466,19 @@ class FusedTrainer(Unit):
         (``AUX_COUNTERS``: {the step's metric: the registry's name}): a
         scalar a layer adds up into the counter ``<name>``, a vector a
         layer into ``<name>.l<i>.e<j>`` (element ``j`` of the ``i``-th
-        layer that counts it).  Called where the decision already syncs
-        (``on_health_sync``, a train class's end) and by whoever wants
-        the counts sooner (the benchmark, at its window's edges).
-        Returns what it added."""
+        layer that counts it); a float (a layer's loss term) sets the
+        gauge ``<name>`` to its sum over the layers, averaged over the
+        train steps since the last publish.  Called where the decision
+        already syncs (``on_health_sync``, a train class's end) and by
+        whoever wants the counts sooner (the benchmark, at its window's
+        edges).  Returns what it added."""
         import jax
         counters = getattr(self, "layer_counters", None)
         if not counters:  # also a trainer from before the counters
             return {}
         self.layer_counters = {}
+        steps = getattr(self, "_counted_steps_", 0)
+        self._counted_steps_ = 0
         names = {}
         for plan in self._plans or ():
             names.update(getattr(plan.forward_cls, "AUX_COUNTERS", {}))
@@ -468,6 +487,10 @@ class FusedTrainer(Unit):
         for name, value in added.items():
             target = names.get(name)
             if target is None:
+                continue
+            if value.dtype.kind == "f":
+                _registry.gauge(target).set(
+                    float(value.sum()) / max(steps, 1))
                 continue
             if value.ndim < 2:  # one scalar a layer
                 _registry.counter(target).inc(int(value.sum()))
@@ -606,6 +629,7 @@ class FusedTrainer(Unit):
         for name in self._layer_metrics:
             self.layer_counters[name] = lazy_add(
                 self.layer_counters.get(name, 0), metrics[name])
+        self._counted_steps_ += 1
         # mse_sum from the step's aux metric matches EvaluatorMSE's
         # definition (per-feature mean, summed over samples); the
         # scalar loss is SSE/batch over ALL elements and would

@@ -214,35 +214,60 @@ def gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
                            eps=1e-5, embed_scale=1.0, lr=1e-4, beta1=0.9,
                            beta2=0.95, adam_eps=1e-8, decay=0.1,
                            init_std=0.02, router_bias_std=0.0,
-                           post_norm_gain=1.0):
-    """A causal decoder of grouped-query attention layers with a norm
-    before and after each sub-layer (models/decoder.py): token embedding
-    times ``embed_scale``, one layer for each entry of ``layer_types`` —
-    ``"window"`` (keys at most ``window`` back, rotary positions) or
-    ``"full"`` (every earlier key, no position signal) — ``heads`` query
-    heads reading ``kv_heads`` key/value heads ``head_width`` wide, the
-    first ``dense_layers`` with a gated feed-forward ``ffn`` wide, the
-    rest routed as :func:`mla_moe_decoder_layers` routes, and the output
-    head over the ``vocab`` rows held.  ``post_norm_gain`` is the value
-    the gains of the norms after a sub-layer start from.  Solver,
-    initialisation and loader as there."""
+                           post_norm_gain=1.0, rope=None, out_gate=True,
+                           post_norms=True, router="sigmoid",
+                           index_heads=None, index_width=None,
+                           index_topk=None, out_init_std=None):
+    """A causal decoder of grouped-query attention layers
+    (models/decoder.py): token embedding times ``embed_scale``, one layer
+    for each entry of ``layer_types`` — ``"window"`` (keys at most
+    ``window`` back), ``"full"`` (every earlier key) or ``"selected"``
+    (the ``index_topk`` earlier keys a lightning indexer of
+    ``index_heads`` heads ``index_width`` wide keeps: an indexer's pieces
+    in the layer, trained by its own loss) — ``heads`` query heads
+    reading ``kv_heads`` key/value heads ``head_width`` wide, with an
+    output gate unless ``out_gate`` is False and a norm after each
+    sub-layer as well as before it unless ``post_norms`` is False (the
+    post-norms' gains start from ``post_norm_gain``).  ``rope`` says,
+    layer by layer, which layers rotate q and k; by default the
+    ``"window"`` layers do and the others get no position signal.  The
+    first ``dense_layers`` have a gated feed-forward ``ffn`` wide (0: none
+    is dense), the rest are routed as :func:`mla_moe_decoder_layers`
+    routes — by a sigmoid with a correction bias, or with ``router``
+    ``"softmax"`` by a softmax renormalised over the chosen — beside a
+    shared expert where ``shared_width`` is not 0; the output head is
+    over the ``vocab`` rows held.  Solver, initialisation
+    (``out_init_std`` for the matrices that write into the residual
+    stream) and loader as there."""
     solver = _adamw_spec(lr, beta1, beta2, adam_eps, decay, init_std, eps)
     routed = {"experts": experts, "experts_held": experts_held,
               "first_expert": first_expert, "top_k": top_k,
               "expert_width": expert_width, "shared_width": shared_width,
               "routed_scale": routed_scale, "route_eps": route_eps,
               "router_bias_stddev": router_bias_std}
+    if router != "sigmoid":
+        routed["router"] = router
+    kinds = ("window", "full", "selected")
+    norms = {"post_norms": True, "post_gain": post_norm_gain} \
+        if post_norms else {"post_norms": False}
     spec = [dict(solver, type="decoder_embedding", vocab=vocab,
                  width=width, scale=embed_scale)]
     for index, kind in enumerate(layer_types):
-        windowed = _layer_kind(kind, ("window", "full")) == "window"
+        kind = _layer_kind(kind, kinds)
+        windowed = kind == "window"
         body = {"ffn": ffn} if index < dense_layers else routed
+        mixer = {} if out_gate else {"out_gate": False}
+        if kind == "selected":
+            mixer.update(index_heads=index_heads, index_width=index_width,
+                         index_topk=index_topk)
+        if out_init_std is not None:
+            mixer["out_stddev"] = out_init_std
         spec.append(dict(
             solver, type="decoder_layer", heads=heads,
             kv_heads=kv_heads, head_width=head_width,
-            window=window if windowed else None, rope=windowed,
-            theta=theta, post_norms=True, post_gain=post_norm_gain,
-            **body))
+            window=window if windowed else None,
+            rope=windowed if rope is None else bool(rope[index]),
+            theta=theta, **norms, **mixer, **body))
     spec.append(dict(solver, type="decoder_head", vocab=vocab))
     return spec
 

@@ -33,8 +33,10 @@ __all__ = ["moe_apply", "moe_reference", "init_moe_params",
 
 def top_k_route(scores, k):
     """The ``k`` largest of ``scores`` along the last axis: (values,
-    indices).  The tree's one top-k selection: the gate here and
-    ``models/decoder.py``'s routed layer both call it."""
+    indices).  The routers' top-k: the gate here and
+    ``models/decoder.py``'s routed layer (sigmoid or softmax) call it.
+    The indexer's selection of keys is another, exact threshold a row
+    (``ops/sparse_attention.py``), which no router shares."""
     return lax.top_k(scores, k)
 
 
