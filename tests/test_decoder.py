@@ -556,6 +556,9 @@ def test_recompute_is_decided_from_the_devices_memory(_precision,
     assert "recomputed in the backward but for" in seen[-1]
     assert "%.2f GB" % (named / 1e9) in seen[-1]
     assert gauge.value == named
+    # no layer selects: the residuals under kept_names() are those under
+    # the list without the selection's name, which adds nothing
+    assert registry.peek("step.kept_selection_bytes").value == 0
     assert told(named) == fused.kept_names() \
         and told(named, used=1 << 20) == fused.kept_names()
     # neither fits: the bare checkpoint, as before

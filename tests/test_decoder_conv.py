@@ -603,6 +603,9 @@ def test_the_recomputed_backward_keeps_one_attention_layers_results(
     monkeypatch.setattr(jax, "local_devices", lambda *a: [Told(limit)])
     assert trainer._backward_should_recompute(plans) == fused.kept_names()
     assert registry.peek("step.kept_residual_bytes").value == named
+    # no layer selects: the residuals under kept_names() are those under
+    # the list without the selection's name, which adds nothing
+    assert registry.peek("step.kept_selection_bytes").value == 0
 
     kept = build_train_step(plans, donate=False)(
         state, x, y, numpy.float32(4), step_count=numpy.int32(1))

@@ -531,9 +531,11 @@ def test_the_trainer_publishes_the_counters_and_the_loss_gauge(_precision):
 
 
 def test_the_recomputed_backward_keeps_what_the_kernels_named(_precision):
-    """Under the trainer's keep-list the sparse forward and the indexer's
-    loss run once a layer, the selection twice (its mask is recomputed,
-    not kept); with every layer recomputed whole each runs twice."""
+    """Under the trainer's keep-list the selection, the sparse forward
+    and the indexer's loss run once a layer (the selection's mask and
+    tiles' counts are kept, not recomputed); without the selection's
+    name the selection runs twice; with every layer recomputed whole
+    each runs twice."""
     sw, layers, plans, state, x, y = program_and_batch()
     with_kernels(plans)
     params = weights_and_gains(state)
@@ -549,11 +551,15 @@ def test_the_recomputed_backward_keeps_what_the_kernels_named(_precision):
             loss, has_aux=True))(params).jaxpr)
 
     kept, whole = calls(KEPT), calls(True)
+    assert KEPT[-1] == sparse.KEPT_SELECTION
     assert kept[sparse.FWD_KERNEL_NAME] == 2 \
         and whole[sparse.FWD_KERNEL_NAME] == 4
     assert kept[sparse.KL_KERNEL_NAME] == 2 \
         and whole[sparse.KL_KERNEL_NAME] == 4
-    assert kept[sparse.SELECT_KERNEL_NAME] == 4
+    assert kept[sparse.SELECT_KERNEL_NAME] == 2 \
+        and whole[sparse.SELECT_KERNEL_NAME] == 4
+    assert calls(KEPT[:-1]) == dict(kept, **{sparse.SELECT_KERNEL_NAME: 4})
+    assert kept == calls(False)
     assert kept[sparse.DQ_KERNEL_NAME] == kept[sparse.DKV_KERNEL_NAME] == 2
     with jax.default_matmul_precision("highest"):
         one = build_train_step(plans, donate=False, bwd_remat=KEPT)(
@@ -565,6 +571,62 @@ def test_the_recomputed_backward_keeps_what_the_kernels_named(_precision):
                     jax.tree_util.tree_leaves(two[0])):
         numpy.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-6)
     assert fused.REMAT_ABOVE > 0
+
+
+def test_the_selection_is_kept_where_its_bytes_fit(_precision, monkeypatch):
+    """The decision, told devices of made-up sizes: where the kernels'
+    names and the selection's fit, both are kept and
+    ``step.kept_selection_bytes`` reads the toy's masks and tiles'
+    counts; where only the kernels' names fit, the keep-list without the
+    selection's name and 0; where neither fits, the bare checkpoint."""
+    sw, layers, plans, state, x, y = program_and_batch()
+    with_kernels(plans)
+    trainer = sw.fused_trainer
+    params = weights_and_gains(state)
+
+    def saved(remat):
+        """What the backward holds under ``remat``, as the decision
+        sizes it (an abstract trace)."""
+        return sum(leaf.size * leaf.dtype.itemsize
+                   for leaf in jax.tree_util.tree_leaves(jax.eval_shape(
+                       lambda p: jax.vjp(lambda q: _forward_for_loss(
+                           plans, q, x, remat=remat), p)[1], params)))
+
+    # a layer's selection at T 256 takes one (256, 256) tile: the mask
+    # (2 rows x 256 x 256 int8) and one int32 count a row
+    selection = 2 * (2 * T * T + 2 * 4)
+    bare = saved(True)
+    both, kernels = saved(KEPT) - bare, saved(KEPT[:-1]) - bare
+    assert both - kernels == selection and kernels > 0
+    state_bytes = sum(a.nbytes for s in state
+                      for a in (s["weights"], s["bias"]) if a is not None)
+    assert saved(False) - state_bytes > both
+
+    class Told(object):
+        def __init__(self, limit):
+            self.stats = {"bytes_limit": limit, "bytes_in_use": 0}
+
+        def memory_stats(self):
+            return self.stats
+
+    seen = []
+    monkeypatch.setattr(trainer, "info",
+                        lambda fmt, *args: seen.append(fmt % args))
+
+    def told(room):
+        limit = int((state_bytes + room) / fused.REMAT_ABOVE) + 1
+        monkeypatch.setattr(jax, "local_devices", lambda *a: [Told(limit)])
+        return trainer._backward_should_recompute(plans), (
+            registry.peek("step.kept_residual_bytes").value,
+            registry.peek("step.kept_selection_bytes").value)
+
+    assert told(both) == (KEPT, (both, selection))
+    assert "the replay does not select again" in seen[-1]
+    assert told(both - 4096) == (KEPT[:-1], (kernels, 0))
+    assert "recomputed in the backward but for" in seen[-1] \
+        and "select again" not in seen[-1]
+    assert told(kernels - 4096) == (True, (0, 0))
+    assert seen[-1].endswith("each layer is recomputed in the backward")
 
 
 # -- the accepted decoders' programs ----------------------------------------

@@ -38,12 +38,15 @@ def kept_names():
     """What a recomputed layer keeps of its forward under the trainer's
     ``bwd_remat``: the names the flash kernels give their output and row
     statistics (ops/attention.py's ``KEPT_NAMES``, which the kernels of
-    attention over a selection give theirs too) and the indexer's loss
-    its gradients (ops/sparse_attention.py); a name no op gives is no
-    change to the program."""
+    attention over a selection give theirs too), the indexer's loss its
+    gradients and the selection its mask and tiles' counts
+    (ops/sparse_attention.py); a name no op gives is no change to the
+    program.  The selection's name comes last: the trainer keeps the
+    list without it where only that fits."""
     from veles_tpu.ops.attention import KEPT_NAMES
-    from veles_tpu.ops.sparse_attention import KEPT_INDEXER_GRADS
-    return KEPT_NAMES + (KEPT_INDEXER_GRADS,)
+    from veles_tpu.ops.sparse_attention import (KEPT_INDEXER_GRADS,
+                                                KEPT_SELECTION)
+    return KEPT_NAMES + (KEPT_INDEXER_GRADS, KEPT_SELECTION)
 
 
 class FusedTrainer(Unit):
@@ -121,6 +124,11 @@ class FusedTrainer(Unit):
         self._m_kept_residual_ = _registry.gauge(
             "step.kept_residual_bytes")
         self._m_kept_residual_.set(0)
+        # what the selection's mask and counts add to those bytes (0
+        # where the backward does not keep them, or no layer selects)
+        self._m_kept_selection_ = _registry.gauge(
+            "step.kept_selection_bytes")
+        self._m_kept_selection_.set(0)
         self._m_dispatch_ = _registry.histogram("step.dispatch_s")
         self._m_eval_dispatch_ = _registry.histogram(
             "step.eval_dispatch_s")
@@ -241,24 +249,27 @@ class FusedTrainer(Unit):
         :func:`kept_names`, each layer recomputed but for what its
         kernels named (the flash forward's output and row statistics, so
         that the kernel runs once a layer; the indexer's loss's
-        gradients); True, each
-        layer recomputed whole.  Decided from what can be observed — the
-        bytes autodiff would save for the backward at this minibatch's
-        shape (abstract traces, nothing runs), beside what the device
-        already holds and one more copy of the parameters for their
-        gradients, against ``REMAT_ABOVE`` of the device's memory: the
-        first of the three that fits, and the last where the layers name
-        nothing.  A device that does not report its memory (the CPU)
-        keeps the activations."""
+        gradients; the selection's mask and tiles' counts, so that the
+        selection runs once a layer); that list without the selection's
+        name; True, each layer recomputed whole.  Decided from what can
+        be observed — the bytes autodiff would save for the backward at
+        this minibatch's shape (abstract traces, nothing runs), beside
+        what the device already holds and one more copy of the
+        parameters for their gradients, against ``REMAT_ABOVE`` of the
+        device's memory: the first of the four that fits, and the last
+        where the layers name nothing.  A device that does not report its
+        memory (the CPU) keeps the activations."""
         import jax
 
         from veles_tpu.compiler import _forward_for_loss
         from veles_tpu.observe import xla_introspect as _xla
+        from veles_tpu.ops.sparse_attention import KEPT_SELECTION
         named = kept_names()
         memory = _xla.device_memory_gauges()
         limit = memory.get("xla.mem.bytes_limit.d0")
         if not limit:
             self._m_kept_residual_.set(0)
+            self._m_kept_selection_.set(0)
             return False
         in_use = memory.get("xla.mem.bytes_in_use.d0", 0)
         loader = self.sw.loader
@@ -285,19 +296,30 @@ class FusedTrainer(Unit):
         # the residuals hold the parameters too: they are there already
         held = max(0, saved_bytes(False) - param_bytes)
         room = REMAT_ABOVE * limit - in_use - param_bytes
-        remat, kept, what = False, 0, "activations are kept"
+        remat, kept, selection, what = False, 0, 0, "activations are kept"
         if held > room:
-            # what the named values add to a recomputed layer's inputs
-            kept = saved_bytes(named) - saved_bytes(True)
+            # what the named values add to a recomputed layer's inputs:
+            # with the selection's mask and counts, else without them
+            bare = saved_bytes(True)
+            without = tuple(n for n in named if n != KEPT_SELECTION)
+            remat, kept = named, saved_bytes(named) - bare
             if 0 < kept <= room:
-                remat = named
+                selection = kept - (saved_bytes(without) - bare)
+            else:
+                remat, kept = without, saved_bytes(without) - bare
+            if 0 < kept <= room:
                 what = ("each layer is recomputed in the backward but "
                         "for %.2f GB that its kernels named, which are "
                         "kept" % (kept / 1e9))
+                if selection:
+                    what += (", the selection's %.2f GB among them, so "
+                             "the replay does not select again"
+                             % (selection / 1e9))
             else:
                 remat, kept = True, 0
                 what = "each layer is recomputed in the backward"
         self._m_kept_residual_.set(kept)
+        self._m_kept_selection_.set(selection)
         self.info("backward: %.2f GB of activations to hold, %.2f GB in "
                   "use, %.2f GB of gradients, device %.2f GB: %s",
                   held / 1e9, in_use / 1e9,

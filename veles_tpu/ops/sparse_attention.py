@@ -20,7 +20,9 @@ serves every attention head of the row.
   read a tile of: reading it costs a tile nothing measurable on the
   chip, docs/kernels.md), the row's max and sum of ``exp(I - max)`` over
   ``S[t]`` and ``|S[t]|``, and the kept pairs of every (query block, key
-  tile).
+  tile).  The mask and those counts are named (``KEPT_SELECTION``), so a
+  layer's checkpoint that keeps them does not run the selection again
+  in the backward.
 - **Attention over the selection** (``veles_sparse_fwd``, ``_dq``,
   ``_dkv``): the causal flash kernels' online softmax and recomputing
   backward (ops/attention.py), grouped heads through the block index
@@ -82,6 +84,11 @@ DKV_KERNEL_NAME = "veles_sparse_dkv"
 #: that keeps it (``fused.FusedTrainer._backward_should_recompute``)
 #: does not run ``veles_indexer_kl`` again in the backward
 KEPT_INDEXER_GRADS = "veles_indexer_grads"
+#: what the selection names of its results: the mask and the tiles' counts
+#: that the attention's ``fwd`` rule keeps for its backward kernels; a
+#: layer's checkpoint that keeps them does not run ``veles_indexer_select``
+#: again in the backward
+KEPT_SELECTION = "veles_indexer_selection"
 
 #: a tile of the attention kernels, long sequences
 _TILE = 512
@@ -284,11 +291,17 @@ def select(q_i, k_i, w_i, topk, blocks=None):
     """The selection of ``q_i`` (B, T, H_I, d) against ``k_i`` (B, T, d)
     under weights ``w_i`` (B, T, H_I), through the kernel: a
     :class:`Selection` (padded to the tiles; ``rows[:, :T]`` are real).
-    Passes no gradient."""
+    Passes no gradient.  The mask and the tiles' counts, what the
+    attention's backward reads of the selection, are named
+    ``KEPT_SELECTION`` (the tiles as the kernel's result cut to them, so
+    nothing the backward reads descends from the raw result); ``rows``
+    is read by the indexer's loss alone, whose gradients are kept."""
     q_i, k_i, w_i = (lax.stop_gradient(x) for x in (q_i, k_i, w_i))
-    return _select_jit(q_i, k_i, w_i, int(topk),
-                       None if blocks is None else tuple(blocks),
-                       interpret_for(q_i, k_i, w_i))
+    mask, rows, tiles = _select_jit(q_i, k_i, w_i, int(topk),
+                                    None if blocks is None else tuple(blocks),
+                                    interpret_for(q_i, k_i, w_i))
+    return Selection(checkpoint_name(mask, KEPT_SELECTION), rows,
+                     checkpoint_name(tiles, KEPT_SELECTION))
 
 
 def select_reference(q_i, k_i, w_i, topk):
