@@ -88,8 +88,13 @@ STALE_BENCHMARK_TESTS = {
     "tests/benchmark/test_scope_metrics.py::"
     "test_accepted_entries_are_a_prefix_and_the_twelve_follow":
         "asserts the lfm2 configuration and cell are the last; the "
-        "sparse decoder's follow them (tests/benchmark/test_keye_vl2.py "
-        "holds the rule as a prefix)",
+        "sparse decoder's follow them",
+    "tests/benchmark/test_keye_vl2.py::"
+    "test_accepted_entries_are_a_prefix_and_new_ones_follow":
+        "asserts keye's configuration and cell are the last; the "
+        "state-space decoder's follow them "
+        "(tests/benchmark/test_nemotron_twotower.py holds the rule as a "
+        "true prefix)",
 }
 
 

@@ -109,7 +109,7 @@ def test_the_parts_are_the_layer_classs_not_the_parsers():
     assert set(PARTS) == {
         decoder.SCOPE_ATTENTION, decoder.SCOPE_CONV, decoder.SCOPE_ROUTER,
         decoder.SCOPE_ROUTED, decoder.SCOPE_SHARED, decoder.SCOPE_FFN,
-        decoder.SCOPE_INDEXER}
+        decoder.SCOPE_INDEXER, decoder.SCOPE_SSM, decoder.SCOPE_SCAN}
     assert xla.layer_class("l12_DecoderHead") == "DecoderHead"
     assert xla.layer_class("loss") == "loss"
     assert xla.layer_class(None) is None

@@ -1,22 +1,27 @@
 """Causal decoder units: token embedding, one layer made of parts — a
 token mixer that is latent attention (MLA), grouped-query attention (over
-every earlier key, a window of them, or a learned selection of them) or a
-gated short convolution, norms before each sub-layer or around it, a
-gated SiLU feed-forward or a routed expert layer — and the output head,
-with a matrix of its own or tied to the embedding's table
-(docs/model_layer.md "Decoder units").  The parts are chosen by the
-layer's own dims (``kv_rank`` makes the mixer latent attention,
-``kv_heads`` grouped-query attention, ``index_heads`` puts an indexer's
-selection in front of it, ``conv_taps`` makes it a short convolution;
-``out_gate`` False takes the sigmoid gate off grouped attention's
-output; ``post_norms`` puts a norm after each sub-layer too; ``ffn``
-makes the feed-forward dense; a routed layer carries a shared expert
-where ``shared_width`` is not 0 and routes by a sigmoid with a
-correction bias or, ``router`` "softmax", by a softmax), never by a
-model's name: the DeepSeek-V3 family is one choice of them, the
-window/full grouped-query family with sandwich norms another, the hybrid
-of short convolutions and grouped-query attention a third, grouped-query
-attention over a lightning indexer's selection a fourth.
+every earlier key, a window of them, or a learned selection of them), a
+gated short convolution or a state-space scan (Mamba-2's), norms before
+each sub-layer or around it, a gated SiLU feed-forward or a routed expert
+layer — and the output head, with a matrix of its own or tied to the
+embedding's table (docs/model_layer.md "Decoder units").  The parts are
+chosen by the layer's own dims (``kv_rank`` makes the mixer latent
+attention, ``kv_heads`` grouped-query attention, ``index_heads`` puts an
+indexer's selection in front of it, ``conv_taps`` makes it a short
+convolution, ``ssm_heads`` a state-space scan; a layer with none of them
+has no mixer; ``out_gate`` False takes the sigmoid gate off grouped
+attention's output, ``qk_norm`` False its norms of q and k;
+``post_norms`` puts a norm after each sub-layer too; ``ffn`` makes the
+feed-forward dense, ``experts`` routed, and a layer with neither has no
+feed-forward part; a routed layer carries a shared expert where
+``shared_width`` is not 0, routes by a sigmoid with a correction bias
+or, ``router`` "softmax", by a softmax, and with ``expert_act``
+"relu2" its experts are not gated), never by a model's name: the
+DeepSeek-V3 family is one choice of them, the window/full grouped-query
+family with sandwich norms another, the hybrid of short convolutions and
+grouped-query attention a third, grouped-query attention over a
+lightning indexer's selection a fourth, layers of one part each —
+state-space scans, grouped-query attention and relu² experts — a fifth.
 
 Built on the contracts of ``transformer.py``: the math is in pure
 functions and ``apply(params, x, **static)`` class methods; a layer's
@@ -86,6 +91,34 @@ with ``conv_taps`` = L, in place of attention::
 and with ``post_norms`` each sub-layer's output is normalised before it
 is added (``h += rms_norm(f)``: the sandwich placement).
 
+The state-space mixer (:func:`ssm_mixer`, Mamba-2's), the mixer of a
+layer with ``ssm_heads`` = H heads ``ssm_head_width`` = P wide, its B and
+C in ``ssm_groups`` = G groups of ``ssm_state`` = N, d = H P::
+
+    [z | xBC | dt] = a W_in           W_in (width, 2 d + 2 G N + H), no bias
+    xBC = silu(c + conv_b), c the causal filter above over xBC, L taps
+    [x | B | C] = xBC                 x: H heads x P; B, C: G groups x N;
+                                      head n reads group n // (H / G)
+    dt = softplus(dt + dt_bias);  A = -exp(a_log)   per head, float32
+    s_t = exp(dt_t A) s_{t-1} + dt_t x_t B_t^T  (per head, P x N, float32)
+    y_t = s_t C_t + d_skip * x_t
+    h += rms_norm_G(y * silu(z)) W_out      (the norm over each of G
+                                              groups of d / G, then a gain)
+
+The scan (:func:`ssd_scan`, its own scope) is the chunked form: within
+a chunk of ``ssm_chunk`` tokens the products ``(L o C B^T) X`` over its
+decays' segment sums, the chunk-end states from ``B^T X``, a recurrence
+over the chunk states (:func:`_carried`), and those states read back
+through ``C``; operands in the compute dtype, decays, sums and states
+float32.  A sequence that is not a whole number of chunks is padded at
+its end, which changes no earlier output.
+
+A layer of one part: with no mixer ``h += f(rms_norm(h))`` for its
+feed-forward ``f`` alone, with no feed-forward part the mixer alone.  A
+routed layer's experts and shared expert with ``expert_act`` "relu2"
+are ``relu(m W_u)^2 W_d``: no gate, and ``e_gate``/``s_gate`` leave the
+layout.
+
 **The share.**  A routed layer is told which experts it holds
 (``first_expert``, ``experts_held``): it routes over ALL ``experts``,
 sorts the step's token-expert assignments by expert, keeps those of its
@@ -105,8 +138,12 @@ never drops one silently.
 **Initialisation.**  Normal, ``weights_stddev`` every matrix but those
 that write into the residual stream (``w_o``, ``w_out`` and every
 ``*_down``), which take ``out_stddev`` (default: the same), and the
-short convolution's filter, uniform within 1 / sqrt(L) (what a
-depthwise ``Conv1d`` starts from).  With one std everywhere
+short convolutions' filters and the scan's filter bias, uniform within
+1 / sqrt(L) (what a depthwise ``Conv1d`` starts from).  The scan's
+``a_log`` starts as log U(1, 16), ``dt_bias`` as the inverse softplus of
+a dt drawn log-uniform in [0.001, 0.1] and floored at 1e-4 (Mamba-2's
+starts, ``DecoderLayer.SSM_A_INIT`` and ``SSM_DT_INIT``), ``d_skip`` at
+1.  With one std everywhere
 the first layer's attention output — an average of values over the
 prefix, so nearly the same vector at every position — outweighs the
 embedding four to one, every token looks alike to the router and all
@@ -127,8 +164,8 @@ from veles_tpu.models.transformer import _GDAutodiff, _SequenceUnit
 __all__ = ["DecoderEmbedding", "DecoderLayer", "DecoderHead",
            "GDDecoderEmbedding", "GDDecoderLayer", "GDDecoderHead",
            "rms_norm", "rotary", "latent_attention", "grouped_attention",
-           "short_conv", "gated_ffn", "routed_experts", "layer_layout",
-           "unpack", "decoder_layer"]
+           "short_conv", "ssm_mixer", "ssd_scan", "gated_ffn", "relu2_ffn",
+           "routed_experts", "layer_layout", "unpack", "decoder_layer"]
 
 #: ``jax.named_scope`` names inside each ``l<k>_DecoderLayer``
 SCOPE_ATTENTION = "attention"
@@ -138,6 +175,11 @@ SCOPE_ROUTED = "routed_experts"
 SCOPE_SHARED = "shared_experts"
 SCOPE_FFN = "dense_ffn"
 SCOPE_INDEXER = "indexer"
+#: the state-space mixer's scopes, siblings (``scope_of`` reads the first
+#: part a name holds, so a scope inside another would vanish into it):
+#: its projections, filter, gate and norm, and the chunked scan alone
+SCOPE_SSM = "ssm_mixer"
+SCOPE_SCAN = "ssm_scan"
 
 
 # -- pure math ---------------------------------------------------------------
@@ -252,9 +294,10 @@ def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
     before the residual add: ``heads`` query heads read ``kv_heads``
     key/value heads (never repeated: the kernels index them), each
     head's q and k RMS-normalised (one ``head_width`` gain each, shared
-    by the heads), rotary in the rotate-half pairing where ``rope``,
-    keys ``window`` back where given, and with ``out_gate`` a sigmoid
-    gate on the output before ``w_o``.  ``w`` holds ``w_q``, ``w_k``,
+    by the heads; not where the gains are None), rotary in the
+    rotate-half pairing where ``rope``, keys ``window`` back where
+    given, and with ``out_gate`` a sigmoid gate on the output before
+    ``w_o``.  ``w`` holds ``w_q``, ``w_k``,
     ``w_v``, ``w_o`` and, gated, ``w_z``.  ``attend(q, k, v, scale)``
     over the folded heads, where given, takes :func:`_attend`'s place:
     a learned selection's keys (:func:`key_selection`)."""
@@ -269,7 +312,8 @@ def grouped_attention(a, w, *, heads, kv_heads, head_width, window, rope,
         b, t, kv_heads, head_width)
     if out_gate:
         z = _dense(a, w["w_z"])
-    q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
+    if q_gain is not None:
+        q, k = rms_norm(q, q_gain, eps), rms_norm(k, k_gain, eps)
     if rope:
         q, k = rotary(q, theta, halves=True), rotary(k, theta, halves=True)
     q, k, v = _fold_heads(q), _fold_heads(k), _fold_heads(v)
@@ -413,21 +457,173 @@ def short_conv(a, w_in, taps, w_out):
     ``u = B * x``, a depthwise causal filter along the sequence —
     ``c[t] = sum_j taps[:, j] * u[t - (L - 1) + j]`` with ``taps`` (D,
     L) and nothing before position 0 — and ``(C * c) W_out``.  The
-    filter is L shifted multiply-adds in float32, which XLA fuses with
-    both gates into one pass over the (B, T, 3 D) projection."""
+    filter (:func:`_causal_filter`) is L shifted multiply-adds in
+    float32, which XLA fuses with both gates into one pass over the (B,
+    T, 3 D) projection."""
     import jax.numpy as jnp
     dtype = a.dtype
-    width, length = taps.shape
-    t = a.shape[1]
+    width = taps.shape[0]
     bcx = _dense(a, w_in).astype(dtype)
     gate_in, gate_out, x = (bcx[..., :width], bcx[..., width:2 * width],
                             bcx[..., 2 * width:])
-    u = jnp.pad((gate_in * x).astype(jnp.float32),
-                ((0, 0), (length - 1, 0), (0, 0)))
-    k = taps.astype(jnp.float32)
-    c = sum(k[:, j] * u[:, j:j + t] for j in range(length))
+    c = _causal_filter((gate_in * x).astype(jnp.float32), taps)
     return _dense((gate_out.astype(jnp.float32) * c).astype(dtype),
                   w_out).astype(dtype)
+
+
+def _causal_filter(u, taps):
+    """The depthwise causal filter over float32 ``u`` (B, T, D):
+    ``c[t] = sum_j taps[:, j] * u[t - (L - 1) + j]`` with ``taps`` (D, L)
+    and nothing before position 0, float32."""
+    import jax.numpy as jnp
+    length = taps.shape[1]
+    t = u.shape[1]
+    u = jnp.pad(u, ((0, 0), (length - 1, 0), (0, 0)))
+    k = taps.astype(jnp.float32)
+    return sum(k[:, j] * u[:, j:j + t] for j in range(length))
+
+
+def _carried(decay, ends):
+    """The recurrence over a sequence's chunk states, float32: ``decay``
+    (C, B, H) each chunk's decay from its start to its end, ``ends`` (C,
+    B, H, P, N) the state each chunk ends in from a zero start ->
+    (C, B, H, P, N) the state each chunk starts from:
+    ``S_0 = 0, S_c = decay_{c-1} S_{c-1} + ends_{c-1}``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def step(state, chunk):
+        d, end = chunk
+        return d[..., None, None] * state + end, state
+
+    return lax.scan(step, jnp.zeros_like(ends[0]), (decay, ends))[1]
+
+
+def ssd_scan(x, dt, a, b, c, chunk):
+    """The selective state's outputs ``y_t = s_t C_t`` of
+    ``s_t = exp(dt_t a) s_{t-1} + dt_t x_t B_t^T`` (``s_{-1} = 0``) in
+    the chunked form, (B, T, H, P) float32: ``x`` (B, T, H, P) in the
+    compute dtype, ``dt`` (B, T, H) and ``a`` (H,) float32, ``b``/``c``
+    (B, T, G, N) in the compute dtype, head n reading group n // (H / G).
+    Within a chunk of ``chunk`` tokens ``y = (L o C B^T)(dt x)`` with
+    ``L[l, s] = exp(sum_{s < j <= l} dt_j a)`` (s <= l), plus the state
+    the chunk starts from read through ``C`` and decayed to each token;
+    the chunk-end states are ``B^T (dt x)`` decayed to the chunk's end,
+    carried from chunk to chunk by :func:`_carried`.  The products'
+    operands are in the compute dtype, the decays, sums and states
+    float32.  A group of heads at a time (no head reads another group's
+    B and C), each computed again in the backward (``jax.checkpoint``),
+    so that one group's decays and states alone are alive at once; the
+    end is padded to a whole chunk (dt 0 and x 0: no earlier output
+    moves)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    f32 = jnp.float32
+    dtype = x.dtype
+    bsz, t, heads, width = x.shape
+    groups, state = b.shape[2:]
+    per = heads // groups
+    pad = -t % chunk
+    n = (t + pad) // chunk
+
+    def chunked(v):
+        """(B, T, ...) -> (B n, chunk, ...)."""
+        v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+        return v.reshape((bsz * n, chunk) + v.shape[2:])
+
+    # a group at a time, a head's tokens last: (G, B n, per, chunk[, P])
+    xs = chunked((x.astype(f32) * dt[..., None]).astype(dtype)).reshape(
+        bsz * n, chunk, groups, per, width).transpose(2, 0, 3, 1, 4)
+    cum = jnp.cumsum(chunked(dt * a).reshape(bsz * n, chunk, groups, per),
+                     axis=1).transpose(2, 0, 3, 1)
+    bs, cs = (chunked(v).transpose(2, 0, 1, 3) for v in (b, c))
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def in_order(v):
+        """(B n, ...) -> (n, B, ...): a sequence's chunks in order."""
+        return v.reshape((bsz, n) + v.shape[1:]).swapaxes(0, 1)
+
+    @jax.checkpoint
+    def group(part):
+        """A group's outputs (B n, per, chunk, P): within each chunk and
+        from the state the chunk starts from."""
+        xg, cumg, bg, cg = part
+        to_end = jnp.exp(cumg[..., -1:] - cumg)
+        ends = jnp.einsum(
+            "chlp,cln->chpn",
+            (xg.astype(f32) * to_end[..., None]).astype(dtype), bg,
+            preferred_element_type=f32)
+        starts = _carried(in_order(jnp.exp(cumg[..., -1])),
+                          in_order(ends)).swapaxes(0, 1).reshape(ends.shape)
+        seg = cumg[..., :, None] - cumg[..., None, :]
+        decays = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+        scores = jnp.einsum("cln,csn->cls", cg, bg,
+                            preferred_element_type=f32)
+        within = jnp.einsum("chls,chsp->chlp",
+                            (decays * scores[:, None]).astype(dtype), xg,
+                            preferred_element_type=f32)
+        carried = jnp.einsum("cln,chpn->chlp", cg, starts.astype(dtype),
+                             preferred_element_type=f32)
+        return within + carried * jnp.exp(cumg)[..., None]
+
+    y = lax.map(group, (xs, cum, bs, cs))
+    y = y.reshape(groups, bsz, n, per, chunk, width).transpose(
+        1, 2, 4, 0, 3, 5).reshape(bsz, n * chunk, heads, width)
+    return y[:, :t]
+
+
+def ssm_mixer(a, w, g, *, heads, head_width, groups, state, chunk, eps):
+    """Mamba-2's state-space sub-layer over normalised ``a`` (B, T, D),
+    before the residual add: ``[z | xBC | dt] = a W_in``, the causal
+    filter over ``xBC`` with its bias and a SiLU, the chunked scan of
+    ``x`` by ``B`` and ``C`` (:func:`ssd_scan`, in its own scope), the
+    skip ``d_skip * x``, the gate ``silu(z)``, an RMS norm over each of
+    ``groups`` groups of the ``heads * head_width`` channels and
+    ``W_out``.  ``w`` holds ``w_in``, ``conv_k``, ``w_out``; ``g`` the
+    float32 ``conv_b``, ``dt_bias``, ``a_log``, ``d_skip`` and
+    ``ssm_norm_gain``.  The float32 stretches between the products —
+    the filter and its SiLU, the skip, gate and norm — are computed
+    again in the backward from their operands (``jax.checkpoint``):
+    kept, their float32 intermediates would outweigh the layer's
+    operands several times."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    dtype = a.dtype
+    bsz, t, _ = a.shape
+    inner = heads * head_width
+    wide = inner + 2 * groups * state
+
+    @jax.checkpoint
+    def filtered(u, taps, bias):
+        return jax.nn.silu(_causal_filter(u.astype(f32), taps)
+                           + bias).astype(dtype)
+
+    @jax.checkpoint
+    def gated(y, x, z, skip, gain):
+        y = (y + skip[:, None] * x.astype(f32)).reshape(bsz, t, inner) \
+            * jax.nn.silu(z.astype(f32))
+        return rms_norm(y.reshape(bsz, t, groups, inner // groups),
+                        gain.reshape(groups, -1), eps).reshape(
+                            bsz, t, inner).astype(dtype)
+
+    with jax.named_scope(SCOPE_SSM):
+        proj = _dense(a, w["w_in"]).astype(dtype)
+        xbc = filtered(proj[..., inner:inner + wide], w["conv_k"],
+                       g["conv_b"])
+        x = xbc[..., :inner].reshape(bsz, t, heads, head_width)
+        b, c = (xbc[..., inner + i * groups * state:
+                    inner + (i + 1) * groups * state].reshape(
+                        bsz, t, groups, state) for i in (0, 1))
+        dt = jax.nn.softplus(proj[..., inner + wide:].astype(f32)
+                             + g["dt_bias"])
+        a_decay = -jnp.exp(g["a_log"])
+    with jax.named_scope(SCOPE_SCAN):
+        y = ssd_scan(x, dt, a_decay, b, c, chunk)
+    with jax.named_scope(SCOPE_SSM):
+        return _dense(gated(y, x, proj[..., :inner], g["d_skip"],
+                            g["ssm_norm_gain"]), w["w_out"]).astype(dtype)
 
 
 def gated_ffn(m, w_gate, w_up, w_down):
@@ -436,6 +632,14 @@ def gated_ffn(m, w_gate, w_up, w_down):
     gate = _dense(m, w_gate)
     up = _dense(m, w_up)
     return _dense((jax.nn.silu(gate) * up).astype(m.dtype),
+                  w_down).astype(m.dtype)
+
+
+def relu2_ffn(m, w_up, w_down):
+    """relu(m W_u)^2 W_d, float32 accumulation, in m's dtype."""
+    import jax
+    import jax.numpy as jnp
+    return _dense(jnp.square(jax.nn.relu(_dense(m, w_up))).astype(m.dtype),
                   w_down).astype(m.dtype)
 
 
@@ -460,12 +664,14 @@ def _gather_sum(rows, pos, valid):
 
 
 @functools.lru_cache(maxsize=None)
-def _expert_rows(chunk):
-    """``expert_rows(m, w_row, e_gate, e_up, e_down, token_of, pos, valid,
-    reach, filled)`` -> the (N, D) weighted sum of the held experts'
-    outputs: the stretch of :func:`routed_experts` from the tokens to
-    the sum, walked over the buffer's rows ``[0, filled)`` in chunks of
-    ``chunk`` rows and over no chunk beyond them.  Row ``r`` holds token
+def _expert_rows(chunk, gated=True):
+    """``expert_rows(m, w_row, e_in, e_down, token_of, pos, valid, reach,
+    filled)`` -> the (N, D) weighted sum of the held experts' outputs:
+    the stretch of :func:`routed_experts` from the tokens to the sum,
+    walked over the buffer's rows ``[0, filled)`` in chunks of
+    ``chunk`` rows and over no chunk beyond them.  ``e_in`` holds the
+    experts' input matrices: ``(e_gate, e_up)`` of gated SiLU experts,
+    ``(e_up,)`` of relu² ones (``gated`` False).  Row ``r`` holds token
     ``token_of[r]`` under weight ``w_row[r]``, expert ``e``'s rows end at
     ``reach[e]``, and ``pos``/``valid`` (N, K) say which row each of a
     token's slots went to.  The loops' bound is a device scalar, so they
@@ -495,8 +701,12 @@ def _expert_rows(chunk):
         return lax.ragged_dot_general(rows, g, sizes, into_experts,
                                       preferred_element_type=f32)
 
-    def act(gate, up, dtype):
-        return (jax.nn.silu(gate) * up).astype(dtype)
+    def act(pre, dtype):
+        """The hidden rows from the pre-activations."""
+        if gated:
+            gate, up = pre
+            return (jax.nn.silu(gate) * up).astype(dtype)
+        return jnp.square(jax.nn.relu(pre[0])).astype(dtype)
 
     def chunk_of(i, token_of, w_row, reach, filled):
         """Chunk ``i``: (its first row, its rows' tokens and weights,
@@ -512,18 +722,18 @@ def _expert_rows(chunk):
     def trips(filled):
         return (filled + chunk - 1) // chunk
 
-    def rows_in(m, tok, e_gate, e_up, sizes):
-        """A chunk's tokens and their two pre-activations."""
+    def rows_in(m, tok, e_in, sizes):
+        """A chunk's tokens and their pre-activations."""
         xs = m[tok]
-        return xs, grouped(xs, e_gate, sizes), grouped(xs, e_up, sizes)
+        return xs, [grouped(xs, e, sizes) for e in e_in]
 
-    def forward(m, w_row, e_gate, e_up, e_down, token_of, pos, valid,
-                reach, filled):
+    def forward(m, w_row, e_in, e_down, token_of, pos, valid, reach,
+                filled):
         def step(i, y):
             r0, tok, w, live, sizes = chunk_of(
                 i, token_of, w_row, reach, filled)
-            _, gate, up = rows_in(m, tok, e_gate, e_up, sizes)
-            y_c = grouped(act(gate, up, m.dtype), e_down, sizes)
+            _, pre = rows_in(m, tok, e_in, sizes)
+            y_c = grouped(act(pre, m.dtype), e_down, sizes)
             return lax.dynamic_update_slice(
                 y, jnp.where(live, y_c * w, 0.0).astype(m.dtype), (r0, 0))
 
@@ -541,7 +751,7 @@ def _expert_rows(chunk):
         return forward(*args), args
 
     def bwd(res, g_out):
-        (m, w_row, e_gate, e_up, e_down, token_of, pos, valid, reach,
+        (m, w_row, e_in, e_down, token_of, pos, valid, reach,
          filled) = res
         dtype = m.dtype
 
@@ -549,36 +759,35 @@ def _expert_rows(chunk):
             g_xs, g_w, *sums = carry
             r0, tok, w, live, sizes = chunk_of(
                 i, token_of, w_row, reach, filled)
-            xs, gate, up = rows_in(m, tok, e_gate, e_up, sizes)
-            hidden, act_back = jax.vjp(
-                lambda gate, up: act(gate, up, dtype), gate, up)
+            xs, pre = rows_in(m, tok, e_in, sizes)
+            hidden, act_back = jax.vjp(lambda *pre: act(pre, dtype), *pre)
             g_y = jnp.where(live, g_out[tok], 0)
             # <g_y, hidden E_down> = <g_y E_down^T, hidden>: the weight's
             # gradient without the down product's output
             g_hidden = back(g_y, e_down, sizes)
             g_w_c = jnp.where(live, jnp.sum(
                 g_hidden * hidden.astype(f32), axis=1, keepdims=True), 0)
-            pre = [g.astype(dtype)
-                   for g in act_back((g_hidden * w).astype(dtype))]
-            g_xs_c = jnp.where(live, back(pre[0], e_gate, sizes)
-                               + back(pre[1], e_up, sizes), 0)
-            grads = [weight_grad(xs, pre[0], sizes),
-                     weight_grad(xs, pre[1], sizes),
-                     weight_grad(hidden, (g_y.astype(f32) * w).astype(dtype),
-                                 sizes)]
+            g_pre = [g.astype(dtype)
+                     for g in act_back((g_hidden * w).astype(dtype))]
+            g_xs_c = back(g_pre[0], e_in[0], sizes)
+            for g, e in zip(g_pre[1:], e_in[1:]):
+                g_xs_c = g_xs_c + back(g, e, sizes)
+            g_xs_c = jnp.where(live, g_xs_c, 0)
+            grads = [weight_grad(xs, g, sizes) for g in g_pre] + [
+                weight_grad(hidden, (g_y.astype(f32) * w).astype(dtype),
+                            sizes)]
             return (lax.dynamic_update_slice(
                         g_xs, g_xs_c.astype(dtype), (r0, 0)),
                     lax.dynamic_update_slice(g_w, g_w_c[:, 0], (r0,)),
                     *(total + g for total, g in zip(sums, grads)))
 
-        g_xs, g_w, g_gate, g_up, g_down = lax.fori_loop(
+        g_xs, g_w, *g_in, g_down = lax.fori_loop(
             0, trips(filled), step, (
                 jnp.zeros((token_of.shape[0], m.shape[1]), dtype),
                 jnp.zeros(w_row.shape, f32),
-                *(jnp.zeros(e.shape, f32)
-                  for e in (e_gate, e_up, e_down))))
+                *(jnp.zeros(e.shape, f32) for e in e_in + (e_down,))))
         return (_gather_sum(g_xs, pos, valid), g_w.astype(w_row.dtype),
-                g_gate.astype(e_gate.dtype), g_up.astype(e_up.dtype),
+                tuple(g.astype(e.dtype) for g, e in zip(g_in, e_in)),
                 g_down.astype(e_down.dtype), None, None, None, None, None)
 
     expert_rows = jax.custom_vjp(forward)
@@ -593,18 +802,19 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
     ``m`` (N, D) tokens, ``idx`` (N, K) chosen experts out of all,
     ``weights`` (N, K) float32; ``e_gate``/``e_up`` (E_held, D, F) and
     ``e_down`` (E_held, F, D) the experts ``first_expert ..
-    first_expert + E_held - 1``.  Assignments are sorted by expert and
-    those of the held experts fill a ``capacity``-row buffer (None: the
-    N x min(K, E_held) rows a step can send at most); the grouped
-    products and every pass over rows walk the rows a step FILLED, in
-    chunks of ``CHUNK`` (:func:`_expert_rows`).  Returns (out (N, D),
-    aux) with aux ``moe_load`` (E_held,) tokens routed to each held
-    expert, ``moe_assignments`` their sum, ``moe_dropped`` how many did
-    not fit the buffer and ``moe_visited_rows`` the rows of the chunks
-    walked."""
+    first_expert + E_held - 1``, gated SiLU experts, or with ``e_gate``
+    None relu² ones, ``relu(m e_up)^2 e_down``.  Assignments are sorted
+    by expert and those of the held experts fill a ``capacity``-row
+    buffer (None: the N x min(K, E_held) rows a step can send at most);
+    the grouped products and every pass over rows walk the rows a step
+    FILLED, in chunks of ``CHUNK`` (:func:`_expert_rows`).  Returns
+    (out (N, D), aux) with aux ``moe_load`` (E_held,) tokens routed to
+    each held expert, ``moe_assignments`` their sum, ``moe_dropped`` how
+    many did not fit the buffer and ``moe_visited_rows`` the rows of the
+    chunks walked."""
     import jax.numpy as jnp
     n, k = idx.shape
-    held = e_gate.shape[0]
+    held = e_up.shape[0]
     if capacity is None:
         capacity = n * min(k, held)
     local = idx - first_expert
@@ -632,9 +842,10 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
                    (0, -capacity % rows))
     token_of = (slot // k).astype(jnp.int32)
     valid = (is_held.reshape(-1) & (pos < capacity)).reshape(n, k)
-    out = _expert_rows(rows)(
-        m, weights.reshape(-1)[slot], e_gate, e_up, e_down, token_of,
-        pos.reshape(n, k), valid, reach, filled)
+    gated = e_gate is not None
+    out = _expert_rows(rows, gated)(
+        m, weights.reshape(-1)[slot], (e_gate, e_up) if gated else (e_up,),
+        e_down, token_of, pos.reshape(n, k), valid, reach, filled)
     aux = {"moe_load": load, "moe_assignments": kept,
            "moe_dropped": jnp.maximum(kept - capacity, 0),
            "moe_visited_rows": (filled + rows - 1) // rows * rows}
@@ -646,20 +857,35 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
 
 def layer_layout(d, *, heads=None, qk_nope=None, qk_rope=None,
                  v_head=None, kv_rank=None, kv_heads=None, head_width=None,
-                 out_gate=True, conv_taps=None, post_norms=False, ffn=None,
-                 experts=None, experts_held=None, expert_width=None,
-                 shared_width=None, router="sigmoid", index_heads=None,
+                 out_gate=True, qk_norm=True, conv_taps=None,
+                 ssm_heads=None, ssm_head_width=None, ssm_groups=None,
+                 ssm_state=None, post_norms=False, ffn=None, experts=None,
+                 experts_held=None, expert_width=None, shared_width=None,
+                 router="sigmoid", expert_act="silu", index_heads=None,
                  index_width=None, **_):
     """((name, shape) of the packed ``weights``, of the packed ``bias``):
-    the ONE definition the initialiser and the apply read.  ``conv_taps``
-    makes the mixer a short convolution, ``kv_rank`` latent attention,
+    the ONE definition the initialiser and the apply read.  ``ssm_heads``
+    makes the mixer a state-space scan (its filter ``conv_taps`` long),
+    else ``conv_taps`` a short convolution, ``kv_rank`` latent attention,
     ``kv_heads`` grouped attention (its gate's ``w_z`` unless
-    ``out_gate`` is False; an indexer's pieces where ``index_heads``);
-    ``post_norms`` adds the gains of a norm after each sub-layer; ``ffn``
-    makes the layer dense, else routed (a correction bias unless the
-    ``router`` is a softmax), beside a shared expert where
-    ``shared_width`` is not 0."""
-    if conv_taps:
+    ``out_gate`` is False, its q and k norms' gains unless ``qk_norm``
+    is; an indexer's pieces where ``index_heads``), and none of them no
+    mixer; ``post_norms`` adds the gains of a norm after each sub-layer;
+    ``ffn`` makes the layer dense, ``experts`` routed (a correction bias
+    unless the ``router`` is a softmax; no gates where ``expert_act`` is
+    "relu2"), beside a shared expert where ``shared_width`` is not 0,
+    and neither leaves the feed-forward part out."""
+    mixer = bool(ssm_heads or conv_taps or kv_rank or kv_heads)
+    if ssm_heads:
+        inner = ssm_heads * ssm_head_width
+        filtered = inner + 2 * ssm_groups * ssm_state
+        weights = [("w_in", (d, inner + filtered + ssm_heads)),
+                   ("conv_k", (filtered, conv_taps)),
+                   ("w_out", (inner, d))]
+        bias = [("ssm_gain", (d,)), ("conv_b", (filtered,)),
+                ("dt_bias", (ssm_heads,)), ("a_log", (ssm_heads,)),
+                ("d_skip", (ssm_heads,)), ("ssm_norm_gain", (inner,))]
+    elif conv_taps:
         weights = [("w_in", (d, 3 * d)), ("conv_k", (d, conv_taps)),
                    ("w_out", (d, d))]
         bias = [("conv_gain", (d,))]
@@ -669,36 +895,44 @@ def layer_layout(d, *, heads=None, qk_nope=None, qk_rope=None,
                    ("w_kvb", (kv_rank, heads * (qk_nope + v_head))),
                    ("w_o", (heads * v_head, d))]
         bias = [("attn_gain", (d,)), ("kv_gain", (kv_rank,))]
-    else:
+    elif kv_heads:
         weights = [("w_q", (d, heads * head_width)),
                    ("w_k", (d, kv_heads * head_width)),
                    ("w_v", (d, kv_heads * head_width))]
         if out_gate:
             weights += [("w_z", (d, heads * head_width))]
         weights += [("w_o", (heads * head_width, d))]
-        bias = [("attn_gain", (d,)), ("q_gain", (head_width,)),
-                ("k_gain", (head_width,))]
+        bias = [("attn_gain", (d,))]
+        if qk_norm:
+            bias += [("q_gain", (head_width,)), ("k_gain", (head_width,))]
         if index_heads:
             weights += [("w_iq", (d, index_heads * index_width)),
                         ("w_ik", (d, index_width)), ("w_iw", (d, index_heads))]
             bias += [("index_k_gain", (index_width,)),
                      ("index_k_bias", (index_width,))]
-    if post_norms:
+    else:
+        weights, bias = [], []
+    feed_forward = bool(ffn or experts)
+    if post_norms and mixer:
         bias += [("post_attn_gain", (d,))]
-    bias += [("ffn_gain", (d,))]
-    if post_norms:
+    if feed_forward:
+        bias += [("ffn_gain", (d,))]
+    if post_norms and feed_forward:
         bias += [("post_ffn_gain", (d,))]
     if ffn:
         weights += [("w_gate", (d, ffn)), ("w_up", (d, ffn)),
                     ("w_down", (ffn, d))]
-    else:
-        weights += [("w_router", (d, experts)),
-                    ("e_gate", (experts_held, d, expert_width)),
-                    ("e_up", (experts_held, d, expert_width)),
+    elif experts:
+        gated = expert_act != "relu2"
+        weights += [("w_router", (d, experts))]
+        if gated:
+            weights += [("e_gate", (experts_held, d, expert_width))]
+        weights += [("e_up", (experts_held, d, expert_width)),
                     ("e_down", (experts_held, expert_width, d))]
         if shared_width:
-            weights += [("s_gate", (d, shared_width)),
-                        ("s_up", (d, shared_width)),
+            if gated:
+                weights += [("s_gate", (d, shared_width))]
+            weights += [("s_up", (d, shared_width)),
                         ("s_down", (shared_width, d))]
         if router != "softmax":
             bias += [("router_bias", (experts,))]
@@ -768,25 +1002,30 @@ def unpack(vec, layout, dtype):
 def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
                   qk_nope=None, qk_rope=None, v_head=None, kv_rank=None,
                   kv_heads=None, head_width=None, window=None, rope=True,
-                  out_gate=True, conv_taps=None, post_norms=False,
+                  out_gate=True, qk_norm=True, conv_taps=None,
+                  ssm_heads=None, ssm_head_width=None, ssm_groups=None,
+                  ssm_state=None, ssm_chunk=None, post_norms=False,
                   ffn=None, index_heads=None, index_width=None,
                   index_topk=None, experts=None, router="sigmoid",
                   experts_held=None, first_expert=0, top_k=None,
                   expert_width=None, shared_width=None, routed_scale=1.0,
-                  route_eps=0.0, capacity=None, theta=1e6, eps=1e-6,
-                  pallas_bwd=None):
+                  route_eps=0.0, expert_act="silu", capacity=None,
+                  theta=1e6, eps=1e-6, pallas_bwd=None):
     """One layer over packed params: (h, aux).  ``aux`` is empty for a
-    dense layer with no selection."""
+    layer with no routed part and no selection."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     dims = dict(heads=heads, qk_nope=qk_nope, qk_rope=qk_rope,
                 v_head=v_head, kv_rank=kv_rank, kv_heads=kv_heads,
-                head_width=head_width, out_gate=out_gate,
-                conv_taps=conv_taps, post_norms=post_norms, ffn=ffn,
+                head_width=head_width, out_gate=out_gate, qk_norm=qk_norm,
+                conv_taps=conv_taps, ssm_heads=ssm_heads,
+                ssm_head_width=ssm_head_width, ssm_groups=ssm_groups,
+                ssm_state=ssm_state, post_norms=post_norms, ffn=ffn,
                 experts=experts, experts_held=experts_held, router=router,
                 expert_width=expert_width, shared_width=shared_width,
-                index_heads=index_heads, index_width=index_width)
+                expert_act=expert_act, index_heads=index_heads,
+                index_width=index_width)
     w_layout, b_layout = layer_layout(h.shape[-1], **dims)
     w = unpack(weights, w_layout, compute_dtype)
     g = unpack(bias, b_layout, jnp.float32)
@@ -809,16 +1048,24 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
             attended = grouped_attention(
                 a, w, heads=heads, kv_heads=kv_heads, head_width=head_width,
                 window=None, rope=rope, theta=theta, eps=eps,
-                q_gain=g["q_gain"], k_gain=g["k_gain"], out_gate=out_gate,
-                pallas_bwd=pallas_bwd, attend=attend)
+                q_gain=g.get("q_gain"), k_gain=g.get("k_gain"),
+                out_gate=out_gate, pallas_bwd=pallas_bwd, attend=attend)
         attended, extra = trained(attended)
         h = added(h, attended, "post_attn_gain")
+    elif ssm_heads:
+        with jax.named_scope(SCOPE_SSM):
+            a = rms_norm(h, g["ssm_gain"], eps)
+        mixed = ssm_mixer(a, w, g, heads=ssm_heads, head_width=ssm_head_width,
+                          groups=ssm_groups, state=ssm_state,
+                          chunk=ssm_chunk, eps=eps)
+        with jax.named_scope(SCOPE_SSM):
+            h = added(h, mixed, "post_attn_gain")
     elif conv_taps:
         with jax.named_scope(SCOPE_CONV):
             mixed = short_conv(rms_norm(h, g["conv_gain"], eps),
                                w["w_in"], w["conv_k"], w["w_out"])
             h = added(h, mixed, "post_attn_gain")
-    else:
+    elif kv_rank or kv_heads:
         with jax.named_scope(SCOPE_ATTENTION):
             a = rms_norm(h, g["attn_gain"], eps)
             if kv_rank:
@@ -830,10 +1077,12 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
                 attended = grouped_attention(
                     a, w, heads=heads, kv_heads=kv_heads,
                     head_width=head_width, window=window, rope=rope,
-                    theta=theta, eps=eps, q_gain=g["q_gain"],
-                    k_gain=g["k_gain"], out_gate=out_gate,
+                    theta=theta, eps=eps, q_gain=g.get("q_gain"),
+                    k_gain=g.get("k_gain"), out_gate=out_gate,
                     pallas_bwd=pallas_bwd)
             h = added(h, attended, "post_attn_gain")
+    if not (ffn or experts):
+        return h, extra
     m = rms_norm(h, g["ffn_gain"], eps)
     if ffn:
         with jax.named_scope(SCOPE_FFN):
@@ -863,13 +1112,16 @@ def decoder_layer(h, weights, bias, *, compute_dtype, heads=None,
             gate = chosen / total * routed_scale
     with jax.named_scope(SCOPE_ROUTED):
         routed, aux = routed_experts(
-            tokens, idx, gate, w["e_gate"], w["e_up"], w["e_down"],
+            tokens, idx, gate, w.get("e_gate"), w["e_up"], w["e_down"],
             first_expert=first_expert, capacity=capacity)
         aux.update(extra)
     shared = None
     if shared_width:
         with jax.named_scope(SCOPE_SHARED):
-            shared = gated_ffn(m, w["s_gate"], w["s_up"], w["s_down"])
+            if expert_act == "relu2":
+                shared = relu2_ffn(m, w["s_up"], w["s_down"])
+            else:
+                shared = gated_ffn(m, w["s_gate"], w["s_up"], w["s_down"])
     routed = routed.reshape(b, t, d)
     if shared is None:
         return added(h, routed, "post_ffn_gain"), aux
@@ -950,9 +1202,9 @@ class DecoderEmbedding(_DecoderUnit):
 
 class DecoderLayer(_DecoderUnit):
     """One layer — a latent-attention, grouped-attention (over a
-    selection where it has an indexer) or short-convolution mixer, norms
-    before or around each sub-layer, dense or routed — packed
-    (:func:`layer_layout`)."""
+    selection where it has an indexer), short-convolution or state-space
+    mixer or none, norms before or around each sub-layer, dense, routed
+    or neither — packed (:func:`layer_layout`)."""
 
     MAPPING = "decoder_layer"
     DIMS = ("heads", "qk_nope", "qk_rope", "v_head", "kv_rank", "kv_heads",
@@ -960,9 +1212,14 @@ class DecoderLayer(_DecoderUnit):
             "post_norms", "ffn", "index_heads", "index_width", "index_topk",
             "experts", "experts_held", "first_expert", "top_k", "router",
             "expert_width", "shared_width", "routed_scale", "route_eps",
-            "capacity", "theta")
+            "capacity", "theta", "qk_norm", "ssm_heads", "ssm_head_width",
+            "ssm_groups", "ssm_state", "ssm_chunk", "expert_act")
     #: the gains of the norms AFTER a sub-layer (``post_gain``)
     POST_GAINS = ("post_attn_gain", "post_ffn_gain")
+
+    #: the scan's starts, Mamba-2's: dt's (min, max, floor) and A's range
+    SSM_DT_INIT = (0.001, 0.1, 1e-4)
+    SSM_A_INIT = (1.0, 16.0)
 
     #: the pieces that write into the residual stream (``out_stddev``)
     RESIDUAL_WRITERS = ("w_o", "w_out", "w_down", "e_down", "s_down")
@@ -999,28 +1256,41 @@ class DecoderLayer(_DecoderUnit):
         self.weights.mem = numpy.concatenate(
             [self._filled(name, piece).ravel()
              for name, piece in w_layout])
-        pieces = []
-        for name, piece in b_layout:
-            if name in ("router_bias", "index_k_bias"):
-                value = numpy.zeros(piece, numpy.float32)
-                if self.router_bias_stddev and name == "router_bias":
-                    self.prng.fill_normal(value, 0.0,
-                                          self.router_bias_stddev)
-            else:
-                value = numpy.full(
-                    piece, self.post_gain if name in self.POST_GAINS
-                    else 1.0, numpy.float32)
-            pieces.append(value)
-        self.bias.mem = numpy.concatenate(pieces)
+        self.bias.mem = numpy.concatenate(
+            [self._bias_filled(name, piece) for name, piece in b_layout])
+
+    def _uniform(self, shape, low, high):
+        value = numpy.zeros(shape, numpy.float32)
+        self.prng.fill(value, low, high)
+        return value
+
+    def _bias_filled(self, name, shape):
+        """A piece of the packed bias as initialised."""
+        if name in ("router_bias", "index_k_bias"):
+            value = numpy.zeros(shape, numpy.float32)
+            if self.router_bias_stddev and name == "router_bias":
+                self.prng.fill_normal(value, 0.0, self.router_bias_stddev)
+            return value
+        if name == "conv_b":
+            bound = 1.0 / numpy.sqrt(self.dims["conv_taps"])
+            return self._uniform(shape, -bound, bound)
+        if name == "a_log":
+            return numpy.log(self._uniform(shape, *self.SSM_A_INIT))
+        if name == "dt_bias":
+            low, high, floor = self.SSM_DT_INIT
+            dt = numpy.maximum(numpy.exp(self._uniform(
+                shape, numpy.log(low), numpy.log(high))), floor)
+            # softplus's inverse, so that softplus(dt_bias) = dt
+            return (dt + numpy.log(-numpy.expm1(-dt))).astype(numpy.float32)
+        return numpy.full(shape, self.post_gain if name in self.POST_GAINS
+                          else 1.0, numpy.float32)
 
     def _filled(self, name, shape):
         """A piece of the packed weights as initialised."""
         if name == "conv_k":
             # a depthwise Conv1d's start: uniform within 1 / sqrt(taps)
-            taps = numpy.zeros(shape, numpy.float32)
             bound = 1.0 / numpy.sqrt(shape[-1])
-            self.prng.fill(taps, -bound, bound)
-            return taps
+            return self._uniform(shape, -bound, bound)
         return self._gaussian(shape, self.out_stddev
                               if name in self.RESIDUAL_WRITERS else None)
 
@@ -1037,7 +1307,8 @@ class DecoderLayer(_DecoderUnit):
     #: ``xla_introspect.scope_of`` reads as the ``part`` of an
     #: instruction under an ``l<k>_DecoderLayer`` scope
     PART_SCOPES = (SCOPE_ATTENTION, SCOPE_CONV, SCOPE_ROUTER, SCOPE_ROUTED,
-                   SCOPE_SHARED, SCOPE_FFN, SCOPE_INDEXER)
+                   SCOPE_SHARED, SCOPE_FFN, SCOPE_SSM, SCOPE_SCAN,
+                   SCOPE_INDEXER)
 
 
 class DecoderHead(_DecoderUnit):

@@ -197,9 +197,10 @@ class FusedTrainer(Unit):
                 grad_compress=self.grad_compress, donate=True,
                 compiler_options=step_compiler_options())
         else:
+            remat = self._backward_should_recompute(plans)
+            self._publish_scan_gauges(plans, remat)
             self._step_fn = build_train_step(
-                plans, loss=self.loss, donate=True,
-                bwd_remat=self._backward_should_recompute(plans),
+                plans, loss=self.loss, donate=True, bwd_remat=remat,
                 compiler_options=step_compiler_options())
         #: adamw's bias correction wants the step's number
         self._counts_steps = any(p.solver == "adamw" for p in plans)
@@ -325,6 +326,25 @@ class FusedTrainer(Unit):
                   held / 1e9, in_use / 1e9,
                   param_bytes / 1e9, limit / 1e9, what)
         return remat
+
+    def _publish_scan_gauges(self, plans, remat):
+        """The state-space layers' gauges, once a build: ``ssm.chunks``,
+        the chunks a layer's scan walks a sequence, and
+        ``ssm.kept_state_bytes``, the float32 states that the chunks start
+        from which the backward holds of the forward (0 where the layers
+        are recomputed: the replay scans again).  Nothing where no layer
+        scans."""
+        scans = [plan.static for plan in plans
+                 if plan.static.get("ssm_chunk")]
+        if not scans:
+            return
+        batch, tokens = self.sw.loader.minibatch_data.shape[:2]
+        chunks = -(-tokens // scans[0]["ssm_chunk"])
+        kept = 0 if remat is not False else sum(
+            4 * batch * chunks * s["ssm_heads"] * s["ssm_head_width"]
+            * s["ssm_state"] for s in scans)
+        _registry.gauge("ssm.chunks").set(chunks)
+        _registry.gauge("ssm.kept_state_bytes").set(kept)
 
     def _abstract_state(self):
         return [{"weights": fwd.weights if fwd.weights else None,

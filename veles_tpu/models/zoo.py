@@ -10,7 +10,8 @@ lowers onto the MXU.
 
 __all__ = ["alexnet_layers", "vgg_layers", "mnist_mlp_layers",
            "autoencoder_layers", "transformer_layers",
-           "mla_moe_decoder_layers", "build_plans_and_state"]
+           "mla_moe_decoder_layers", "hybrid_moe_decoder_layers",
+           "build_plans_and_state"]
 
 
 def build_plans_and_state(specs, input_shape, seed=0):
@@ -314,6 +315,53 @@ def conv_gqa_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
                          **body))
     head = {"tied_to": 0} if tied_head else {}
     spec.append(dict(solver, type="decoder_head", vocab=vocab, **head))
+    return spec
+
+
+def hybrid_moe_decoder_layers(vocab, width, layer_types, heads, kv_heads,
+                              head_width, ssm_heads, ssm_head_width,
+                              ssm_groups, ssm_state, ssm_chunk, conv_taps,
+                              experts, experts_held, top_k, expert_width,
+                              shared_width, first_expert=0,
+                              routed_scale=1.0, eps=1e-5, lr=1e-4,
+                              beta1=0.9, beta2=0.95, adam_eps=1e-8,
+                              decay=0.1, init_std=0.02, out_init_std=None):
+    """A causal decoder whose layers are each ONE part
+    (models/decoder.py), one for each entry of ``layer_types``:
+    ``"ssm"`` (Mamba-2's state-space mixer: ``ssm_heads`` heads
+    ``ssm_head_width`` wide, B and C in ``ssm_groups`` groups of
+    ``ssm_state``, scanned in chunks of ``ssm_chunk`` tokens, its filter
+    ``conv_taps`` long), ``"attention"`` (``heads`` query heads reading
+    ``kv_heads`` key/value heads ``head_width`` wide over every earlier
+    key: no position signal, no norm of q or k, no output gate) or
+    ``"routed"`` (``experts`` routed by a sigmoid with a correction
+    bias, ``top_k`` a token, of which this program holds
+    ``experts_held`` from ``first_expert`` on, relu² experts
+    ``expert_width`` wide beside a relu² shared expert ``shared_width``
+    wide); pre-norms only; token embedding and an untied output head over
+    the ``vocab`` rows held.  Solver, initialisation (``out_init_std`` for
+    the matrices that write into the residual stream) and loader as
+    :func:`mla_moe_decoder_layers`'."""
+    solver = _adamw_spec(lr, beta1, beta2, adam_eps, decay, init_std, eps)
+    parts = {
+        "ssm": {"ssm_heads": ssm_heads, "ssm_head_width": ssm_head_width,
+                "ssm_groups": ssm_groups, "ssm_state": ssm_state,
+                "ssm_chunk": ssm_chunk, "conv_taps": conv_taps},
+        "attention": {"heads": heads, "kv_heads": kv_heads,
+                      "head_width": head_width, "rope": False,
+                      "out_gate": False, "qk_norm": False},
+        "routed": {"experts": experts, "experts_held": experts_held,
+                   "first_expert": first_expert, "top_k": top_k,
+                   "expert_width": expert_width,
+                   "shared_width": shared_width,
+                   "routed_scale": routed_scale, "expert_act": "relu2"}}
+    spec = [dict(solver, type="decoder_embedding", vocab=vocab,
+                 width=width)]
+    for kind in layer_types:
+        spec.append(dict(solver, type="decoder_layer",
+                         out_stddev=out_init_std,
+                         **parts[_layer_kind(kind, tuple(parts))]))
+    spec.append(dict(solver, type="decoder_head", vocab=vocab))
     return spec
 
 
