@@ -184,6 +184,85 @@ def test_the_scan_keeps_float32_sums_from_bfloat16_operands():
     assert 1e-4 < off < 2e-2
 
 
+def sequential_carry(log_decay, ends):
+    """The states the chunks start from, one chunk a step:
+    ``S_0 = 0, S_c = exp(log_decay_{c-1}) S_{c-1} + ends_{c-1}``."""
+    def step(state, chunk):
+        decay, end = chunk
+        return jnp.exp(decay)[..., None, None] * state + end, state
+
+    return jax.lax.scan(step, jnp.zeros_like(ends[0]), (log_decay, ends))[1]
+
+
+@pytest.mark.parametrize("n, low, high, block", [
+    (128, -0.05, 0.0, None),   # slow: a state lives across every chunk
+    (128, -3.0, -0.5, None),   # fast
+    (128, -205.0, -150.0, None),  # every chunk's decay underflows to 0
+    (37, -0.2, 0.0, 4),        # blocks of 4, the last one short
+    (37, -205.0, -150.0, 4),
+])
+def test_the_chunks_pass_their_states_as_the_recurrence_does(
+        monkeypatch, n, low, high, block):
+    """``_carried`` against the recurrence one chunk a step, outputs and
+    gradients by the ends and by the log decays, at the precision a
+    product has by default (no ``highest`` context here: the matrix
+    product asks for float32 itself); where the decays underflow, every
+    number finite; with ``CARRY_BLOCK`` smaller than the chunks, the
+    blocks passing their states by the recurrence."""
+    if block:
+        monkeypatch.setattr(decoder, "CARRY_BLOCK", block)
+    rng = numpy.random.RandomState(n)
+    log_decay = jnp.asarray(rng.uniform(low, high, (n, 2, 3)), jnp.float32)
+    ends = jnp.asarray(rng.randn(n, 2, 3, 4, 5), jnp.float32)
+    weigh = jnp.asarray(rng.randn(n, 2, 3, 4, 5), jnp.float32)
+
+    def weighed(carry):
+        return lambda lam, e: jnp.sum(carry(lam, e) * weigh)
+
+    got = decoder._carried(log_decay, ends)
+    want = sequential_carry(log_decay, ends)
+    scale = float(jnp.abs(want).max())
+    numpy.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * scale)
+    g_got = jax.grad(weighed(decoder._carried), (0, 1))(log_decay, ends)
+    g_want = jax.grad(weighed(sequential_carry), (0, 1))(log_decay, ends)
+    for name, g, w in zip(("log_decay", "ends"), g_got, g_want):
+        assert bool(jnp.isfinite(g).all()), name
+        numpy.testing.assert_allclose(
+            g, w, rtol=1e-5, atol=1e-6 * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+def test_the_scan_of_128_chunks_holds_no_loop_over_them():
+    """``ssd_scan`` over 128 chunks, forward and backward: the only loop
+    is the map over the groups of heads, and the chunk states pass in a
+    product at float32 precision."""
+    x, dt, a_log, b, c = scan_operands(6, 256, heads=4, width=3, groups=2,
+                                       state=5)
+
+    def scan(x, dt, b, c):
+        return jnp.sum(decoder.ssd_scan(x[None], dt[None],
+                                        -jnp.exp(a_log), b[None], c[None],
+                                        2))
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    for f in (scan, jax.grad(scan, argnums=(0, 1, 2, 3))):
+        found = list(equations(jax.make_jaxpr(f)(x, dt, b, c).jaxpr))
+        loops = {e.params["length"] for e in found
+                 if e.primitive.name == "scan"}
+        assert loops == {2}, loops  # the 2 groups
+        assert not any(e.primitive.name == "while" for e in found)
+        passing = [e for e in found if e.primitive.name == "dot_general"
+                   and e.params["precision"] is not None]
+        assert passing and all(
+            e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+            for e in passing)
+
+
 # -- the layers ---------------------------------------------------------------
 
 
@@ -468,10 +547,13 @@ def test_the_gauges_count_chunks_and_the_states_kept(_precision,
     """``ssm.chunks``: 32 tokens in chunks of 8 are 4 (and 12 make 3);
     ``ssm.kept_state_bytes``: where the backward keeps every activation,
     the float32 states 4 rows x 4 chunks x 4 heads x 16 x 8 of each of
-    the four layers; where the layers are recomputed, 0."""
+    the four layers; where the layers are recomputed, 0;
+    ``ssm.carry_blocks``: the 3 chunks are one block, and 2 with blocks
+    of 2."""
     sw, layers, plans, state, x, y = program_and_batch()
     trainer = sw.fused_trainer
     assert registry.peek("ssm.chunks").value == 4
+    assert registry.peek("ssm.carry_blocks").value == 1
     assert registry.peek("ssm.kept_state_bytes").value == \
         4 * 4 * 4 * 4 * 16 * 8 * 4
     trainer._publish_scan_gauges(plans, True)
@@ -481,3 +563,7 @@ def test_the_gauges_count_chunks_and_the_states_kept(_precision,
             plan.static["ssm_chunk"] = 12
     trainer._publish_scan_gauges(plans, False)
     assert registry.peek("ssm.chunks").value == 3
+    assert registry.peek("ssm.carry_blocks").value == 1
+    monkeypatch.setattr(decoder, "CARRY_BLOCK", 2)
+    trainer._publish_scan_gauges(plans, False)
+    assert registry.peek("ssm.carry_blocks").value == 2

@@ -107,9 +107,11 @@ C in ``ssm_groups`` = G groups of ``ssm_state`` = N, d = H P::
 
 The scan (:func:`ssd_scan`, its own scope) is the chunked form: within
 a chunk of ``ssm_chunk`` tokens the products ``(L o C B^T) X`` over its
-decays' segment sums, the chunk-end states from ``B^T X``, a recurrence
-over the chunk states (:func:`_carried`), and those states read back
-through ``C``; operands in the compute dtype, decays, sums and states
+decays' segment sums, the chunk-end states from ``B^T X``, the states
+the chunks start from passed by one float32 matrix product over the
+chunks' segment sums of log decays (:func:`_carried`, no loop over the
+chunks within a block of :data:`CARRY_BLOCK`), and those states read
+back through ``C``; operands in the compute dtype, decays, sums and states
 float32.  A sequence that is not a whole number of chunks is padded at
 its end, which changes no earlier output.
 
@@ -483,20 +485,75 @@ def _causal_filter(u, taps):
     return sum(k[:, j] * u[:, j:j + t] for j in range(length))
 
 
-def _carried(decay, ends):
-    """The recurrence over a sequence's chunk states, float32: ``decay``
-    (C, B, H) each chunk's decay from its start to its end, ``ends`` (C,
-    B, H, P, N) the state each chunk ends in from a zero start ->
-    (C, B, H, P, N) the state each chunk starts from:
-    ``S_0 = 0, S_c = decay_{c-1} S_{c-1} + ends_{c-1}``."""
+# The chunks whose states pass in one matrix product (:func:`_carried`).
+# The product's operations grow with the block (each of n chunks sums a
+# block of P x N states), the recurrence across blocks with its n / block
+# sequential steps, each a few microseconds of loop and small launches.
+# At 128, 16,384 tokens in chunks of 128 are one block and no loop: a
+# group of 8 heads of 64 x 128 passes its states in 2.1 GFLOP, six
+# bfloat16 passes at float32 precision, against 128 steps of the loop;
+# 131,072 tokens take 8 steps, not one (1,024 x 1,024) product of 8 x
+# the operations.
+CARRY_BLOCK = 128
+
+
+def _passing(log_decay):
+    """The weights by which chunk j's end state reaches the start of
+    chunk c, ``W[c, j] = exp(sum_{j < k < c} log_decay_k)`` for j < c and
+    0 on and above the diagonal, from ``log_decay`` (..., K) -> (..., K,
+    K).  Each entry is the cumulative sum of its own segment's terms
+    alone (Mamba-2's ``segsum``), never the difference of two sums over
+    the whole block, whose float32 rounding would move every decay that
+    survives."""
+    import jax.numpy as jnp
+    k = log_decay.shape[-1]
+    c, j = numpy.arange(k)[:, None], numpy.arange(k)[None, :]
+    entering = jnp.pad(log_decay[..., :-1],
+                       [(0, 0)] * (log_decay.ndim - 1) + [(1, 0)])
+    terms = jnp.where(j < c - 1, entering[..., :, None], 0.0)
+    return jnp.where(j < c, jnp.exp(jnp.cumsum(terms, axis=-2)), 0.0)
+
+
+def _carried(log_decay, ends):
+    """The states a sequence's chunks start from, float32: ``log_decay``
+    (n, B, H) the log of each chunk's decay from its start to its end,
+    ``ends`` (n, B, H, P, N) the state each chunk ends in from a zero
+    start -> (n, B, H, P, N) ``S_c = sum_{j < c} W[c, j] ends_j``, which
+    is ``S_0 = 0, S_c = exp(log_decay_{c-1}) S_{c-1} + ends_{c-1}``.
+    Within a block of :data:`CARRY_BLOCK` chunks one matrix product by
+    the weights of :func:`_passing`, at float32 precision (``HIGHEST``:
+    the default is one bfloat16 pass on the TPU); from block to block the
+    recurrence, each block's end state decayed by its whole decay.  The
+    log decays, not their ``exp``: a chunk's decay underflows to 0 where
+    its log is below about -104, and a log of that would be -inf."""
     import jax.numpy as jnp
     from jax import lax
+    n = ends.shape[0]
+    block = min(CARRY_BLOCK, n)
+    pad = -n % block
+    blocks = (n + pad) // block
+    lam = jnp.pad(log_decay, [(0, pad)] + [(0, 0)] * (log_decay.ndim - 1))
+    ends = jnp.pad(ends, [(0, pad)] + [(0, 0)] * (ends.ndim - 1))
+    lam = lam.reshape((blocks, block) + lam.shape[1:])
+    ends = ends.reshape((blocks, block) + ends.shape[1:])
+    weights = _passing(jnp.moveaxis(lam, 1, -1))    # (blocks, B, H, K, K)
+    starts = jnp.einsum("gbhcj,gjbhpn->gcbhpn", weights, ends,
+                        precision=lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)
+    if blocks > 1:
+        def step(state, block):
+            decay, end = block
+            return decay[..., None, None] * state + end, state
 
-    def step(state, chunk):
-        d, end = chunk
-        return d[..., None, None] * state + end, state
-
-    return lax.scan(step, jnp.zeros_like(ends[0]), (decay, ends))[1]
+        block_ends = (jnp.exp(lam[:, -1])[..., None, None] * starts[:, -1]
+                      + ends[:, -1])
+        entering = lax.scan(step, jnp.zeros_like(block_ends[0]),
+                            (jnp.exp(lam.sum(axis=1)), block_ends))[1]
+        into = jnp.exp(jnp.cumsum(jnp.pad(
+            lam[:, :-1], [(0, 0), (1, 0)] + [(0, 0)] * (lam.ndim - 2)),
+            axis=1))
+        starts = starts + into[..., None, None] * entering[:, None]
+    return starts.reshape((blocks * block,) + starts.shape[2:])[:n]
 
 
 def ssd_scan(x, dt, a, b, c, chunk):
@@ -509,7 +566,8 @@ def ssd_scan(x, dt, a, b, c, chunk):
     ``L[l, s] = exp(sum_{s < j <= l} dt_j a)`` (s <= l), plus the state
     the chunk starts from read through ``C`` and decayed to each token;
     the chunk-end states are ``B^T (dt x)`` decayed to the chunk's end,
-    carried from chunk to chunk by :func:`_carried`.  The products'
+    passed on to the chunks after them by :func:`_carried` (a matrix
+    product, no loop over the chunks within a block).  The products'
     operands are in the compute dtype, the decays, sums and states
     float32.  A group of heads at a time (no head reads another group's
     B and C), each computed again in the backward (``jax.checkpoint``),
@@ -554,7 +612,7 @@ def ssd_scan(x, dt, a, b, c, chunk):
             "chlp,cln->chpn",
             (xg.astype(f32) * to_end[..., None]).astype(dtype), bg,
             preferred_element_type=f32)
-        starts = _carried(in_order(jnp.exp(cumg[..., -1])),
+        starts = _carried(in_order(cumg[..., -1]),
                           in_order(ends)).swapaxes(0, 1).reshape(ends.shape)
         seg = cumg[..., :, None] - cumg[..., None, :]
         decays = jnp.exp(jnp.where(causal, seg, -jnp.inf))

@@ -329,21 +329,26 @@ class FusedTrainer(Unit):
 
     def _publish_scan_gauges(self, plans, remat):
         """The state-space layers' gauges, once a build: ``ssm.chunks``,
-        the chunks a layer's scan walks a sequence, and
-        ``ssm.kept_state_bytes``, the float32 states that the chunks start
-        from which the backward holds of the forward (0 where the layers
-        are recomputed: the replay scans again).  Nothing where no layer
-        scans."""
+        the chunks a layer's scan walks a sequence, ``ssm.carry_blocks``,
+        the blocks of ``decoder.CARRY_BLOCK`` chunks its states pass
+        through a sequence (above 1 the recurrence from block to block
+        runs), and ``ssm.kept_state_bytes``, the float32 states that the
+        chunks start from which the backward holds of the forward (0
+        where the layers are recomputed: the replay scans again).
+        Nothing where no layer scans."""
         scans = [plan.static for plan in plans
                  if plan.static.get("ssm_chunk")]
         if not scans:
             return
+        from veles_tpu.models import decoder
         batch, tokens = self.sw.loader.minibatch_data.shape[:2]
         chunks = -(-tokens // scans[0]["ssm_chunk"])
         kept = 0 if remat is not False else sum(
             4 * batch * chunks * s["ssm_heads"] * s["ssm_head_width"]
             * s["ssm_state"] for s in scans)
         _registry.gauge("ssm.chunks").set(chunks)
+        _registry.gauge("ssm.carry_blocks").set(
+            -(-chunks // decoder.CARRY_BLOCK))
         _registry.gauge("ssm.kept_state_bytes").set(kept)
 
     def _abstract_state(self):
