@@ -245,10 +245,12 @@ def straight_line_routed(m, idx, weights, e_gate, e_up, e_down, *,
                          first_expert, capacity):
     """The routed layer as it stood before the chunked walk (PR 29's
     body, a stable sort for its counting sort and stock autodiff for its
-    paired gathers): every pass over all ``capacity`` rows."""
+    paired gathers; relu² experts where ``e_gate`` is None): every pass
+    over all ``capacity`` rows, a token's rows, each rounded to ``m``'s
+    dtype, summed by a float32 scatter-add."""
     from jax import lax
     n, k = idx.shape
-    held = e_gate.shape[0]
+    held = e_up.shape[0]
     local = idx - first_expert
     is_held = (local >= 0) & (local < held)
     key = jnp.where(is_held, local, held).reshape(-1)
@@ -259,12 +261,16 @@ def straight_line_routed(m, idx, weights, e_gate, e_up, e_down, *,
     xs = m[slot // k]
     product = functools.partial(lax.ragged_dot, group_sizes=sizes,
                                 preferred_element_type=jnp.float32)
-    hidden = jax.nn.silu(product(xs, e_gate)) * product(xs, e_up)
+    if e_gate is None:
+        hidden = jnp.square(jax.nn.relu(product(xs, e_up)))
+    else:
+        hidden = jax.nn.silu(product(xs, e_gate)) * product(xs, e_up)
     y = product(hidden.astype(m.dtype), e_down)
     row_valid = jnp.arange(capacity) < jnp.minimum(kept, capacity)
     y = jnp.where(row_valid[:, None],
                   y * weights.reshape(-1)[slot][:, None], 0.0)
-    out = jnp.zeros(m.shape, jnp.float32).at[slot // k].add(y)
+    out = jnp.zeros(m.shape, jnp.float32).at[slot // k].add(
+        y.astype(m.dtype).astype(jnp.float32))
     return out.astype(m.dtype), {
         "moe_load": load, "moe_assignments": kept,
         "moe_dropped": jnp.maximum(kept - capacity, 0)}
@@ -274,27 +280,48 @@ def straight_line_routed(m, idx, weights, e_gate, e_up, e_down, *,
 FILL_TOKENS = decoder.CHUNK // 2 + 40
 
 
-@pytest.mark.parametrize("fill, capacity", [
-    (0, None), (1, None), (decoder.CHUNK, None), (decoder.CHUNK + 1, None),
-    (2 * FILL_TOKENS, None), (decoder.CHUNK + 40, decoder.CHUNK + 16)],
+@pytest.mark.parametrize("fill, capacity, gated, twice, dtype", [
+    (0, None, True, False, "float32"), (1, None, True, False, "float32"),
+    (decoder.CHUNK, None, True, False, "float32"),
+    (decoder.CHUNK + 1, None, True, False, "float32"),
+    (2 * FILL_TOKENS, None, True, False, "float32"),
+    (decoder.CHUNK + 40, decoder.CHUNK + 16, True, False, "float32"),
+    (2, None, True, True, "float32"),
+    (decoder.CHUNK + 1, None, False, False, "float32"),
+    (2 * FILL_TOKENS, None, True, False, "bfloat16")],
     ids=["nothing", "one_row", "one_chunk", "one_chunk_and_a_row",
-         "every_row", "more_than_a_reduced_buffer"])
+         "every_row", "more_than_a_reduced_buffer",
+         "a_token_twice_in_one_chunk", "relu2_experts",
+         "every_row_bfloat16"])
 def test_the_walk_over_filled_chunks_is_the_straight_line_body(
-        _precision, fill, capacity):
+        _precision, fill, capacity, gated, twice, dtype):
     """``fill`` assignments to the 3 held experts of 8: output, every
     gradient and the counters are the whole-buffer body's, whatever
-    share of the buffer's chunks the loops visit."""
+    share of the buffer's chunks the loops visit.  ``twice``: the
+    assignments are one token's two slots, on two held experts, so one
+    chunk's scatter-add meets that token twice; ``gated`` False: relu²
+    experts, no ``e_gate``.  In bfloat16 the output is the reference's to
+    the last bit: a token's two rows are each rounded to bfloat16 and
+    added in float32 on both sides (two terms add the same in either
+    order), so a rounding moved or left out fails here."""
     rng = numpy.random.RandomState(fill % 97)
     n, k, width, inner = FILL_TOKENS, 2, 16, 8
     if capacity is None:
         capacity = n * k
-    m = jnp.asarray(rng.randn(n, width).astype(numpy.float32))
+    m = jnp.asarray(rng.randn(n, width).astype(numpy.float32), dtype)
     gate = jnp.asarray(rng.rand(n, k).astype(numpy.float32))
-    experts = [jnp.asarray(rng.randn(3, *s).astype(numpy.float32) * 0.2)
+    experts = [jnp.asarray(rng.randn(3, *s).astype(numpy.float32) * 0.2,
+                           dtype)
                for s in ((width, inner), (width, inner), (inner, width))]
+    if not gated:
+        experts[0] = None
     idx = rng.choice([0, 1, 5, 6, 7], size=n * k)     # held: 2, 3, 4
-    chosen = rng.permutation(n * k)[:fill]
-    idx[chosen] = rng.randint(2, 5, size=fill)
+    if twice:
+        chosen = k * rng.randint(n) + numpy.arange(k)
+        idx[chosen] = rng.permutation([2, 3, 4])[:k]
+    else:
+        chosen = rng.permutation(n * k)[:fill]
+        idx[chosen] = rng.randint(2, 5, size=fill)
     idx = jnp.asarray(idx.reshape(n, k).astype(numpy.int32))
     cotangent = jnp.asarray(rng.randn(n, width).astype(numpy.float32))
 
@@ -310,11 +337,23 @@ def test_the_walk_over_filled_chunks_is_the_straight_line_body(
 
     (_, (out, aux)), grads = both(decoder.routed_experts)
     (_, (want, want_aux)), want_grads = both(straight_line_routed)
-    numpy.testing.assert_allclose(out, want, atol=2e-6)
+    out, want = out.astype(jnp.float32), want.astype(jnp.float32)
+    if dtype == "bfloat16":
+        numpy.testing.assert_array_equal(out, want)
+        # the reference's stock autodiff rounds its intermediate
+        # products elsewhere: two ulps at the largest gradient
+        tolerance = 2.0 ** -7
+    else:
+        numpy.testing.assert_allclose(out, want, atol=2e-6)
+        tolerance = 2e-6
     for name, g, g_want in zip(("m", "weights", "e_gate", "e_up", "e_down"),
                                grads, want_grads):
+        if g_want is None:                            # relu²: no e_gate
+            assert g is None
+            continue
         numpy.testing.assert_allclose(
-            g, g_want, atol=2e-6 * max(1.0, float(jnp.abs(g_want).max())),
+            g.astype(jnp.float32), g_want.astype(jnp.float32),
+            atol=tolerance * max(1.0, float(jnp.abs(g_want).max())),
             err_msg=name)
     for name, value in want_aux.items():
         numpy.testing.assert_array_equal(aux[name], value, err_msg=name)
@@ -339,10 +378,12 @@ def _equations(jaxpr):
 
 def test_the_routed_stretch_is_one_loop_over_chunks(_precision,
                                                     monkeypatch):
-    """The toy's forward holds one ``while`` a routed layer, and between
-    the tokens and the sum over a token's slots nothing has the buffer's
-    rows but the zero-filled buffer, the writes of a chunk into it and
-    the loop that carries it."""
+    """The toy's forward holds one ``while`` a routed layer and its
+    gradient one more; nothing in the forward or the backward has the
+    buffer's rows x D (rows are walked a chunk at a time and never
+    stored whole), and each loop carries one (N, D) float32 sum that
+    its chunks scatter-add their rows into."""
+    _precision("bfloat16")
     monkeypatch.setattr(decoder, "CHUNK", 64)
     sw, layers, plans, state, x, y = program_and_batch()
     params = [{"weights": s["weights"], "bias": s["bias"]} for s in state]
@@ -350,17 +391,28 @@ def test_the_routed_stretch_is_one_loop_over_chunks(_precision,
     assert text.count("stablehlo.while") == 2     # the toy's routed layers
     capacity = 4 * T * 3                          # tokens x top_k (3 < 4)
     assert capacity % 64 == 0 and capacity != 4 * T * 4
-    jaxpr = jax.make_jaxpr(build_forward(plans))(params, x)
-    whole = {}
-    for eqn in _equations(jaxpr.jaxpr):
-        for var in eqn.outvars:
-            shape = getattr(var.aval, "shape", ())
-            if len(shape) == 2 and shape[0] == capacity:
-                whole.setdefault(eqn.primitive.name, set()).add(shape)
-    assert set(whole) <= {"broadcast_in_dim", "dynamic_update_slice",
-                          "while", "pjit", "jit", "custom_vjp_call"}, whole
-    assert whole["dynamic_update_slice"] == {(capacity, 64)}
-    chunked = {eqn.primitive.name for eqn in _equations(jaxpr.jaxpr)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: build_forward(plans)(
+        p, x).astype(jnp.float32).sum()))(params)
+    equations = list(_equations(jaxpr.jaxpr))
+    # the buffer's index vectors (a row's token, weight, position) are
+    # all that has its rows
+    whole = {(eqn.primitive.name, var.aval.shape) for eqn in equations
+             for var in eqn.outvars
+             if getattr(var.aval, "shape", ())[:1] == (capacity,)
+             and numpy.prod(var.aval.shape[1:]) > 1}
+    assert not whole, whole
+    loops = [eqn for eqn in equations if eqn.primitive.name == "while"]
+    assert len(loops) == 4                        # forward and backward
+    def is_sum(var):                              # (N, D) float32
+        return (getattr(var.aval, "shape", ()), var.aval.dtype) == (
+            (4 * T, 64), jnp.float32)
+    for loop in loops:
+        assert sum(map(is_sum, loop.outvars)) == 1, loop.outvars
+        assert "scatter-add" in {
+            eqn.primitive.name
+            for eqn in _equations(loop.params["body_jaxpr"].jaxpr)
+            if any(map(is_sum, eqn.outvars))}
+    chunked = {eqn.primitive.name for eqn in equations
                for var in eqn.outvars
                if getattr(var.aval, "shape", ())[:1] == (64,)}
     assert {"ragged_dot_general", "gather", "logistic"} <= chunked, chunked

@@ -417,3 +417,38 @@ def test_a_recomputed_decoder_layer_runs_the_flash_forward_once(
     assert kernels == sorted(
         [attention.FWD_KERNEL_NAME] * forwards
         + [attention.DQ_KERNEL_NAME, attention.DKV_KERNEL_NAME]), calls
+
+
+def test_the_routed_layer_at_nemotrons_widths_sums_tokens_by_scatters(
+        topo, one_chip, mosaic):
+    """The ``nemotron_tt`` configuration's routed layer: 16,384 tokens
+    2,688 wide, the top 6 of 128 relu² experts 1,856 wide, 8 held,
+    bfloat16, forward and backward.  No instruction has the buffer's
+    rows (tokens x 6) by the width: each of the two loops over chunks
+    adds a chunk's rows to one (N, D) float32 sum by a scatter the
+    compiler keeps as a scatter (it sorts the chunk's tokens first), not
+    one it expands into a loop over rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from veles_tpu.models import decoder
+    n, width, top_k, held, inner = 16384, 2688, 6, 8, 1856
+
+    def both(m, idx, gate, e_up, e_down, g_out):
+        def layer(m, gate, e_up, e_down):
+            return decoder.routed_experts(
+                m, idx, gate, None, e_up, e_down, first_expert=0,
+                capacity=None)[0]
+        out, vjp = jax.vjp(layer, m, gate, e_up, e_down)
+        return out, vjp(g_out)
+
+    text = compiled_text(
+        both, one_chip, aval((n, width), jnp.bfloat16),
+        aval((n, top_k), jnp.int32), aval((n, top_k), jnp.float32),
+        aval((held, width, inner), jnp.bfloat16),
+        aval((held, inner, width), jnp.bfloat16),
+        aval((n, width), jnp.bfloat16))
+    assert "[%d,%d]" % (n * top_k, width) not in text
+    assert len(re.findall(r" while\(", text)) == 2
+    sums = re.findall(r"= f32\[%d,%d\]\{[^}]*\} scatter\(" % (n, width), text)
+    assert len(sums) == 2, sums

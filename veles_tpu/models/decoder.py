@@ -129,10 +129,10 @@ own experts in a buffer, and runs grouped products
 left out — that partial result goes on — and nothing stands in for the
 chips that hold them or for the exchange.  The buffer holds the most a
 step can send: tokens x min(top_k, experts held) rows, so nothing is
-ever dropped, and a step pays for the rows it filled: everything between
-the sort and the sum over a token's slots walks them in chunks of
-``CHUNK`` rows and stops at the fill (:func:`_expert_rows`).  A layer
-built with a smaller
+ever dropped, and a step pays for the rows it filled: everything after
+the sort walks them in chunks of ``CHUNK`` rows and stops at the fill,
+each chunk adding its rows to their tokens' sums in a float32 carry
+(:func:`_expert_rows`).  A layer built with a smaller
 ``capacity`` (no factory or configuration sets one) DROPS the
 assignments that do not fit and counts them (``moe_dropped``): the layer
 never drops one silently.
@@ -707,35 +707,23 @@ def relu2_ffn(m, w_up, w_down):
 CHUNK = 8192
 
 
-def _gather_sum(rows, pos, valid):
-    """``sum_k rows[pos[:, k]]`` over the valid slots, (N, D): a gather a
-    slot (the kept assignments are a permutation, which XLA's scatter
-    cannot know), summed in float32."""
-    import jax.numpy as jnp
-    total = None
-    for j in range(pos.shape[1]):
-        picked = jnp.where(valid[:, j, None],
-                           rows[jnp.where(valid[:, j], pos[:, j], 0)],
-                           0).astype(jnp.float32)
-        total = picked if total is None else total + picked
-    return total.astype(rows.dtype)
-
-
 @functools.lru_cache(maxsize=None)
 def _expert_rows(chunk, gated=True):
-    """``expert_rows(m, w_row, e_in, e_down, token_of, pos, valid, reach,
-    filled)`` -> the (N, D) weighted sum of the held experts' outputs:
-    the stretch of :func:`routed_experts` from the tokens to the sum,
-    walked over the buffer's rows ``[0, filled)`` in chunks of
-    ``chunk`` rows and over no chunk beyond them.  ``e_in`` holds the
-    experts' input matrices: ``(e_gate, e_up)`` of gated SiLU experts,
-    ``(e_up,)`` of relu² ones (``gated`` False).  Row ``r`` holds token
-    ``token_of[r]`` under weight ``w_row[r]``, expert ``e``'s rows end at
-    ``reach[e]``, and ``pos``/``valid`` (N, K) say which row each of a
-    token's slots went to.  The loops' bound is a device scalar, so they
+    """``expert_rows(m, w_row, e_in, e_down, token_of, reach, filled)``
+    -> the (N, D) weighted sum of the held experts' outputs: the stretch
+    of :func:`routed_experts` from the tokens to the sum, walked over the
+    buffer's rows ``[0, filled)`` in chunks of ``chunk`` rows and over no
+    chunk beyond them.  ``e_in`` holds the experts' input matrices:
+    ``(e_gate, e_up)`` of gated SiLU experts, ``(e_up,)`` of relu² ones
+    (``gated`` False).  Row ``r`` holds token ``token_of[r]`` under
+    weight ``w_row[r]`` and expert ``e``'s rows end at ``reach[e]``.
+    Each chunk adds its filled rows, rounded to ``m``'s dtype, to their
+    tokens' sums in a float32 ``(N, D)`` carry (a scatter-add at
+    ``token_of``), so the sum follows the fill and no array has the
+    buffer's rows x D.  The loops' bound is a device scalar, so they
     lower to ``while`` and the backward is written, not derived: the
-    dispatch gather ``m[token_of]`` and the sum over a token's slots are
-    each the other's transpose, as gathers both ways."""
+    dispatch gather ``m[token_of]`` and that scatter-add are each the
+    other's transpose."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -785,19 +773,17 @@ def _expert_rows(chunk, gated=True):
         xs = m[tok]
         return xs, [grouped(xs, e, sizes) for e in e_in]
 
-    def forward(m, w_row, e_in, e_down, token_of, pos, valid, reach,
-                filled):
-        def step(i, y):
-            r0, tok, w, live, sizes = chunk_of(
+    def forward(m, w_row, e_in, e_down, token_of, reach, filled):
+        def step(i, out):
+            _, tok, w, live, sizes = chunk_of(
                 i, token_of, w_row, reach, filled)
             _, pre = rows_in(m, tok, e_in, sizes)
             y_c = grouped(act(pre, m.dtype), e_down, sizes)
-            return lax.dynamic_update_slice(
-                y, jnp.where(live, y_c * w, 0.0).astype(m.dtype), (r0, 0))
+            return out.at[tok].add(jnp.where(
+                live, y_c * w, 0.0).astype(m.dtype).astype(f32))
 
-        y = lax.fori_loop(0, trips(filled), step, jnp.zeros(
-            (token_of.shape[0], m.shape[1]), m.dtype))
-        return _gather_sum(y, pos, valid)
+        return lax.fori_loop(0, trips(filled), step,
+                             jnp.zeros(m.shape, f32)).astype(m.dtype)
 
     def fwd(*args):
         # no row is kept for the backward, whose loop computes a chunk's
@@ -809,12 +795,11 @@ def _expert_rows(chunk, gated=True):
         return forward(*args), args
 
     def bwd(res, g_out):
-        (m, w_row, e_in, e_down, token_of, pos, valid, reach,
-         filled) = res
+        m, w_row, e_in, e_down, token_of, reach, filled = res
         dtype = m.dtype
 
         def step(i, carry):
-            g_xs, g_w, *sums = carry
+            g_m, g_w, *sums = carry
             r0, tok, w, live, sizes = chunk_of(
                 i, token_of, w_row, reach, filled)
             xs, pre = rows_in(m, tok, e_in, sizes)
@@ -834,19 +819,17 @@ def _expert_rows(chunk, gated=True):
             grads = [weight_grad(xs, g, sizes) for g in g_pre] + [
                 weight_grad(hidden, (g_y.astype(f32) * w).astype(dtype),
                             sizes)]
-            return (lax.dynamic_update_slice(
-                        g_xs, g_xs_c.astype(dtype), (r0, 0)),
+            return (g_m.at[tok].add(g_xs_c.astype(dtype).astype(f32)),
                     lax.dynamic_update_slice(g_w, g_w_c[:, 0], (r0,)),
                     *(total + g for total, g in zip(sums, grads)))
 
-        g_xs, g_w, *g_in, g_down = lax.fori_loop(
+        g_m, g_w, *g_in, g_down = lax.fori_loop(
             0, trips(filled), step, (
-                jnp.zeros((token_of.shape[0], m.shape[1]), dtype),
-                jnp.zeros(w_row.shape, f32),
+                jnp.zeros(m.shape, f32), jnp.zeros(w_row.shape, f32),
                 *(jnp.zeros(e.shape, f32) for e in e_in + (e_down,))))
-        return (_gather_sum(g_xs, pos, valid), g_w.astype(w_row.dtype),
+        return (g_m.astype(dtype), g_w.astype(w_row.dtype),
                 tuple(g.astype(e.dtype) for g, e in zip(g_in, e_in)),
-                g_down.astype(e_down.dtype), None, None, None, None, None)
+                g_down.astype(e_down.dtype), None, None, None)
 
     expert_rows = jax.custom_vjp(forward)
     expert_rows.defvjp(fwd, bwd)
@@ -862,14 +845,16 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
     ``e_down`` (E_held, F, D) the experts ``first_expert ..
     first_expert + E_held - 1``, gated SiLU experts, or with ``e_gate``
     None relu² ones, ``relu(m e_up)^2 e_down``.  Assignments are sorted
-    by expert and those of the held experts fill a ``capacity``-row
-    buffer (None: the N x min(K, E_held) rows a step can send at most);
-    the grouped products and every pass over rows walk the rows a step
-    FILLED, in chunks of ``CHUNK`` (:func:`_expert_rows`).  Returns
+    by expert and those of the held experts are the rows of a
+    ``capacity``-row buffer (None: the N x min(K, E_held) rows a step
+    can send at most), a token and a weight a row; the grouped products
+    and every pass over rows walk the rows a step FILLED, in chunks of
+    ``CHUNK``, and each chunk adds its rows to their tokens' float32
+    sums inside the loop (:func:`_expert_rows`).  Returns
     (out (N, D), aux) with aux ``moe_load`` (E_held,) tokens routed to
     each held expert, ``moe_assignments`` their sum, ``moe_dropped`` how
     many did not fit the buffer and ``moe_visited_rows`` the rows of the
-    chunks walked."""
+    chunks walked, by the grouped products and the combine alike."""
     import jax.numpy as jnp
     n, k = idx.shape
     held = e_up.shape[0]
@@ -899,11 +884,10 @@ def routed_experts(m, idx, weights, e_gate, e_up, e_down, *, first_expert,
     slot = jnp.pad(order[:capacity],                # row -> assignment
                    (0, -capacity % rows))
     token_of = (slot // k).astype(jnp.int32)
-    valid = (is_held.reshape(-1) & (pos < capacity)).reshape(n, k)
     gated = e_gate is not None
     out = _expert_rows(rows, gated)(
         m, weights.reshape(-1)[slot], (e_gate, e_up) if gated else (e_up,),
-        e_down, token_of, pos.reshape(n, k), valid, reach, filled)
+        e_down, token_of, reach, filled)
     aux = {"moe_load": load, "moe_assignments": kept,
            "moe_dropped": jnp.maximum(kept - capacity, 0),
            "moe_visited_rows": (filled + rows - 1) // rows * rows}
